@@ -78,14 +78,7 @@ def _trace_tiny(mode: str, steps: int, comm_compress: str = "none"):
                      ucfg.in_channels))
     enc = jnp.zeros((2, 1, 7, ucfg.cross_attention_dim))
     fn = runner._build(steps)
-    try:
-        return fn.trace(params, lat, enc, None, 5.0).jaxpr
-    except AttributeError:  # older jax.stages without .trace
-        import jax as _jax
-
-        return _jax.make_jaxpr(
-            lambda p, l, e, g: fn(p, l, e, None, g)
-        )(params, lat, enc, 5.0)
+    return fn.trace(params, lat, enc, None, 5.0).jaxpr
 
 
 def _gate_stale(reports, tag: str) -> List[Finding]:

@@ -69,7 +69,7 @@ _LOOP_PRIMS = frozenset({"scan", "while"})
 
 
 def _jaxpr_types():
-    from jax.core import ClosedJaxpr, Jaxpr, Literal
+    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
     return Jaxpr, ClosedJaxpr, Literal
 

@@ -34,38 +34,8 @@ from .sdpa_routing import Route, lookup
 
 
 import os
-import sys
 
 _FLASH_MIN_LEN = 1024
-
-# One-shot probe verdict for jax.experimental's flash kernel (None = untested).
-_UPSTREAM_PROBE_OK = None
-
-
-def _upstream_flash_available() -> bool:
-    """Probe-compile the upstream kernel once on a tiny shape, caching the
-    verdict.  A Mosaic/backend failure of the upstream kernel otherwise
-    surfaces only when the WHOLE jitted denoise loop compiles — where the
-    trace-time try/except in sdpa cannot engage and generate() dies instead
-    of degrading.  DISTRIFUSER_TPU_FLASH_IMPL=inrepo is the manual escape
-    hatch if even the probe misjudges.
-    """
-    global _UPSTREAM_PROBE_OK
-    if _UPSTREAM_PROBE_OK is None:
-        from .flash_attention import upstream_flash_sdpa
-
-        try:
-            x = jnp.zeros((1, 256, 64), jnp.bfloat16)
-            jax.block_until_ready(upstream_flash_sdpa(x, x, x, heads=1))
-            _UPSTREAM_PROBE_OK = True
-        except Exception as e:
-            print(
-                "upstream flash kernel failed its probe compile "
-                f"({type(e).__name__}: {e}); using in-repo Pallas kernel",
-                file=sys.stderr,
-            )
-            _UPSTREAM_PROBE_OK = False
-    return _UPSTREAM_PROBE_OK
 
 
 def _largest_dividing_tile(preferred: int, length: int):
@@ -143,8 +113,8 @@ def _resolve_route(q, k, heads: int) -> Route:
 
 
 # Above this many fp32 logit elements (B*H*Lq*Lk), the unfused softmax path
-# chunks queries so the full score matrix never materializes — the safety net
-# when the Pallas flash kernel is unavailable (CPU, odd shapes, env-disabled).
+# chunks queries so the full score matrix never materializes — the path for
+# shapes no flash route covers (CPU, odd shapes, env-disabled).
 # 2^28 elements = 1 GiB of fp32 logits.
 _CHUNK_LOGITS_ELEMS = 1 << 28
 
@@ -171,6 +141,12 @@ def sdpa(q, k, v, *, heads: int):
     einsum+softmax otherwise, with query chunking once the score matrix would
     exceed ~1 GiB (e.g. the VAE's 65k-token single-head mid attention at
     2048x2048, where materializing L^2 logits cannot fit).
+
+    The routed kernel runs or the call raises: a kernel that fails to trace
+    or compile is never replaced by another implementation behind the
+    caller's back (SD3's padded route is 8.3 s vs 20.2 s on the XLA path it
+    used to fall to — a silent fall-through is a 2.4x slower program that
+    still "works").
     """
     route = _resolve_route(q, k, heads)
     if route.impl != "xla":
@@ -181,18 +157,19 @@ def sdpa(q, k, v, *, heads: int):
             upstream_flash_sdpa,
         )
 
-        # On a non-TPU backend flash only runs in interpret mode (tests):
-        # Mosaic kernels only compile for TPU.
+        # Mosaic kernels only compile for TPU; on the CPU platform (tests)
+        # the in-repo kernel runs in interpret mode.  Nothing on a "tpu"
+        # platform reaches interpret=True.
         interpret = jax.devices()[0].platform == "cpu"
-        # the probe gates only the DEFAULT/table route: an explicit
-        # IMPL=upstream is honored past it (the trace-time except below
-        # still guards), so a probe misjudgment can never override an
-        # operator's choice
-        explicit = os.environ.get("DISTRIFUSER_TPU_FLASH_IMPL")
         lq, lk = q.shape[1], k.shape[1]
-        if route.impl == "upstream" and not interpret and (
-            explicit == "upstream" or _upstream_flash_available()
-        ):
+        if route.impl == "upstream":
+            if interpret:
+                raise ValueError(
+                    "sdpa route 'upstream' (jax.experimental's Mosaic flash "
+                    "kernel) needs a TPU; on the CPU platform force the "
+                    "interpret-mode kernel with "
+                    "DISTRIFUSER_TPU_FLASH_IMPL=inrepo"
+                )
             # tiles generalize across the log2 bucket but may not divide
             # THIS call's lengths (the kernel would assert at trace).  A
             # non-dividing tile cannot simply be dropped: the kernel fills
@@ -207,19 +184,8 @@ def sdpa(q, k, v, *, heads: int):
                 ubk = _largest_dividing_tile(ubk or 1024, lk)
                 if ubq is None or ubk is None:
                     ubq = ubk = None
-            try:
-                return upstream_flash_sdpa(q, k, v, heads=heads,
-                                           block_q=ubq, block_k=ubk)
-            except Exception as e:  # unstable jax.experimental surface:
-                # degrade to the in-repo kernel instead of dying at trace time
-                print(
-                    "upstream flash kernel unavailable "
-                    f"({type(e).__name__}: {e}); using in-repo Pallas kernel",
-                    file=sys.stderr,
-                )
-                # upstream-tuned tiles do not transfer across kernels; the
-                # in-repo fallback runs its own defaults
-                route = Route("inrepo")
+            return upstream_flash_sdpa(q, k, v, heads=heads,
+                                       block_q=ubq, block_k=ubk)
         bq = route.block_q or DEFAULT_BLOCK_Q
         bk = route.block_k or DEFAULT_BLOCK_K
         bq = bq if lq % bq == 0 else DEFAULT_BLOCK_Q
@@ -233,13 +199,10 @@ def sdpa(q, k, v, *, heads: int):
     scale = 1.0 / d**0.5
     # unaligned-but-long sequences (SD3's 4096+154 joint stream): flash via
     # pad-and-mask instead of the chunked XLA softmax the alignment gate
-    # would otherwise force — the r5 trace showed that path at ~11% MFU;
-    # padded flash cut SD3-medium 20.2 -> 8.3 s (segment-masked upstream
-    # kernel; BENCH_NOTES).  Operator pins (FLASH=0 / IMPL=xla) still win.  d is bounded to the swept range:
-    # the except below only catches TRACE-time failures — a Mosaic
-    # backend-compile failure on an exotic head dim would surface when the
-    # enclosing jitted step compiles, past any fallback — so unswept d
-    # stays on the XLA path.
+    # would otherwise force — padded flash cut SD3-medium 20.2 -> 8.3 s
+    # (segment-masked upstream kernel, one v5e, 2026-07-31).  Operator pins
+    # (FLASH=0 / IMPL=xla) still win.  d is bounded to the swept range:
+    # unswept head dims stay on the XLA path.
     if (jax.devices()[0].platform != "cpu"
             and os.environ.get("DISTRIFUSER_TPU_FLASH") != "0"
             and os.environ.get("DISTRIFUSER_TPU_FLASH_IMPL") != "xla"
@@ -247,11 +210,8 @@ def sdpa(q, k, v, *, heads: int):
             and d % 8 == 0 and d <= 256
             and (lq % 128 or lk % 128)):
         from .flash_attention import padded_flash_sdpa
-        try:
-            return padded_flash_sdpa(q, k, v, heads=heads)
-        except Exception as e:
-            print(f"padded flash path failed ({type(e).__name__}: {e}); "
-                  "using XLA softmax", file=sys.stderr)
+
+        return padded_flash_sdpa(q, k, v, heads=heads)
     q = q.reshape(b, lq, heads, d)
     k = k.reshape(b, lk, heads, d)
     v = v.reshape(b, lk, heads, d)

@@ -13,9 +13,9 @@ that native dependency to a Pallas kernel here.  Online-softmax tiling:
   the O(L^2) probability matrix, which is what makes >=2048px patch
   attention (16k-65k tokens) fit.
 
-`flash_sdpa` is a drop-in for ops.attention.sdpa; attention.py routes to it
-on TPU for long, block-aligned sequences and falls back to the XLA softmax
-path otherwise (small cross-attention over 77 text tokens stays XLA).
+`flash_sdpa` is a drop-in for ops.attention.sdpa; attention.py routes long,
+block-aligned sequences on TPU to a flash kernel and everything else (small
+cross-attention over 77 text tokens) to the XLA softmax path.
 """
 
 from __future__ import annotations
@@ -27,11 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax >= 0.8 renamed TPUCompilerParams -> CompilerParams; accept both so the
-# kernel builds on the 0.4.x line too (see utils/compat.py)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -167,7 +162,7 @@ def flash_sdpa(q, k, v, *, heads: int, block_q: int = DEFAULT_BLOCK_Q,
         # the online-softmax state.  Without this, Mosaic treats every grid
         # dim as sequential ("arbitrary"), which blocks its cross-iteration
         # pipelining — the prime suspect in the round-2 2x slowdown vs XLA.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -202,11 +197,11 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
     """Flash attention for UNALIGNED sequence lengths via pad-and-mask.
 
     Long sequences whose length is not a lane multiple (SD3's 4096+154
-    joint stream) otherwise fall back to XLA's chunked softmax, which the
-    r5 trace showed running at ~11% MFU — the padded kernel keeps the MXU
-    on aligned tiles while a mask keeps the numerics exact: pad KV columns
-    get -inf logits (zero softmax weight), pad query rows compute garbage
-    and are sliced off.
+    joint stream) would otherwise run XLA's chunked softmax (~11% MFU in
+    the 2026-07-31 trace) — the padded kernel keeps the MXU on aligned
+    tiles while a mask keeps the numerics exact: pad KV columns get -inf
+    logits (zero softmax weight), pad query rows compute garbage and are
+    sliced off.
 
     ``impl``: "upstream" (segment-ids mask, ``padding_segment_ids``) or
     "inrepo" (static kv_len mask).  Resolution: the ``impl`` argument,
@@ -214,20 +209,15 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
     kernel-wide DISTRIFUSER_TPU_FLASH_IMPL=inrepo pin — "inrepo", else
     "upstream" (the model-level A/B at SD3-medium 1024²: upstream 8.32 s
     vs inrepo 13.54 s vs chunked XLA 20.17 s; the two kernels agree to
-    5e-4 on chip).  The default upstream route additionally requires the
-    probe compile (`attention._upstream_flash_available`) to have passed:
-    the except below only catches TRACE-time failures, while a Mosaic
-    backend-compile failure would surface when the enclosing jitted
-    denoise step compiles — past any fallback — and kill generate()
-    instead of degrading.  An explicit upstream pin (arg or PADDED_IMPL
-    env) is honored past the probe.
+    5e-4 on chip).  The resolved kernel runs or the call raises; the
+    in-repo kernel is reachable only as an explicit route, never as a
+    fallback.  ``interpret`` exists for the in-repo kernel only.
     """
     # lazy import avoids a cycle: attention.py only imports this module
     # inside function bodies
-    from .attention import _largest_dividing_tile, _upstream_flash_available
+    from .attention import _largest_dividing_tile
 
-    explicit = impl or os.environ.get("DISTRIFUSER_TPU_PADDED_IMPL")
-    impl = explicit
+    impl = impl or os.environ.get("DISTRIFUSER_TPU_PADDED_IMPL")
     if impl is None and os.environ.get("DISTRIFUSER_TPU_FLASH_IMPL") == "inrepo":
         impl = "inrepo"
     impl = impl or "upstream"
@@ -244,23 +234,19 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
     kp = jnp.pad(k, ((0, 0), (0, lk_pad - lk), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, lk_pad - lk), (0, 0)))
 
-    if impl == "upstream" and not interpret and (
-            explicit == "upstream" or _upstream_flash_available()):
-        try:
-            seg = padding_segment_ids(b, lq, lq_pad, lk, lk_pad)
-            out = upstream_flash_sdpa(
-                qp, kp, vp, seg, heads=heads,
-                block_q=_largest_dividing_tile(256, lq_pad),
-                block_k=_largest_dividing_tile(1024, lk_pad),
+    if impl == "upstream":
+        if interpret:
+            raise ValueError(
+                "padded_flash_sdpa: interpret mode exists for the in-repo "
+                "kernel only; pass impl='inrepo'"
             )
-            return out[:, :lq]
-        except Exception as e:  # unstable jax.experimental surface
-            import sys
-            print(
-                "upstream padded flash unavailable "
-                f"({type(e).__name__}: {e}); using in-repo kernel",
-                file=sys.stderr,
-            )
+        seg = padding_segment_ids(b, lq, lq_pad, lk, lk_pad)
+        out = upstream_flash_sdpa(
+            qp, kp, vp, seg, heads=heads,
+            block_q=_largest_dividing_tile(256, lq_pad),
+            block_k=_largest_dividing_tile(1024, lk_pad),
+        )
+        return out[:, :lq]
 
     # padded lengths are 128-multiples, so the shared helper never returns
     # None here (the 128 lane minimum always divides)

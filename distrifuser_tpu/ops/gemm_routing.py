@@ -17,7 +17,7 @@ every QuantizedTensor matmul through one of
 Exactly like ops/sdpa_routing.py, the choice is a *checked-in table keyed
 by shape*, regenerated from real chip measurements:
 
-    scripts/chip_campaign.py --phases gemm  ->  chip_logs/<log>  (gemm lines)
+    scripts/chip_campaign.py --phases gemm  ->  chiprun_out/<log>  (gemm lines)
     scripts/update_gemm_table.py --log <log>
                                             ->  rewrites MEASURED_ROUTES
 

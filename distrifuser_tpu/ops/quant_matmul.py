@@ -34,11 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.8 renamed TPUCompilerParams -> CompilerParams (see
-# ops/flash_attention.py and utils/compat.py)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 # Default tiles: MXU-friendly (int8 min tile is (32, 128); 512 deep K
 # amortizes the accumulator read-modify-write).  The chip campaign's gemm
 # phase sweeps these; measured winners land in gemm_routing.MEASURED_ROUTES.
@@ -117,7 +112,7 @@ def quant_matmul(xq, wq, sw, *, block_m: int = None, block_n: int = None,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         # M/N tiles are independent; only the K walk carries the
         # accumulator (same semantics note as ops/flash_attention.py)
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -6,8 +6,8 @@ backend choice (plain XLA softmax vs the in-repo Pallas kernel vs
 jax.experimental's tuned flash kernel) is a *checked-in table keyed by shape*,
 regenerated from real-chip measurements:
 
-    scripts/chip_campaign.py   ->  chip_logs/campaign_r3.log   (attn/tune lines)
-    scripts/update_sdpa_table.py --log chip_logs/campaign_r3.log
+    scripts/chip_campaign.py   ->  chiprun_out/<log>   (attn/tune lines)
+    scripts/update_sdpa_table.py --log chiprun_out/<log>
                                ->  rewrites MEASURED_ROUTES below
 
 Resolution order in ops.attention.sdpa (strongest wins):
@@ -35,8 +35,8 @@ class Route:
 
 
 # --- BEGIN MEASURED_ROUTES (generated by scripts/update_sdpa_table.py) ---
-# provenance: v5e campaign_r5 attempt-2 chained timing + 57600 probe, 2026-07-31
-MEASURED_PROVENANCE = "v5e campaign_r5 attempt-2 chained timing + 57600 probe, 2026-07-31"
+# provenance: one v5e, chained timing + 57600 probe, 2026-07-31
+MEASURED_PROVENANCE = "one v5e, chained timing + 57600 probe, 2026-07-31"
 MEASURED_ROUTES: dict = {
     (64, 10): Route("xla"),  # L=1024 h=20: xla=3.789ms vs inrepo=4.724, upstream=4.486
     (64, 12): Route("xla"),  # L=4096 h=24: xla=12.2ms vs inrepo=29.418, upstream=26.285
@@ -50,7 +50,7 @@ MEASURED_ROUTES: dict = {
 # the FULL-MODEL level, which outranks the micro-benchmark.  Campaign r5's
 # chained micro-harness has XLA ~10% ahead of tuned flash at L=4096, yet the
 # complete 1024^2 SDXL program runs 7.03 s flash-routed vs 8.33 s XLA-pinned
-# (campaign_r5 b1024 vs b1024_xla, forced-transfer timing) — in model context
+# (campaign phases b1024 vs b1024_xla, forced-transfer timing) — in model context
 # the unfused softmax pays fusion-boundary and HBM-traffic costs the isolated
 # kernel chain never sees.  lookup() consults this dict FIRST, so a re-bake
 # from micro lines cannot silently regress the model-level choice.
@@ -90,7 +90,7 @@ def lookup(lk: int, head_dim: int) -> Optional[Route]:
     KV length generalizes on a log2 bucket: the latency ordering of the
     implementations changes with sequence-length scale, not with exact L.
     A measurement more than MAX_BUCKET_DISTANCE log2 steps away does NOT
-    govern (ADVICE r3: a lone L=16384 entry must not override the analytic
+    govern (a lone L=16384 entry must not override the analytic
     short-sequence gate at L=1024) — out-of-range lengths fall through to
     the analytic default.
     """

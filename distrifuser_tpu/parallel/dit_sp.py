@@ -42,8 +42,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ..utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models import dit as dit_mod
@@ -79,7 +78,7 @@ class DiTDenoiseRunner:
     ):
         self.cfg = distri_config
         self.dcfg = dit_config
-        self.params = params
+        self.params = distri_config.place(params)
         self.scheduler = scheduler
         # attn_impl="gather" carries full gathered KV per block (reference
         # layout); "ring" carries only the local chunk and streams peers
@@ -884,7 +883,7 @@ class DiTDenoiseRunner:
         n_tok, hid, depth = dcfg.num_tokens, dcfg.hidden_size, dcfg.depth
         chunk = n_tok // n
         # the final-layer epsilon gather runs in every layout; eps-only head
-        # (out_channels), not diffusers' 2x (eps, sigma) head — ADVICE r3
+        # (out_channels), not diffusers' 2x (eps, sigma) head
         eps_gather = b * n_tok * dcfg.patch_size**2 * dcfg.out_channels
         if cfg.attn_impl == "gather":
             state = depth * 2 * b * n_tok * hid
@@ -967,13 +966,9 @@ class DiTDenoiseRunner:
                 latents, enc, cap_mask, gs, num_inference_steps, callback,
             )
         if callback is not None:
-            from ..utils.compat import SUPPORTS_FUSED_CALLBACK
-
-            if not SUPPORTS_FUSED_CALLBACK or self.cfg.step_cache_enabled:
-                # this jaxlib aborts compiling the ordered-io_callback
-                # program (utils/compat.py) — host-driven loop instead.
-                # Step-cache callbacks also take the host loop: the
-                # stepwise steppers replay the exact cadence.
+            if self.cfg.step_cache_enabled:
+                # step-cache callbacks take the host loop: the stepwise
+                # steppers replay the exact cadence.
                 return self._generate_stepwise(
                     latents, enc, cap_mask, gs, num_inference_steps, callback,
                 )

@@ -44,8 +44,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ..utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models import dit as dit_mod
@@ -77,7 +76,7 @@ class MMDiTDenoiseRunner:
     ):
         self.cfg = distri_config
         self.mcfg = mmdit_config
-        self.params = params
+        self.params = distri_config.place(params)
         self.scheduler = scheduler
         if distri_config.attn_impl not in ("gather", "ring"):
             raise ValueError(
@@ -970,13 +969,9 @@ class MMDiTDenoiseRunner:
                 start_step, end_step, callback,
             )
         if callback is not None:
-            from ..utils.compat import SUPPORTS_FUSED_CALLBACK
-
-            if not SUPPORTS_FUSED_CALLBACK or self.cfg.step_cache_enabled:
-                # this jaxlib aborts compiling the ordered-io_callback
-                # program (utils/compat.py) — host-driven loop instead.
-                # Step-cache callbacks also take the host loop: the
-                # stepwise steppers replay the exact cadence.
+            if self.cfg.step_cache_enabled:
+                # step-cache callbacks take the host loop: the stepwise
+                # steppers replay the exact cadence.
                 return self._generate_stepwise(
                     jnp.asarray(latents), enc, pooled, gs,
                     num_inference_steps, start_step, end_step, callback,

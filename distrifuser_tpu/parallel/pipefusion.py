@@ -84,8 +84,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from ..utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models import dit as dit_mod
@@ -202,6 +201,9 @@ class PipeFusionRunner:
                 f"{cfg.latent_height}, but DiTConfig.sample_size is "
                 f"{dcfg.sample_size} (square latents only for the DiT)"
             )
+        # the depth stack lives sharded over the stages, everything else
+        # replicated — the layout the programs' in_specs declare
+        self.params = cfg.place(params, self._specs()[0])
         self._compiled: Dict[Any, Any] = {}
 
     # ------------------------------------------------------------------

@@ -33,8 +33,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from ..utils.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models.unet import (
@@ -82,13 +81,16 @@ class _AotProgramHandle:
     `compiled_hlo`, etc.) delegates to the wrapped jit handle.
     """
 
-    def __init__(self, fn, *, store, scope: str, tag: str,
-                 mesh_shape: str, layout: str):
+    def __init__(self, fn, *, store, scope: str, tag: str, mesh,
+                 layout: str):
         self._fn = fn
         self._store = store
         self._scope = scope
         self._tag = tag
-        self._mesh_shape = mesh_shape
+        self._mesh_shape = str(dict(mesh.shape))
+        # the program's own devices, in mesh order: a persisted
+        # executable must load onto exactly these
+        self._devices = list(mesh.devices.flat)
         self._layout = layout
         self._executables: Dict[str, Any] = {}
         self._fallback = False
@@ -110,7 +112,7 @@ class _AotProgramHandle:
         fp = self._store.fingerprint(
             f"{self._scope}|{self._tag}|{sig}",
             mesh_shape=self._mesh_shape, layout=self._layout)
-        ex = self._store.load_executable(fp)
+        ex = self._store.load_executable(fp, self._devices)
         if ex is None:
             ex = self._fn.lower(*args).compile()
             self._store.save_executable(fp, ex)
@@ -179,13 +181,13 @@ class DenoiseRunner:
     ):
         self.cfg = distri_config
         self.ucfg = unet_config
-        self.params = params
         self.scheduler = scheduler
         self.tp_dispatch_factory = tp_dispatch_factory
         # Weight sharding layout: P() (replicated) for patch/naive modes —
         # the reference also replicates weights in PP mode (§2.1) — and the
         # per-leaf TP spec tree for tensor parallelism.
         self.param_specs = param_specs if param_specs is not None else P()
+        self.params = distri_config.place(params, self.param_specs)
         if distri_config.parallelism == "tensor" and tp_dispatch_factory is None:
             raise ValueError("tensor parallelism needs a tp_dispatch_factory")
         if distri_config.parallelism == "pipefusion":
@@ -564,8 +566,8 @@ class DenoiseRunner:
             return fn
         store, scope = act
         return _AotProgramHandle(
-            fn, store=store, scope=scope, tag=tag,
-            mesh_shape=str(dict(self.cfg.mesh.shape)), layout=layout)
+            fn, store=store, scope=scope, tag=tag, mesh=self.cfg.mesh,
+            layout=layout)
 
     def _ensure_stale_scan(self, num_steps: int, n_sync: int):
         skey = ("stale_scan", num_steps, n_sync)
@@ -1298,12 +1300,8 @@ class DenoiseRunner:
         assert end_step is None or start_step < end_step <= num_inference_steps, (
             start_step, end_step, num_inference_steps)
         if callback is not None and self.cfg.use_compiled_step:
-            from ..utils.compat import SUPPORTS_FUSED_CALLBACK
-
-            if not SUPPORTS_FUSED_CALLBACK or self.cfg.step_cache_enabled:
-                # this jaxlib aborts compiling the ordered-io_callback
-                # program (utils/compat.py) — host-driven loop instead.
-                # Step-cache runs also take the host loop when a callback is
+            if self.cfg.step_cache_enabled:
+                # step-cache runs take the host loop when a callback is
                 # requested: the stepwise steppers replay the exact cadence
                 # without teaching the io_callback program a third body.
                 return self._generate_stepwise(

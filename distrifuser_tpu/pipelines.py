@@ -236,7 +236,7 @@ class PipelineOutput:
     # Set when any tokenizer degraded to the hash-based SimpleTokenizer
     # (weightless smoke/bench runs): the images are NOT real-prompt outputs
     # and must never be quality-judged.  Carried on the artifact itself —
-    # a stderr warning alone scrolls away (VERDICT r4 weak #5).
+    # a stderr warning alone scrolls away.
     weightless_tokenizer: bool = False
     warning: Optional[str] = None
 
@@ -300,7 +300,7 @@ def _build_decoder(cfg: DistriConfig, vae_config: vae_mod.VAEConfig):
         # Sequence-parallel decode over the same sp axis as the denoiser
         # (beyond the reference, which decodes replicated on every rank):
         # exact, n x faster, 1/n activation footprint.
-        from .utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from .parallel.collectives import gather_rows
@@ -713,12 +713,10 @@ class _GenerationMixin:
         the host-driven stepwise loop (the reference's --no_cuda_graph
         path) — same numerics, per-step dispatch instead of one program.
 
-        This is the compat-shim fallback (utils/compat.py routes
-        callback-carrying generates stepwise on jaxlibs that abort on the
-        fused io_callback program) promoted to a *policy*: the serve
-        layer's degradation ladder (serve/resilience.py) calls it when
-        the fused program fails to compile or OOMs, because the stepwise
-        loop is a far smaller program to compile and hold.  Call before
+        The serve layer's degradation ladder (serve/resilience.py) calls
+        it as a *policy* when the fused program fails to compile or OOMs,
+        because the stepwise loop is a far smaller program to compile and
+        hold.  Call before
         `prepare()`/generation; already-compiled fused programs stay
         cached and are simply not dispatched to while disabled.
 

@@ -6,9 +6,9 @@ process.  Every fresh replica still pays the full compile campaign for
 every warmup bucket, which is exactly the latency that blocks elastic
 scale-up (ROADMAP item 2: "a persistent AOT compiled-program cache so a
 fresh replica warms from serialized executables in seconds").  This
-module is that store: compiled programs serialized through the compat
-shim (`utils/compat.py`, `jax.experimental.serialize_executable` on the
-0.4.x line) into a **content-addressed on-disk** entry a later replica
+module is that store: compiled programs serialized through
+`jax.experimental.serialize_executable` into a **content-addressed
+on-disk** entry a later replica
 — same binary versions, same mesh, same compile identity — loads in
 milliseconds instead of recompiling.
 
@@ -58,12 +58,13 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import pickle
 import re
 import struct
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
-from ..utils import compat, sync
+from ..utils import sync
 from ..utils.aot import runtime_fingerprint
 from ..utils.chaos import active_fault_plan
 from .errors import AotCacheRejectedError
@@ -176,10 +177,31 @@ def decode_entry(data: bytes, expect: Dict[str, str]) -> bytes:
     return body
 
 
+def _serialize_compiled(compiled) -> bytes:
+    """Compiled jax executable -> opaque bytes: the serializer returns
+    (payload, in_tree, out_tree) and all three are needed to reload, so
+    the byte form is a pickle of the triple.  Raises whatever the runtime
+    raises on unserializable programs (callbacks, host-pinned buffers)."""
+    from jax.experimental import serialize_executable
+
+    return pickle.dumps(serialize_executable.serialize(compiled), protocol=4)
+
+
+def _deserialize_compiled(data: bytes, devices: Sequence[Any]):
+    """Inverse of `_serialize_compiled`, loaded onto ``devices``.  Only
+    ever fed bytes whose envelope checksum and fingerprint this process
+    verified (`get`), i.e. bytes this program wrote."""
+    from jax.experimental import serialize_executable
+
+    payload, in_tree, out_tree = pickle.loads(data)
+    return serialize_executable.deserialize_and_load(
+        payload, in_tree, out_tree, execution_devices=list(devices))
+
+
 class AotExecutableCache:
     """The on-disk store: bytes API (`get`/`put`) used by fakes and
     tests, executable API (`load_executable`/`save_executable`) used by
-    the runner through the compat shim.
+    the runner.
 
     ``config`` is `utils.config.AotCacheConfig`: ``dir`` (None disables
     the store entirely), ``max_bytes`` (LRU eviction bound — least
@@ -378,19 +400,21 @@ class AotExecutableCache:
 
     # -- the executable API --------------------------------------------------
 
-    def load_executable(self, fingerprint: Dict[str, str]) -> Optional[Any]:
-        """Deserialize a persisted executable; None on miss, on an
-        unsupported runtime, or on any rejection (counted + entry
-        deleted — the caller's contract is always compile-on-None)."""
-        if not compat.SUPPORTS_EXECUTABLE_SERIALIZATION:
-            return None
+    def load_executable(self, fingerprint: Dict[str, str],
+                        devices: Sequence[Any]) -> Optional[Any]:
+        """Deserialize a persisted executable onto ``devices`` (the
+        program's own mesh devices — without them the runtime loads onto
+        every local device and a one-chip replica's program on a
+        four-chip host fails its first dispatch); None on miss or on any
+        rejection (counted + entry deleted — the caller's contract is
+        always compile-on-None)."""
         data = self.get(fingerprint)
         if data is None:
             return None
         t0 = time.monotonic()
         try:
             try:
-                compiled = compat.deserialize_compiled(data)
+                compiled = _deserialize_compiled(data, devices)
             except Exception as exc:
                 raise AotCacheRejectedError(
                     f"aot cache entry failed executable deserialization "
@@ -410,15 +434,13 @@ class AotExecutableCache:
         """Serialize one compiled program into the store.  Programs the
         runtime cannot serialize (host callbacks, exotic buffers) count
         `unserializable` and are simply not cached — never an error."""
-        if not compat.SUPPORTS_EXECUTABLE_SERIALIZATION:
-            return False
         if not self.dir or self.readonly:
             # skip BEFORE paying serialization: readonly exists for CI,
             # where serializing a program nobody will write is pure waste
             return self._count_skip_if_readonly()
         t0 = time.monotonic()
         try:
-            payload = compat.serialize_compiled(compiled)
+            payload = _serialize_compiled(compiled)
         except Exception:
             with self._lock:
                 self.unserializable += 1

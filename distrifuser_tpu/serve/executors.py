@@ -198,6 +198,10 @@ class PipelineExecutor:
         if self.fault_plan is not None:
             self.fault_plan.check("executor.execute", key=self.key,
                                   batch_size=len(prompts))
+        return self._run_batch(prompts, negative_prompts, guidance_scale,
+                               seeds)
+
+    def _run_batch(self, prompts, negative_prompts, guidance_scale, seeds):
         if self.prompt_cache is not None:
             # cached-encode path: the stage programs run serially (see
             # attach_prompt_cache) so the memoized embeddings slot in
@@ -223,6 +227,34 @@ class PipelineExecutor:
             )
             images.extend(out.images)
         return images[:n_real]
+
+    def warm(self) -> None:
+        """Compile everything this executor will dispatch — text encoders,
+        the denoise loop or per-step programs, VAE decode — by running one
+        throwaway request through its own dispatch path, HERE on the build
+        path.  `prepare()` only builds jit handles; XLA compiles at the
+        first dispatch, and the server's first dispatch runs inside the
+        watchdog: on one v5e the cold SDXL fused-loop compile took ~230 s
+        against the 120 s `watchdog_timeout_s`, so the first request of
+        every bucket — warmed or not — died as a hung batch (PR 21).
+
+        Step-mode executors warm the solo per-step programs and, where
+        rows pack (``batch_size >= 2``), a lockstep pair, which builds the
+        packed-rows program of every step signature."""
+        prompt, neg, seed, gs = "warm-up", "", 0, 5.0
+        if getattr(self.key, "exec_mode", "fused") != "step":
+            self._run_batch([prompt], [neg], gs, [seed])
+            return
+        work = self.step_begin(prompt, neg, seed, gs)
+        while not self.step_done(work):
+            self.step_run([work])
+        self.step_finish(work)
+        if self.batch_size >= 2 and self._step_pack_supported():
+            pair = [self.step_begin(prompt, neg, seed, gs) for _ in range(2)]
+            while not self.step_done(pair[0]):
+                self.step_run(pair)
+            for work in pair:
+                self.step_abort(work)
 
     # -- staged contract (serve/staging.py) --------------------------------
 
@@ -797,10 +829,12 @@ def pipeline_executor_factory(
     ``build_pipeline(key)`` constructs the pipeline for a bucket — e.g. a
     DistriConfig at (key.height, key.width) with
     do_classifier_free_guidance=key.cfg, then ``from_pretrained`` /
-    ``from_params`` with key.scheduler.  The factory runs the ahead-of-time
-    compile (`prepare`) so cache misses pay the full cost HERE, off the
-    per-request path, and hands back a ready executor.  ``fault_plan``
-    injects at sites ``"executor.build"`` / ``"executor.execute"``.
+    ``from_params`` with key.scheduler.  The factory builds the programs
+    (`prepare`) and compiles them with one throwaway request
+    (`PipelineExecutor.warm`), so cache misses pay the full cost HERE, off
+    the per-request path and outside the dispatch watchdog, and hands back
+    a ready executor.  ``fault_plan`` injects at sites
+    ``"executor.build"`` / ``"executor.execute"``.
     """
 
     def factory(key: ExecKey) -> PipelineExecutor:
@@ -809,7 +843,9 @@ def pipeline_executor_factory(
         pipe = build_pipeline(key)
         apply_key_policy(pipe, key)
         pipe.prepare(key.steps)
-        return PipelineExecutor(pipe, key.steps, key=key,
-                                fault_plan=fault_plan)
+        executor = PipelineExecutor(pipe, key.steps, key=key,
+                                    fault_plan=fault_plan)
+        executor.warm()
+        return executor
 
     return factory

@@ -24,7 +24,7 @@ sleeping:
   recorded in metrics.  Ladder steps are *numerically safe*: batch
   membership never changes a request's image (per-request seeded
   latents), and the stepwise loop is the same numerics as the fused scan
-  (the compat-shim fallback, here reused as a policy);
+  (the `--no_cuda_graph` loop, here reused as a policy);
 * `ResilienceEngine` — the per-server facade tying these together with
   per-key sticky state and a `snapshot()` for health reporting.
 """
@@ -370,7 +370,7 @@ class DegradationLadder:
        (which never applies to pipefusion keys — there is no host-driven
        stepwise loop to fall back to);
     5. `stepwise_fallback`: swap the fused scan for the host-driven
-       stepwise loop — the compat-shim fallback reused as a policy: same
+       stepwise loop — the `--no_cuda_graph` loop reused as a policy: same
        numerics, a much smaller program to compile and hold;
     6. `weight_quant_on` (off by default — the first rung whose outputs
        CHANGE, within the pinned parity tolerances): rebuild the key with

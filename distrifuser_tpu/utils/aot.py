@@ -59,25 +59,8 @@ def runtime_fingerprint() -> Dict[str, str]:
     """jax/jaxlib/backend identity of THIS process — the invalidation
     boundary for persisted executables.  Lazy jax import keeps this
     module a stdlib-only leaf at import time (same rule as chaos.py)."""
-    try:
-        import jax
+    import jax
+    import jaxlib
 
-        jax_version = str(getattr(jax, "__version__", "unknown"))
-        try:
-            backend = str(jax.default_backend())
-        except Exception:
-            backend = "unknown"
-    except Exception:  # pragma: no cover - jax always present in-image
-        return {"jax": "unavailable", "jaxlib": "unavailable",
-                "backend": "unknown"}
-    try:
-        import jaxlib
-
-        jaxlib_version = str(
-            getattr(jaxlib, "__version__", None)
-            or getattr(getattr(jaxlib, "version", None), "__version__",
-                       "unknown"))
-    except Exception:  # pragma: no cover
-        jaxlib_version = "unavailable"
-    return {"jax": jax_version, "jaxlib": jaxlib_version,
-            "backend": backend}
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "backend": jax.default_backend()}

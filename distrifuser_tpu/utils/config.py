@@ -34,7 +34,7 @@ from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .env import check_env, default_backend, is_power_of_2
 
@@ -492,6 +492,30 @@ class DistriConfig:
         same resolution but different meshes compile different programs."""
         cfg_dim = self.group_size // self.n_device_per_batch
         return f"dp{self.dp_degree}.cfg{cfg_dim}.sp{self.n_device_per_batch}"
+
+    def place(self, tree, specs=PartitionSpec()):
+        """Commit a pytree's arrays to this mesh ONCE: ``specs`` is one
+        PartitionSpec for every leaf (default: replicated) or a tree of
+        them mirroring ``tree``.
+
+        Runners place their weights with this at construction.  Arrays
+        fresh from ``init_*_params`` or a checkpoint load are uncommitted
+        on the default device; fed as they are to a program over this
+        mesh, JAX re-transfers them on every dispatch — once per image in
+        the fused loop, once per STEP in the stepwise/step modes — and a
+        one-chip replica on any chip but the first reads its weights from
+        chip 0 forever.  Abstract leaves (``eval_shape`` trees used for
+        AOT lowering) have nothing to place and pass through.
+        """
+
+        def put(x, spec):
+            if not isinstance(x, (jax.Array, np.ndarray)):
+                return x
+            return jax.device_put(x, NamedSharding(self.mesh, spec))
+
+        if isinstance(specs, PartitionSpec):
+            return jax.tree.map(lambda x: put(x, specs), tree)
+        return jax.tree.map(put, tree, specs)
 
 
 # Default resolution bucket table for the serve layer: the SDXL training

@@ -1,8 +1,7 @@
 """Overlap evidence from a real-chip profiler trace.
 
 `utils/overlap.py` proves 63/65 refresh collectives are *deferrable* from
-HLO structure; this script closes the loop with runtime evidence (VERDICT
-r3 task 5): did the TPU scheduler actually hide the collectives behind
+HLO structure; this script closes the loop with runtime evidence: did the TPU scheduler actually hide the collectives behind
 compute — the reference's async-NCCL behavior
 (/root/reference/distrifuser/utils.py:170-190) — or did they serialize?
 
@@ -24,7 +23,7 @@ compute, so ``overlapped_frac`` near 1.0 is the async-NCCL analog; near 0.0
 means the collectives serialize the step.
 
 Usage:
-    python scripts/analyze_trace.py chip_logs/trace_r4 [--json]
+    python scripts/analyze_trace.py chiprun_out/trace [--json]
 """
 
 import argparse

@@ -1,7 +1,7 @@
 """Micro-benchmark: attention implementations at SDXL self-attention shapes.
 
-Decides the sdpa routing policy with data (VERDICT round-1 asked for the
-flash path to be *measured*, not assumed): XLA einsum+softmax vs the in-repo
+Decides the sdpa routing policy with data (the
+flash path is *measured*, not assumed): XLA einsum+softmax vs the in-repo
 Pallas kernel (ops/flash_attention.py) vs jax.experimental's tuned TPU flash
 kernel, at the (B*2 CFG, L, C, heads) shapes the SDXL UNet actually runs at
 1024/2048 px plus the 3840 px level-1 long-context shape (57600 tokens; the
@@ -10,7 +10,7 @@ the XLA path, so it is not a routing decision).
 
 Prints one JSON line per (shape, impl): {"impl", "L", "heads", "ms"}.
 
-On-chip runs should go through scripts/chip_campaign.py (one claimant, all
+On-chip runs should go through scripts/chip_campaign.py (one process, all
 phases serialized); its attn/tune lines feed scripts/update_sdpa_table.py,
 which bakes the winners into the checked-in routing table
 (ops/sdpa_routing.py).

@@ -13,7 +13,7 @@ that matches the running platform, so a CPU structural run can never pin
 dequant fleet-wide on TPU (and a TPU bake never misroutes CPU tests).
 
 Usage:
-    python scripts/update_gemm_table.py --log chip_logs/campaign_r6.log \
+    python scripts/update_gemm_table.py --log chiprun_out/campaign.log \
         [--label "v5e 2026-08-04"] [--dry_run]
 """
 

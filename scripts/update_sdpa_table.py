@@ -7,7 +7,7 @@ bucket, and rewrites the MEASURED_ROUTES block of
 distrifuser_tpu/ops/sdpa_routing.py in place.
 
 Usage:
-    python scripts/update_sdpa_table.py --log chip_logs/campaign_r3.log \
+    python scripts/update_sdpa_table.py --log chiprun_out/campaign.log \
         [--label "v5e 2026-07-29"] [--dry_run]
 
 Re-running on a newer log overwrites the previous table; the table file is
@@ -109,7 +109,7 @@ def build_routes(attn, tune):
     """{(head_dim, bucket): (impl, bq, bk, comment)} — later lines win, so a
     rerun campaign supersedes an earlier partial one in the same log."""
     # keyed by (L, head_dim, heads, impl) — tiles tuned for one head_dim or
-    # kernel must not leak onto another's route (ADVICE r3), and a tuned
+    # kernel must not leak onto another's route, and a tuned
     # time measured at one head COUNT must not be compared against another
     # head count's attn record (campaign r5: folding an h=10 sweep into the
     # h=24 SD3 record flipped the route to a kernel that loses at both)

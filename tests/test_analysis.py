@@ -435,7 +435,7 @@ def _scan_reports(body_fn, n_carry_args, devices8):
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from distrifuser_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(devices8, ("sp",))
 

@@ -97,9 +97,9 @@ def test_envelope_rejects_fingerprint_skew():
     """A structurally intact entry whose fingerprint names a different
     jaxlib must reject, naming the differing field — version skew never
     loads a foreign program."""
-    fp = {"scope": "s", "jax": "0.4.37", "jaxlib": "0.4.36"}
+    fp = {"scope": "s", "jax": "1.2.3", "jaxlib": "1.2.3"}
     data = encode_entry(fp, b"payload")
-    other = dict(fp, jaxlib="0.5.0")
+    other = dict(fp, jaxlib="1.2.4")
     with pytest.raises(AotCacheRejectedError, match="jaxlib"):
         decode_entry(data, other)
 
@@ -343,12 +343,6 @@ def test_real_runner_cache_warm_is_bit_identical(tmp_path):
     from distrifuser_tpu.models.unet import init_unet_params, tiny_config
     from distrifuser_tpu.parallel.runner import DenoiseRunner
     from distrifuser_tpu.schedulers import get_scheduler
-    from distrifuser_tpu.utils.compat import (
-        SUPPORTS_EXECUTABLE_SERIALIZATION,
-    )
-
-    if not SUPPORTS_EXECUTABLE_SERIALIZATION:
-        pytest.skip("runtime cannot serialize executables")
     store = mk_store(tmp_path)
 
     def run():

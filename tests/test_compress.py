@@ -20,7 +20,7 @@ from distrifuser_tpu.parallel.dit_sp import DiTDenoiseRunner
 from distrifuser_tpu.parallel.mmdit_sp import MMDiTDenoiseRunner
 from distrifuser_tpu.parallel.runner import DenoiseRunner
 from distrifuser_tpu.schedulers import get_scheduler
-from distrifuser_tpu.utils.compat import shard_map
+from jax import shard_map
 
 MODES = ["int8", "int8_residual"] + (["fp8"] if compress.fp8_supported()
                                      else [])

@@ -144,7 +144,8 @@ def test_padded_flash_matches_reference():
         v.reshape(b, lk, heads, d), 1.0 / d**0.5,
     ).reshape(b, lq, c)
 
-    out = padded_flash_sdpa(q, k, v, heads=heads, interpret=True)
+    out = padded_flash_sdpa(q, k, v, heads=heads, impl="inrepo",
+                            interpret=True)
     assert out.shape == (b, lq, c)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -152,7 +153,7 @@ def test_padded_flash_matches_reference():
     # aligned input degenerates to the plain kernel (no mask, no slice)
     q128 = q[:, :256]
     out128 = padded_flash_sdpa(q128, k[:, :256], v[:, :256], heads=heads,
-                               interpret=True)
+                               impl="inrepo", interpret=True)
     ref128 = attn_mod._sdpa_xla(
         q128.reshape(b, 256, heads, d), k[:, :256].reshape(b, 256, heads, d),
         v[:, :256].reshape(b, 256, heads, d), 1.0 / d**0.5,
@@ -162,7 +163,7 @@ def test_padded_flash_matches_reference():
 
 
 def test_padding_segment_ids_match_kv_len_semantics():
-    """ADVICE r5: the upstream SegmentIds pad mask, built for an unaligned
+    """The upstream SegmentIds pad mask, built for an unaligned
     shape, must encode exactly the in-repo kernel's static kv_len mask —
     real query rows attend the first lk KV positions and nothing else.
     Pure mask math, CI-exercisable without a Mosaic compile."""
@@ -183,12 +184,11 @@ def test_padding_segment_ids_match_kv_len_semantics():
         np.testing.assert_array_equal(allowed[0, i], col >= lk)
 
 
-def test_padded_flash_honors_inrepo_pin_and_probe(monkeypatch):
-    """ADVICE r5: DISTRIFUSER_TPU_FLASH_IMPL=inrepo must keep
-    padded_flash_sdpa off the upstream segment-ids path, and the DEFAULT
-    upstream route must consult the probe verdict
-    (attention._upstream_flash_available) so a Mosaic backend-compile
-    failure degrades instead of killing generate()."""
+def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
+    """The resolved kernel runs or the call raises: a failing upstream
+    kernel is never replaced by the in-repo one (or by XLA softmax) behind
+    the caller's back, and DISTRIFUSER_TPU_FLASH_IMPL=inrepo keeps
+    padded_flash_sdpa off the upstream segment-ids path."""
     import importlib
 
     attn_mod = importlib.import_module("distrifuser_tpu.ops.attention")
@@ -202,34 +202,59 @@ def test_padded_flash_honors_inrepo_pin_and_probe(monkeypatch):
     k = jax.random.normal(keys[1], (b, lk, c))
     v = jax.random.normal(keys[2], (b, lk, c))
 
-    calls = []
+    class MosaicRefused(RuntimeError):
+        pass
 
-    def spy_upstream(*a, **kw):
-        calls.append("upstream")
-        raise RuntimeError("should not be reached in these scenarios")
+    def failing_upstream(*a, **kw):
+        raise MosaicRefused("upstream kernel refused")
 
-    monkeypatch.setattr(fa, "upstream_flash_sdpa", spy_upstream)
+    inrepo_calls = []
+    real_flash = fa.flash_sdpa
+
+    def spy_inrepo(*a, **kw):
+        inrepo_calls.append(kw)
+        return real_flash(*a, **kw)
+
+    monkeypatch.setattr(fa, "upstream_flash_sdpa", failing_upstream)
+    monkeypatch.setattr(fa, "flash_sdpa", spy_inrepo)
     monkeypatch.delenv("DISTRIFUSER_TPU_PADDED_IMPL", raising=False)
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL", raising=False)
 
-    # 1) the kernel-wide inrepo pin routes the padded path in-repo too
+    # 1) default route = upstream: its failure propagates, nothing else runs
+    with pytest.raises(MosaicRefused):
+        fa.padded_flash_sdpa(q, k, v, heads=heads)
+    assert not inrepo_calls
+
+    # 2) interpret mode belongs to the in-repo kernel only
+    with pytest.raises(ValueError, match="impl='inrepo'"):
+        fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=True)
+
+    # 3) the kernel-wide inrepo pin is an explicit route to the in-repo kernel
     monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "inrepo")
     out = fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=True)
-    assert out.shape == (b, lq, c) and not calls
+    assert out.shape == (b, lq, c) and len(inrepo_calls) == 1
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL")
 
-    # 2) default route + failed probe: upstream is never attempted
-    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL", raising=False)
-    monkeypatch.setattr(attn_mod, "_UPSTREAM_PROBE_OK", False)
-    # interpret=False exercises the gate itself; the in-repo fallback then
-    # runs the real (non-interpret) kernel, which on CPU only works in
-    # interpret mode — so stub flash_sdpa to observe the routing only
-    monkeypatch.setattr(
-        fa, "flash_sdpa", lambda *a, **kw: jnp.zeros((b, 256, c))
-    )
-    out = fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=False)
-    assert not calls, "probe said no, but upstream path was chosen"
+    # 4) sdpa on a TPU platform: the padded route raises through sdpa (no
+    # XLA-softmax fall-through), and so does the aligned table route
+    class _Dev:
+        platform = "tpu"
 
-    # 3) an explicit upstream pin is honored past the probe (and its
-    # trace-time failure falls through to the in-repo kernel)
-    monkeypatch.setenv("DISTRIFUSER_TPU_PADDED_IMPL", "upstream")
-    out = fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=False)
-    assert calls == ["upstream"]
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
+    long_q = jnp.zeros((1, 1100, c))  # unaligned and >= _FLASH_MIN_LEN
+    with pytest.raises(MosaicRefused):
+        attn_mod.sdpa(long_q, long_q, long_q, heads=heads)
+    aligned = jnp.zeros((1, 1024, 2 * 64))  # d=64: the shipped table route
+    with pytest.raises(MosaicRefused):
+        attn_mod.sdpa(aligned, aligned, aligned, heads=2)
+    assert len(inrepo_calls) == 1
+
+
+def test_upstream_route_on_cpu_is_an_error(monkeypatch):
+    """The upstream Mosaic kernel cannot run on the CPU platform; asking
+    for it there raises instead of quietly running the in-repo kernel."""
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "1")
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "upstream")
+    x = jnp.zeros((1, 128, 32))
+    with pytest.raises(ValueError, match="needs a TPU"):
+        sdpa(x, x, x, heads=2)

@@ -1,4 +1,4 @@
-"""Full-architecture multi-device numerics (VERDICT r3 weak #4).
+"""Full-architecture multi-device numerics.
 
 The tiny-config tests prove the mesh/collective wiring and the AOT leg
 proves the real geometry compiles 8-way; this adds the missing piece —
@@ -7,7 +7,7 @@ and matching the single-device run.  It costs ~8-12 minutes of CPU compile
 (two full-UNet program sets through one core), so it is gated behind
 ``DISTRIFUSER_TPU_HEAVY_TESTS=1`` rather than running in every suite pass.
 Measured 2026-07-30: 2-dev cfg_split vs 1-dev max|diff| = 6.5e-05 (fp32,
-256px, 2 steps) — recorded in BENCH_NOTES.md.
+256px, 2 steps).
 """
 
 import os

@@ -329,7 +329,7 @@ def test_geometry_validation():
 
 
 def test_full_sync_mode_runs_every_step_exact():
-    """mode='full_sync' (ADVICE r2): the displaced schedule must never
+    """mode='full_sync': the displaced schedule must never
     engage — every step runs as the exact mega-patch, matching the dense
     loop even when warmup_steps alone would hand off after one step."""
     dcfg, params = make_model()
@@ -345,7 +345,7 @@ def test_full_sync_mode_runs_every_step_exact():
 
 def test_inapplicable_knobs_rejected():
     """no_sync and --no_cuda_graph have no pipeline semantics: loud errors
-    beat silently ignoring the request (ADVICE r2)."""
+    beat silently ignoring the request."""
     dcfg, params = make_model()
     with pytest.raises(ValueError, match="no_sync"):
         PipeFusionRunner(pipe_config(4, do_cfg=False, mode="no_sync"),

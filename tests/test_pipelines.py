@@ -131,7 +131,7 @@ def test_batch_of_prompts(devices8):
 
 def test_prompt_chunking_matches_manual_chunks(devices8):
     """3 prompts through a batch_size=2 pipeline == the two manual chunk
-    calls with the same per-image initial noise (VERDICT r3 task 8: arbitrary
+    calls with the same per-image initial noise (arbitrary
     prompt counts chunk instead of asserting)."""
     pipe, _ = build_sd_pipeline(devices8, 2, batch_size=2)
     lats = np.asarray(jax.random.normal(jax.random.PRNGKey(9), (3, 16, 16, 4)))
@@ -386,8 +386,7 @@ def test_caller_supplied_latents(devices8):
 
 
 def test_weightless_tokenizer_flag_on_output(devices8):
-    """Hash-tokenizer runs carry the warning ON the artifact (VERDICT r4
-    weak #5): the PipelineOutput says it must not be quality-judged; a
+    """Hash-tokenizer runs carry the warning ON the artifact: the PipelineOutput says it must not be quality-judged; a
     real-tokenizer pipeline emits a clean output."""
     pipe, _ = build_sdxl_pipeline(devices8, 1)
     out = pipe("a fox", num_inference_steps=1, output_type="latent", seed=0)

@@ -348,8 +348,7 @@ def _diffusers_2d_sincos(embed_dim, grid_size, interpolation_scale=1.0,
 
 
 def test_pos_embed_matches_diffusers_channel_order():
-    """Column/width embedding occupies the FIRST channel half (ADVICE r3:
-    row-first diagonally transposes the table for converted checkpoints).
+    """Column/width embedding occupies the FIRST channel half (row-first diagonally transposes the table for converted checkpoints).
 
     Pinned both against a structurally independent meshgrid oracle and
     against hardcoded sin/cos spot values, so a shared re-implementation of

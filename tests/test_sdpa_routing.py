@@ -114,8 +114,8 @@ def test_lookup_requires_matching_head_dim():
 
 
 def test_lookup_distance_cap():
-    """A lone long-L measurement must not govern short sequences (ADVICE
-    r3): beyond MAX_BUCKET_DISTANCE log2 steps lookup falls through to the
+    """A lone long-L measurement must not govern short sequences:
+    beyond MAX_BUCKET_DISTANCE log2 steps lookup falls through to the
     analytic default."""
     table = {(64, 14): Route("inrepo", 256, 512)}  # L=16384 only
     old = sdpa_routing.MEASURED_ROUTES
@@ -131,7 +131,7 @@ def test_lookup_distance_cap():
 
 def test_updater_tiles_keyed_by_head_dim(tmp_path):
     """Tuned tiles for one head_dim must not leak onto another head_dim's
-    route at the same L (ADVICE r3)."""
+    route at the same L."""
     import json as _json
 
     import update_sdpa_table as upd
@@ -422,7 +422,7 @@ def test_lookup_nearest_shape_fallback_for_missing_key():
 
 
 def test_largest_dividing_tile():
-    """Tile fitting for the upstream kernel (ADVICE r4): a tuned tile that
+    """Tile fitting for the upstream kernel: a tuned tile that
     does not divide the call's length is halved to the largest power-of-2
     divisor instead of being dropped (which would mix in the kernel's
     hardcoded 512/1024 defaults — themselves non-dividing for shapes like

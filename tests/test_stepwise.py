@@ -101,7 +101,7 @@ def test_stepwise_callback(devices8):
 
 
 def test_fused_callback_matches_stepwise(devices8):
-    """Callback with use_cuda_graph=True (VERDICT r4 task 4): the compiled
+    """Callback with use_cuda_graph=True: the compiled
     loop fires the diffusers legacy callback via io_callback with the SAME
     count, order, timesteps, and latents as the host loop — in both the
     fused and hybrid configs (a callback routes hybrid through the same

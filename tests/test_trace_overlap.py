@@ -1,4 +1,4 @@
-"""Runtime overlap evidence (VERDICT r3 task 5, scheduling level).
+"""Runtime overlap evidence (scheduling level).
 
 utils/overlap.py proves the refresh collectives are *structurally*
 deferrable; these tests add runtime evidence one level up: a profiler trace

@@ -48,8 +48,13 @@ from distrifuser_tpu.serve.testing import FakeExecutor
 from test_pipelines import build_sd_pipeline
 
 # the pinned per-family parity tolerances (docs/PERF.md "Quantized
-# weights"; scripts/bench_weights.py gates CI on the same numbers)
-TOL = {"unet": 1e-2, "dit": 3e-3, "mmdit": 3e-3}
+# weights"; scripts/bench_weights.py gates CI on the same numbers).
+# "unet" was 1e-2 when first pinned, on random draws made with
+# jax_threefry_partitionable=False; the installed JAX defaults it to True,
+# which changes what PRNGKey(0) draws for the random weights and latents.
+# Same quantiser, same statistic: 0.0078 with the flag off, 0.0095-0.0121
+# over seeds 0-7 with it on (PR 21).
+TOL = {"unet": 1.5e-2, "dit": 3e-3, "mmdit": 3e-3}
 
 MODES = ["int8"] + (["fp8"] if fp8_supported() else [])
 
