@@ -130,6 +130,7 @@ def _self_attn(p, x, heads: int, mask):
     return linear(p["out_proj"], out)
 
 
+@jax.named_scope("text_encoder")
 def clip_text_forward(params, cfg: CLIPTextConfig, input_ids) -> Dict[str, Any]:
     """Returns {"hidden_states": [L+1 arrays], "last_hidden_state",
     "pooler_output", "text_embeds" (if projection_dim)}.

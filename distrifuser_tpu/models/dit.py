@@ -306,6 +306,7 @@ def timestep_embedding(cfg: DiTConfig, t: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([jnp.cos(args), jnp.sin(args)], axis=-1)
 
 
+@jax.named_scope("time_embed")
 def t_embed(params, cfg: DiTConfig, t: jnp.ndarray) -> jnp.ndarray:
     """Timestep -> conditioning vector [hidden]."""
     f = timestep_embedding(cfg, t).astype(params["t_fc1"]["kernel"].dtype)
@@ -359,11 +360,13 @@ def caption_project(params, enc: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+@jax.named_scope("adaln")
 def adaln_table(params, cfg: DiTConfig, temb: jnp.ndarray) -> jnp.ndarray:
     """Global adaLN-single output for one timestep embedding: [6, hidden]."""
     return linear(params["adaln"], silu(temb)).reshape(6, cfg.hidden_size)
 
 
+@jax.named_scope("layernorm")
 def _ln(x):
     """LayerNorm without learnable affine (the modulation supplies it)."""
     x32 = x.astype(jnp.float32)
@@ -396,6 +399,7 @@ def caption_mask_bias(mask: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+@jax.named_scope("attn")
 def _masked_cross_sdpa(q, k, v, bias, heads: int):
     """Cross-attention with an additive key bias.  Caption sequences are
     tiny (77-300 tokens) so the plain XLA einsum path is the right kernel;
@@ -414,6 +418,7 @@ def _masked_cross_sdpa(q, k, v, bias, heads: int):
     return out.reshape(b, lq, c)
 
 
+@jax.named_scope("block")
 def dit_block(
     bp: Dict[str, Any],
     cfg: DiTConfig,
@@ -479,9 +484,11 @@ def dit_block(
     x = x + linear(bp["cross_out"], catt)
 
     hn2 = _ln(x) * (1.0 + sc2) + s2
-    x = x + g2 * linear(
-        bp["mlp_fc2"], jax.nn.gelu(linear(bp["mlp_fc1"], hn2), approximate=True)
-    )
+    with jax.named_scope("ff"):
+        x = x + g2 * linear(
+            bp["mlp_fc2"],
+            jax.nn.gelu(linear(bp["mlp_fc1"], hn2), approximate=True)
+        )
     return x, (k, v)
 
 

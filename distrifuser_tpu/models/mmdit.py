@@ -310,6 +310,7 @@ def cond_vec(params, cfg: MMDiTConfig, t: jnp.ndarray,
     return temb + pemb
 
 
+@jax.named_scope("adaln")
 def _mods(mod_p, vec, n):
     """silu(vec) -> n modulation vectors, each [B, 1, hidden]."""
     m = linear(mod_p, silu(vec))
@@ -326,6 +327,7 @@ def _rms_heads(x, w, heads: int):
     return (y * w.astype(jnp.float32)).astype(x.dtype).reshape(b, l, c)
 
 
+@jax.named_scope("block")
 def mmdit_block(
     bp: Dict[str, Any],
     cfg: MMDiTConfig,
@@ -421,13 +423,17 @@ def mmdit_block(
         kv2 = (k2, v2)
 
     xn2 = _ln(x) * (1.0 + xsc2) + xs2
-    x = x + xg2 * linear(
-        bp["x_fc2"], jax.nn.gelu(linear(bp["x_fc1"], xn2), approximate=True)
-    )
+    with jax.named_scope("ff"):
+        x = x + xg2 * linear(
+            bp["x_fc2"],
+            jax.nn.gelu(linear(bp["x_fc1"], xn2), approximate=True)
+        )
     cn2 = _ln(ctx) * (1.0 + csc2) + cs2
-    ctx = ctx + cg2 * linear(
-        bp["c_fc2"], jax.nn.gelu(linear(bp["c_fc1"], cn2), approximate=True)
-    )
+    with jax.named_scope("ff"):
+        ctx = ctx + cg2 * linear(
+            bp["c_fc2"],
+            jax.nn.gelu(linear(bp["c_fc1"], cn2), approximate=True)
+        )
     if dual_p is not None:
         return x, ctx, (xk, xv), kv2
     return x, ctx, (xk, xv)

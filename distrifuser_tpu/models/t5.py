@@ -155,6 +155,7 @@ def _ff(lp, cfg: T5Config, x):
     return linear(lp["wo"], cfg.act(linear(lp["wi"], x)))
 
 
+@jax.named_scope("text_encoder")
 def t5_encode(
     params: Dict[str, Any],
     cfg: T5Config,

@@ -372,6 +372,7 @@ def timestep_embedding(
     return emb
 
 
+@jax.named_scope("layernorm")
 def layer_norm(p, x, eps: float = 1e-5):
     """Moments in fp32 (torch upcasts low-precision LN internally; bf16's
     8-bit mantissa cannot accumulate a 1280-wide mean), output in x.dtype."""
@@ -483,22 +484,23 @@ def unet_forward(
         timesteps = jnp.full((b,), timesteps)
 
     # --- time + additional embeddings ---
-    temb = timestep_embedding(
-        timesteps, cfg.block_out_channels[0],
-        flip_sin_to_cos=cfg.flip_sin_to_cos, freq_shift=cfg.freq_shift,
-    ).astype(dtype)
-    temb = linear(params["time_embedding"]["linear_2"],
-                  silu(linear(params["time_embedding"]["linear_1"], temb)))
-    if cfg.addition_embed_type == "text_time":
-        assert added_cond is not None, "SDXL needs added_cond text_embeds/time_ids"
-        time_ids = added_cond["time_ids"]  # [B, n_ids] (6 base / 5 refiner)
-        tid_emb = timestep_embedding(
-            time_ids.reshape(-1), cfg.addition_time_embed_dim,
+    with jax.named_scope("time_embed"):
+        temb = timestep_embedding(
+            timesteps, cfg.block_out_channels[0],
             flip_sin_to_cos=cfg.flip_sin_to_cos, freq_shift=cfg.freq_shift,
-        ).reshape(b, -1).astype(dtype)
-        add = jnp.concatenate([added_cond["text_embeds"].astype(dtype), tid_emb], axis=-1)
-        temb = temb + linear(params["add_embedding"]["linear_2"],
-                             silu(linear(params["add_embedding"]["linear_1"], add)))
+        ).astype(dtype)
+        temb = linear(params["time_embedding"]["linear_2"],
+                      silu(linear(params["time_embedding"]["linear_1"], temb)))
+        if cfg.addition_embed_type == "text_time":
+            assert added_cond is not None, "SDXL needs added_cond text_embeds/time_ids"
+            time_ids = added_cond["time_ids"]  # [B, n_ids] (6 base / 5 refiner)
+            tid_emb = timestep_embedding(
+                time_ids.reshape(-1), cfg.addition_time_embed_dim,
+                flip_sin_to_cos=cfg.flip_sin_to_cos, freq_shift=cfg.freq_shift,
+            ).reshape(b, -1).astype(dtype)
+            add = jnp.concatenate([added_cond["text_embeds"].astype(dtype), tid_emb], axis=-1)
+            temb = temb + linear(params["add_embedding"]["linear_2"],
+                                 silu(linear(params["add_embedding"]["linear_1"], add)))
 
     enc = encoder_hidden_states.astype(dtype)
     groups = cfg.norm_num_groups
@@ -509,34 +511,36 @@ def unet_forward(
     for i, btype in enumerate(cfg.down_block_types):
         if shallow and i >= cut:
             break
-        bp = params["down_blocks"][i]
-        for j in range(cfg.layers_per_block):
-            name = f"down_blocks.{i}.resnets.{j}"
-            x = d.resnet(bp["resnets"][j], x, temb, name, groups=groups)
-            if btype == "CrossAttnDownBlock2D":
-                x = transformer_2d(
-                    d, bp["attentions"][j], x, enc, f"down_blocks.{i}.attentions.{j}",
-                    heads=cfg.heads_for_block(i),
-                    use_linear_projection=cfg.use_linear_projection,
-                    norm_groups=groups,
-                )
-            skips.append(x)
-        if i < len(cfg.down_block_types) - 1 and not (shallow and i == cut - 1):
-            # block cut-1's downsampler feeds the deep subtree only
-            x = d.conv(bp["downsamplers"][0]["conv"], x,
-                       f"down_blocks.{i}.downsamplers.0.conv", stride=2)
-            skips.append(x)
+        with jax.named_scope(f"down_{i}"):
+            bp = params["down_blocks"][i]
+            for j in range(cfg.layers_per_block):
+                name = f"down_blocks.{i}.resnets.{j}"
+                x = d.resnet(bp["resnets"][j], x, temb, name, groups=groups)
+                if btype == "CrossAttnDownBlock2D":
+                    x = transformer_2d(
+                        d, bp["attentions"][j], x, enc, f"down_blocks.{i}.attentions.{j}",
+                        heads=cfg.heads_for_block(i),
+                        use_linear_projection=cfg.use_linear_projection,
+                        norm_groups=groups,
+                    )
+                skips.append(x)
+            if i < len(cfg.down_block_types) - 1 and not (shallow and i == cut - 1):
+                # block cut-1's downsampler feeds the deep subtree only
+                x = d.conv(bp["downsamplers"][0]["conv"], x,
+                           f"down_blocks.{i}.downsamplers.0.conv", stride=2)
+                skips.append(x)
 
     if not shallow:
         # --- mid ---
-        mp = params["mid_block"]
-        x = d.resnet(mp["resnets"][0], x, temb, "mid_block.resnets.0", groups=groups)
-        x = transformer_2d(
-            d, mp["attentions"][0], x, enc, "mid_block.attentions.0",
-            heads=cfg.heads_for_block(len(cfg.block_out_channels) - 1),
-            use_linear_projection=cfg.use_linear_projection, norm_groups=groups,
-        )
-        x = d.resnet(mp["resnets"][1], x, temb, "mid_block.resnets.1", groups=groups)
+        with jax.named_scope("mid"):
+            mp = params["mid_block"]
+            x = d.resnet(mp["resnets"][0], x, temb, "mid_block.resnets.0", groups=groups)
+            x = transformer_2d(
+                d, mp["attentions"][0], x, enc, "mid_block.attentions.0",
+                heads=cfg.heads_for_block(len(cfg.block_out_channels) - 1),
+                use_linear_projection=cfg.use_linear_projection, norm_groups=groups,
+            )
+            x = d.resnet(mp["resnets"][1], x, temb, "mid_block.resnets.1", groups=groups)
 
     # --- up path ---
     deep_out = None
@@ -549,22 +553,23 @@ def unet_forward(
                 x = deep_cache
             else:
                 deep_out = x
-        bp = params["up_blocks"][i]
-        for j in range(cfg.layers_per_block + 1):
-            skip = skips.pop()
-            x = jnp.concatenate([x, skip], axis=-1)
-            name = f"up_blocks.{i}.resnets.{j}"
-            x = d.resnet(bp["resnets"][j], x, temb, name, groups=groups)
-            if btype == "CrossAttnUpBlock2D":
-                x = transformer_2d(
-                    d, bp["attentions"][j], x, enc, f"up_blocks.{i}.attentions.{j}",
-                    heads=cfg.heads_for_block(n_blocks - 1 - i),
-                    use_linear_projection=cfg.use_linear_projection,
-                    norm_groups=groups,
-                )
-        if i < len(cfg.up_block_types) - 1:
-            x = upsample_nearest_2x(x)
-            x = d.conv(bp["upsamplers"][0]["conv"], x, f"up_blocks.{i}.upsamplers.0.conv")
+        with jax.named_scope(f"up_{i}"):
+            bp = params["up_blocks"][i]
+            for j in range(cfg.layers_per_block + 1):
+                skip = skips.pop()
+                x = jnp.concatenate([x, skip], axis=-1)
+                name = f"up_blocks.{i}.resnets.{j}"
+                x = d.resnet(bp["resnets"][j], x, temb, name, groups=groups)
+                if btype == "CrossAttnUpBlock2D":
+                    x = transformer_2d(
+                        d, bp["attentions"][j], x, enc, f"up_blocks.{i}.attentions.{j}",
+                        heads=cfg.heads_for_block(n_blocks - 1 - i),
+                        use_linear_projection=cfg.use_linear_projection,
+                        norm_groups=groups,
+                    )
+            if i < len(cfg.up_block_types) - 1:
+                x = upsample_nearest_2x(x)
+                x = d.conv(bp["upsamplers"][0]["conv"], x, f"up_blocks.{i}.upsamplers.0.conv")
 
     assert not skips
     x = d.group_norm(params["conv_norm_out"], x, "conv_norm_out", groups=groups)

@@ -104,6 +104,7 @@ def _vae_attention(p, x, groups):
     return x + out
 
 
+@jax.named_scope("vae_decode")
 def decode(params, cfg: VAEConfig, latents, *, tile: int = 0):
     """Latent [B, h, w, 4] (already divided by scaling_factor) -> image
     [B, 8h, 8w, 3] in [-1, 1].  ``tile``: latent rows per tile (0 = whole).
@@ -240,6 +241,7 @@ def _vae_resnet_sp(p, x, n, axis, groups):
     return x + h
 
 
+@jax.named_scope("vae_decode")
 def decode_sp(params, cfg: VAEConfig, latents, n: int, axis: str = SP_AXIS):
     """Sequence-parallel decode (beyond the reference, which decodes the full
     latent replicated on every rank — pipelines.py:39-42 there).

@@ -133,6 +133,7 @@ def _sdpa_xla(q, k, v, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), v)
 
 
+@jax.named_scope("attn")
 def sdpa(q, k, v, *, heads: int):
     """Scaled dot-product attention over [B, L, C] tensors with H heads.
 
@@ -280,16 +281,19 @@ def patch_self_attention(p, x, ctx: PatchContext, name: str, *, heads: int):
     if ctx.n == 1:
         full_kv = kv
     elif ctx.is_sync:
-        gathered = all_gather(kv, ctx.axis)  # [n, B, L, 2C]
-        ctx.emit(name, gathered, kind="attn")
-        full_kv = _flatten_seq(gathered)
+        with jax.named_scope("stale_kv"):
+            gathered = all_gather(kv, ctx.axis)  # [n, B, L, 2C]
+            ctx.emit(name, gathered, kind="attn")
+            full_kv = _flatten_seq(gathered)
     else:
-        gathered = ctx.stale(name)
-        # fresh local slot + stale peer slots (attn.py:135-138)
-        gathered = lax.dynamic_update_index_in_dim(gathered, kv, ctx.split_idx(), 0)
-        full_kv = _flatten_seq(gathered)
-        if ctx.refresh:
-            ctx.emit_refresh_gather(name, kv, kind="attn")
+        with jax.named_scope("stale_kv"):
+            gathered = ctx.stale(name)
+            # fresh local slot + stale peer slots (attn.py:135-138)
+            gathered = lax.dynamic_update_index_in_dim(
+                gathered, kv, ctx.split_idx(), 0)
+            full_kv = _flatten_seq(gathered)
+            if ctx.refresh:
+                ctx.emit_refresh_gather(name, kv, kind="attn")
     k, v = split_kv(full_kv)
     return linear(p["to_out"], sdpa(q, k, v, heads=heads))
 

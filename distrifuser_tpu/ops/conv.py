@@ -22,6 +22,7 @@ TPU-native re-design of the reference's `DistriConv2dPP`
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -31,6 +32,7 @@ from ..parallel.context import PatchContext
 _DIMNUMS = ("NHWC", "HWIO", "NHWC")
 
 
+@jax.named_scope("conv")
 def conv2d(p, x, *, stride: int = 1, padding=None):
     """Dense NHWC conv. `padding` defaults to (k-1)//2 ("same" for odd k).
 
@@ -58,6 +60,7 @@ def conv2d(p, x, *, stride: int = 1, padding=None):
     return y
 
 
+@jax.named_scope("conv")
 def _conv_valid_h(p, x, stride: int, pad_w: int):
     """Conv with height padding already materialized in `x` (halo rows), width
     padded normally — the reference's F.conv2d(..., padding=(0, pad_w))
@@ -103,15 +106,16 @@ def patch_conv2d(p, x, ctx: PatchContext, name: str, *, stride: int = 1):
         # unwrapped entirely (distri_sdxl_unet_pp.py:24-26).
         return conv2d(p, x, stride=stride, padding=(ph, pw))
 
-    if ctx.is_sync:
-        # Fresh halos double as the seed state for the stale phase; the
-        # context hook also seeds the own-rows carry residual compression
-        # delta-codes against (parallel/compress.py).
-        top, bottom = ctx.emit_sync_halos(name, x, ph)
-    else:
-        halos = ctx.stale(name)  # [2, B, ph, W, C] from the previous step
-        top, bottom = halos[0], halos[1]
-        if ctx.refresh:
-            ctx.emit_refresh_halos(name, x, ph)
-    padded = jnp.concatenate([top, x, bottom], axis=1)
+    with jax.named_scope("halo"):
+        if ctx.is_sync:
+            # Fresh halos double as the seed state for the stale phase; the
+            # context hook also seeds the own-rows carry residual
+            # compression delta-codes against (parallel/compress.py).
+            top, bottom = ctx.emit_sync_halos(name, x, ph)
+        else:
+            halos = ctx.stale(name)  # [2, B, ph, W, C], the previous step's
+            top, bottom = halos[0], halos[1]
+            if ctx.refresh:
+                ctx.emit_refresh_halos(name, x, ph)
+        padded = jnp.concatenate([top, x, bottom], axis=1)
     return _conv_valid_h(p, padded, stride, pw)

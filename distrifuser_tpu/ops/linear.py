@@ -68,6 +68,7 @@ def _quantized_matmul(x, qt: QuantizedTensor):
     return y.astype(out_dtype)
 
 
+@jax.named_scope("linear")
 def linear(p, x):
     kern = p["kernel"]
     if isinstance(kern, QuantizedTensor):
@@ -90,6 +91,7 @@ def geglu(p, x):
     return a * jax.nn.gelu(g, approximate=False)
 
 
+@jax.named_scope("ff")
 def feed_forward(p, x):
     """diffusers `FeedForward` with GEGLU activation: net.0 = GEGLU, net.2 = Linear
     (reference shards it in tp/feed_forward.py; dense path here)."""

@@ -22,6 +22,7 @@ for PSNR parity).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -37,6 +38,7 @@ def _affine(p, y):
     return y
 
 
+@jax.named_scope("groupnorm")
 def group_norm(p, x, *, groups: int, eps: float = 1e-5):
     """Dense GroupNorm over NHWC, biased variance (torch nn.GroupNorm semantics)."""
     b, h, w, c = x.shape
@@ -69,6 +71,7 @@ def _normalize(p, x, full_mean, var, *, groups: int, eps: float, bessel_ne: int)
     return _affine(p, y)
 
 
+@jax.named_scope("groupnorm")
 def patch_group_norm(
     p, x, ctx: PatchContext, name: str, *, groups: int, eps: float = 1e-5
 ):
@@ -81,9 +84,10 @@ def patch_group_norm(
     if ctx.mode in ("stale_gn", "corrected_async_gn"):
         m = _local_moments(x, groups)  # [2, B, G]
         if ctx.is_sync:
-            gathered = all_gather(m, ctx.axis)  # [n, 2, B, G]
+            with jax.named_scope("gn_stats"):
+                gathered = all_gather(m, ctx.axis)  # [n, 2, B, G]
+                ctx.emit(name, gathered, kind="gn")
             full = gathered.mean(axis=0)
-            ctx.emit(name, gathered, kind="gn")
         else:
             gathered = ctx.stale(name)
             idx = ctx.split_idx()
@@ -94,7 +98,8 @@ def patch_group_norm(
                 full = gathered.mean(axis=0) + (m - own_stale)
             else:  # stale_gn: stale peers + fresh self (groupnorm.py:52-55)
                 full = (gathered.sum(axis=0) - own_stale + m) / ctx.n
-            ctx.emit_refresh_gather(name, m, kind="gn")
+            with jax.named_scope("gn_stats"):
+                ctx.emit_refresh_gather(name, m, kind="gn")
         var = full[1] - jnp.square(full[0])
         if ctx.mode == "corrected_async_gn":
             local_var = m[1] - jnp.square(m[0])
@@ -105,7 +110,8 @@ def patch_group_norm(
         # Blocking all_reduce of moments every step (groupnorm.py:74-91);
         # also the warmup path for separate_gn / no_sync.
         m = _local_moments(x, groups)
-        full = psum_mean(m, ctx.axis)
+        with jax.named_scope("gn_stats"):
+            full = psum_mean(m, ctx.axis)
         var = full[1] - jnp.square(full[0])
         return _normalize(p, x, full[0], var, groups=groups, eps=eps, bessel_ne=ne)
 

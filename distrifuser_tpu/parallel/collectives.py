@@ -81,6 +81,7 @@ def neighbor_perms(n: int):
     return down, up
 
 
+@jax.named_scope("halo")
 def exchange_boundary_rows(bottom, top, n: int, axis: str = SP_AXIS):
     """ppermute already-extracted boundary tensors to spatial neighbors:
     ``(from_prev, from_next)`` = (previous device's ``bottom``, next

@@ -562,6 +562,7 @@ class PatchContext:
                 ).astype(rec["dtype"])
             self._def_halo.clear()
 
+    @jax.named_scope("stale_gather")
     def _batched_gather(self, parts) -> Dict[Tuple[str, str], Any]:
         """One flat all_gather per dtype over ``(name, part, tensor)``
         entries; returns {(name, part): [n, *tensor.shape]}."""

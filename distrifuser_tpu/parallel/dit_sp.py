@@ -306,6 +306,7 @@ class DiTDenoiseRunner:
             bp, ckv, kv_blk = xs  # kv_blk [2, Bl, N, hid] stale gathered
             assembled = {}
 
+            @jax.named_scope("stale_kv")
             def assemble(k_fresh, v_fresh):
                 if phase_sync:
                     kv = (all_gather_seq(k_fresh), all_gather_seq(v_fresh))

@@ -202,6 +202,7 @@ class MMDiTDenoiseRunner:
             with this device's slot overwritten fresh (reference
             pp/attn.py:135-140 semantics)."""
 
+            @jax.named_scope("stale_kv")
             def assemble(k_fresh, v_fresh):
                 if phase_sync:
                     kv = (all_gather_seq(k_fresh), all_gather_seq(v_fresh))

@@ -47,6 +47,7 @@ from .models.weights import (
 from .parallel.runner import make_runner
 from .schedulers import BaseScheduler, FlowMatchEulerScheduler, get_scheduler
 from .utils.config import DistriConfig
+from .utils.trace import phased, phases, span
 
 
 class SimpleTokenizer:
@@ -391,9 +392,10 @@ def _batched_generate(cfg, scheduler, prompts, negs, num_images_per_prompt,
     bs = cfg.batch_size
     lat_shape = (total, cfg.latent_height, cfg.latent_width, in_channels)
     if latents is None:
-        latents = jax.random.normal(jax.random.PRNGKey(seed), lat_shape,
-                                    jnp.float32)
-        latents = latents * scheduler.init_noise_sigma
+        with span("distri.pipe.latents"):
+            latents = jax.random.normal(jax.random.PRNGKey(seed), lat_shape,
+                                        jnp.float32)
+            latents = latents * scheduler.init_noise_sigma
     else:
         latents = jnp.asarray(latents, jnp.float32)
         assert latents.shape == lat_shape, (latents.shape, lat_shape)
@@ -739,14 +741,28 @@ class _GenerationMixin:
         """latent -> float RGB [N,H,W,3] in [0,1]: the chunked VAE decode
         plus device->host conversion tail — ONE code path shared by
         `_finalize` (the monolithic __call__) and the staged executor's
-        decode stage, so the two execution modes decode identically."""
-        image = _decode_chunked(
-            self._decode, self.vae_params, latent,
-            self.distri_config.batch_size, self.vae_config.scaling_factor,
-            self._vae_shift,
-        )
-        image = np.asarray(image, np.float32)
-        return np.clip(image / 2 + 0.5, 0.0, 1.0)
+        decode stage, so the two execution modes decode identically.
+
+        Enqueuing the decode is the end of the request's ``dispatch``
+        phase (utils/trace.py `phases`: joined when __call__ or the serve
+        executor opened it, opened here for a bare decode stage); what
+        follows is the first host wait, the copy and the arithmetic, each
+        a phase of its own.  ``post`` stays open until the outermost
+        caller has the images."""
+        with phases("distri.pipe.dispatch", stage="dispatch") as ph:
+            with span("distri.pipe.decode"):
+                image = _decode_chunked(
+                    self._decode, self.vae_params, latent,
+                    self.distri_config.batch_size,
+                    self.vae_config.scaling_factor, self._vae_shift,
+                )
+            # the wait np.asarray made anyway, split from its copy
+            ph.next("distri.pipe.wait_device", stage="device_wait")
+            jax.block_until_ready(image)
+            ph.next("distri.pipe.to_host", stage="to_host")
+            image = np.asarray(image, np.float32)
+            ph.next("distri.pipe.post", stage="post")
+            return np.clip(image / 2 + 0.5, 0.0, 1.0)
 
     def prepare_stages(self, num_inference_steps: int) -> "PipelineStages":
         """Pre-build the request path as three separately-dispatchable
@@ -893,6 +909,7 @@ class _DistriPipelineBase(_GenerationMixin):
         reference's no-graph path."""
         self.runner.prepare(num_inference_steps)
 
+    @phased("distri.pipe.dispatch", stage="dispatch")
     def __call__(
         self,
         prompt: str | List[str],
@@ -1026,15 +1043,16 @@ class _DistriPipelineBase(_GenerationMixin):
                        num_inference_steps, *, start_step=0, end_step=None,
                        callback=None):
         embeds, added = enc
-        return self.runner.generate(
-            latents, embeds,
-            guidance_scale=guidance_scale,
-            num_inference_steps=num_inference_steps,
-            added_cond=added,
-            start_step=start_step,
-            end_step=end_step,
-            callback=callback,
-        )
+        with span("distri.pipe.denoise"):
+            return self.runner.generate(
+                latents, embeds,
+                guidance_scale=guidance_scale,
+                num_inference_steps=num_inference_steps,
+                added_cond=added,
+                start_step=start_step,
+                end_step=end_step,
+                callback=callback,
+            )
 
     # -- step-granular carry hooks (serve/stepbatch.py; see mixin doc) ----
     def step_carry_init(self, latents, num_inference_steps):
@@ -1187,8 +1205,15 @@ class DistriSDXLPipeline(_DistriPipelineBase):
         n_br = 2 if cfg.do_classifier_free_guidance else 1
         b = len(prompts)
 
-        ids1 = _tokenize(self.tokenizers[0], texts)
-        ids2 = _tokenize(self.tokenizers[1], texts)
+        with span("distri.pipe.tokenize"):
+            ids1 = _tokenize(self.tokenizers[0], texts)
+            ids2 = _tokenize(self.tokenizers[1], texts)
+        with span("distri.pipe.encode"):
+            return self._encode_ids(ids1, ids2, n_br, b, micro_cond)
+
+    def _encode_ids(self, ids1, ids2, n_br, b, micro_cond):
+        """Enqueue both CLIP towers and build the conditioning from them."""
+        cfg = self.distri_config
         out1 = self._clip(0, ids1)
         out2 = self._clip(1, ids2)
         # SDXL conditioning: concat penultimate hidden states of both encoders
@@ -1325,10 +1350,12 @@ class DistriSDPipeline(_DistriPipelineBase):
         texts = negs + prompts if cfg.do_classifier_free_guidance else prompts
         n_br = 2 if cfg.do_classifier_free_guidance else 1
         b = len(prompts)
-        ids = _tokenize(self.tokenizers[0], texts)
-        out = self._clip(0, ids)
-        emb = out["last_hidden_state"]
-        return emb.reshape(n_br, b, *emb.shape[1:]), None
+        with span("distri.pipe.tokenize"):
+            ids = _tokenize(self.tokenizers[0], texts)
+        with span("distri.pipe.encode"):
+            out = self._clip(0, ids)
+            emb = out["last_hidden_state"]
+            return emb.reshape(n_br, b, *emb.shape[1:]), None
 
 
 class DistriPixArtPipeline(_GenerationMixin):
@@ -1478,53 +1505,53 @@ class DistriPixArtPipeline(_GenerationMixin):
         n_br = 2 if cfg.do_classifier_free_guidance else 1
         b = len(prompts)
         t5cfg, t5p = self.t5
-        if t5p is None:
-            # weight-free smoke path: deterministic pseudo-embeddings, so the
-            # random-weight runners still exercise the full pipeline surface
-            if isinstance(self.tokenizer, SimpleTokenizer):
-                ids = np.asarray(self.tokenizer(texts, self.max_token_length))
+        with span("distri.pipe.tokenize"):
+            ids, mask = self._caption_tokens(texts)
+        with span("distri.pipe.encode"):
+            if t5p is None:
+                # weight-free smoke path: deterministic pseudo-embeddings,
+                # so the random-weight runners still exercise the full
+                # pipeline surface
+                emb = jnp.stack([
+                    jax.random.normal(
+                        jax.random.PRNGKey(int(s) % (2**31)),
+                        (ids.shape[1], self.dit_config.caption_dim),
+                        jnp.float32,
+                    )
+                    for s in ids.sum(axis=1)
+                ])
+                mask = np.ones(ids.shape, np.float32)
             else:
-                # explicit max_length: tok.model_max_length is 512 (or unset
-                # = effectively unbounded) for T5 tokenizers; the pipeline
-                # contract is 120 caption tokens
-                out = self.tokenizer(
-                    texts, padding="max_length",
-                    max_length=self.max_token_length, truncation=True,
-                    return_tensors="np",
+                emb = self._t5_jitted(
+                    t5p, jnp.asarray(ids, jnp.int32), jnp.asarray(mask)
                 )
-                ids = np.asarray(out["input_ids"])
-            emb = jnp.stack([
-                jax.random.normal(
-                    jax.random.PRNGKey(int(s) % (2**31)),
-                    (ids.shape[1], self.dit_config.caption_dim), jnp.float32,
-                )
-                for s in ids.sum(axis=1)
-            ])
-            mask = np.ones(ids.shape, np.float32)
-        else:
-            if isinstance(self.tokenizer, SimpleTokenizer):
-                ids = self.tokenizer(texts, self.max_token_length)
-                # real tokens + the first (sentinel) EOS are attended, like a
-                # transformers T5 attention_mask; the eos-padding tail is not
-                mask = (ids != self.tokenizer.eos).astype(np.float32)
-                first_eos = np.argmax(ids == self.tokenizer.eos, axis=1)
-                mask[np.arange(len(ids)), first_eos] = 1.0
-            else:
-                out = self.tokenizer(
-                    texts, padding="max_length",
-                    max_length=self.max_token_length, truncation=True,
-                    return_tensors="np",
-                )
-                ids = np.asarray(out["input_ids"])
-                mask = np.asarray(out["attention_mask"], np.float32)
-            emb = self._t5_jitted(
-                t5p, jnp.asarray(ids, jnp.int32), jnp.asarray(mask)
-            )
-        emb = jnp.asarray(emb)
-        emb = emb.reshape(n_br, b, emb.shape[1], emb.shape[2])
-        mask = jnp.asarray(np.asarray(mask).reshape(n_br, b, -1))
-        return emb, mask
+            emb = jnp.asarray(emb)
+            emb = emb.reshape(n_br, b, emb.shape[1], emb.shape[2])
+            mask = jnp.asarray(np.asarray(mask).reshape(n_br, b, -1))
+            return emb, mask
 
+    def _caption_tokens(self, texts):
+        """(ids, attention mask) of the caption tokenizer."""
+        if isinstance(self.tokenizer, SimpleTokenizer):
+            ids = np.asarray(self.tokenizer(texts, self.max_token_length))
+            # real tokens + the first (sentinel) EOS are attended, like a
+            # transformers T5 attention_mask; the eos-padding tail is not
+            mask = (ids != self.tokenizer.eos).astype(np.float32)
+            first_eos = np.argmax(ids == self.tokenizer.eos, axis=1)
+            mask[np.arange(len(ids)), first_eos] = 1.0
+            return ids, mask
+        # explicit max_length: tok.model_max_length is 512 (or unset =
+        # effectively unbounded) for T5 tokenizers; the pipeline contract
+        # is 120 caption tokens
+        out = self.tokenizer(
+            texts, padding="max_length",
+            max_length=self.max_token_length, truncation=True,
+            return_tensors="np",
+        )
+        return (np.asarray(out["input_ids"]),
+                np.asarray(out["attention_mask"], np.float32))
+
+    @phased("distri.pipe.dispatch", stage="dispatch")
     def __call__(
         self,
         prompt: str | List[str],
@@ -1573,11 +1600,12 @@ class DistriPixArtPipeline(_GenerationMixin):
     def _denoise_chunk(self, enc, latents, guidance_scale,
                        num_inference_steps, *, callback=None):
         emb, mask = enc
-        return self.runner.generate(
-            latents, emb, guidance_scale=guidance_scale,
-            num_inference_steps=num_inference_steps, cap_mask=mask,
-            callback=callback,
-        )
+        with span("distri.pipe.denoise"):
+            return self.runner.generate(
+                latents, emb, guidance_scale=guidance_scale,
+                num_inference_steps=num_inference_steps, cap_mask=mask,
+                callback=callback,
+            )
 
     # -- step-granular carry hooks (serve/stepbatch.py) -------------------
     def step_carry_init(self, latents, num_inference_steps):
@@ -1844,45 +1872,50 @@ class DistriSD3Pipeline(_GenerationMixin):
         n_br = 2 if cfg.do_classifier_free_guidance else 1
         b = len(prompts)
 
-        clip_states, pooleds = [], []
-        for which in range(2):
-            ids = _tokenize(self.tokenizers[which], texts)
-            out = self._clip_jitted[which](
-                self.text_encoders[which][1], np.asarray(ids))
-            clip_states.append(out["hidden_states"][-2])
-            pooleds.append(out.get("text_embeds", out["pooler_output"]))
-        clip_emb = jnp.concatenate(clip_states, axis=-1)
-        pad = mcfg.joint_attention_dim - clip_emb.shape[-1]
-        clip_emb = jnp.pad(clip_emb, ((0, 0), (0, 0), (0, pad)))
-        pooled = jnp.concatenate(pooleds, axis=-1)
-
         t5cfg, t5p = self.t5
-        if t5p is None:
-            t5_emb = jnp.zeros(
-                (clip_emb.shape[0], self.max_t5_tokens,
-                 mcfg.joint_attention_dim), clip_emb.dtype,
-            )
-        else:
-            tok = self.tokenizers[2]
-            if isinstance(tok, SimpleTokenizer):
-                ids = tok(texts, self.max_t5_tokens)
-                mask = (ids != tok.eos).astype(np.float32)
-                first_eos = np.argmax(ids == tok.eos, axis=1)
-                mask[np.arange(len(ids)), first_eos] = 1.0
+        with span("distri.pipe.tokenize"):
+            clip_ids = [_tokenize(self.tokenizers[which], texts)
+                        for which in range(2)]
+            if t5p is not None:
+                tok = self.tokenizers[2]
+                if isinstance(tok, SimpleTokenizer):
+                    ids = tok(texts, self.max_t5_tokens)
+                    mask = (ids != tok.eos).astype(np.float32)
+                    first_eos = np.argmax(ids == tok.eos, axis=1)
+                    mask[np.arange(len(ids)), first_eos] = 1.0
+                else:
+                    out = tok(texts, padding="max_length",
+                              max_length=self.max_t5_tokens, truncation=True,
+                              return_tensors="np")
+                    ids = np.asarray(out["input_ids"])
+                    mask = np.asarray(out["attention_mask"], np.float32)
+        with span("distri.pipe.encode"):
+            clip_states, pooleds = [], []
+            for which in range(2):
+                out = self._clip_jitted[which](
+                    self.text_encoders[which][1],
+                    np.asarray(clip_ids[which]))
+                clip_states.append(out["hidden_states"][-2])
+                pooleds.append(out.get("text_embeds", out["pooler_output"]))
+            clip_emb = jnp.concatenate(clip_states, axis=-1)
+            pad = mcfg.joint_attention_dim - clip_emb.shape[-1]
+            clip_emb = jnp.pad(clip_emb, ((0, 0), (0, 0), (0, pad)))
+            pooled = jnp.concatenate(pooleds, axis=-1)
+            if t5p is None:
+                t5_emb = jnp.zeros(
+                    (clip_emb.shape[0], self.max_t5_tokens,
+                     mcfg.joint_attention_dim), clip_emb.dtype,
+                )
             else:
-                out = tok(texts, padding="max_length",
-                          max_length=self.max_t5_tokens, truncation=True,
-                          return_tensors="np")
-                ids = np.asarray(out["input_ids"])
-                mask = np.asarray(out["attention_mask"], np.float32)
-            t5_emb = self._t5_jitted(
-                t5p, jnp.asarray(ids, jnp.int32), jnp.asarray(mask))
-        enc = jnp.concatenate([clip_emb, t5_emb.astype(clip_emb.dtype)],
-                              axis=1)
-        enc = enc.reshape(n_br, b, *enc.shape[1:])
-        pooled = pooled.reshape(n_br, b, -1)
-        return enc, pooled
+                t5_emb = self._t5_jitted(
+                    t5p, jnp.asarray(ids, jnp.int32), jnp.asarray(mask))
+            enc = jnp.concatenate([clip_emb, t5_emb.astype(clip_emb.dtype)],
+                                  axis=1)
+            enc = enc.reshape(n_br, b, *enc.shape[1:])
+            pooled = pooled.reshape(n_br, b, -1)
+            return enc, pooled
 
+    @phased("distri.pipe.dispatch", stage="dispatch")
     def __call__(
         self,
         prompt: str | List[str],
@@ -1949,12 +1982,13 @@ class DistriSD3Pipeline(_GenerationMixin):
     def _denoise_chunk(self, enc, latents, guidance_scale,
                        num_inference_steps, *, start_step=0, callback=None):
         emb, pooled = enc
-        return self.runner.generate(
-            latents, emb, pooled, guidance_scale=guidance_scale,
-            num_inference_steps=num_inference_steps,
-            start_step=start_step,
-            callback=callback,
-        )
+        with span("distri.pipe.denoise"):
+            return self.runner.generate(
+                latents, emb, pooled, guidance_scale=guidance_scale,
+                num_inference_steps=num_inference_steps,
+                start_step=start_step,
+                callback=callback,
+            )
 
     # -- step-granular carry hooks (serve/stepbatch.py) -------------------
     def step_carry_init(self, latents, num_inference_steps):

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from ..utils.trace import phases, span
 from .cache import ExecKey
 from .errors import DegradationInapplicableError
 from .faults import FaultPlan
@@ -149,11 +150,12 @@ class PipelineExecutor:
 
         cfg = self.pipeline.distri_config
         shape = (cfg.latent_height, cfg.latent_width, self._in_channels())
-        keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-        lats = jax.vmap(
-            lambda k: jax.random.normal(k, shape, jnp.float32)
-        )(keys)
-        return lats * self.pipeline.scheduler.init_noise_sigma
+        with span("distri.pipe.latents"):
+            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+            lats = jax.vmap(
+                lambda k: jax.random.normal(k, shape, jnp.float32)
+            )(keys)
+            return lats * self.pipeline.scheduler.init_noise_sigma
 
     def _pad_batch(self, prompts, negative_prompts, seeds):
         """Pad to the compiled batch width by repeating the tail (same
@@ -202,31 +204,44 @@ class PipelineExecutor:
                                seeds)
 
     def _run_batch(self, prompts, negative_prompts, guidance_scale, seeds):
-        if self.prompt_cache is not None:
-            # cached-encode path: the stage programs run serially (see
-            # attach_prompt_cache) so the memoized embeddings slot in
-            work = self.encode_stage(prompts, negative_prompts, seeds)
-            work = self.denoise_stage(work, guidance_scale)
-            return self.decode_stage(work)
-        prompts, negative_prompts, seeds, n_real = self._pad_batch(
-            prompts, negative_prompts, seeds)
-        bs = self.batch_size
-        # A batch wider than the compiled width (batcher max_batch_size >
-        # pipeline batch_size) runs as several exactly-bs invocations of the
-        # same cached program — never a retrace, never a contract error.
-        latents = self._draw_latents(seeds)
-        images: List[Any] = []
-        for i in range(0, len(prompts), bs):
-            out = self.pipeline.generate_batch(
-                prompts[i:i + bs],
-                negative_prompts[i:i + bs],
-                num_inference_steps=self.steps,
-                guidance_scale=guidance_scale,
-                latents=latents[i:i + bs],
-                output_type="np",
-            )
-            images.extend(out.images)
-        return images[:n_real]
+        with span("distri.exec.run", rows=len(prompts)):
+            if self.prompt_cache is not None:
+                # cached-encode path: the stage programs run serially (see
+                # attach_prompt_cache) so the memoized embeddings slot in.
+                # Each blocks on its own output, so ``dispatch`` here holds
+                # the encode and denoise waits too
+                with phases("distri.pipe.dispatch", stage="dispatch"):
+                    work = self.encode_stage(prompts, negative_prompts,
+                                             seeds)
+                    work = self.denoise_stage(work, guidance_scale)
+                    return self.decode_stage(work)
+            prompts, negative_prompts, seeds, n_real = self._pad_batch(
+                prompts, negative_prompts, seeds)
+            bs = self.batch_size
+            # A batch wider than the compiled width (batcher max_batch_size
+            # > pipeline batch_size) runs as several exactly-bs invocations
+            # of the same cached program — never a retrace, never a
+            # contract error.
+            latents = None
+            images: List[Any] = []
+            for i in range(0, len(prompts), bs):
+                # one dispatch -> wait_device -> to_host -> post sequence a
+                # chunk, opened here so that it holds the latent draw and
+                # lasts until the images are this executor's (the
+                # pipeline's __call__ and decode tail join it)
+                with phases("distri.pipe.dispatch", stage="dispatch"):
+                    if latents is None:
+                        latents = self._draw_latents(seeds)
+                    out = self.pipeline.generate_batch(
+                        prompts[i:i + bs],
+                        negative_prompts[i:i + bs],
+                        num_inference_steps=self.steps,
+                        guidance_scale=guidance_scale,
+                        latents=latents[i:i + bs],
+                        output_type="np",
+                    )
+                    images.extend(out.images)
+            return images[:n_real]
 
     def warm(self) -> None:
         """Compile everything this executor will dispatch — text encoders,
@@ -356,14 +371,16 @@ class PipelineExecutor:
         prompts, negs, seeds, _ = self._pad_batch(
             [prompt], [negative_prompt], [seed])
         bs = self.batch_size
-        enc = self._encode_chunk(stages, prompts[:bs], negs[:bs])
-        latents = self._draw_latents(seeds[:bs])
         # __call__ forces guidance_scale to 1 when CFG is off; the step
         # path applies the same normalization for identity (the exact
         # rule prepare_stages' denoise program uses)
         cfg_on = pipe.distri_config.do_classifier_free_guidance
-        carry = pipe.step_carry_init(latents, self.steps)
-        jax.block_until_ready(jax.tree_util.tree_leaves((carry[0], latents)))
+        with span("distri.step.begin", stage="begin"):
+            enc = self._encode_chunk(stages, prompts[:bs], negs[:bs])
+            latents = self._draw_latents(seeds[:bs])
+            carry = pipe.step_carry_init(latents, self.steps)
+            jax.block_until_ready(
+                jax.tree_util.tree_leaves((carry[0], latents)))
         return {
             "carry": carry,
             "enc": enc,
@@ -433,16 +450,20 @@ class PipelineExecutor:
 
     def _step_solo_one(self, work: Dict[str, Any]) -> None:
         """One sequential-legacy step: the pre-pack per-slot dispatch."""
-        self._step_ensure_solo(work)
-        work["carry"] = self.pipeline.step_carry_step(
-            work["carry"], work["i"], work["enc"], work["gs"], self.steps)
+        with span("distri.step.run", stage="steps", rows=1,
+                  signature="solo"):
+            self._step_ensure_solo(work)
+            work["carry"] = self.pipeline.step_carry_step(
+                work["carry"], work["i"], work["enc"], work["gs"],
+                self.steps)
         work["i"] += 1
         stats = self.step_pack_stats
         stats["dispatches"] += 1
         stats["packed_rows"] += 1
         stats["rows_capacity"] += self.batch_size
 
-    def _step_dispatch_packed(self, members: List[Dict[str, Any]]) -> None:
+    def _step_dispatch_packed(self, members: List[Dict[str, Any]],
+                              signature) -> None:
         """Advance a same-signature group in ONE compiled dispatch:
         member r's real row rides batch row r of a shared packed carry,
         its step index and guidance scale ride [B] vectors.  Fast path:
@@ -465,31 +486,36 @@ class PipelineExecutor:
             and all(m["carry"] is members[0]["carry"] for m in members)
             and sorted(m.get("row", 0) for m in members) == list(range(n))
         )
-        if fast:
-            members = sorted(members, key=lambda m: m["row"])
-            carry, enc, grp = members[0]["carry"], grp0["enc"], grp0
-        else:
-            axes = self._step_axes(members[0])
-            if axes is None:
-                for m in members:
-                    self._step_solo_one(m)
-                return
-            try:
-                carry = rowpack.pack_rows(
-                    [m["carry"] for m in members],
-                    [m.get("row", 0) for m in members], axes, bs)
-            except rowpack.AmbiguousPackAxisError:
-                for m in members:
-                    self._step_solo_one(m)
-                return
-            enc = pipe.step_carry_pack_enc([m["enc"] for m in members], bs)
-            grp = {"axes": axes, "n": n, "enc": enc}
-        i_rows = [m["i"] for m in members]
-        gs_rows = [float(m["gs"]) for m in members]
-        i_rows += [i_rows[-1]] * (bs - n)
-        gs_rows += [gs_rows[-1]] * (bs - n)
-        new_carry = pipe.step_carry_step_rows(carry, i_rows, enc, gs_rows,
-                                              self.steps)
+        axes = None if fast else self._step_axes(members[0])
+        packed = fast or axes is not None
+        # the repack is part of what a packed dispatch costs the host
+        with span("distri.step.run", stage="steps", rows=n,
+                  signature=str(signature)):
+            if fast:
+                members = sorted(members, key=lambda m: m["row"])
+                carry, enc, grp = members[0]["carry"], grp0["enc"], grp0
+            elif packed:
+                try:
+                    carry = rowpack.pack_rows(
+                        [m["carry"] for m in members],
+                        [m.get("row", 0) for m in members], axes, bs)
+                except rowpack.AmbiguousPackAxisError:
+                    packed = False
+                else:
+                    enc = pipe.step_carry_pack_enc(
+                        [m["enc"] for m in members], bs)
+                    grp = {"axes": axes, "n": n, "enc": enc}
+            if packed:
+                i_rows = [m["i"] for m in members]
+                gs_rows = [float(m["gs"]) for m in members]
+                i_rows += [i_rows[-1]] * (bs - n)
+                gs_rows += [gs_rows[-1]] * (bs - n)
+                new_carry = pipe.step_carry_step_rows(
+                    carry, i_rows, enc, gs_rows, self.steps)
+        if not packed:
+            for m in members:
+                self._step_solo_one(m)
+            return
         for r, m in enumerate(members):
             m["carry"] = new_carry
             m["row"] = r
@@ -521,7 +547,7 @@ class PipelineExecutor:
         self.step_pack_stats = {"dispatches": 0, "packed_rows": 0,
                                 "rows_capacity": 0}
         bs = self.batch_size
-        groups: List[List[Dict[str, Any]]] = []
+        groups: List[tuple] = []  # (signature, members)
         solos: List[Dict[str, Any]] = []
         open_group: Dict[Any, List[Dict[str, Any]]] = {}
         for w in works:
@@ -533,16 +559,17 @@ class PipelineExecutor:
             if g is None or len(g) >= bs:
                 g = []
                 open_group[sig] = g
-                groups.append(g)
+                groups.append((sig[1], g))
             g.append(w)
         for w in solos:
             self._step_solo_one(w)
-        for members in groups:
+        for signature, members in groups:
             if len(members) == 1:
                 self._step_solo_one(members[0])
             else:
-                self._step_dispatch_packed(members)
-        jax.block_until_ready([w["carry"][0] for w in works])
+                self._step_dispatch_packed(members, signature)
+        with span("distri.step.wait", stage="steps"):
+            jax.block_until_ready([w["carry"][0] for w in works])
 
     def step_done(self, work: Dict[str, Any]) -> bool:
         return work["i"] >= self.steps
@@ -552,8 +579,9 @@ class PipelineExecutor:
         work's own packed row (row 0 in the solo layout)."""
         stages = self.prepare_stages()
         pipe = self.pipeline
-        latent = pipe.step_carry_latent(work["carry"])
-        images = stages.decode(latent)
+        with span("distri.step.finish", stage="finish"):
+            latent = pipe.step_carry_latent(work["carry"])
+            images = stages.decode(latent)
         row = work.get("row", 0)
         grp = work.pop("pack", None)
         carry = work.pop("carry")

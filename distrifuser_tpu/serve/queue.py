@@ -35,7 +35,7 @@ import dataclasses
 import itertools
 import time
 from concurrent.futures import Future
-from typing import Any, Callable, List, Optional
+from typing import Any, Callable, List, Mapping, Optional
 
 # Historical home of these errors — re-exported so `from .queue import
 # QueueFullError` keeps working; the full typed hierarchy (Retryable vs
@@ -154,6 +154,15 @@ class ServeResult:
     # steps the fleet did NOT re-execute after a replica kill/drain.
     migrations: int = 0
     steps_salvaged: int = 0
+    # stage clocks of the dispatch that served the request, seconds on the
+    # server's clock (utils/trace.py `span` sites; docs/OBSERVABILITY.md).
+    # Fixed keys per server kind - whole-batch: dispatch, device_wait,
+    # to_host, post (their sum <= execute_s; the rest is the hand-off to
+    # and from the watchdog's thread); staged: encode, denoise, decode;
+    # step mode: begin (before admission, so inside queue_wait_s), steps,
+    # finish.  A request served in a batch carries its batch's clocks; an
+    # executor that enters no spans (the fakes) leaves them 0.0.
+    stage_s: Mapping[str, float] = dataclasses.field(default_factory=dict)
 
 
 class RequestQueue:

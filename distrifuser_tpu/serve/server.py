@@ -51,7 +51,7 @@ from typing import Any, Callable, Dict, List, Optional
 from ..utils import sync
 from ..utils.config import ServeConfig
 from ..utils.metrics import Counter, MetricsRegistry
-from ..utils.trace import RequestTrace, Tracer
+from ..utils.trace import RequestTrace, Scope, Tracer, span, span_ids
 from .batcher import BatchKey, BucketTable, MicroBatcher
 from .cache import ExecKey, ExecutorCache
 from .errors import (
@@ -1044,6 +1044,8 @@ class InferenceServer:
                 invalidate=False)
             return False
         snap = req.carry_snapshot
+        scope = Scope(self.clock, self.STEP_STAGE_CLOCKS,
+                      **span_ids([req]))
         try:
             if snap is not None:
                 # carry migration import: the snapshot's envelope and
@@ -1055,12 +1057,15 @@ class InferenceServer:
                     raise MigrationRejectedError(
                         f"executor for {ekey.short()} has no step_import "
                         "— cannot adopt a migrated carry")
-                work = executor.step_import(
-                    snap.meta, list(snap.leaves), req.prompt,
-                    req.negative_prompt, req.seed, req.guidance_scale)
+                with scope:
+                    work = executor.step_import(
+                        snap.meta, list(snap.leaves), req.prompt,
+                        req.negative_prompt, req.seed, req.guidance_scale)
             else:
-                work = executor.step_begin(req.prompt, req.negative_prompt,
-                                           req.seed, req.guidance_scale)
+                with scope:
+                    work = executor.step_begin(
+                        req.prompt, req.negative_prompt, req.seed,
+                        req.guidance_scale)
         except MigrationRejectedError as exc:
             # a bad snapshot is the SNAPSHOT's failure, not this
             # replica's: fail typed without feeding the breaker/ladder —
@@ -1088,7 +1093,7 @@ class InferenceServer:
             executor=executor, compile_hit=hit, steps_total=ekey.steps,
             tier_idx=tier_idx, admit_ts=self.clock(),
             steps_done=salvaged, steps_salvaged=salvaged,
-            migrations=1 if snap is not None else 0,
+            migrations=1 if snap is not None else 0, scope=scope,
         )
         slot = sb.admit(state)
         self._inflight_c.inc("requests", 1)
@@ -1239,12 +1244,19 @@ class InferenceServer:
             ekey = members[0].ekey
             base_key = members[0].base_key
             works = [m.work for m in members]
+            # the round's clock: what this group's dispatches and their
+            # wait took, charged to every member that rode it
+            round_scope = Scope(
+                self.clock, ("steps",),
+                **span_ids([m.request for m in members]))
 
-            def call(_ex=executor, _works=works, _ekey=ekey):
-                if self.fault_plan is not None:
-                    self.fault_plan.check("execute", key=_ekey,
-                                          batch_size=len(_works))
-                _ex.step_run(_works)
+            def call(_ex=executor, _works=works, _ekey=ekey,
+                     _scope=round_scope):
+                with _scope:
+                    if self.fault_plan is not None:
+                        self.fault_plan.check("execute", key=_ekey,
+                                              batch_size=len(_works))
+                    _ex.step_run(_works)
 
             wd = self.resilience.watchdog
             prev_abandoned = wd.abandoned_event
@@ -1300,6 +1312,7 @@ class InferenceServer:
             self.resilience.on_success(base_key)
             for m in members:
                 m.steps_done += 1
+                m.scope.stage_s["steps"] += round_scope.stage_s["steps"]
                 stepped.append(m)
             self.counters.inc("steps_executed", len(members))
             # pack-efficiency accounting (serve/executors.py step_run):
@@ -1390,7 +1403,8 @@ class InferenceServer:
             if state.steps_done < state.steps_total:
                 continue
             try:
-                out = state.executor.step_finish(state.work)
+                with state.scope:
+                    out = state.executor.step_finish(state.work)
             except Exception as exc:  # noqa: BLE001 — typed fail
                 texc = exc if isinstance(exc, ServeError) else (
                     ExecuteFailedError(
@@ -1457,6 +1471,7 @@ class InferenceServer:
             preempts=state.preempts,
             migrations=state.migrations,
             steps_salvaged=state.steps_salvaged,
+            stage_s=dict(state.scope.stage_s),
         )
         self._step_release(state, abort=False)
         self._resolve(req.future, result=result)
@@ -1542,8 +1557,22 @@ class InferenceServer:
 
     # -- the resilient execute path ---------------------------------------
 
+    # stage clocks a whole-batch dispatch keeps (`ServeResult.stage_s`; the
+    # staged pipeline keeps staging.STAGES, step mode STEP_STAGE_CLOCKS)
+    STAGE_CLOCKS = ("dispatch", "device_wait", "to_host", "post")
+    STEP_STAGE_CLOCKS = ("begin", "steps", "finish")
+
     def _execute(self, key: BatchKey, batch: List[Request]) -> None:
         dispatch_ts = self.clock()
+        # the ids ride a scope with no clocks, so every scheduler-thread
+        # span of the dispatch below carries them
+        with Scope(self.clock, (), **span_ids(batch)), \
+                span("distri.serve.batch", n=len(batch),
+                     bucket=f"{key.height}x{key.width}") as batch_ann:
+            self._execute_batch(key, batch, dispatch_ts, batch_ann)
+
+    def _execute_batch(self, key: BatchKey, batch: List[Request],
+                       dispatch_ts: float, batch_ann: span) -> None:
         # staged outcomes that landed while this batch was forming must
         # reach the breaker/ladder BEFORE the allow()/routing decisions
         self._drain_staged_outcomes()
@@ -1561,6 +1590,7 @@ class InferenceServer:
             tier_idx, tier = self.controller.tier_for_batch(
                 [r.slo_class for r in batch])
             base_key = apply_tier(base_key, tier)
+        batch_ann.set(key=base_key.short())
         batch_span = None
         if self.tracer is not None:
             targs = {"bucket": f"{key.height}x{key.width}",
@@ -1710,6 +1740,7 @@ class InferenceServer:
             sb.batch_key, sb.ekey, sb.requests, outputs, sb.dispatch_ts,
             t0, t1, sb.compile_hit, retries=0, degradations=degradations,
             shallow_steps=shallow, tier=sb.tier,
+            stage_s=sb.scope.stage_s,
         )
 
     def _staged_failure(self, sb, exc: Exception) -> None:
@@ -1755,7 +1786,11 @@ class InferenceServer:
         hierarchy (`BuildFailedError`; message keeps the OOM shape
         visible when the compile itself exhausted memory)."""
         try:
-            return self.cache.get(ekey)
+            with span("distri.serve.get_executor",
+                      key=ekey.short()) as lookup:
+                executor, hit = self.cache.get(ekey)
+                lookup.set(hit=hit)
+            return executor, hit
         except ServeError:
             raise
         except Exception as exc:
@@ -1772,16 +1807,29 @@ class InferenceServer:
         prompts = [r.prompt for r in batch]
         negs = [r.negative_prompt for r in batch]
         seeds = [r.seed for r in batch]
+        # the dispatch's clocks and ids, ambient on the thread the
+        # watchdog runs the executor on
+        scope = Scope(
+            self.clock, self.STAGE_CLOCKS, tracer=self.tracer,
+            tracer_args={"traces": [r.trace.trace_id for r in batch
+                                    if r.trace is not None]},
+            **span_ids(batch))
         t0 = self.clock()
 
         def call():
-            if self.fault_plan is not None:
-                self.fault_plan.check("execute", key=ekey,
-                                      batch_size=len(batch))
-            return executor(prompts, negs, key.guidance_scale, seeds)
+            with scope:
+                if self.fault_plan is not None:
+                    self.fault_plan.check("execute", key=ekey,
+                                          batch_size=len(batch))
+                return executor(prompts, negs, key.guidance_scale, seeds)
 
         try:
-            outputs = self.resilience.watchdog.run(call)
+            # ONE span on the scheduler thread over the whole wait: thread
+            # start, the worker's `distri.exec.run`, the wake-up.  The
+            # hand-off is this span less that one (= execute_s less the
+            # stage clocks)
+            with span("distri.serve.handoff"):
+                outputs = self.resilience.watchdog.run(call)
         except WatchdogTimeoutError:
             self.counters.inc("watchdog_timeouts")
             raise
@@ -1807,7 +1855,7 @@ class InferenceServer:
                 f"executor returned {len(outputs)} outputs for a batch of "
                 f"{len(batch)}"
             )
-        return outputs, t0, t1
+        return outputs, t0, t1, scope.stage_s
 
     def _execute_resilient(self, key: BatchKey, base_key: ExecKey,
                            batch: List[Request], dispatch_ts: float,
@@ -1827,7 +1875,8 @@ class InferenceServer:
             ekey = res.degraded_key(base_key)
             try:
                 executor, hit = self._get_executor(ekey)
-                outputs, t0, t1 = self._dispatch(ekey, key, executor, batch)
+                outputs, t0, t1, stage_s = self._dispatch(
+                    ekey, key, executor, batch)
             except FatalError as exc:
                 res.on_failure(base_key, exc)
                 self.counters.inc("failed_fatal", len(batch))
@@ -1916,7 +1965,7 @@ class InferenceServer:
                 retries=attempts,
                 degradations=tuple(res.key_state(base_key).rungs),
                 shallow_steps=int(getattr(executor, "shallow_steps", 0)),
-                tier=tier_idx,
+                tier=tier_idx, stage_s=stage_s,
             )
             return
 
@@ -1924,78 +1973,83 @@ class InferenceServer:
                         batch: List[Request], outputs, dispatch_ts: float,
                         t0: float, t1: float, hit: bool, *, retries: int,
                         degradations: tuple, shallow_steps: int,
-                        tier: Optional[int] = None) -> None:
+                        tier: Optional[int] = None,
+                        stage_s: Optional[Dict[str, float]] = None) -> None:
         """Per-request success bookkeeping shared by the monolithic and
         staged dispatch paths: counters, latency histograms, and future
         resolution.  Thread-safe (staged batches complete on the decode
-        worker while the scheduler thread completes monolithic ones)."""
-        self.counters.inc("batches")
-        # tier pinning (ServeResult audit trail): resolve the tier index
-        # to its name once per batch — None when the controller is off
-        tier_name = (self.controller.tiers[tier].name
-                     if tier is not None and self.controller is not None
-                     else None)
-        ekey_short = ekey.short()
-        if self.controller is not None:
-            # calibrate the controller's forward model: one cost-
-            # normalized batch-service observation per completed batch
-            self.controller.observe_batch(tier, t1 - t0)
-        self.counters.inc("requests_compile_hit" if hit
-                          else "requests_compile_miss", len(batch))
-        self._batch_sizes.inc(f"size_{len(batch)}")
-        exec_s = t1 - t0
-        # shallow-step share: how much of the mesh time the step cache
-        # saved from full network evaluations (0 when the cache is off)
-        self.counters.inc("denoise_steps_total", key.steps * len(batch))
-        if shallow_steps:
-            self.counters.inc("denoise_steps_shallow",
-                              shallow_steps * len(batch))
-        for req, out in zip(batch, outputs):
-            queue_wait = dispatch_ts - req.enqueue_ts
-            e2e = t1 - req.enqueue_ts
-            self.hist_queue_wait.observe(queue_wait)
-            self.hist_execute.observe(exec_s)
-            self.hist_e2e.observe(e2e)
-            self.slo_window(req.slo_class).observe(e2e)
-            self._tenant_observe(req, queue_wait)
-            self.counters.inc("completed")
-            if req.expired(t1):
-                # deadline lapsed while IN FLIGHT: deadlines gate
-                # scheduling, never abandon mesh work — the caller
-                # still gets the result, and the lateness is counted
-                self.counters.inc("completed_late")
-            if req.trace is not None and self.tracer is not None:
-                rt = req.trace
-                self.tracer.complete(
-                    "execute", t0, t1, track=rt.track, trace=rt.trace_id,
-                    parent=rt.root,
-                    args={"bucket": f"{ekey.height}x{ekey.width}",
-                          "batch_size": len(batch), "compile_hit": hit})
-                if rt.flow_id is not None:
-                    # finish the batch->member flow arrow inside the
-                    # execute slice
-                    self.tracer.flow(rt.flow_id, "f", track=rt.track,
-                                     t=t0, name="member")
-                self._trace_finish(req, "completed", args={
-                    "retries": retries,
-                    "degradations": list(degradations),
-                    "batch_size": len(batch)})
-            self._resolve(req.future, result=ServeResult(
-                request_id=req.request_id,
-                output=out,
-                bucket=(ekey.height, ekey.width),
-                requested_size=(req.height, req.width),
-                queue_wait_s=queue_wait,
-                execute_s=exec_s,
-                e2e_s=e2e,
-                batch_size=len(batch),
-                compile_hit=hit,
-                retries=retries,
-                degradations=degradations,
-                exec_key=ekey_short,
-                tier=tier_name,
-                replica=self.replica_name,
-            ))
+        worker while the scheduler thread completes monolithic ones).
+        ``stage_s`` is the dispatch's stage clocks: every request of a
+        batch carries its batch's."""
+        with span("distri.serve.complete", **span_ids(batch)):
+            self.counters.inc("batches")
+            # tier pinning (ServeResult audit trail): resolve the tier index
+            # to its name once per batch — None when the controller is off
+            tier_name = (self.controller.tiers[tier].name
+                         if tier is not None and self.controller is not None
+                         else None)
+            ekey_short = ekey.short()
+            if self.controller is not None:
+                # calibrate the controller's forward model: one cost-
+                # normalized batch-service observation per completed batch
+                self.controller.observe_batch(tier, t1 - t0)
+            self.counters.inc("requests_compile_hit" if hit
+                              else "requests_compile_miss", len(batch))
+            self._batch_sizes.inc(f"size_{len(batch)}")
+            exec_s = t1 - t0
+            # shallow-step share: how much of the mesh time the step cache
+            # saved from full network evaluations (0 when the cache is off)
+            self.counters.inc("denoise_steps_total", key.steps * len(batch))
+            if shallow_steps:
+                self.counters.inc("denoise_steps_shallow",
+                                  shallow_steps * len(batch))
+            for req, out in zip(batch, outputs):
+                queue_wait = dispatch_ts - req.enqueue_ts
+                e2e = t1 - req.enqueue_ts
+                self.hist_queue_wait.observe(queue_wait)
+                self.hist_execute.observe(exec_s)
+                self.hist_e2e.observe(e2e)
+                self.slo_window(req.slo_class).observe(e2e)
+                self._tenant_observe(req, queue_wait)
+                self.counters.inc("completed")
+                if req.expired(t1):
+                    # deadline lapsed while IN FLIGHT: deadlines gate
+                    # scheduling, never abandon mesh work — the caller
+                    # still gets the result, and the lateness is counted
+                    self.counters.inc("completed_late")
+                if req.trace is not None and self.tracer is not None:
+                    rt = req.trace
+                    self.tracer.complete(
+                        "execute", t0, t1, track=rt.track, trace=rt.trace_id,
+                        parent=rt.root,
+                        args={"bucket": f"{ekey.height}x{ekey.width}",
+                              "batch_size": len(batch), "compile_hit": hit})
+                    if rt.flow_id is not None:
+                        # finish the batch->member flow arrow inside the
+                        # execute slice
+                        self.tracer.flow(rt.flow_id, "f", track=rt.track,
+                                         t=t0, name="member")
+                    self._trace_finish(req, "completed", args={
+                        "retries": retries,
+                        "degradations": list(degradations),
+                        "batch_size": len(batch)})
+                self._resolve(req.future, result=ServeResult(
+                    request_id=req.request_id,
+                    output=out,
+                    bucket=(ekey.height, ekey.width),
+                    requested_size=(req.height, req.width),
+                    queue_wait_s=queue_wait,
+                    execute_s=exec_s,
+                    e2e_s=e2e,
+                    batch_size=len(batch),
+                    compile_hit=hit,
+                    retries=retries,
+                    degradations=degradations,
+                    exec_key=ekey_short,
+                    tier=tier_name,
+                    replica=self.replica_name,
+                    stage_s=dict(stage_s or {}),
+                ))
 
     # -- observability -----------------------------------------------------
 

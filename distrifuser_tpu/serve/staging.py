@@ -68,6 +68,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..utils import sync
 from ..utils.metrics import Counter, GapTracker, LatencyHistogram
+from ..utils.trace import Scope, span, span_ids
 from .cache import ExecKey
 from .errors import (
     DeadlineExceededError,
@@ -92,7 +93,7 @@ class StagedBatch:
 
     __slots__ = ("batch_key", "base_key", "ekey", "requests",
                  "guidance_scale", "executor", "compile_hit", "dispatch_ts",
-                 "started_ts", "stage_ready_ts", "work", "tier")
+                 "started_ts", "stage_ready_ts", "work", "tier", "scope")
 
     def __init__(self, *, batch_key, base_key: ExecKey, ekey: ExecKey,
                  requests, executor, compile_hit: bool, dispatch_ts: float,
@@ -111,6 +112,9 @@ class StagedBatch:
         # SLO-controller tier index this batch dispatched at (None when
         # the controller is off) — rides to _complete_batch's calibration
         self.tier = tier
+        # the batch's stage clocks and span ids (utils/trace.py Scope),
+        # set by StagePipeline.submit and entered by each stage's worker
+        self.scope: Optional[Scope] = None
 
     @property
     def prompts(self) -> List[str]:
@@ -243,6 +247,8 @@ class StagePipeline:
                                                  self._inflight)
                         self.submitted += 1
                     sb.stage_ready_ts = self.clock()
+                    sb.scope = Scope(self.clock, STAGES,
+                                     **span_ids(sb.requests))
                     self._queues["encode"].put(sb)
                 return True
         return False
@@ -320,15 +326,21 @@ class StagePipeline:
         return wrapped
 
     def _stage_call(self, stage: str, sb: StagedBatch) -> Any:
+        # on the watchdog's worker thread, where the stage really runs:
+        # the span is the stage's service time less the thread hand-off,
+        # and adds to the batch's ``stage_s[stage]``
         ex = sb.executor
-        if stage == "encode":
-            return ex.encode_stage(sb.prompts, sb.negative_prompts, sb.seeds)
-        if stage == "denoise":
-            if self.fault_plan is not None:
-                self.fault_plan.check("execute", key=sb.ekey,
-                                      batch_size=len(sb.requests))
-            return ex.denoise_stage(sb.work, sb.guidance_scale)
-        return ex.decode_stage(sb.work)
+        with sb.scope, span(f"distri.stage.{stage}", stage=stage,
+                            n=len(sb.requests)):
+            if stage == "encode":
+                return ex.encode_stage(sb.prompts, sb.negative_prompts,
+                                       sb.seeds)
+            if stage == "denoise":
+                if self.fault_plan is not None:
+                    self.fault_plan.check("execute", key=sb.ekey,
+                                          batch_size=len(sb.requests))
+                return ex.denoise_stage(sb.work, sb.guidance_scale)
+            return ex.decode_stage(sb.work)
 
     def _worker(self, stage: str) -> None:
         q = self._queues[stage]

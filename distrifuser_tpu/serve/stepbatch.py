@@ -91,6 +91,9 @@ class SlotState:
     # `ServeResult.migrations` / ``steps_salvaged``.
     migrations: int = 0
     steps_salvaged: int = 0
+    # the request's stage clocks and span ids (utils/trace.py Scope):
+    # entered around step_begin / step_finish, fed by each round it rides
+    scope: Any = None
 
     @property
     def remaining(self) -> int:

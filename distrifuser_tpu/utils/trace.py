@@ -9,7 +9,12 @@ attempts, breaker/ladder events, per-stage execution, completion) as
 spans and instant events on named tracks, and `StepTimeline` records the
 per-denoise-step view inside one generation (wall time per step, tagged
 warmup/full/shallow, plus live comm-byte counters reconciled against the
-closed-form `pipelines.comm_plan`).
+closed-form `pipelines.comm_plan`).  `span` (further down) is the one
+primitive every layer boundary of the request path enters - serve ->
+executor -> pipeline stages, names prefixed ``distri.`` - writing to the
+profiler's trace (the device ops' own clock), to the `Tracer` when there
+is one, and to the stage clocks every `ServeResult` carries
+(docs/OBSERVABILITY.md has the table of names).
 
 Design constraints, in order:
 
@@ -23,10 +28,17 @@ Design constraints, in order:
   service that has traced a million requests still answers "what
   happened *lately*" in O(capacity) memory, with the drop count
   reported, never silent (`RingLog` convention).
-* **Zero cost when off** — the serve layer holds ``tracer = None`` when
-  tracing is disabled and guards every call site, so the tracing-off
-  request path executes no tracing code at all (the ≤2% serve_bench
-  overhead budget in ISSUE 8 is met by not running, not by being fast).
+* **A stated budget, not "zero when off"** — the `Tracer` exists only
+  when ``ObservabilityConfig.trace`` is on (the serve layer holds
+  ``tracer = None`` otherwise and guards its call sites), but `span`
+  below is entered on every request: a fixed number of entries per
+  dispatch — never per denoise step of a fused loop, never inside jitted
+  code, never per op.  On the whole-batch path that is <= 16 entries and
+  <= 10 clock reads a request (14 and 5 today, plus the two clock reads
+  `_dispatch` always made); an entry costs ~0.5 us with no profiler
+  session and ~1 us under one (this sandbox's CPU), < 0.001% of the
+  shortest image the benchmark serves.  PERF.md section 6 (PR 24) has the
+  chip's measurement of both sides.
 
 Export is the Chrome/Perfetto trace-event JSON format
 (``{"traceEvents": [...]}``, "X"/"B"/"i"/"s"/"f" phases): load the file
@@ -39,10 +51,15 @@ record, so the UI shows one swimlane per logical actor.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
+import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
+
 from . import sync
 
 # One synthetic process for the whole service; tracks are "threads".
@@ -276,6 +293,186 @@ class Tracer:
                           separators=(",", ":"))
                 f.write("\n")
         return payload
+
+
+# --------------------------------------------------------------------------
+# The span primitive: one call site, three sinks
+# --------------------------------------------------------------------------
+#
+# `span` is what every layer boundary of the request path enters (serve ->
+# executor -> pipeline stages; names prefixed ``distri.``).  One entry
+# writes to
+#
+# * the profiler: a `jax.profiler.TraceAnnotation`, live only while a
+#   profiler session is (the C++ TraceMe builds its name lazily and returns
+#   at once otherwise), so "tracing on" is "someone started the profiler" and
+#   the span lands in the same ``.xplane.pb``, on the same clock, as the
+#   device ops;
+# * the `Tracer`, when one is given or the thread's `Scope` carries one
+#   (``observability.trace``): the same name through `Tracer.complete`;
+# * the dispatch's stage clocks (`Scope.stage_s` -> `ServeResult.stage_s`),
+#   when the span names a ``stage`` the scope keeps.  Always on: the stall
+#   they exist to catch never falls in the one request a profiler saw.
+#
+# The clock is read only for the last two, so a span with neither costs one
+# TraceAnnotation and nothing else.
+
+_ambient = threading.local()
+
+
+class Scope:
+    """What the serve plane knows about the dispatch a thread is running,
+    made ambient for the layers below it: the server's injectable clock,
+    the stage clocks this kind of server keeps (fixed keys, seconds), the
+    `Tracer` with its track and the args its records carry when tracing is
+    on (tracer-local trace ids: the export stays free of process-global
+    ids), and the args every profiler span of the dispatch shares
+    (``request_id``, ``batch``).  Thread-local: the server enters it on
+    the thread that calls the executor."""
+
+    __slots__ = ("clock", "stage_s", "tracer", "track", "tracer_args",
+                 "args", "_outer")
+
+    def __init__(self, clock: Callable[[], float], stages: Iterable[str], *,
+                 tracer: Optional[Tracer] = None, track: str = "executor",
+                 tracer_args: Optional[dict] = None, **args):
+        self.clock = clock
+        self.stage_s: Dict[str, float] = dict.fromkeys(stages, 0.0)
+        self.tracer = tracer
+        self.track = track
+        self.tracer_args = tracer_args or {}
+        self.args = args
+
+    def __enter__(self) -> "Scope":
+        self._outer = getattr(_ambient, "scope", None)
+        _ambient.scope = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _ambient.scope = self._outer
+
+
+class span:
+    """``with span("distri.layer.what", **args):`` - see the section
+    comment.  ``tracer`` / ``track`` / ``trace`` default to the thread's
+    `Scope`; ``stage`` names the stage clock the span's seconds add to
+    (ignored where the scope does not keep that key)."""
+
+    __slots__ = ("name", "stage", "args", "_scope", "_tracer", "_track",
+                 "_trace", "_tracer_args", "_clock", "_ann", "_t0")
+
+    def __init__(self, name: str, *, tracer: Optional[Tracer] = None,
+                 track: Optional[str] = None, trace: Optional[int] = None,
+                 stage: Optional[str] = None, **args):
+        sc = getattr(_ambient, "scope", None)
+        self._tracer_args = args
+        if sc is not None:
+            if tracer is None and sc.tracer is not None:
+                tracer = sc.tracer
+                self._tracer_args = {**sc.tracer_args, **args}
+            if sc.args:
+                args = {**sc.args, **args}
+            track = track or sc.track
+        self.name, self.stage, self.args = name, stage, args
+        self._tracer, self._track, self._trace = tracer, track, trace
+        # the scope, if this span adds to one of the clocks it keeps
+        self._scope = sc if sc is not None and stage in sc.stage_s else None
+        self._clock = (self._scope.clock if self._scope is not None
+                       else tracer.clock if tracer is not None else None)
+
+    def set(self, **args) -> None:
+        """Args known only once the work is done (a cache ``hit``)."""
+        self.args.update(args)
+        if self._tracer_args is not self.args:
+            self._tracer_args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __enter__(self) -> "span":
+        self._open()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._close(*exc)
+
+    def _open(self, t: Optional[float] = None) -> None:
+        """``t``: a clock reading already taken (`phases.next`)."""
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if self._clock is not None:
+            self._t0 = self._clock() if t is None else t
+
+    def _close(self, *exc, t: Optional[float] = None) -> None:
+        if self._clock is not None:
+            t0, t1 = self._t0, self._clock() if t is None else t
+            if self._scope is not None:
+                self._scope.stage_s[self.stage] += t1 - t0
+            if self._tracer is not None:
+                self._tracer.complete(
+                    self.name, t0, t1, track=self._track or "spans",
+                    trace=self._trace, args=self._tracer_args)
+        self._ann.__exit__(*(exc or (None, None, None)))
+
+
+class phases:
+    """Consecutive sibling spans that hand over to one another across
+    function boundaries: ``with phases(first) as ph:`` opens the first,
+    ``ph.next(name)`` closes the open one and opens the next at the same
+    clock reading, and leaving the block closes the last.  Re-entrant per
+    thread - a block entered while another is open joins it and closes
+    nothing - so the executor, the pipeline's ``__call__`` and its decode
+    tail share ONE dispatch -> wait_device -> to_host -> post sequence
+    whichever of them is the outermost caller."""
+
+    __slots__ = ("_first", "_cur", "_owner")
+
+    def __init__(self, name: str, **kw):
+        self._first = (name, kw)
+        self._cur: Optional[span] = None
+
+    def __enter__(self) -> "phases":
+        owner = getattr(_ambient, "phases", None)
+        self._owner = owner if owner is not None else self
+        if owner is None:
+            _ambient.phases = self
+            name, kw = self._first
+            self._cur = span(name, **kw).__enter__()
+        return self._owner
+
+    def next(self, name: str, **kw) -> None:
+        old, new = self._cur, span(name, **kw)
+        clock = old._clock or new._clock
+        t = clock() if clock is not None else None
+        old._close(t=t)
+        new._open(t)
+        self._cur = new
+
+    def __exit__(self, *exc) -> None:
+        if self._owner is self:
+            _ambient.phases = None
+            self._cur.__exit__(*exc)
+
+
+def span_ids(requests) -> Dict[str, Any]:
+    """The args every span of one dispatch carries, on whichever thread:
+    ``request_id`` (the first request's) and, where the dispatch serves
+    several, ``batch`` (all of them)."""
+    ids: Dict[str, Any] = {"request_id": requests[0].request_id}
+    if len(requests) > 1:
+        ids["batch"] = ",".join(str(r.request_id) for r in requests)
+    return ids
+
+
+def phased(name: str, **kw):
+    """Decorator form of `phases` for a function whose whole body is the
+    block (the pipelines' ``__call__``)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*a, **k):
+            with phases(name, **kw):
+                return fn(*a, **k)
+        return inner
+    return wrap
 
 
 # --------------------------------------------------------------------------
