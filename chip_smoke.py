@@ -213,19 +213,20 @@ def leg_kernels(rehearse: bool) -> dict:
         padded_len, padded_heads = 4250, 24  # SD3 joint 4096 + 154
         qmm_shape = (8192, 640, 5120)  # level-1 GEGLU projection, CFG batch 2
 
+    kernels = {"inrepo": flash_sdpa, "upstream": upstream_flash_sdpa}
     for level, length, heads in levels:
         route = sdpa_routing.lookup(length, 64)
         if tiles is None:
-            check(route is not None and route.impl == "upstream",
+            check(route is not None and route.impl in kernels,
                   f"kernels: table route for L={length} d=64 is {route}, "
-                  "expected the upstream flash kernel")
-            bq, bk = route.block_q, route.block_k
+                  "expected a flash kernel")
+            impl, bq, bk = route.impl, route.block_q, route.block_k
         else:
-            bq, bk = tiles
-        kern = lambda q, k, v: upstream_flash_sdpa(  # noqa: E731
-            q, k, v, heads=heads, block_q=bq, block_k=bk)
-        name = f"upstream_flash {level} L={length} h={heads} d=64 {bq}x{bk}"
-        out, (q, k, v) = flash_case(name, kern, 2, length, length, heads, 64)
+            impl, (bq, bk) = "inrepo", tiles
+        kern = functools.partial(kernels[impl], heads=heads, block_k=bk)
+        name = f"{impl}_flash {level} L={length} h={heads} d=64 {bq}x{bk}"
+        out, (q, k, v) = flash_case(name, functools.partial(kern, block_q=bq),
+                                    2, length, length, heads, 64)
         if not rehearse:
             # the routed entry the models call reaches THIS kernel with THESE
             # tiles: same jitted program, so the bits are equal; any other
@@ -233,14 +234,19 @@ def leg_kernels(rehearse: bool) -> dict:
             routed = jax.block_until_ready(sdpa(q, k, v, heads=heads))
             check(bool(jnp.array_equal(routed, out)),
                   f"kernels: sdpa() at L={length} h={heads} did not run the "
-                  "table's upstream route (bits differ from the direct call)")
+                  f"table's {impl} route (bits differ from the direct call)")
         # the four-chip local shape: sp=2 halves the queries, KV is gathered
-        flash_case(f"{name} local Lq={length // 2}", kern, 1, length // 2,
-                   length, heads, 64)
+        # (sdpa cuts the tiles to what divides the local length)
+        flash_case(f"{name} local Lq={length // 2}",
+                   functools.partial(kern, block_q=min(bq, length // 2)),
+                   1, length // 2, length, heads, 64)
 
+    # the kernel the table sends longer sequences to, at the tiles it had here
     length, heads = levels[0][1], levels[0][2]
-    flash_case(f"inrepo_flash L={length} h={heads} d=64 defaults",
-               lambda q, k, v: flash_sdpa(q, k, v, heads=heads),
+    ubq, ubk = tiles or (256, 1024)
+    flash_case(f"upstream_flash L={length} h={heads} d=64 {ubq}x{ubk}",
+               lambda q, k, v: upstream_flash_sdpa(
+                   q, k, v, heads=heads, block_q=ubq, block_k=ubk),
                2, length, length, heads, 64)
     flash_case(f"padded_flash L={padded_len} h={padded_heads} d=64 "
                "(upstream, segment ids)",

@@ -77,17 +77,32 @@ def check_tables() -> List[Finding]:
     check_provenance(sdpa_routing, SDPA_PATH,
                      "# --- BEGIN MEASURED_ROUTES",
                      "# --- END MEASURED_ROUTES ---", "sdpa")
-    for key, route in sdpa_routing.MEASURED_ROUTES.items():
-        if not (isinstance(key, tuple) and len(key) == 2
-                and all(isinstance(x, int) for x in key)):
-            findings.append(_finding(
-                SDPA_PATH, f"sdpa route key malformed: {key!r}",
-                f"sdpa:key:{key!r}"))
-        if not isinstance(route, sdpa_routing.Route) or route.impl not in (
-                "xla", "inrepo", "upstream"):
-            findings.append(_finding(
-                SDPA_PATH, f"sdpa route value malformed: {key!r} -> "
-                f"{route!r}", f"sdpa:value:{key!r}"))
+    for table in ("MEASURED_ROUTES", "MODEL_VALIDATED_OVERRIDES"):
+        for key, route in getattr(sdpa_routing, table).items():
+            if not (isinstance(key, tuple) and len(key) == 2
+                    and all(isinstance(x, int) for x in key)):
+                findings.append(_finding(
+                    SDPA_PATH, f"sdpa route key malformed: {key!r}",
+                    f"sdpa:key:{key!r}"))
+                continue
+            if not isinstance(route, sdpa_routing.Route) or (
+                    route.impl not in ("xla", "inrepo", "upstream")):
+                findings.append(_finding(
+                    SDPA_PATH, f"sdpa route value malformed: {key!r} -> "
+                    f"{route!r}", f"sdpa:value:{key!r}"))
+                continue
+            # a tile is a power of two from the 128-lane minimum up to its
+            # bucket's length, so it divides that length.  sdpa fits tiles
+            # to each call, but an entry that does not fit its own bucket
+            # records a measurement that cannot have been made.
+            for tile in (route.block_q, route.block_k):
+                if tile is not None and not (
+                        isinstance(tile, int) and 128 <= tile <= 2 ** key[1]
+                        and tile & (tile - 1) == 0):
+                    findings.append(_finding(
+                        SDPA_PATH, f"sdpa {table} {key!r}: tile {tile!r} is "
+                        f"not a power of two in [128, 2^{key[1]}]",
+                        f"sdpa:tile:{table}:{key!r}"))
 
     check_provenance(gemm_routing, GEMM_PATH,
                      "# --- BEGIN MEASURED_GEMM_ROUTES",

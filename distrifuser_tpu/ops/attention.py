@@ -187,10 +187,11 @@ def sdpa(q, k, v, *, heads: int):
                     ubq = ubk = None
             return upstream_flash_sdpa(q, k, v, heads=heads,
                                        block_q=ubq, block_k=ubk)
-        bq = route.block_q or DEFAULT_BLOCK_Q
-        bk = route.block_k or DEFAULT_BLOCK_K
-        bq = bq if lq % bq == 0 else DEFAULT_BLOCK_Q
-        bk = bk if lk % bk == 0 else DEFAULT_BLOCK_K
+        # same fitting as above: the table's tiles hold for the whole log2
+        # bucket and for the patch path's local Lq, so each is cut down to
+        # the largest power-of-2 that divides THIS call's length
+        bq = _largest_dividing_tile(route.block_q or DEFAULT_BLOCK_Q, lq)
+        bk = _largest_dividing_tile(route.block_k or DEFAULT_BLOCK_K, lk)
         return flash_sdpa(
             q, k, v, heads=heads, block_q=bq, block_k=bk, interpret=interpret
         )

@@ -1,19 +1,31 @@
-"""Pallas flash attention for TPU: the fused-SDPA native kernel.
+"""Flash attention for TPU: the fused-SDPA native kernels.
 
 The reference reaches fused attention through torch's
 F.scaled_dot_product_attention (cuDNN/FlashAttention,
 /root/reference/distrifuser/modules/pp/attn.py:87,153) — SURVEY.md §2.10 maps
-that native dependency to a Pallas kernel here.  Online-softmax tiling:
+that native dependency to a Pallas kernel here.  Two kernels, one routing
+table (ops/sdpa_routing.py):
 
-* grid (batch*heads, Lq/Bq, Lk/Bk); the innermost grid dim walks KV blocks
-  sequentially while Pallas double-buffers their HBM->VMEM streams;
-* fp32 running max / normalizer / accumulator in VMEM scratch, carried
-  across KV steps, finalized on the last one;
-* logits never materialize beyond one (Bq, Bk) tile — O(L) memory instead of
-  the O(L^2) probability matrix, which is what makes >=2048px patch
-  attention (16k-65k tokens) fit.
+* ``flash_sdpa`` — the in-repo kernel, **sequence-minor**.  XLA's TPU layout
+  assignment writes every projection output ``[B, L, C]`` with the tokens on
+  the lanes (layout ``{1,2,0}``, physically ``[B, C, L]``), and ``to_out``
+  reads its input the same way.  The kernel takes Q, K, V and writes O as
+  ``[B*H, D, L]`` — a bitcast of what is already in HBM — so no layout copy
+  stands before or behind it (the upstream kernel's ``[B, H, L, D]`` costs
+  three transposes in and one out per call, lane-padded from d=64/72 to
+  128).  Grid ``(B*H, Lq/block_q)``, both parallel; one head's whole K and V
+  stay in VMEM across its q blocks; the KV walk is a loop INSIDE the kernel
+  over ``block_k`` chunks, unrolled so that the scheduler overlaps one
+  chunk's softmax (VPU/EUP) with the next chunk's Q·Kᵀ (MXU) — a grid step
+  per KV block, as the upstream kernel has, runs them one after the other.
+  Online softmax with fp32 running max / sum / accumulator carried as loop
+  values; the 1/sqrt(d) scale rides in the exponent (``exp2((s-m)·c)``:
+  no pass over the score tile), normalisation happens once at the end.
+* ``upstream_flash_sdpa`` — jax.experimental's kernel under the same
+  signature, for the shapes the table leaves with it (very long sequences,
+  the segment-masked padded route).
 
-`flash_sdpa` is a drop-in for ops.attention.sdpa; attention.py routes long,
+``flash_sdpa`` is a drop-in for ops.attention.sdpa; attention.py routes long,
 block-aligned sequences on TPU to a flash kernel and everything else (small
 cross-attention over 77 text tokens) to the XLA softmax path.
 """
@@ -21,6 +33,7 @@ cross-attention over 77 text tokens) to the XLA softmax path.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -31,51 +44,63 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
+# KV chunks per iteration of the in-kernel loop, as straight-line code: the
+# overlap of one chunk's softmax with the next chunk's matmul exists only
+# inside a basic block (1.57 ms unrolled vs 2.06 ms rolled a call at
+# L=4096, d=72: one v5e, PR 25).  Up to this many chunks there is no loop.
+_KV_UNROLL = 8
+# what one kernel instance may ask of the 128 MiB of VMEM a v5e core has
+_VMEM_CEILING = 100 << 20
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, scale,
-                  kv_len=None):
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _flash_kernel(qT_ref, kT_ref, vT_ref, oT_ref, *, scale, block_k, kv_len):
+    """One (head, q block): qT [D, Bq], the head's kT / vT [D, Lk] -> oT."""
+    d, bq = qT_ref.shape[1], qT_ref.shape[2]
+    n_full, tail = divmod(kv_len, block_k)
+    q = qT_ref[0].T  # [Bq, D]: one small transpose per q block
+    # logits stay the raw fp32 products; the softmax scale is folded into
+    # the exponent's base-2 conversion, which exp pays anyway
+    c = scale * math.log2(math.e)
 
-    @pl.when(j == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def chunk(start, carry, masked=False):
+        m, l, acc = carry
+        kt = kT_ref[0, :, pl.ds(start, block_k)]  # [D, Bk]
+        vt = vT_ref[0, :, pl.ds(start, block_k)]
+        s = jax.lax.dot_general(q, kt, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if masked:
+            # alignment padding: KV columns at or beyond the real length
+            # leave the softmax (pad q rows are the caller's to slice off)
+            col = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < kv_len, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2((s - m_new) * c)  # [Bq, Bk]
+        corr = jnp.exp2((m - m_new) * c)  # [Bq, 1]
+        l = corr * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = corr * acc + jax.lax.dot_general(
+            p.astype(vt.dtype), vt, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)  # [Bq, D]
+        return m_new, l, acc
 
-    q = q_ref[0]  # [Bq, D]
-    k = k_ref[0]  # [Bk, D]
-    v = v_ref[0]  # [Bk, D]
+    carry = (jnp.full((bq, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32),
+             jnp.zeros((bq, d), jnp.float32))
+    n_loop = n_full // _KV_UNROLL if n_full > _KV_UNROLL else 0
+    if n_loop:
+        def group(g, carry):
+            base = pl.multiple_of(g * (_KV_UNROLL * block_k), block_k)
+            for j in range(_KV_UNROLL):
+                carry = chunk(base + j * block_k, carry)
+            return carry
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [Bq, Bk] fp32
-    if kv_len is not None:
-        # alignment-padding support: KV columns at or beyond the real
-        # length are masked out of the softmax, so padding K/V up to a
-        # block multiple is numerically exact (pad q rows are the caller's
-        # to slice off).  One iota+compare+select per tile — negligible
-        # against the dot.
-        bk = s.shape[1]
-        col = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < kv_len, s, _NEG_INF)
-
-    m_prev = m_scr[:, :1]  # [Bq, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)  # [Bq, Bk]
-    corr = jnp.exp(m_prev - m_new)  # [Bq, 1]
-
-    l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == nk - 1)
-    def _():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+        carry = jax.lax.fori_loop(0, n_loop, group, carry)
+    for j in range(n_loop * _KV_UNROLL, n_full):
+        carry = chunk(j * block_k, carry)
+    if tail:
+        # the one chunk the mask crosses; whole chunks beyond it never run
+        carry = chunk(n_full * block_k, carry, masked=True)
+    _, l, acc = carry
+    oT_ref[0] = (acc / l).T.astype(oT_ref.dtype)  # [D, Bq]
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "block_q", "block_k"))
@@ -124,51 +149,59 @@ def flash_sdpa(q, k, v, *, heads: int, block_q: int = DEFAULT_BLOCK_Q,
     """Drop-in for ops.attention.sdpa: [B, L, C] inputs, H heads.
 
     Requires Lq % block_q == 0 and Lk % block_k == 0 (attention.py checks
-    before routing here).  ``kv_len`` (static): treat only the first
-    ``kv_len`` KV positions as real — the alignment-padding mask for
-    unaligned sequences (SD3's 4250-token joint stream padded to 4352).
+    before routing here); Lq != Lk is fine (patch parallelism: local Q,
+    gathered KV).  ``kv_len`` (static): treat only the first ``kv_len`` KV
+    positions as real — the alignment-padding mask for unaligned sequences
+    (SD3's 4250-token joint stream padded to 4352).  A head's K and V have
+    to fit VMEM whole: a longer sequence is refused, not run some other way.
     """
     b, lq, c = q.shape
     lk = k.shape[1]
     d = c // heads
-    scale = 1.0 / d**0.5
+    kv_len = lk if kv_len is None else kv_len
+    if lq % block_q:
+        raise ValueError(f"flash_sdpa: block_q={block_q} must divide Lq={lq}")
+    if lk % block_k or not 0 < kv_len <= lk:
+        raise ValueError(f"flash_sdpa: block_k={block_k} must divide Lk={lk}, "
+                         f"and kv_len={kv_len} lie in (0, Lk]")
 
-    def to_heads(x, l):
-        return (
-            x.reshape(b, l, heads, d).transpose(0, 2, 1, 3).reshape(b * heads, l, d)
-        )
+    item = jnp.dtype(q.dtype).itemsize
+    d_pad = -(-d // 16) * 16
+    live = min(-(-kv_len // block_k), _KV_UNROLL)
+    vmem = (2 * 2 * d_pad * (lk + block_q) * item  # q, k, v, o: two buffers
+            + live * block_q * block_k * 10  # s, p in fp32, p in bf16
+            + 3 * block_q * 128 * 4)  # m, l, acc
+    if vmem > _VMEM_CEILING:
+        raise ValueError(
+            f"flash_sdpa: Lk={lk} at d={d}, tiles {block_q}x{block_k} needs "
+            f"~{vmem >> 20} MiB of VMEM (one head's K and V stay resident); "
+            f"over {_VMEM_CEILING >> 20} MiB. Take smaller tiles, or route "
+            "this shape to 'upstream'.")
 
-    qh, kh, vh = to_heads(q, lq), to_heads(k, lk), to_heads(v, lk)
+    def seq_minor(x, l):  # [B, L, C] -> [B*H, D, L]: a bitcast of {1,2,0}
+        return x.reshape(b, l, heads, d).transpose(0, 2, 3, 1).reshape(
+            b * heads, d, l)
 
-    grid = (b * heads, lq // block_q, lk // block_k)
+    kv_spec = pl.BlockSpec((1, d, lk), lambda g, i: (g, 0, 0))
+    q_spec = pl.BlockSpec((1, d, block_q), lambda g, i: (g, 0, i))
     out = pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, kv_len=kv_len),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda h, i, j: (h, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda h, i, j: (h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * heads, lq, d), q.dtype),
-        scratch_shapes=[
-            # (block_q, 128): fp32 lane width — same layout the upstream TPU
-            # kernel uses for its m/l scratch (MIN_BLOCK_SIZE=128)
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running normalizer
-            pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
-        ],
-        # batch*heads and q-blocks are independent; only the KV walk carries
-        # the online-softmax state.  Without this, Mosaic treats every grid
-        # dim as sequential ("arbitrary"), which blocks its cross-iteration
-        # pipelining — the prime suspect in the round-2 2x slowdown vs XLA.
+        functools.partial(_flash_kernel, scale=1.0 / d**0.5, block_k=block_k,
+                          kv_len=kv_len),
+        grid=(b * heads, lq // block_q),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b * heads, d, lq), q.dtype),
+        # every (head, q block) is independent: the KV walk is inside
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=vmem + (8 << 20),
         ),
         interpret=interpret,
-    )(qh, kh, vh)
-
-    return out.reshape(b, heads, lq, d).transpose(0, 2, 1, 3).reshape(b, lq, c)
+        # the device op's name: the benchmark's attention metrics match
+        # `^flash` / `attention`, and the trace counts its calls
+        name="flash_attention_seq_minor",
+    )(seq_minor(q, lq), seq_minor(k, lk), seq_minor(v, lk))
+    return out.reshape(b, heads, d, lq).transpose(0, 3, 1, 2).reshape(b, lq, c)
 
 
 def padding_segment_ids(b: int, lq: int, lq_pad: int, lk: int, lk_pad: int):
@@ -207,9 +240,11 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
     "inrepo" (static kv_len mask).  Resolution: the ``impl`` argument,
     else DISTRIFUSER_TPU_PADDED_IMPL, else — honoring the operator's
     kernel-wide DISTRIFUSER_TPU_FLASH_IMPL=inrepo pin — "inrepo", else
-    "upstream" (the model-level A/B at SD3-medium 1024²: upstream 8.32 s
-    vs inrepo 13.54 s vs chunked XLA 20.17 s; the two kernels agree to
-    5e-4 on chip).  The resolved kernel runs or the call raises; the
+    "upstream" (the model-level A/B at SD3-medium 1024², 2026-07:
+    upstream 8.32 s vs inrepo 13.54 s vs chunked XLA 20.17 s, the two
+    kernels agreeing to 5e-4 on chip — against the in-repo kernel of that
+    date; the sequence-minor one that replaced it in PR 25 has not been
+    A/B'd on this route).  The resolved kernel runs or the call raises; the
     in-repo kernel is reachable only as an explicit route, never as a
     fallback.  ``interpret`` exists for the in-repo kernel only.
     """

@@ -2,8 +2,8 @@
 
 The reference always runs fused SDPA — torch picks the cuDNN/Flash backend
 internally (/root/reference/distrifuser/modules/pp/attn.py:153).  Here the
-backend choice (plain XLA softmax vs the in-repo Pallas kernel vs
-jax.experimental's tuned flash kernel) is a *checked-in table keyed by shape*,
+backend choice (plain XLA softmax vs the in-repo sequence-minor Pallas kernel
+vs jax.experimental's tuned flash kernel) is a *checked-in table keyed by shape*,
 regenerated from real-chip measurements:
 
     scripts/chip_campaign.py   ->  chiprun_out/<log>   (attn/tune lines)
@@ -54,20 +54,41 @@ MEASURED_ROUTES: dict = {
 # the unfused softmax pays fusion-boundary and HBM-traffic costs the isolated
 # kernel chain never sees.  lookup() consults this dict FIRST, so a re-bake
 # from micro lines cannot silently regress the model-level choice.
+#
+# PR 25, on chip (one v5e, 2026-09-28): the three shapes of the benchmark's
+# cells moved from Route("upstream", 256, 1024) to the in-repo kernel, which
+# is sequence-minor since that PR (ops/flash_attention.py): each entry on its
+# own model-level A/B — the full-depth denoiser forward at the cell's
+# published widths, batch 2, 1024^2, route patched and nothing else — and
+# then the cells themselves (root PERF.md section 6, "PR 25").  Two things
+# are gained per call: the four layout copies around the upstream kernel
+# (q, k, v in, o out; tokens-on-lanes -> head-dim-on-lanes and back) become
+# bitcasts, and the kernel's unrolled KV loop overlaps softmax with matmul
+# (kernel alone, chained: 2.117 -> 1.596 ms at d=72 L=4096 h=16; 1.301 ->
+# 1.008 ms at d=64 L=4096 h=10; 0.2116 -> 0.180 ms at d=64 L=1024 h=20).
+# The entries key on kv_len, so the patch path (local Q, gathered KV)
+# inherits them; no cell has timed it there yet.
 MODEL_VALIDATED_OVERRIDES: dict = {
     # (head_dim, log2 bucket): Route
-    (64, 12): Route("upstream", 256, 1024),  # b1024 7.034s vs b1024_xla
-                                             # 8.330s, 2026-07-31
-    (64, 10): Route("upstream", 256, 1024),  # b1024 6.220s with this vs
-                                             # 7.034s xla-at-level-2;
-                                             # chained micro was a tie
-                                             # (3.56 vs 3.79 ms) —
-                                             # model A/B decides, 2026-07-31
-    (72, 12): Route("upstream", 256, 1024),  # PixArt-XL 1024px 20-step:
-                                             # 2.43s flash vs 4.91s xla —
-                                             # 2.0x, though the chained
-                                             # micro had xla 2x AHEAD
-                                             # (9.2 vs 18.8 ms), 2026-07-31
+    (64, 12): Route("inrepo", 1024, 512),   # SDXL UNet forward 126.85 ->
+                                            # 123.53 ms with this entry
+                                            # alone, 119.03 with (64,10)
+                                            # too; upstream 256x1024 was
+                                            # b1024 7.034 s vs b1024_xla
+                                            # 8.330 s, 2026-07-31
+    (64, 10): Route("inrepo", 1024, 1024),  # SDXL UNet forward 126.85 ->
+                                            # 122.40 ms alone; 60 calls a
+                                            # step.  upstream 256x1024 was
+                                            # b1024 6.220 s vs 7.034 s
+                                            # xla-at-level-2, 2026-07-31
+    (72, 12): Route("inrepo", 1024, 512),   # PixArt-XL DiT forward 115.41
+                                            # -> 92.54 ms (512x512 93.23,
+                                            # 1024x1024 92.72, 2048x512
+                                            # 95.69).  upstream 256x1024
+                                            # was 2.43 s vs 4.91 s xla an
+                                            # image, though the chained
+                                            # micro had xla 2x AHEAD
+                                            # (9.2 vs 18.8 ms), 2026-07-31
 }
 
 # How far (in log2 steps of kv_len) a measured entry is allowed to

@@ -244,10 +244,26 @@ def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
     long_q = jnp.zeros((1, 1100, c))  # unaligned and >= _FLASH_MIN_LEN
     with pytest.raises(MosaicRefused):
         attn_mod.sdpa(long_q, long_q, long_q, heads=heads)
-    aligned = jnp.zeros((1, 1024, 2 * 64))  # d=64: the shipped table route
+    far = jnp.zeros((1, 16384, 2 * 64))  # d=64, bucket 14: upstream's
     with pytest.raises(MosaicRefused):
-        attn_mod.sdpa(aligned, aligned, aligned, heads=2)
+        attn_mod.sdpa(far, far, far, heads=2)
     assert len(inrepo_calls) == 1
+
+    # ... and the cells' shapes, which the table sends to the in-repo
+    # kernel: when IT is refused, neither the upstream kernel nor XLA runs
+    def failing_inrepo(*a, **kw):
+        raise MosaicRefused("in-repo kernel refused")
+
+    upstream_calls = []
+    monkeypatch.setattr(fa, "flash_sdpa", failing_inrepo)
+    monkeypatch.setattr(fa, "upstream_flash_sdpa",
+                        lambda *a, **kw: upstream_calls.append(kw))
+    monkeypatch.setattr(attn_mod, "_sdpa_xla",
+                        lambda *a, **kw: upstream_calls.append(kw))
+    aligned = jnp.zeros((1, 1024, 2 * 64))  # d=64, bucket 10
+    with pytest.raises(MosaicRefused, match="in-repo"):
+        attn_mod.sdpa(aligned, aligned, aligned, heads=2)
+    assert not upstream_calls
 
 
 def test_upstream_route_on_cpu_is_an_error(monkeypatch):
@@ -258,3 +274,96 @@ def test_upstream_route_on_cpu_is_an_error(monkeypatch):
     x = jnp.zeros((1, 128, 32))
     with pytest.raises(ValueError, match="needs a TPU"):
         sdpa(x, x, x, heads=2)
+
+
+def _oracle(q, k, v, heads, kv_len=None):
+    """`_sdpa_xla` over the first `kv_len` KV positions, on [B, L, C]."""
+    from distrifuser_tpu.ops.attention import _sdpa_xla
+
+    b, lq, c = q.shape
+    d = c // heads
+    if kv_len is not None:
+        k, v = k[:, :kv_len], v[:, :kv_len]
+    lk = k.shape[1]
+    return _sdpa_xla(
+        q.reshape(b, lq, heads, d), k.reshape(b, lk, heads, d),
+        v.reshape(b, lk, heads, d), 1.0 / d**0.5).reshape(b, lq, c)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 128),
+                                             (128, 256)])
+@pytest.mark.parametrize("lq,lk,kv_len", [
+    (256, 256, None),   # the cells' self-attention: Lq == Lk
+    (256, 512, None),   # patch-parallel: local Q rows, gathered KV
+    (256, 256, 200),    # the pad mask crosses a chunk (and, at 256, is the
+    (256, 512, 300),    # whole loop); whole chunks beyond it never run
+    (256, 512, 256),    # the mask ends exactly on a chunk boundary
+])
+@pytest.mark.parametrize("d", [64, 72])
+def test_seq_minor_kernel_matches_xla(d, lq, lk, kv_len, block_q, block_k):
+    """The sequence-minor kernel against the XLA softmax at the cells' head
+    dims (SDXL's 64, PixArt's 72), every loop shape `kv_len` can make."""
+    b, heads = 2, 2
+    c = heads * d
+    keys = jax.random.split(jax.random.PRNGKey(d + lq + lk), 3)
+    q = jax.random.normal(keys[0], (b, lq, c))
+    k = jax.random.normal(keys[1], (b, lk, c))
+    v = jax.random.normal(keys[2], (b, lk, c))
+    got = flash_sdpa(q, k, v, heads=heads, block_q=block_q, block_k=block_k,
+                     interpret=True, kv_len=kv_len)
+    assert got.shape == (b, lq, c)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_oracle(q, k, v, heads, kv_len)),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kv_len", [None, 2200])
+def test_seq_minor_kernel_long_kv_takes_the_grouped_loop(kv_len):
+    """More KV chunks than the kernel lays out as straight-line code: whole
+    groups in a loop, the remainder after it, then the masked chunk."""
+    from distrifuser_tpu.ops.flash_attention import _KV_UNROLL
+
+    b, heads, d, lq, lk, block_k = 1, 1, 64, 128, 2304, 128
+    assert (kv_len or lk) // block_k > 2 * _KV_UNROLL
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (b, lq, d))
+    k = jax.random.normal(keys[1], (b, lk, d))
+    v = jax.random.normal(keys[2], (b, lk, d))
+    got = flash_sdpa(q, k, v, heads=heads, block_q=128, block_k=block_k,
+                     interpret=True, kv_len=kv_len)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_oracle(q, k, v, heads, kv_len)),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("d", [64, 72])
+def test_seq_minor_kernel_bf16_against_float32_softmax(d):
+    """bf16 operands, float32 statistics and accumulator: as far from a
+    float32 softmax as bf16 inputs put any kernel, and no further."""
+    b, heads, lq, lk = 1, 2, 256, 512
+    c = heads * d
+    keys = jax.random.split(jax.random.PRNGKey(d), 3)
+    q, k, v = (jax.random.normal(kk, (b, l, c), jnp.bfloat16)
+               for kk, l in zip(keys, (lq, lk, lk)))
+    got = flash_sdpa(q, k, v, heads=heads, block_q=128, block_k=256,
+                     interpret=True)
+    assert got.dtype == jnp.bfloat16
+    want = _oracle(*(x.astype(jnp.float32) for x in (q, k, v)), heads)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               atol=5e-3)
+
+
+def test_seq_minor_kernel_refuses_what_does_not_fit():
+    """Tiles that do not divide the lengths, and a KV too long for one
+    head's K and V to stay in VMEM, are refused at trace - loudly."""
+    x = jnp.zeros((1, 256, 128))
+    with pytest.raises(ValueError, match="block_q"):
+        flash_sdpa(x, x, x, heads=2, block_q=96, interpret=True)
+    with pytest.raises(ValueError, match="block_k"):
+        flash_sdpa(x, x, x, heads=2, block_k=96, interpret=True)
+    long_kv = jax.ShapeDtypeStruct((1, 1 << 20, 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((1, 256, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="VMEM"):
+        jax.eval_shape(lambda q, k, v: flash_sdpa(q, k, v, heads=2,
+                                                  interpret=True),
+                       q, long_kv, long_kv)

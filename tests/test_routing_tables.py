@@ -36,3 +36,24 @@ def test_gemm_table_backend_declared_when_measured():
         assert gemm_routing.MEASURED_BACKEND in ("cpu", "tpu", "gpu")
     # provenance is never empty, measured or not
     assert gemm_routing.MEASURED_PROVENANCE.strip()
+
+
+def test_override_table_is_linted_like_the_measured_one(monkeypatch):
+    """MODEL_VALIDATED_OVERRIDES is what routes the benchmark's cells: a
+    malformed key, an unknown impl or a tile that cannot divide its bucket's
+    length is a finding there too."""
+    from distrifuser_tpu.analysis.checkers import route_tables
+    from distrifuser_tpu.ops import sdpa_routing
+    from distrifuser_tpu.ops.sdpa_routing import Route
+
+    assert route_tables.check_tables() == []
+    for bad, ident in [
+        ({(72, 12): Route("inrepo", 1000, 512)}, "sdpa:tile:"),
+        ({(72, 12): Route("inrepo", 1024, 64)}, "sdpa:tile:"),
+        ({(64, 10): Route("inrepo", 2048, 512)}, "sdpa:tile:"),
+        ({(72, 12): Route("mosaic", 256, 512)}, "sdpa:value:"),
+        ({(72, "12"): Route("inrepo", 256, 512)}, "sdpa:key:"),
+    ]:
+        monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES", bad)
+        found = route_tables.check_tables()
+        assert any(f.identity.startswith(ident) for f in found), (bad, found)

@@ -22,6 +22,11 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts"))
 
 
+# the autouse fixture below empties the shipped override table for the
+# tests of the lookup rules; the tests of what ships get it back from here
+SHIPPED_OVERRIDES = dict(sdpa_routing.MODEL_VALIDATED_OVERRIDES)
+
+
 class _Dev:
     def __init__(self, platform):
         self.platform = platform
@@ -434,3 +439,86 @@ def test_largest_dividing_tile():
     assert fit(1024, 77) is None          # below the 128 lane minimum
     assert fit(128, 384) == 128
     assert fit(1024, 1000) is None        # no pow2 >=128 divides 1000
+
+
+# the benchmark's cells: (head_dim, heads, L) of every self-attention shape
+# that `sdxl-1024-solo` and `pixart-1024-solo` run through the table
+CELL_SHAPES = [(72, 16, 4096), (64, 10, 4096), (64, 20, 1024)]
+
+
+@pytest.mark.parametrize("d,heads,l", CELL_SHAPES)
+def test_cell_shapes_resolve_to_the_seq_minor_kernel(monkeypatch, d, heads, l):
+    """PR 25: the three shapes of the benchmark's cells route to the in-repo
+    (sequence-minor) kernel, with tiles that divide the cell's length and
+    the local Q lengths of the patch path (L/2, L/4 rows, KV gathered)."""
+    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
+                        SHIPPED_OVERRIDES)
+    route = _route(monkeypatch, lq=l, lk=l, c=heads * d, heads=heads)
+    assert route.impl == "inrepo", route
+    for tile in (route.block_q, route.block_k):
+        assert tile and tile >= 128 and tile & (tile - 1) == 0
+    assert l % route.block_q == 0 and l % route.block_k == 0
+    # the patch path keys on kv_len, so it inherits the entry
+    for n in (2, 4):
+        assert _route(monkeypatch, lq=l // n, lk=l, c=heads * d,
+                      heads=heads) == route
+
+
+def test_inrepo_route_tiles_are_fitted_to_the_call(monkeypatch):
+    """`sdpa` cuts a route's tiles down to what divides this call's lengths
+    (a bucket holds lengths its tiles do not divide; the patch path's local
+    Lq is a fraction of the cell's) and hands them to `flash_sdpa`."""
+    import jax.numpy as jnp
+
+    fa = importlib.import_module("distrifuser_tpu.ops.flash_attention")
+    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
+                        {(64, 12): Route("inrepo", 1024, 512)})
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu")])
+    seen = []
+
+    def spy(q, k, v, **kw):
+        seen.append(kw)
+        return q
+
+    monkeypatch.setattr(fa, "flash_sdpa", spy)
+    for lq, lk in [(4096, 4096), (512, 4096), (3840, 3840)]:
+        attention.sdpa(jnp.zeros((1, lq, 128)), jnp.zeros((1, lk, 128)),
+                       jnp.zeros((1, lk, 128)), heads=2)
+    assert [(kw["block_q"], kw["block_k"]) for kw in seen] == [
+        (1024, 512), (512, 512), (256, 256)]
+    assert all(kw["interpret"] is False for kw in seen)
+
+
+def test_env_overrides_govern_the_moved_entries(monkeypatch):
+    """The documented hatches against the shipped table: FLASH=0 and
+    IMPL=xla pin XLA, IMPL=upstream pins the upstream kernel, and BQ / BK
+    replace the entry's tiles one axis at a time."""
+    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
+                        SHIPPED_OVERRIDES)
+    shipped = _route(monkeypatch, c=16 * 72, heads=16)
+    assert shipped.impl == "inrepo"
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_BK", "256")
+    assert _route(monkeypatch, c=16 * 72, heads=16) == Route(
+        "inrepo", shipped.block_q, 256)
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_BQ", "128")
+    assert _route(monkeypatch, c=16 * 72, heads=16) == Route(
+        "inrepo", 128, 256)
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_BQ")
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_BK")
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "upstream")
+    assert _route(monkeypatch, c=16 * 72, heads=16).impl == "upstream"
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "xla")
+    assert _route(monkeypatch, c=16 * 72, heads=16) == Route("xla")
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL")
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "0")
+    assert _route(monkeypatch, c=16 * 72, heads=16) == Route("xla")
+
+
+def test_unmoved_entries_stay_where_they_were(monkeypatch):
+    """(64, 14), (64, 16) keep the upstream kernel; a length between the
+    buckets of a moved entry and an unmoved one goes to the nearer."""
+    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
+                        SHIPPED_OVERRIDES)
+    assert _route(monkeypatch, lq=16384, lk=16384).impl == "upstream"
+    assert _route(monkeypatch, lq=57600 // 128 * 128,
+                  lk=57600 // 128 * 128).impl == "upstream"

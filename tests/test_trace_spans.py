@@ -397,3 +397,86 @@ def test_flash_custom_call_carries_the_attn_scope(topo, monkeypatch):
     assert calls and all(
         re.search(r'op_name="[^"]*/down_1/attn/[^"]*pallas_call', ln)
         for ln in calls), calls
+
+
+def _attention_patterns():
+    """The pattern lists of the benchmark's attention metrics, as the reader
+    joins them (`harness.readers._kernel_seconds_per_step`)."""
+    import glob
+    import json
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    files = sorted(glob.glob(os.path.join(
+        here, "benchmark", "layer_metrics", "attn_*.json")))
+    assert files
+    return [re.compile("|".join(json.load(open(f))["params"]["patterns"]),
+                       re.I) for f in files]
+
+
+def _pixart_block_case(sds):
+    from distrifuser_tpu.models.dit import dit_block, init_dit_params, pixart_config
+
+    cfg = pixart_config()
+    blocks = jax.eval_shape(lambda k: init_dit_params(k, cfg),
+                            jax.random.PRNGKey(0))["blocks"]
+    bp = jax.tree.map(lambda x: sds(x.shape[1:]), blocks)
+    n, c = (cfg.sample_size // cfg.patch_size) ** 2, cfg.hidden_size
+    fn = lambda bp, x, c6, kv: dit_block(bp, cfg, x, c6, kv)[0]  # noqa: E731
+    return fn, (bp, sds((2, n, c)), sds((6, c)), sds((2, 120, 2 * c))), (
+        2, n, cfg.num_heads, c // cfg.num_heads)
+
+
+def _sdxl_attention_case(l, c, heads):
+    def build(sds):
+        from distrifuser_tpu.ops.attention import attention
+
+        p = {"to_q": {"kernel": sds((c, c))},
+             "to_kv": {"kernel": sds((c, 2 * c))},
+             "to_out": {"kernel": sds((c, c)), "bias": sds((c,))}}
+        fn = lambda p, x: x + attention(p, x, heads=heads)  # noqa: E731
+        return fn, (p, sds((2, l, c))), (2, l, heads, c // heads)
+    return build
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(_pixart_block_case, id="pixart_dit_block_L4096_d72"),
+    pytest.param(_sdxl_attention_case(4096, 640, 10), id="sdxl_L4096_d64"),
+    pytest.param(_sdxl_attention_case(1024, 1280, 20), id="sdxl_L1024_d64"),
+])
+def test_seq_minor_flash_stands_between_bitcasts(topo, monkeypatch, build):
+    """Compiled for the described chip at the cells' widths, self-attention
+    is the `flash_attention_seq_minor` custom call, under a name every
+    attention metric of the benchmark matches, and no copy or transpose of a
+    [B, L, H, D]-shaped tensor stands around it: q, k, v and o are bitcasts
+    of what the projections write and read.  The split of `to_kv`'s fused
+    output into K and V is allowed: at most one fusion that slices it."""
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: topo.devices)
+    one = SingleDeviceSharding(topo.devices[0])
+    fn, args, (b, l, h, d) = build(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one))
+    lines = jax.jit(fn).lower(*args).compile().as_text().splitlines()
+
+    calls = [ln for ln in lines if "custom-call(" in ln
+             and 'custom_call_target="tpu_custom_call"' in ln]
+    names = [re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", ln).group(1)
+             for ln in calls]
+    assert len(names) == 1 and names[0].startswith(
+        "flash_attention_seq_minor"), names
+    for rx in _attention_patterns():
+        assert rx.search(names[0]), (rx.pattern, names[0])
+    assert re.search(rf"bf16\[{b * h},{d},{l}\]", calls[0])
+
+    heads_shaped = sorted([b, l, h, d])
+    layout_ops = []
+    for ln in lines:
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", ln)
+        if m and sorted(map(int, m.group(1).split(","))) == heads_shaped:
+            layout_ops.append(ln.strip()[:120])
+    assert not layout_ops, layout_ops
+    kv_split = [ln for ln in lines if re.search(
+        rf"= \(bf16\[{b},{l},{h * d}\]\S*, bf16\[{b},{l},{h * d}\]\S*\) "
+        r"fusion\(", ln)]
+    assert len(kv_split) <= 1, kv_split
