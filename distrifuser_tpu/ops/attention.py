@@ -324,3 +324,29 @@ def cross_attention(
         cached_kv = linear(p["to_kv"], encoder_hidden_states)
     k, v = split_kv(cached_kv)
     return linear(p["to_out"], sdpa(q, k, v, heads=heads))
+
+
+def causal_gqa_sdpa(q, k, v, *, q_positions):
+    """Causal attention of ONE sequence with fewer KV heads than query heads
+    (grouped-query): each KV head serves ``Hq // Hkv`` query heads.
+
+    ``q`` [T, Hq, D]; ``k`` / ``v`` [S, Hkv, D] - a whole prompt (S = T) or
+    a cache of which only the first rows are filled; ``q_positions`` [T]:
+    query ``i`` sees keys ``0 .. q_positions[i]``, so rows of a cache not
+    written yet are never read into the result.  Softmax in float32, the
+    MXU fed the model dtype, like `_sdpa_xla`.  One XLA route, beside the
+    table-routed `sdpa`: a language model's prefill here is a thousand
+    tokens in one layer of eleven, and its decode step one query row
+    against a cache - neither is where a kernel would be felt.
+    """
+    t, hq, d = q.shape
+    s, hkv, _ = k.shape
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} KV heads")
+    q = q.reshape(t, hkv, hq // hkv, d)
+    logits = jnp.einsum("tkgd,skd->kgts", q, k,
+                        preferred_element_type=jnp.float32) / d**0.5
+    visible = jnp.arange(s)[None, :] <= q_positions[:, None]
+    logits = jnp.where(visible[None, None], logits, -jnp.inf)
+    w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("kgts,skd->tkgd", w, v).reshape(t, hq, d)

@@ -24,16 +24,20 @@ fixed, SURVEY.md §2.6).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import os
 import sys
-from typing import Any, List, Optional
+import zlib
+from typing import Any, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .models import clip as clip_mod
+from .models import nemotron_h as lm_mod
 from .models import unet as unet_mod
 from .models import vae as vae_mod
 from .models.weights import (
@@ -46,6 +50,7 @@ from .models.weights import (
 )
 from .parallel.runner import make_runner
 from .schedulers import BaseScheduler, FlowMatchEulerScheduler, get_scheduler
+from .utils import sync
 from .utils.config import DistriConfig
 from .utils.trace import phased, phases, span
 
@@ -67,14 +72,12 @@ class SimpleTokenizer:
         self.bos = bos
 
     def __call__(self, texts: List[str], max_length: int = 77):
-        import zlib
-
         ids = np.full((len(texts), max_length), self.eos, np.int64)
         for i, t in enumerate(texts):
             # crc32, not hash(): process-independent, so multi-host pods and
             # repeated runs tokenize identically
             toks = [self.bos] + [
-                zlib.crc32(w.encode()) % (self.vocab_size - 2)
+                word_hash(w, self.vocab_size - 2)
                 for w in t.lower().split()
             ][: max_length - 2]
             toks.append(self.eos)
@@ -231,6 +234,150 @@ def _tokenize(tok, texts: List[str]) -> np.ndarray:
     return np.asarray(out["input_ids"])
 
 
+@dataclasses.dataclass(frozen=True)
+class RewriteSpec:
+    """The rewrite stage's lengths: a fixed instruction of
+    ``instruction_tokens`` ids (drawn once from ``instruction_seed`` over the
+    language model's vocabulary), then ``user_tokens`` ids of the caller's
+    words, ``new_tokens`` decoded greedily with no early stop, of which the
+    last ``prompt_tokens`` are the rewritten prompt (what follows the
+    thinking trace, by position)."""
+
+    instruction_tokens: int
+    user_tokens: int
+    new_tokens: int
+    prompt_tokens: int
+    instruction_seed: int = 0
+
+
+class ServedRewrite(NamedTuple):
+    """What one request's rewrite read and wrote: ``prompt_ids`` on the
+    host, the rest still on the device."""
+
+    prompt_ids: np.ndarray  # [instruction + user] int32
+    new_ids: Any  # [new_tokens] int32
+    logits: Any  # [new_tokens, vocab] float32: what each id was chosen from
+    counters: Any  # [4] int32, `models.nemotron_h.COUNTERS`
+    # the experts every token chose: the prompt's [E layers, prompt, top_k],
+    # the decoded tokens' [new_tokens, E layers, top_k]
+    experts: Any
+
+
+def word_hash(word: str, vocab_size: int) -> int:
+    """crc32 of a word modulo a vocabulary, process-independent: the
+    weightless tokenizers' one rule (`SimpleTokenizer` keeps the last two
+    ids of its vocabulary for BOS / EOS)."""
+    return zlib.crc32(word.encode()) % vocab_size
+
+
+class PromptRewriter:
+    """Think, then rewrite: the stage in front of the text encoders.
+
+    A request's prompt and a fixed instruction go through the language model
+    (`models.nemotron_h`): one prefill program over exactly
+    ``instruction_tokens + user_tokens`` ids - the caller's words through the
+    word hash, cut or repeated to ``user_tokens`` so that there is one
+    compiled shape and no padding to mask out of the convolution and the
+    state - then one decode program that runs all ``new_tokens`` greedy steps
+    on the device, with no host visit a token, and ends by looking the last
+    ``prompt_tokens`` ids up in a table of each id's word hash in the text
+    encoders' vocabularies (an id's word is its decimal string: no vocabulary
+    ships with the repo).  So the ids reach the encoders without visiting the
+    host and the request path stays asynchronous up to the image's copy.
+    Prefill is computed in full on every request (no prefix cache).
+
+    The last ``keep`` requests' ids, logits, routing and counters stay
+    reachable in ``served`` (device arrays: nothing is copied until someone reads them)."""
+
+    def __init__(self, config: lm_mod.NemotronHConfig, params,
+                 spec: RewriteSpec, tokenizers, keep: int = 2):
+        if not all(isinstance(t, SimpleTokenizer) for t in tokenizers):
+            raise ValueError("the rewrite stage hands ids on through the "
+                             "weightless word hash; a vocabulary-backed "
+                             "tokenizer would need the model's own detokenizer")
+        prompt_len = spec.instruction_tokens + spec.user_tokens
+        if prompt_len % config.chunk_size:
+            raise ValueError(
+                f"instruction_tokens + user_tokens = {prompt_len} is not a "
+                f"multiple of the scan's chunk size {config.chunk_size}")
+        if not 0 < spec.prompt_tokens <= spec.new_tokens:
+            raise ValueError("prompt_tokens must lie in 1..new_tokens")
+        self.config, self.params, self.spec = config, params, spec
+        rng = np.random.default_rng(spec.instruction_seed)
+        self.instruction = rng.integers(
+            0, config.vocab_size, spec.instruction_tokens).astype(np.int32)
+        self.served = collections.deque(maxlen=keep)
+        self._decode_args = None
+        # per text encoder: each language-model id's word hash there, and
+        # the row the ids are set into (BOS, n ids, EOS to the end)
+        self._tables = [jnp.asarray(
+            [word_hash(str(i), tok.vocab_size - 2)
+             for i in range(config.vocab_size)], jnp.int32)
+            for tok in tokenizers]
+        frames = [(tok.bos, tok.eos,
+                   min(spec.prompt_tokens, tok.model_max_length - 2),
+                   tok.model_max_length) for tok in tokenizers]
+
+        def rewrite_prefill(params, ids):
+            return lm_mod.prefill(params, config, ids,
+                                  max_len=prompt_len + spec.new_tokens)
+
+        def rewrite_decode(params, logits, state, counters, tables):
+            new_ids, chosen_from, experts, _, counters = lm_mod.decode(
+                params, config, logits, state, counters, position=prompt_len,
+                new_tokens=spec.new_tokens)
+            encoder_ids = []
+            for table, (bos, eos, n, length) in zip(tables, frames):
+                row = jnp.full((length,), eos, jnp.int32).at[0].set(bos)
+                encoder_ids.append(
+                    row.at[1:1 + n].set(table[new_ids[-n:]])[None])
+            return new_ids, chosen_from, experts, counters, encoder_ids
+
+        self._prefill = jax.jit(rewrite_prefill)
+        self._decode = jax.jit(rewrite_decode)
+
+    def lm_ids(self, prompt: str) -> np.ndarray:
+        """The instruction, then the prompt's words through the word hash,
+        cut or repeated to ``user_tokens`` (an empty prompt is id 0)."""
+        words = [word_hash(w, self.config.vocab_size)
+                 for w in prompt.lower().split()] or [0]
+        n = self.spec.user_tokens
+        user = (words * -(-n // len(words)))[:n]
+        return np.concatenate([self.instruction,
+                               np.asarray(user, np.int32)])
+
+    def __call__(self, prompts: List[str]):
+        """-> one [len(prompts), model_max_length] int32 device array per
+        text encoder."""
+        rows = []
+        for prompt in prompts:
+            ids = self.lm_ids(prompt)
+            with span("distri.rewrite.prefill"):
+                logits, state, counters, chosen = self._prefill(
+                    self.params, ids)
+            with span("distri.rewrite.decode"):
+                args = (self.params, logits, state, counters, self._tables)
+                if self._decode_args is None:
+                    self._decode_args = jax.tree.map(
+                        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+                (new_ids, chosen_from, experts, counters,
+                 encoder_ids) = self._decode(*args)
+            self.served.append(ServedRewrite(
+                ids, new_ids, chosen_from, counters, (chosen, experts)))
+            rows.append(encoder_ids)
+        if len(rows) == 1:
+            return rows[0]
+        return [jnp.concatenate(per_encoder) for per_encoder in zip(*rows)]
+
+    def decode_program_text(self) -> Optional[str]:
+        """The compiled decode program as HLO text (every instruction with
+        the named scopes it came from), once a request has run; through
+        JAX's compile cache where one is set."""
+        if self._decode_args is None:
+            return None
+        return self._decode.lower(*self._decode_args).compile().as_text()
+
+
 @dataclasses.dataclass
 class PipelineOutput:
     images: List[Any]
@@ -255,6 +402,11 @@ class PipelineStages:
     request path the staged serving executor (serve/staging.py) pipelines
     across micro-batches:
 
+    * ``rewrite(prompts) -> ids`` — only where a `PromptRewriter` is
+      resident (else None): the language model thinks and rewrites each
+      prompt, and hands the text encoders' ids on without a host visit;
+      ``encode`` takes them as its third argument, and runs the stage
+      itself when called without;
     * ``encode(prompts, negs) -> embeddings`` — tokenize + text-encode one
       compiled-batch-width chunk; the returned pytree is family-opaque
       (UNet: (embeds, added_cond); DiT: (embeds, caption_mask); MMDiT:
@@ -278,6 +430,7 @@ class PipelineStages:
     denoise: Any
     decode: Any
     init_noise_sigma: float
+    rewrite: Any = None
 
 
 def _mk_output(images, tokenizers) -> PipelineOutput:
@@ -443,6 +596,48 @@ def _quantize_aux(cfg, vae_params, text_encoders=(), t5_params=None):
     )
 
 
+class _HostImages:
+    """Float32 host buffers for the images a pipeline hands out, taken back
+    when the caller lets go of the image and handed out again.
+
+    A fresh 13 MB array a request is memory the process has never touched:
+    on the chip machine's kernel its page faults cost a request 25-40 ms -
+    in some processes and not in others, by where glibc happens to put the
+    allocating thread's arena - which a 2 s image shows as two clusters of
+    runs 27 ms apart (root PERF.md section 6, PR 27).  A buffer that comes
+    back is warm.  `take` returns an array whose memory belongs to a lease:
+    every view of it (the per-image slices a batch is cut into) keeps the
+    lease alive, and only when the last one is gone does the buffer return
+    - so an image a caller still holds is never written again.  A caller
+    that keeps every image simply gets fresh buffers, as before."""
+
+    class _Lease:
+        def __init__(self, buffer, give_back):
+            self._buffer, self._give_back = buffer, give_back
+            self.__array_interface__ = buffer.__array_interface__
+
+        def __del__(self):
+            self._give_back(self._buffer)
+
+    def __init__(self, keep: int = 4):
+        self._free, self._keep = [], keep
+        self._lock = sync.Lock()
+
+    def _give_back(self, buffer):
+        with self._lock:
+            if len(self._free) < self._keep:
+                self._free.append(buffer)
+
+    def take(self, shape):
+        with self._lock:
+            at = next((i for i, b in enumerate(self._free)
+                       if b.shape == tuple(shape)), None)
+            buffer = self._free.pop(at) if at is not None else None
+        if buffer is None:
+            buffer = np.empty(shape, np.float32)
+        return np.asarray(self._Lease(buffer, self._give_back))
+
+
 class _GenerationMixin:
     """Machinery shared by EVERY pipeline family (UNet, DiT, MMDiT): the
     output packaging tail of __call__, the staged-execution surface
@@ -460,6 +655,26 @@ class _GenerationMixin:
     # via `attach_step_timeline`: None (the default) adds nothing to the
     # dispatch path.
     step_timeline = None
+
+    @functools.cached_property
+    def _host_images(self) -> _HostImages:
+        return _HostImages()
+
+    # The think-then-rewrite stage (`PromptRewriter`), resident beside the
+    # diffusion model where a family takes one (`DistriSDXLPipeline`).
+    rewriter = None
+
+    def _rewrite(self, prompts):
+        """The rewrite stage: enqueue the language model's programs for each
+        prompt and return the text encoders' ids, still on the device.  Its
+        host time is the ``rewrite`` stage clock, cut out of ``dispatch``
+        (the same hand-over `_decode_to_np` makes at the other end)."""
+        with phases("distri.pipe.dispatch", stage="dispatch") as ph:
+            ph.next("distri.pipe.rewrite", stage="rewrite")
+            try:
+                return self.rewriter(prompts)
+            finally:
+                ph.next("distri.pipe.dispatch", stage="dispatch")
 
     def attach_step_timeline(self, timeline):
         """Record every generation's per-denoise-step wall timings
@@ -702,6 +917,8 @@ class _GenerationMixin:
         if t5 is not None and t5[1] is not None:
             text += params_nbytes(t5[1])
         parts["text_encoders"] = text
+        if self.rewriter is not None:
+            parts["rewriter"] = params_nbytes(self.rewriter.params)
         return {
             "weight_quant": cfg.weight_quant,
             "weight_quant_aux": cfg.weight_quant_aux,
@@ -760,9 +977,17 @@ class _GenerationMixin:
             ph.next("distri.pipe.wait_device", stage="device_wait")
             jax.block_until_ready(image)
             ph.next("distri.pipe.to_host", stage="to_host")
-            image = np.asarray(image, np.float32)
+            # into host memory this pipeline has used before (see
+            # `_HostImages`), widened on the way
+            host = np.asarray(image)
+            image = self._host_images.take(host.shape)
+            np.copyto(image, host, casting="unsafe")
             ph.next("distri.pipe.post", stage="post")
-            return np.clip(image / 2 + 0.5, 0.0, 1.0)
+            # clip(image / 2 + 0.5) in place: the same bits, no second and
+            # third image-sized array for the host to page in
+            image *= 0.5
+            image += 0.5
+            return np.clip(image, 0.0, 1.0, out=image)
 
     def prepare_stages(self, num_inference_steps: int) -> "PipelineStages":
         """Pre-build the request path as three separately-dispatchable
@@ -795,6 +1020,7 @@ class _GenerationMixin:
             denoise=denoise,
             decode=self._decode_to_np,
             init_noise_sigma=float(self.scheduler.init_noise_sigma),
+            rewrite=self._rewrite if self.rewriter is not None else None,
         )
 
     def _finalize(self, latent, output_type, tokenizers) -> "PipelineOutput":
@@ -1027,17 +1253,23 @@ class _DistriPipelineBase(_GenerationMixin):
     # -- helpers ----------------------------------------------------------
     def _clip(self, which: int, ids):
         _, cparams = self.text_encoders[which]
-        return self._clip_jitted[which](cparams, np.asarray(ids))
+        # a rewriter's ids are already on the device, and stay there
+        if not isinstance(ids, jax.Array):
+            ids = np.asarray(ids)
+        return self._clip_jitted[which](cparams, ids)
 
     def _encode(self, prompts, negs, micro_cond=None):
         raise NotImplementedError
 
     # -- stage hooks (prepare_stages / __call__ share these) ---------------
-    def _stage_encode(self, prompts, negs):
+    def _stage_encode(self, prompts, negs, rewritten=None):
         """Encode-stage program: no micro-conditioning (the serve surface
         has none), which `_encode` resolves to the same defaults __call__
-        passes — identical embeddings either way."""
-        return self._encode(prompts, negs, None)
+        passes — identical embeddings either way.  ``rewritten``: the
+        rewrite stage's ids, where the caller ran that stage itself."""
+        if rewritten is None:
+            return self._encode(prompts, negs, None)
+        return self._encode(prompts, negs, None, rewritten)
 
     def _denoise_chunk(self, enc, latents, guidance_scale,
                        num_inference_steps, *, start_step=0, end_step=None,
@@ -1191,23 +1423,41 @@ class DistriSDXLPipeline(_DistriPipelineBase):
     @classmethod
     def from_params(cls, distri_config, unet_config, unet_params, vae_config,
                     vae_params, text_configs, text_params, scheduler="ddim",
-                    tokenizers=None):
+                    tokenizers=None, rewriter=None):
+        """``rewriter``: ``(NemotronHConfig, its params, RewriteSpec)`` puts
+        the think-then-rewrite stage in front of the text encoders."""
+        if rewriter is not None and distri_config.world_size != 1:
+            raise NotImplementedError(
+                "the rewrite stage runs on one chip: its expert layer has no "
+                "exchange between chips")
         sched = scheduler if isinstance(scheduler, BaseScheduler) else get_scheduler(scheduler)
         toks = tokenizers or [SimpleTokenizer(tc.vocab_size) for tc in text_configs]
-        return cls(
+        pipe = cls(
             distri_config, unet_config, unet_params, vae_config, vae_params,
             sched, toks, list(zip(text_configs, text_params)),
         )
+        if rewriter is not None:
+            pipe.rewriter = PromptRewriter(*rewriter, toks)
+        return pipe
 
-    def _encode(self, prompts, negs, micro_cond=None):
+    def _encode(self, prompts, negs, micro_cond=None, rewritten=None):
         cfg = self.distri_config
         texts = negs + prompts if cfg.do_classifier_free_guidance else prompts
         n_br = 2 if cfg.do_classifier_free_guidance else 1
         b = len(prompts)
 
+        if self.rewriter is not None and rewritten is None:
+            rewritten = self._rewrite(prompts)
         with span("distri.pipe.tokenize"):
-            ids1 = _tokenize(self.tokenizers[0], texts)
-            ids2 = _tokenize(self.tokenizers[1], texts)
+            if rewritten is None:
+                ids1 = _tokenize(self.tokenizers[0], texts)
+                ids2 = _tokenize(self.tokenizers[1], texts)
+            else:
+                # the negative branch keeps the caller's words
+                ids1, ids2 = [
+                    ids if n_br == 1 else jnp.concatenate(
+                        [jnp.asarray(_tokenize(tok, negs), ids.dtype), ids])
+                    for tok, ids in zip(self.tokenizers, rewritten)]
         with span("distri.pipe.encode"):
             return self._encode_ids(ids1, ids2, n_br, b, micro_cond)
 

@@ -98,6 +98,11 @@ class PipelineExecutor:
         # surfaced per key by ExecutorCache.weight_bytes / metrics_snapshot
         report = getattr(pipeline, "weight_report", None)
         self.weight_nbytes = report()["total_bytes"] if report else None
+        # a think-then-rewrite language model resident beside the diffusion
+        # model (pipelines.PromptRewriter): its weights are in the ledger
+        # entry above, its host time in the ``rewrite`` stage clock
+        self.rewriter_resident = getattr(pipeline, "rewriter",
+                                         None) is not None
         # prompt/embedding LRU (serve/promptcache.py), attached by the
         # owning server via attach_prompt_cache: None = encode always runs
         self.prompt_cache = None
@@ -184,11 +189,16 @@ class PipelineExecutor:
     def _encode_chunk(self, stages, p_chunk, n_chunk):
         """One compiled-width encode, memoized by (family, tokenizer
         hash, prompt chunk) when a prompt cache is attached."""
+        def encode():
+            # the rewrite stage first, where a rewriter is resident
+            if stages.rewrite is None:
+                return stages.encode(p_chunk, n_chunk)
+            return stages.encode(p_chunk, n_chunk, stages.rewrite(p_chunk))
+
         if self.prompt_cache is None:
-            return stages.encode(p_chunk, n_chunk)
+            return encode()
         key = (self._encode_cache_family, tuple(p_chunk), tuple(n_chunk))
-        return self.prompt_cache.get_or_encode(
-            key, lambda: stages.encode(p_chunk, n_chunk))
+        return self.prompt_cache.get_or_encode(key, encode)
 
     def __call__(
         self,

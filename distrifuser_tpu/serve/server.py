@@ -1559,7 +1559,9 @@ class InferenceServer:
 
     # stage clocks a whole-batch dispatch keeps (`ServeResult.stage_s`; the
     # staged pipeline keeps staging.STAGES, step mode STEP_STAGE_CLOCKS)
-    STAGE_CLOCKS = ("dispatch", "device_wait", "to_host", "post")
+    # ``rewrite`` is cut out of ``dispatch`` where the executor's pipeline
+    # holds a prompt rewriter, and stays 0.0 where it holds none
+    STAGE_CLOCKS = ("dispatch", "device_wait", "to_host", "post", "rewrite")
     STEP_STAGE_CLOCKS = ("begin", "steps", "finish")
 
     def _execute(self, key: BatchKey, batch: List[Request]) -> None:

@@ -402,3 +402,32 @@ def test_weightless_tokenizer_flag_on_output(devices8):
     pipe.tokenizers = [_FakeRealTok(), _FakeRealTok()]
     out2 = pipe("a fox", num_inference_steps=1, output_type="latent", seed=0)
     assert not out2.weightless_tokenizer and out2.warning is None
+
+
+def test_host_image_buffers_come_back_only_when_every_view_is_gone():
+    """`_HostImages`: an image a caller still holds - or any slice of it -
+    is never written again; once the last view is dropped the same memory
+    is handed out for the next image."""
+    import gc
+
+    from distrifuser_tpu.pipelines import _HostImages
+
+    pool = _HostImages()
+    first = pool.take((2, 4, 4, 3))
+    first[...] = 1.0
+    where = first.__array_interface__["data"][0]
+    kept = first[1]  # what a caller keeps of a batch
+    del first
+    gc.collect()
+    second = pool.take((2, 4, 4, 3))
+    assert second.__array_interface__["data"][0] != where
+    second[...] = 2.0
+    assert float(kept.min()) == 1.0 == float(kept.max())
+    del kept, second
+    gc.collect()
+    # both buffers are back: the next two images are written where the
+    # first two were
+    again = [pool.take((2, 4, 4, 3)) for _ in range(2)]
+    assert all(a.flags.writeable and a.dtype == np.float32 for a in again)
+    assert where in {a.__array_interface__["data"][0] for a in again}
+    assert pool.take((1, 2, 2, 3)).shape == (1, 2, 2, 3)  # another size

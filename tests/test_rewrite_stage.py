@@ -1,0 +1,149 @@
+"""The think-then-rewrite stage on the request path: `PromptRewriter` in
+front of a tiny SDXL pipeline, through `InferenceServer` end to end."""
+
+import jax
+import numpy as np
+import pytest
+
+from distrifuser_tpu import DistriConfig
+from distrifuser_tpu.models import nemotron_h as lm
+from distrifuser_tpu.models.clip import (
+    CLIPTextConfig,
+    init_clip_params,
+    tiny_clip_config,
+)
+from distrifuser_tpu.models.unet import init_unet_params, tiny_config
+from distrifuser_tpu.models.vae import init_vae_params, tiny_vae_config
+from distrifuser_tpu.pipelines import (
+    DistriSDXLPipeline,
+    PromptRewriter,
+    RewriteSpec,
+    SimpleTokenizer,
+)
+from distrifuser_tpu.serve import ExecKey, InferenceServer, ServeConfig
+from distrifuser_tpu.serve.executors import pipeline_executor_factory
+
+LM = lm.NemotronHConfig(
+    pattern="MEM*E", vocab_size=300, hidden_size=32, mamba_num_heads=4,
+    mamba_head_dim=8, n_groups=2, ssm_state_size=8, chunk_size=8,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    n_routed_experts=16, n_local_experts=4, first_local_expert=4,
+    num_experts_per_tok=4, moe_latent_size=16, moe_intermediate_size=24,
+    moe_shared_expert_intermediate_size=32)
+SPEC = RewriteSpec(instruction_tokens=10, user_tokens=6, new_tokens=12,
+                   prompt_tokens=5, instruction_seed=1)
+STEPS = 2
+
+
+def build(devices, rewriter, **cfg_kw):
+    dcfg = DistriConfig(devices=devices[:1], height=128, width=128,
+                        warmup_steps=1, **cfg_kw)
+    tc1 = tiny_clip_config(hidden=16)
+    tc2 = CLIPTextConfig(vocab_size=1000, hidden_size=16, num_hidden_layers=2,
+                         num_attention_heads=4, intermediate_size=32,
+                         projection_dim=32)
+    ucfg, vcfg = tiny_config(cross_attention_dim=32, sdxl=True), \
+        tiny_vae_config()
+    return DistriSDXLPipeline.from_params(
+        dcfg, ucfg, init_unet_params(jax.random.PRNGKey(0), ucfg), vcfg,
+        init_vae_params(jax.random.PRNGKey(1), vcfg), [tc1, tc2],
+        [init_clip_params(jax.random.PRNGKey(2), tc1),
+         init_clip_params(jax.random.PRNGKey(3), tc2)],
+        rewriter=((LM, lm.init_nemotron_h_params(jax.random.PRNGKey(9), LM),
+                   SPEC) if rewriter else None))
+
+
+def server(devices, rewriter=True, **kw):
+    def factory(key: ExecKey):
+        return build(devices, rewriter, do_classifier_free_guidance=key.cfg)
+
+    config = ServeConfig(max_batch_size=1, batch_window_s=0.0,
+                         buckets=((128, 128),), default_steps=STEPS,
+                         warmup_buckets=((128, 128, STEPS),), **kw)
+    return InferenceServer(pipeline_executor_factory(factory), config,
+                           model_id="tiny-rewrite-sdxl", scheduler="euler",
+                           mesh_plan="dp1.cfg1.sp1")
+
+
+def test_the_rewriters_ids_are_the_tokenizers_of_the_decimal_words():
+    toks = [SimpleTokenizer(1000), SimpleTokenizer(777)]
+    rw = PromptRewriter(LM, lm.init_nemotron_h_params(
+        jax.random.PRNGKey(9), LM), SPEC, toks)
+    ids = rw.lm_ids("A red fox")
+    assert ids.shape == (16,) and ids.dtype == np.int32
+    # the instruction, then the words cut or repeated to user_tokens
+    assert np.array_equal(ids[:10], rw.instruction)
+    assert np.array_equal(ids[10:13], ids[13:16]) and (ids < 300).all()
+    assert np.array_equal(rw.lm_ids("")[10:], np.zeros(6, np.int32))
+    out = rw(["a red fox"])
+    served = rw.served[-1]
+    text = " ".join(str(i) for i in np.asarray(served.new_ids)[-5:])
+    for tok, got in zip(toks, out):
+        assert isinstance(got, jax.Array) and got.shape == (1, 77)
+        assert np.array_equal(np.asarray(got), tok([text]))
+    assert np.array_equal(served.prompt_ids, rw.lm_ids("a red fox"))
+    assert served.logits.shape == (12, 300)
+    assert np.array_equal(np.asarray(served.logits).argmax(1),
+                          np.asarray(served.new_ids))
+    two = rw(["a red fox", "blue"])
+    assert two[0].shape == (2, 77)
+    assert np.array_equal(np.asarray(two[0][0]), np.asarray(out[0][0]))
+    assert "lm.mamba" in rw.decode_program_text()
+
+
+def test_a_rewriter_needs_the_word_hash_and_whole_chunks():
+    params = lm.init_nemotron_h_params(jax.random.PRNGKey(9), LM)
+    with pytest.raises(ValueError, match="multiple of the scan"):
+        PromptRewriter(LM, params, RewriteSpec(10, 5, 12, 5),
+                       [SimpleTokenizer(1000)])
+    with pytest.raises(ValueError, match="prompt_tokens"):
+        PromptRewriter(LM, params, RewriteSpec(10, 6, 4, 5),
+                       [SimpleTokenizer(1000)])
+    with pytest.raises(ValueError, match="word hash"):
+        PromptRewriter(LM, params, SPEC, [object()])
+
+
+@pytest.mark.parametrize("kind", ["whole", "staged"])
+def test_rewrite_stage_through_the_server(devices8, kind):
+    kw = {"pipeline_stages": True} if kind == "staged" else {}
+    with server(devices8, **kw) as srv:
+        a = srv.submit("a red fox in the forest", height=128, width=128,
+                       guidance_scale=0.0, seed=3).result(timeout=600)
+        b = srv.submit("a red fox in the forest", height=128, width=128,
+                       guidance_scale=0.0, seed=3).result(timeout=600)
+        c = srv.submit("an old sailor", height=128, width=128,
+                       guidance_scale=5.0, seed=3).result(timeout=600)
+    assert np.array_equal(a.output, b.output)  # the same request, the same bytes
+    assert np.isfinite(c.output).all() and not np.array_equal(
+        a.output, c.output)
+    if kind == "whole":
+        assert tuple(a.stage_s) == InferenceServer.STAGE_CLOCKS
+        assert a.stage_s["rewrite"] > 0 and a.stage_s["dispatch"] > 0
+        assert sum(a.stage_s.values()) <= a.execute_s
+
+
+def test_the_rewrite_changes_what_the_encoders_see(devices8):
+    plain, rewriting = build(devices8, False), build(devices8, True)
+    kw = dict(num_inference_steps=STEPS, seed=1, output_type="np")
+    a = plain("a red fox", **kw).images[0]
+    b = rewriting("a red fox", **kw).images[0]
+    assert a.shape == b.shape and not np.array_equal(a, b)
+    report = rewriting.weight_report()["per_component_nbytes"]
+    assert report["rewriter"] > 0 and "rewriter" not in \
+        plain.weight_report()["per_component_nbytes"]
+    stages = rewriting.prepare_stages(STEPS)
+    assert stages.rewrite is not None and plain.prepare_stages(
+        STEPS).rewrite is None
+    # the stage run by the caller, or by encode itself: the same embeddings
+    ids = stages.rewrite(["a red fox"])
+    e1 = stages.encode(["a red fox"], [""], ids)
+    e2 = stages.encode(["a red fox"], [""])
+    for x, y in zip(jax.tree.leaves(e1), jax.tree.leaves(e2)):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_several_chips_are_refused(devices8):
+    with pytest.raises(NotImplementedError, match="one chip"):
+        DistriSDXLPipeline.from_params(
+            DistriConfig(devices=devices8[:2], height=128, width=128),
+            None, None, None, None, [], [], rewriter=(LM, None, SPEC))
