@@ -17,7 +17,8 @@ has 1200 s.  Legs, in order:
   device    platform must be "tpu"; versions and the compile cache in use
   kernels   every Pallas kernel a default or table route can reach, compiled
             (never interpreted) at the shapes the models use, against
-            `_sdpa_xla` / a plain matmul under a written tolerance
+            `_sdpa_xla` / a plain matmul / the grouped expert matmul under
+            a written tolerance
   serve     >= 3 requests + one repeated seed at 1024^2 / 50-step DDIM / CFG
             through a whole-batch server, then through a step-batching
             server with a request joining while another is mid-denoise
@@ -143,6 +144,12 @@ FLASH_ATOL = 2e-2
 # multiply can differ; fp8 products are exact in float32 and only the order
 # of the float32 accumulation differs.  Relative to the largest output.
 QMM_RTOL = {"int8": 1e-5, "fp8": 1e-4}
+# The gather mat-vec kernel against the grouped matmul, bf16 weights and
+# float32 accumulation on both sides: the sums run in another order, so a
+# hidden value near a bf16 rounding boundary can land one ulp (2^-8) apart;
+# a dropped expert or f-tile is an error of the order of the output itself.
+# Relative to the largest output.
+MOE_RTOL = 5e-3
 
 
 def leg_kernels(rehearse: bool) -> dict:
@@ -252,6 +259,49 @@ def leg_kernels(rehearse: bool) -> dict:
                "(upstream, segment ids)",
                lambda q, k, v: padded_flash_sdpa(q, k, v, heads=padded_heads),
                2, padded_len, padded_len, padded_heads, 64)
+
+    # the decode step's routed experts, from their ids: one token's 22
+    # chosen experts, the held ones all over the held range, against the
+    # grouped matmul that three copies of the token (66 rows) reach
+    from distrifuser_tpu.ops import moe
+
+    if rehearse:
+        latent, inter, held_experts, moe_tile = 256, 384, 8, 128
+    else:
+        # Nemotron-3-Super: moe_latent_size, moe_intermediate_size, one
+        # chip's share of eight; None: the kernel's own tile
+        latent, inter, held_experts, moe_tile = 1024, 2688, 64, None
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    token = jax.random.normal(ks[0], (1, latent), dtype)
+    w1 = (jax.random.normal(ks[1], (held_experts, latent, inter), dtype)
+          * latent ** -0.5).astype(dtype)
+    w2 = (jax.random.normal(ks[2], (held_experts, inter, latent), dtype)
+          * inter ** -0.5).astype(dtype)
+    last = held_experts - 1
+    chosen = jnp.asarray([[0, last, last // 2, 1] + list(
+        range(held_experts + 3, held_experts + 21))], jnp.int32)
+    shares = jax.random.uniform(ks[3], chosen.shape, jnp.float32, 0.05, 0.5)
+    with interpret():
+        got, n = jax.block_until_ready(moe.gather_expert_sum(
+            token, chosen, shares, w1, w2, first_expert=0, tile=moe_tile))
+    want, n_want = moe.local_expert_sum(
+        jnp.tile(token, (3, 1)), jnp.tile(chosen, (3, 1)),
+        jnp.tile(shares, (3, 1)), w1, w2, first_expert=0)
+    scale = float(jnp.max(jnp.abs(want[0])))
+    err = float(jnp.max(jnp.abs(got[0] - want[0])))
+    name = (f"expert_gather_matvec d={latent} f={inter} "
+            f"{held_experts} held experts")
+    check(bool(jnp.isfinite(got).all()) and scale > 0,
+          f"kernels: {name}: output not finite")
+    check(int(n) == 4 and int(n_want) == 12,
+          f"kernels: {name}: fetched {int(n)} expert blocks for 4 held "
+          f"assignments (the grouped path counted {int(n_want)} for 12)")
+    check(err <= MOE_RTOL * scale,
+          f"kernels: {name}: max |kernel - grouped matmul| = {err:.3g} > "
+          f"{MOE_RTOL} x {scale:.3g}")
+    say(f"kernels: {name}: compiled, max abs err {err:.3g} "
+        f"(largest output {scale:.3g})")
+    done.append(name)
 
     m, kdim, n = qmm_shape
     x = jax.random.normal(jax.random.PRNGKey(1), (m, kdim), dtype)

@@ -9,29 +9,46 @@ between them) that is the layer's routed output.  This module is the
 per-chip part and nothing else: on one chip the layer runs without its
 exchange, and what the absent experts would have added is left out.
 
-`local_expert_sum` is a grouped matmul: the (token, expert) assignments that
-fall on held experts are sorted by expert, each expert's rows go through its
-own pair of matrices (`lax.ragged_dot`, which the TPU compiler lowers to a
-grouped Mosaic kernel that visits only the non-empty groups' weights - at
-one decoded token, the two or three experts that token chose here, not all
-that are held), and the rows are summed back per token under the router's
+`local_expert_sum` has two forms and takes one by the rows of the call.
+
+A prompt (thousands of rows) is a grouped matmul: the (token, expert)
+assignments that fall on held experts are sorted by expert, each expert's
+rows go through its own pair of matrices (`lax.ragged_dot`, which the TPU
+compiler lowers to a grouped Mosaic kernel that visits only the non-empty
+groups' weights), and the rows are summed back per token under the router's
 weights.  Tokens are routed unevenly and none is dropped: there is no
 capacity.
+
+A decode step (one token, `k` assignment rows, two or three of them on held
+experts) is bandwidth work on those experts' weights and nothing else, and
+the grouped kernel is built for the other case: below `MIN_GROUPED_ROWS` rows
+the compiler even lowers it to one dense matmul over EVERY held expert.  On a
+TPU such a call goes to `gather_expert_sum`, one Pallas kernel a layer: the
+ids, the router's weights and which slots are held are scalars; each
+chosen-and-held expert's `w1[e]`, `w2[e]` are fetched from HBM by id, once,
+in f-tiles that a ring of buffers keeps in flight; both mat-vecs and the
+activation between them run on a tile while the next ones arrive - no sort,
+no group sizes, no padding, no 64-row intermediate.  Off the TPU (the CPU
+tests) the grouped matmul serves small calls too, and the kernel runs only
+interpreted, from tests.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 # Below 64 rows the TPU compiler lowers `ragged_dot` to one dense masked
 # matmul over EVERY group - all the held experts' weights read for one
 # decoded token (seen in the program compiled for a v5e, PR 27) - and from 64
 # rows on to the grouped kernel that visits only the groups that have rows.
-# A decode step's 22 assignment rows are therefore padded with rows that
-# belong to no group.
+# Calls below it take the gather kernel.
 MIN_GROUPED_ROWS = 64
 
 
@@ -52,14 +69,149 @@ def route(u, router_kernel, score_bias, *, top_k: int, scale: float):
     return idx.astype(jnp.int32), weights
 
 
+# The gather kernel's share of the 128 MiB of VMEM a v5e core has: a ring of
+# `_RING` (w1 tile, w2 tile) buffers, `_RING - 1` of them arriving while one
+# is computed on.  Timed alone on one v5e (PR 28, 2.74 held experts a call):
+# tiles 384 / 896 / 2688 at 45.5-46.0 / 46.0-46.2 / 47.8-48.0 us, rings of
+# 2, 3, 4 alike: any tile that lets the first one land early will do.
+_MAX_TILE = 1024
+_RING = 3
+
+
+def _gather_kernel(idx_ref, wts_ref, x_ref, w1_hbm, w2_hbm, out_ref, n_ref,
+                   held_ref, w1_buf, w2_buf, sems, *, first_expert, tile):
+    """All T * k assignment slots of a call: idx / wts [T * k] in SMEM, x
+    [T, d] in VMEM, the held experts' w1 [E, d, f] / w2 [E, f, d] left in
+    HBM -> out [T, d] float32, n [1] = the expert blocks fetched."""
+    n_slots = idx_ref.shape[0]
+    e_local, d, f = w1_hbm.shape
+    t = x_ref.shape[0]
+    k = n_slots // t
+    n_tiles = f // tile
+    ring = w1_buf.shape[0]
+
+    # the slots whose expert is held here, in slot order (scalar core)
+    def scan(s, n):
+        e = idx_ref[s] - first_expert
+        held = (e >= 0) & (e < e_local)
+
+        @pl.when(held)
+        def _():
+            held_ref[n] = s
+        return n + held.astype(jnp.int32)
+
+    n_held = lax.fori_loop(0, n_slots, scan, jnp.int32(0))
+    n_ref[0] = n_held
+    # one chunk = one f-tile of one held slot's expert: w1[e][:, tile] and
+    # w2[e][tile, :], the split exact because the activation is elementwise
+    n_chunks = n_held * n_tiles
+
+    def copies(c):
+        e = idx_ref[held_ref[c // n_tiles]] - first_expert
+        b = c % ring
+        if n_tiles == 1:
+            src1, src2 = w1_hbm.at[e], w2_hbm.at[e]
+        else:
+            off = pl.multiple_of((c % n_tiles) * tile, 128)
+            src1 = w1_hbm.at[e, :, pl.ds(off, tile)]
+            src2 = w2_hbm.at[e, pl.ds(off, tile), :]
+        return (pltpu.make_async_copy(src1, w1_buf.at[b], sems.at[0, b]),
+                pltpu.make_async_copy(src2, w2_buf.at[b], sems.at[1, b]))
+
+    for c in range(ring - 1):  # ring - 1 chunks in flight from here on
+        @pl.when(c < n_chunks)
+        def _():
+            for copy in copies(c):
+                copy.start()
+
+    x = x_ref[...]
+    row = lax.broadcasted_iota(jnp.int32, (t, 1), 0)
+
+    def step(c, acc):
+        @pl.when(c + ring - 1 < n_chunks)
+        def _():
+            for copy in copies(c + ring - 1):
+                copy.start()
+        for copy in copies(c):
+            copy.wait()
+        b = c % ring
+        slot = held_ref[c // n_tiles]
+        # at one row the MXU and a VPU multiply-reduce both hide under the
+        # tile's DMA (45.9-46.2 / 46.2-48.0 us a call); the MXU form is the
+        # grouped path's arithmetic, hidden rounded to bf16 and all
+        hidden = jnp.dot(x, w1_buf[b], preferred_element_type=F32)
+        hidden = jnp.square(jnp.maximum(hidden, 0.0)).astype(x.dtype)
+        out = jnp.dot(hidden, w2_buf[b], preferred_element_type=F32)
+        # the slot's token takes it, under the router's weight
+        return acc + jnp.where(row == slot // k, wts_ref[slot], 0.0) * out
+
+    out_ref[...] = lax.fori_loop(0, n_chunks, step, jnp.zeros((t, d), F32))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("first_expert", "tile", "interpret"))
+def gather_expert_sum(x, idx, weights, w1, w2, *, first_expert: int,
+                      tile: int = None, interpret: bool = False):
+    """`local_expert_sum` for a few tokens, as one Pallas TPU kernel: every
+    chosen-and-held expert's two matrices fetched from HBM by id, once, in
+    ``tile`` columns of f at a time (default: the largest multiple of 128
+    that divides f, up to `_MAX_TILE`); a slot whose expert lies elsewhere
+    costs a scalar comparison and no DMA.  bf16 (the weights' dtype) into
+    the MXU, float32 accumulation, float32 out, like the grouped path.
+    Needs d and f in multiples of 128; ``interpret`` runs it on the CPU."""
+    t, k = idx.shape
+    _, d, f = w1.shape
+    if tile is None:
+        tile = next((c for c in range(min(f, _MAX_TILE), 0, -128)
+                     if f % c == 0), 0)
+    if d % 128 or not tile or f % tile or tile % 128:
+        raise ValueError(f"gather_expert_sum: d={d} and tile={tile} (f={f}) "
+                         "must be multiples of 128, and tile divide f")
+    ring_bytes = 2 * _RING * d * tile * jnp.dtype(w1.dtype).itemsize
+    out, n = pl.pallas_call(
+        functools.partial(_gather_kernel, first_expert=first_expert,
+                          tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # ids and router weights, in SMEM
+            grid=(1,),
+            in_specs=[pl.BlockSpec((t, d), lambda i, *_: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((t, d), lambda i, *_: (0, 0)),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.SMEM((t * k,), jnp.int32),
+                            pltpu.VMEM((_RING, d, tile), w1.dtype),
+                            pltpu.VMEM((_RING, tile, d), w2.dtype),
+                            pltpu.SemaphoreType.DMA((2, _RING))]),
+        out_shape=[jax.ShapeDtypeStruct((t, d), F32),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=ring_bytes + (16 << 20)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        # the device op's name: `lm.moe.experts` stays in its op_name, which
+        # is how the benchmark's `moe_experts_ms_per_token` finds it
+        name="expert_gather_matvec",
+    )(idx.reshape(-1), weights.reshape(-1).astype(F32), x, w1, w2)
+    return out, n[0]
+
+
 def local_expert_sum(x, idx, weights, w1, w2, *, first_expert: int):
     """sum over the chosen experts HELD HERE of w_i * relu(x W1_i)^2 W2_i.
 
     ``x`` [T, d]; ``idx`` / ``weights`` [T, k] from `route` (ids over all
     experts); ``w1`` [E_local, d, f], ``w2`` [E_local, f, d]: experts
     ``first_expert .. first_expert + E_local - 1``.  Returns ([T, d]
-    float32, how many of the T * k assignments fell on held experts)."""
+    float32, how many of the T * k assignments fell on held experts).
+
+    Fewer than `MIN_GROUPED_ROWS` assignments (a decode step) on a TPU go
+    through `gather_expert_sum`; everything else is the grouped matmul."""
     t, k = idx.shape
+    _, d, f = w1.shape
+    if (t * k < MIN_GROUPED_ROWS and d % 128 == 0 and f % 128 == 0
+            and jax.devices()[0].platform == "tpu"):
+        return gather_expert_sum(x, idx, weights, w1, w2,
+                                 first_expert=first_expert)
     e_local = w1.shape[0]
     local = idx - first_expert
     held = (local >= 0) & (local < e_local)
@@ -69,12 +221,10 @@ def local_expert_sum(x, idx, weights, w1, w2, *, first_expert: int):
     sizes = jnp.bincount(group, length=e_local + 1)[:e_local].astype(
         jnp.int32)
     n_held = jnp.sum(sizes)
-    rows = x[order // k]
-    if t * k < MIN_GROUPED_ROWS:
-        rows = jnp.pad(rows, ((0, MIN_GROUPED_ROWS - t * k), (0, 0)))
-    hidden = lax.ragged_dot(rows, w1, sizes, preferred_element_type=F32)
+    hidden = lax.ragged_dot(x[order // k], w1, sizes,
+                            preferred_element_type=F32)
     hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
-    out = lax.ragged_dot(hidden, w2, sizes, preferred_element_type=F32)[:t * k]
+    out = lax.ragged_dot(hidden, w2, sizes, preferred_element_type=F32)
     # rows past the last group belong to no expert held here
     in_a_group = jnp.arange(t * k) < n_held
     out = jnp.where(in_a_group[:, None],

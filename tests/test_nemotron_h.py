@@ -231,3 +231,70 @@ def test_balanced_selection_bias_evens_the_load(params):
     even = first_layer_load(biases[0])
     assert np.abs(uneven - share).max() > 0.3 * share
     assert np.abs(even - share).max() < 0.1 * share
+
+
+def _chosen(held_ids, first, e_local, k=22, total=64, seed=0):
+    """One token's k distinct expert ids: ``held_ids`` and, for the rest,
+    experts outside [first, first + e_local), shuffled."""
+    rng = np.random.default_rng(seed)
+    outside = [e for e in range(total)
+               if not first <= e < first + e_local and e not in held_ids]
+    rest = rng.permutation(outside)[:k - len(held_ids)]
+    return rng.permutation(np.concatenate(
+        [np.asarray(held_ids, np.int64), rest])).astype(np.int32)
+
+
+# (first_expert, per token the held experts among its 22 chosen); 16 held
+GATHER_CASES = {
+    "t1_held0": (0, [[]]),
+    "t1_held1": (0, [[5]]),
+    "t1_held3": (0, [[2, 9, 14]]),
+    "t1_held8": (0, [[0, 3, 4, 7, 8, 11, 12, 15]]),
+    "t2_44_rows": (0, [[1, 6, 13], [6, 10]]),
+    "first_expert_24": (24, [[25, 30, 38]]),
+    "both_ends_of_the_held_range": (24, [[24, 39]]),
+}
+
+
+@pytest.mark.parametrize("case", GATHER_CASES)
+def test_gather_kernel_against_the_grouped_path_and_a_dense_loop(case):
+    """`gather_expert_sum` (the decode-shaped call on a TPU; interpreted
+    here) is `local_expert_sum`'s grouped matmul and a dense loop over the
+    held experts, in bf16 with float32 accumulation, and counts alike."""
+    first, held_ids = GATHER_CASES[case]
+    d, f, e_local, k = 256, 384, 16, 22
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    t = len(held_ids)
+    x = jax.random.normal(keys[0], (t, d), jnp.bfloat16)
+    w1 = (jax.random.normal(keys[1], (e_local, d, f)) * d ** -0.5).astype(
+        jnp.bfloat16)
+    w2 = (jax.random.normal(keys[2], (e_local, f, d)) * f ** -0.5).astype(
+        jnp.bfloat16)
+    # the experts just outside the held range are among the chosen too
+    idx = jnp.asarray(np.stack([_chosen(h, first, e_local, seed=i)
+                                for i, h in enumerate(held_ids)]))
+    assert t * k < moe.MIN_GROUPED_ROWS and all(
+        len(set(row)) == k for row in np.asarray(idx).tolist())
+    weights = jax.random.uniform(keys[3], (t, k), jnp.float32, 0.05, 0.5)
+
+    # (the interpreter's callbacks run JAX ops of their own: wait for them
+    # before this thread dispatches more)
+    got, n = jax.block_until_ready(moe.gather_expert_sum(
+        x, idx, weights, w1, w2, first_expert=first, tile=128,
+        interpret=True))
+    grouped, n_grouped = moe.local_expert_sum(x, idx, weights, w1, w2,
+                                              first_expert=first)
+    dense = np.zeros((t, d), np.float32)
+    for ti in range(t):
+        for e, w in zip(np.asarray(idx[ti]), np.asarray(weights[ti])):
+            if first <= e < first + e_local:
+                hidden = jnp.dot(x[ti], w1[e - first],
+                                 preferred_element_type=jnp.float32)
+                hidden = jnp.square(jax.nn.relu(hidden)).astype(x.dtype)
+                dense[ti] += w * np.asarray(jnp.dot(
+                    hidden, w2[e - first],
+                    preferred_element_type=jnp.float32))
+    assert got.dtype == jnp.float32
+    assert int(n) == int(n_grouped) == sum(map(len, held_ids))
+    close(got, grouped)
+    close(got, dense)

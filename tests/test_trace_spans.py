@@ -486,13 +486,16 @@ def test_seq_minor_flash_stands_between_bitcasts(topo, monkeypatch, build):
     assert len(kv_split) <= 1, kv_split
 
 
-def test_decode_program_at_published_widths_compiles_for_the_chip(topo):
-    """The rewrite stage's decode program - Nemotron-3-Super's published
-    widths, one chip's share - compiled for the described v5e: every expert
-    layer's two matmuls are the grouped kernel that visits only the experts
-    a token chose (below 64 rows the compiler lowers `ragged_dot` to one
-    dense matmul over ALL the held experts: `ops/moe.py` pads for that), the
-    language model's scopes are on its ops, and weights and state fit."""
+def test_decode_program_at_published_widths_compiles_for_the_chip(
+        topo, monkeypatch):
+    """The rewrite stage's two programs - Nemotron-3-Super's published
+    widths, one chip's share - compiled for the described v5e.  Decode: every
+    expert layer's routed experts are ONE call of the gather mat-vec kernel
+    (`ops/moe.py gather_expert_sum`: the experts a token chose here, by id)
+    under the `lm.moe.experts` scope, with no grouped matmul, no sort and no
+    64-row padding around it, and never the dense form over all the held
+    experts; the language model's scopes are on its ops; weights and state
+    fit.  Prefill: its thousands of rows stay on the grouped kernel."""
     import json
 
     from jax.sharding import SingleDeviceSharding
@@ -504,6 +507,8 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(topo):
         SimpleTokenizer,
     )
 
+    # `local_expert_sum` asks the first device for its platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: topo.devices)
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "benchmark", "configs",
                            "nemotron-3-super-sdxl-rewrite.json")) as f:
@@ -527,11 +532,27 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(topo):
         [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32, sharding=one)]
     ).compile()
     text = compiled.as_text()
-    grouped = [ln for ln in text.splitlines()
-               if "custom-call(" in ln and "ragged-dot" in ln
-               and "metadata" not in ln.split("custom-call(")[0]
-               and re.search(r"= f32\[64,(2688|1024)\]", ln)]
-    assert len(grouped) == 2 * cfg.pattern.count("E"), len(grouped)
+    n_e = cfg.pattern.count("E")
+
+    def instructions(text):
+        """(what stands left of ``metadata=``, op_name) per instruction."""
+        for ln in text.splitlines():
+            m = re.search(r'metadata=\{[^}]*?op_name="([^"]*)"', ln)
+            if " = " in ln:
+                yield ln.split("metadata=")[0], m.group(1) if m else ""
+
+    experts = [body for body, scope in instructions(text)
+               if "/lm.moe.experts/" in scope]
+    assert experts
+    kernel = [body for body in experts
+              if 'custom_call_target="tpu_custom_call"' in body]
+    assert len(kernel) == n_e and all(
+        re.match(r"\s*(?:ROOT )?%expert_gather_matvec[\w.\-]* = ", body)
+        for body in kernel), kernel
+    assert "ragged-dot" not in text
+    for body in experts:
+        assert not re.search(r" sort\(", body), body
+        assert not re.search(r"bf16\[64,1024\]", body), body
     assert not re.search(r"convolution[\w.\-]* \(kernel[^)]*bf16\[64,1024,2688\]",
                          text)  # the dense form over all the held experts
     for scope in ("lm.mamba", "lm.attn", "lm.moe.router", "lm.moe.experts",
@@ -540,3 +561,13 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(topo):
     mem = compiled.memory_analysis()
     assert 5.4e9 < mem.argument_size_in_bytes < 5.7e9
     assert mem.temp_size_in_bytes < 0.5e9
+
+    prefill = rw._prefill.lower(params, ids).compile().as_text()
+    # (the compiler names them itself: op_name "ragged-dot-none", no scope)
+    rows = ids.shape[0] * cfg.num_experts_per_tok
+    grouped = [body for body, _ in instructions(prefill)
+               if re.match(r"\s*%ragged-dot-none[\w.\-]* = "
+                           rf"f32\[{rows},(2688|1024)\]\S* custom-call\(",
+                           body)]
+    assert len(grouped) == 2 * n_e, len(grouped)
+    assert "expert_gather_matvec" not in prefill
