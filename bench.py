@@ -162,7 +162,7 @@ def main():
     parser.add_argument("--weight_quant", type=str, default="none",
                         choices=["none", "int8", "fp8"])
     parser.add_argument("--quant_compute", type=str, default="auto",
-                        choices=["off", "auto", "dot", "pallas"])
+                        choices=["off", "auto", "dot"])
     args = parser.parse_args()
     deadline = time.time() + args.total_budget_s - 90.0  # margin before driver
     disarm_watchdog = _arm_watchdog(deadline)

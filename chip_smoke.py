@@ -138,12 +138,6 @@ def leg_device(cache_dir: str) -> dict:
 # 1e-3..4e-3 measured on one v5e (PR 21), 2e-2 leaves room and still catches
 # a wrong mask or a dropped tile (errors of order 1e-1 and up).
 FLASH_ATOL = 2e-2
-# quant_matmul against a plain matmul of the SAME quantized operands: int8
-# products accumulate exactly in int32 and, cast to float32, exactly in the
-# reference too (|sum| < 2^24 for K <= 1040), so only the float32 scale
-# multiply can differ; fp8 products are exact in float32 and only the order
-# of the float32 accumulation differs.  Relative to the largest output.
-QMM_RTOL = {"int8": 1e-5, "fp8": 1e-4}
 # The gather mat-vec kernel against the grouped matmul, bf16 weights and
 # float32 accumulation on both sides: the sums run in another order, so a
 # hidden value near a bf16 rounding boundary can land one ulp (2^-8) apart;
@@ -161,12 +155,6 @@ def leg_kernels(rehearse: bool) -> dict:
         flash_sdpa,
         padded_flash_sdpa,
         upstream_flash_sdpa,
-    )
-    from distrifuser_tpu.ops.quant_matmul import quant_matmul
-    from distrifuser_tpu.parallel.compress import (
-        fp8_supported,
-        quantize,
-        quantize_weight,
     )
 
     dtype = jnp.float32 if rehearse else jnp.bfloat16
@@ -211,20 +199,19 @@ def leg_kernels(rehearse: bool) -> dict:
         levels = [("level1", 256, 2), ("level2", 256, 4)]
         tiles = (128, 128)
         padded_len, padded_heads = 330, 2
-        qmm_shape = (64, 128, 256)
     else:
         # SDXL 1024^2: level 1 is 64x64 tokens x 10 heads, level 2 is 32x32
         # x 20 heads, d = 64; CFG batch 2 on one chip
         levels = [("level1", 4096, 10), ("level2", 1024, 20)]
         tiles = None  # the routing table's
         padded_len, padded_heads = 4250, 24  # SD3 joint 4096 + 154
-        qmm_shape = (8192, 640, 5120)  # level-1 GEGLU projection, CFG batch 2
 
     kernels = {"inrepo": flash_sdpa, "upstream": upstream_flash_sdpa}
     for level, length, heads in levels:
-        route = sdpa_routing.lookup(length, 64)
         if tiles is None:
-            check(route is not None and route.impl in kernels,
+            route = sdpa_routing.route(length, length, heads * 64, heads,
+                                       jax.devices()[0].platform)
+            check(route.impl in kernels,
                   f"kernels: table route for L={length} d=64 is {route}, "
                   "expected a flash kernel")
             impl, bq, bk = route.impl, route.block_q, route.block_k
@@ -303,29 +290,6 @@ def leg_kernels(rehearse: bool) -> dict:
         f"(largest output {scale:.3g})")
     done.append(name)
 
-    m, kdim, n = qmm_shape
-    x = jax.random.normal(jax.random.PRNGKey(1), (m, kdim), dtype)
-    w = jax.random.normal(jax.random.PRNGKey(2), (kdim, n), dtype) * 0.05
-    for mode in ["int8"] + (["fp8"] if fp8_supported() else []):
-        # the operands ops/linear.py feeds the kernel
-        xq, _ = quantize(x, mode, axis=-1)
-        qt = quantize_weight(w, mode)
-        wq, sw = qt.payload, qt.channel_scale()
-        with interpret():
-            got = jax.block_until_ready(quant_matmul(xq, wq, sw))
-        want = jnp.matmul(xq.astype(jnp.float32), wq.astype(jnp.float32),
-                          precision=jax.lax.Precision.HIGHEST) * sw
-        scale = float(jnp.max(jnp.abs(want)))
-        err = float(jnp.max(jnp.abs(got - want)))
-        name = f"quant_matmul {mode} {m}x{kdim}x{n} default tiles"
-        check(bool(jnp.isfinite(got).all()) and scale > 0,
-              f"kernels: {name}: output not finite")
-        check(err <= QMM_RTOL[mode] * scale,
-              f"kernels: {name}: max |kernel - matmul| = {err:.3g} > "
-              f"{QMM_RTOL[mode]} x {scale:.3g}")
-        say(f"kernels: {name}: compiled, max abs err {err:.3g} "
-            f"(largest output {scale:.3g})")
-        done.append(name)
     return {"kernels_compiled": done, "kernels_interpreted": bool(rehearse)}
 
 

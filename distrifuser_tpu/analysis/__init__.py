@@ -17,8 +17,8 @@ runner.  This package machine-checks them:
   ``file:line``, severity, and a stable fingerprint;
 * **suppressions** live in a checked-in baseline (analysis/baseline.txt)
   where every entry requires a ``# provenance:`` reason line — the same
-  contract the measured routing tables enforce on their data
-  (scripts/lint_route_tables.py, itself folded in as a checker);
+  contract the attention route table enforces on its rows (each names the
+  origin of its verdict; the `route-tables` checker);
 * ``python -m distrifuser_tpu.analysis --strict`` is the one entry point,
   wired into tier-1 CI as a hard gate before pytest.
 

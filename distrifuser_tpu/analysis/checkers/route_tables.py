@@ -1,134 +1,91 @@
-"""Measured routing tables: provenance + parse (the original lint).
+"""The attention route table: well formed, and every row with its origin.
 
-The sdpa/gemm routing tables are measurement DATA committed as code
-(regenerated by scripts/update_sdpa_table.py / update_gemm_table.py from
-chip-campaign logs).  A hand-edit that drops the provenance line,
-desyncs it from the generated block's comment, or malforms a key would
-silently turn "reviewable measurement" into "unexplained magic
-constant".  This checker is `scripts/lint_route_tables.py` folded into
-the framework — the script remains as a thin shim so existing workflows
-and tests/test_routing_tables.py keep one behavior — and is the pattern
-the rest of distrilint generalizes: invariants as checks, suppressions
-as reviewed data.
+`ops/sdpa_routing.py TABLE` is measurement DATA committed as code: per head
+dim, inclusive ranges of kv_len, each with the kernel and tiles that won
+there and where that was measured.  A hand-edit that overlaps two ranges,
+names a kernel that does not exist, writes a tile no length of its range
+could have run, or drops the origin would silently turn "reviewable
+measurement" into "unexplained magic constant".  Invariants as checks,
+suppressions as reviewed data — the pattern the rest of distrilint
+generalizes.
 """
 
 from __future__ import annotations
 
-import re
-from typing import List, Optional
+from typing import List
 
 from ..core import CheckContext, Finding
 
 NAME = "route-tables"
-DESCRIPTION = ("sdpa + gemm measured tables parse and carry provenance "
-               "synced to their generated blocks")
+DESCRIPTION = ("the sdpa route table parses; ranges disjoint and ascending, "
+               "kernels known, tiles fit, every row with its origin")
 
 SDPA_PATH = "distrifuser_tpu/ops/sdpa_routing.py"
-GEMM_PATH = "distrifuser_tpu/ops/gemm_routing.py"
+TABLE_IMPLS = ("xla", "inrepo", "upstream")
 
 
-def _finding(path: str, message: str, identity: str) -> Finding:
-    return Finding(checker=NAME, path=path, line=0, message=message,
+def _finding(message: str, identity: str) -> Finding:
+    return Finding(checker=NAME, path=SDPA_PATH, line=0, message=message,
                    identity=identity)
 
 
-def _block_provenance(src: str, begin: str, end: str) -> Optional[str]:
-    m = re.search(re.escape(begin) + r"(.*?)" + re.escape(end), src,
-                  flags=re.DOTALL)
-    if not m:
-        return None
-    p = re.search(r"^# provenance: (.+)$", m.group(1), flags=re.MULTILINE)
-    return p.group(1).strip() if p else ""
-
-
 def check_tables() -> List[Finding]:
-    """The table checks, import-based (tables must parse to be checked
+    """The table checks, import-based (the table must parse to be checked
     at all — an ImportError IS the finding)."""
     findings: List[Finding] = []
     try:
-        from ...ops import gemm_routing, sdpa_routing
+        from ...ops import sdpa_routing
     except Exception as exc:
-        return [_finding(SDPA_PATH,
-                         f"routing tables failed to import: {exc}",
+        return [_finding(f"route table failed to import: {exc}",
                          "import-error")]
 
-    def check_provenance(mod, path: str, begin: str, end: str, tag: str):
-        with open(mod.__file__) as f:
-            src = f.read()
-        comment = _block_provenance(src, begin, end)
-        if comment is None:
+    for d, rows in sdpa_routing.TABLE.items():
+        if not (isinstance(d, int) and d > 0 and isinstance(rows, tuple)):
             findings.append(_finding(
-                path, f"{tag}: generated block markers missing",
-                f"{tag}:markers"))
-        elif not comment:
-            findings.append(_finding(
-                path, f"{tag}: no '# provenance:' line in the generated "
-                "block", f"{tag}:comment-missing"))
-        prov = getattr(mod, "MEASURED_PROVENANCE", None)
-        if not (isinstance(prov, str) and prov.strip()):
-            findings.append(_finding(
-                path, f"{tag}: MEASURED_PROVENANCE missing/empty",
-                f"{tag}:provenance-missing"))
-        elif comment and comment != prov:
-            findings.append(_finding(
-                path, f"{tag}: provenance comment {comment!r} != "
-                f"MEASURED_PROVENANCE {prov!r}", f"{tag}:desync"))
-
-    check_provenance(sdpa_routing, SDPA_PATH,
-                     "# --- BEGIN MEASURED_ROUTES",
-                     "# --- END MEASURED_ROUTES ---", "sdpa")
-    for table in ("MEASURED_ROUTES", "MODEL_VALIDATED_OVERRIDES"):
-        for key, route in getattr(sdpa_routing, table).items():
-            if not (isinstance(key, tuple) and len(key) == 2
-                    and all(isinstance(x, int) for x in key)):
+                f"sdpa table key malformed: {d!r} (a head dim -> a tuple "
+                "of rows)", f"sdpa:key:{d!r}"))
+            continue
+        last_hi = 0
+        for row in rows:
+            where = f"{d!r}:{tuple(row)[:2]!r}"
+            if not (isinstance(row, sdpa_routing.Row)
+                    and isinstance(row.kv_lo, int)
+                    and isinstance(row.kv_hi, int)
+                    and 0 < row.kv_lo <= row.kv_hi):
                 findings.append(_finding(
-                    SDPA_PATH, f"sdpa route key malformed: {key!r}",
-                    f"sdpa:key:{key!r}"))
+                    f"sdpa row malformed at head dim {d}: {row!r}",
+                    f"sdpa:key:{where}"))
                 continue
-            if not isinstance(route, sdpa_routing.Route) or (
-                    route.impl not in ("xla", "inrepo", "upstream")):
+            if row.kv_lo <= last_hi:
                 findings.append(_finding(
-                    SDPA_PATH, f"sdpa route value malformed: {key!r} -> "
-                    f"{route!r}", f"sdpa:value:{key!r}"))
+                    f"sdpa rows of head dim {d} overlap or descend at "
+                    f"{row.kv_lo}..{row.kv_hi} (previous row ends at "
+                    f"{last_hi})", f"sdpa:order:{where}"))
+            last_hi = max(last_hi, row.kv_hi)
+            route = row.route
+            if not isinstance(route, sdpa_routing.Route) or (
+                    route.impl not in TABLE_IMPLS) or route.kernel is not None:
+                findings.append(_finding(
+                    f"sdpa route value malformed: {where} -> {route!r}",
+                    f"sdpa:value:{where}"))
                 continue
             # a tile is a power of two from the 128-lane minimum up to its
-            # bucket's length, so it divides that length.  sdpa fits tiles
-            # to each call, but an entry that does not fit its own bucket
-            # records a measurement that cannot have been made.
+            # range's longest length.  sdpa fits tiles to each call, but a
+            # row whose tile fits no length of its range records a
+            # measurement that cannot have been made.
             for tile in (route.block_q, route.block_k):
                 if tile is not None and not (
-                        isinstance(tile, int) and 128 <= tile <= 2 ** key[1]
+                        isinstance(tile, int) and 128 <= tile <= row.kv_hi
                         and tile & (tile - 1) == 0):
                     findings.append(_finding(
-                        SDPA_PATH, f"sdpa {table} {key!r}: tile {tile!r} is "
-                        f"not a power of two in [128, 2^{key[1]}]",
-                        f"sdpa:tile:{table}:{key!r}"))
-
-    check_provenance(gemm_routing, GEMM_PATH,
-                     "# --- BEGIN MEASURED_GEMM_ROUTES",
-                     "# --- END MEASURED_GEMM_ROUTES ---", "gemm")
-    backend = getattr(gemm_routing, "MEASURED_BACKEND", None)
-    if not isinstance(backend, str):
-        findings.append(_finding(
-            GEMM_PATH, "gemm: MEASURED_BACKEND missing",
-            "gemm:backend-missing"))
-    if gemm_routing.MEASURED_ROUTES and not backend:
-        findings.append(_finding(
-            GEMM_PATH, "gemm table has routes but no MEASURED_BACKEND — "
-            "unscoped measurements would govern every platform",
-            "gemm:backend-unscoped"))
-    for key, route in gemm_routing.MEASURED_ROUTES.items():
-        if not (isinstance(key, tuple) and len(key) == 2
-                and isinstance(key[0], str) and key[0] in ("int8", "fp8")
-                and isinstance(key[1], int)):
-            findings.append(_finding(
-                GEMM_PATH, f"gemm route key malformed: {key!r}",
-                f"gemm:key:{key!r}"))
-        if not isinstance(route, gemm_routing.GemmRoute) or (
-                route.impl not in gemm_routing.GEMM_IMPLS):
-            findings.append(_finding(
-                GEMM_PATH, f"gemm route value malformed: {key!r} -> "
-                f"{route!r}", f"gemm:value:{key!r}"))
+                        f"sdpa row {where}: tile {tile!r} is not a power "
+                        f"of two in [128, {row.kv_hi}]",
+                        f"sdpa:tile:{where}"))
+            if not (isinstance(row.origin, str) and row.origin.strip()):
+                findings.append(_finding(
+                    f"sdpa row {where}: no origin (the ledger line or the "
+                    "measurement its verdict came from)",
+                    f"sdpa:origin:{where}"))
     return findings
 
 

@@ -573,10 +573,9 @@ def quantize_params(tree, mode: str, *, compute: str = "dequant",
     already carry quantized leaves).
 
     ``compute`` tags each QuantizedTensor with its execution policy
-    ("dequant" = PR-6 lazy-dequant storage semantics; "auto"/"dot"/
-    "pallas" route the consuming matmul through the low-precision paths
-    of ops/gemm_routing.py — DistriConfig.quant_compute maps "off" to
-    "dequant" here).  ``channel_tile`` groups output channels per scale
+    ("dequant" = PR-6 lazy-dequant storage semantics; "auto"/"dot"
+    run the consuming matmul as a low-precision dot, ops/linear.py —
+    DistriConfig.quant_compute maps "off" to "dequant" here).  ``channel_tile`` groups output channels per scale
     (1 = per-channel, the parity-pinned default).  On an ALREADY-quantized
     tree at the same mode, payloads and scales are kept bit-identical and
     only the compute policy re-tags (a reloaded archive carries storage,
@@ -689,10 +688,10 @@ def set_quant_compute(tree, policy: str):
     from ..parallel.compress import QuantizedTensor
 
     leaf = "dequant" if policy == "off" else policy
-    if leaf not in ("dequant", "auto", "dot", "pallas"):
+    if leaf not in ("dequant", "auto", "dot"):
         raise ValueError(
-            f"quant_compute policy must be 'off', 'auto', 'dot', or "
-            f"'pallas', got {policy!r}"
+            f"quant_compute policy must be 'off', 'auto', or 'dot', got "
+            f"{policy!r}"
         )
 
     def walk(node):

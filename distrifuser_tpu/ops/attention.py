@@ -29,87 +29,8 @@ from jax import lax
 
 from ..parallel.collectives import all_gather
 from ..parallel.context import PatchContext
+from . import flash_attention, sdpa_routing
 from .linear import linear
-from .sdpa_routing import Route, lookup
-
-
-import os
-
-_FLASH_MIN_LEN = 1024
-
-
-def _largest_dividing_tile(preferred: int, length: int):
-    """Largest power-of-2 tile <= ``preferred`` that divides ``length``.
-
-    Walks down from the power-of-2 floor of min(preferred, length) by
-    halving; returns None below 128 (the TPU lane minimum) — callers treat
-    that as "no usable tile".
-    """
-    tile = 1 << (min(preferred, length).bit_length() - 1)
-    while tile >= 128:
-        if length % tile == 0:
-            return tile
-        tile //= 2
-    return None
-
-
-def _resolve_route(q, k, heads: int) -> Route:
-    """Pick the SDPA backend for this shape.
-
-    Resolution order (sdpa_routing module docstring): operator env overrides
-    (DISTRIFUSER_TPU_FLASH=0 disables flash, =1 forces it — interpret mode
-    off-TPU is for tests only; _IMPL/_BQ/_BK select kernel and tiles), then
-    the checked-in measured table, then the analytic default (flash for
-    long block-aligned sequences on TPU).
-
-    NOTE: env overrides are read at TRACE time. jit caches do not key on
-    os.environ, so changing DISTRIFUSER_TPU_FLASH* after a program has
-    been traced silently keeps the old route; call
-    ``jax.clear_caches()`` (or build a fresh runner/pipeline) after
-    changing them.  The overrides are a research escape hatch — the
-    supported configuration surface is DistriConfig + the measured table.
-    """
-    b, lq, c = q.shape
-    lk = k.shape[1]
-    d = c // heads
-    aligned = lq % 128 == 0 and lk % 128 == 0 and d % 8 == 0 and c % heads == 0
-    cpu = jax.devices()[0].platform == "cpu"
-
-    env = os.environ.get("DISTRIFUSER_TPU_FLASH")
-    explicit_impl = os.environ.get("DISTRIFUSER_TPU_FLASH_IMPL")
-    bq = os.environ.get("DISTRIFUSER_TPU_FLASH_BQ")
-    bk = os.environ.get("DISTRIFUSER_TPU_FLASH_BK")
-    tiles = (int(bq) if bq else None, int(bk) if bk else None)
-
-    if env == "0" or not aligned:
-        return Route("xla")
-    forced = env == "1"
-    if explicit_impl:
-        if explicit_impl == "xla":
-            return Route("xla")
-        if forced or (not cpu and lk >= _FLASH_MIN_LEN):
-            return Route(explicit_impl, *tiles)
-        return Route("xla")
-    if forced:
-        # explicit tile tuning targets the in-repo kernel; CPU = interpret
-        impl = "inrepo" if (cpu or tiles != (None, None)) else "upstream"
-        return Route(impl, *tiles)
-    if cpu:
-        return Route("xla")
-
-    measured = lookup(lk, d)
-    if tiles != (None, None) and lk >= _FLASH_MIN_LEN:
-        # explicit tile tuning selects the in-repo kernel; measured tiles
-        # fill whichever axis the operator left unset
-        inrepo_measured = measured if measured and measured.impl == "inrepo" else None
-        return Route(
-            "inrepo",
-            tiles[0] or (inrepo_measured.block_q if inrepo_measured else None),
-            tiles[1] or (inrepo_measured.block_k if inrepo_measured else None),
-        )
-    if measured is not None:
-        return Route(measured.impl, measured.block_q, measured.block_k)
-    return Route("upstream" if lk >= _FLASH_MIN_LEN else "xla")
 
 
 # Above this many fp32 logit elements (B*H*Lq*Lk), the unfused softmax path
@@ -137,10 +58,11 @@ def _sdpa_xla(q, k, v, scale):
 def sdpa(q, k, v, *, heads: int):
     """Scaled dot-product attention over [B, L, C] tensors with H heads.
 
-    The analog of F.scaled_dot_product_attention (attn.py:87,153): the Pallas
-    flash kernel (ops/flash_attention.py) for long sequences on TPU; XLA
-    einsum+softmax otherwise, with query chunking once the score matrix would
-    exceed ~1 GiB (e.g. the VAE's 65k-token single-head mid attention at
+    The analog of F.scaled_dot_product_attention (attn.py:87,153): the
+    kernel `sdpa_routing.route` names for this shape — a Pallas flash kernel
+    (ops/flash_attention.py), its tiles fitted to the call here — or XLA
+    einsum+softmax, with query chunking once the score matrix would exceed
+    ~1 GiB (on the CPU, the VAE's 65k-token single-head mid attention at
     2048x2048, where materializing L^2 logits cannot fit).
 
     The routed kernel runs or the call raises: a kernel that fails to trace
@@ -149,71 +71,51 @@ def sdpa(q, k, v, *, heads: int):
     used to fall to — a silent fall-through is a 2.4x slower program that
     still "works").
     """
-    route = _resolve_route(q, k, heads)
-    if route.impl != "xla":
-        from .flash_attention import (
-            DEFAULT_BLOCK_K,
-            DEFAULT_BLOCK_Q,
-            flash_sdpa,
-            upstream_flash_sdpa,
-        )
-
-        # Mosaic kernels only compile for TPU; on the CPU platform (tests)
-        # the in-repo kernel runs in interpret mode.  Nothing on a "tpu"
-        # platform reaches interpret=True.
-        interpret = jax.devices()[0].platform == "cpu"
-        lq, lk = q.shape[1], k.shape[1]
-        if route.impl == "upstream":
-            if interpret:
-                raise ValueError(
-                    "sdpa route 'upstream' (jax.experimental's Mosaic flash "
-                    "kernel) needs a TPU; on the CPU platform force the "
-                    "interpret-mode kernel with "
-                    "DISTRIFUSER_TPU_FLASH_IMPL=inrepo"
-                )
-            # tiles generalize across the log2 bucket but may not divide
-            # THIS call's lengths (the kernel would assert at trace).  A
-            # non-dividing tile cannot simply be dropped: the kernel fills
-            # a lone None with its hardcoded 512/1024 defaults, which may
-            # themselves not divide (e.g. Lk=57600 % 1024 != 0) — so fit
-            # each tile down to the largest power-of-2 divisor, and if
-            # either cannot be fitted pass NO tiles (full upstream
-            # per-generation defaults) rather than a mixed pair.
-            ubq, ubk = route.block_q, route.block_k
-            if ubq or ubk:
-                ubq = _largest_dividing_tile(ubq or 512, lq)
-                ubk = _largest_dividing_tile(ubk or 1024, lk)
-                if ubq is None or ubk is None:
-                    ubq = ubk = None
-            return upstream_flash_sdpa(q, k, v, heads=heads,
-                                       block_q=ubq, block_k=ubk)
-        # same fitting as above: the table's tiles hold for the whole log2
-        # bucket and for the patch path's local Lq, so each is cut down to
-        # the largest power-of-2 that divides THIS call's length
-        bq = _largest_dividing_tile(route.block_q or DEFAULT_BLOCK_Q, lq)
-        bk = _largest_dividing_tile(route.block_k or DEFAULT_BLOCK_K, lk)
-        return flash_sdpa(
-            q, k, v, heads=heads, block_q=bq, block_k=bk, interpret=interpret
-        )
     b, lq, c = q.shape
     lk = k.shape[1]
+    platform = jax.devices()[0].platform
+    route = sdpa_routing.route(lq, lk, c, heads, platform)
+    fit = flash_attention.largest_dividing_tile
+    if route.impl == "padded":
+        return flash_attention.padded_flash_sdpa(q, k, v, heads=heads,
+                                                 impl=route.kernel)
+    if route.impl == "upstream":
+        if platform == "cpu":
+            raise ValueError(
+                "sdpa route 'upstream' (jax.experimental's Mosaic flash "
+                "kernel) needs a TPU; on the CPU platform the in-repo "
+                "kernel runs in interpret mode (DISTRIFUSER_TPU_FLASH=1)"
+            )
+        # a row's tiles hold for its whole range but may not divide THIS
+        # call's lengths (the kernel would assert at trace).  A
+        # non-dividing tile cannot simply be dropped: the kernel fills
+        # a lone None with its hardcoded 512/1024 defaults, which may
+        # themselves not divide (e.g. Lk=57600 % 1024 != 0) — so fit
+        # each tile down to the largest power-of-2 divisor, and if
+        # either cannot be fitted pass NO tiles (full upstream
+        # per-generation defaults) rather than a mixed pair.
+        ubq, ubk = route.block_q, route.block_k
+        if ubq or ubk:
+            ubq, ubk = fit(ubq or 512, lq), fit(ubk or 1024, lk)
+            if ubq is None or ubk is None:
+                ubq = ubk = None
+        return flash_attention.upstream_flash_sdpa(
+            q, k, v, heads=heads, block_q=ubq, block_k=ubk)
+    if route.impl == "inrepo":
+        # same fitting as above, and for the patch path's local Lq.  Mosaic
+        # kernels only compile for TPU; on the CPU platform (tests) the
+        # kernel runs in interpret mode.  Nothing on a "tpu" platform
+        # reaches interpret=True.
+        return flash_attention.flash_sdpa(
+            q, k, v, heads=heads,
+            block_q=fit(route.block_q or flash_attention.DEFAULT_BLOCK_Q, lq),
+            block_k=fit(route.block_k or flash_attention.DEFAULT_BLOCK_K, lk),
+            interpret=platform == "cpu",
+        )
+    if route.impl != "xla":
+        raise ValueError(f"sdpa: no kernel for route {route!r}")
     d = c // heads
     scale = 1.0 / d**0.5
-    # unaligned-but-long sequences (SD3's 4096+154 joint stream): flash via
-    # pad-and-mask instead of the chunked XLA softmax the alignment gate
-    # would otherwise force — padded flash cut SD3-medium 20.2 -> 8.3 s
-    # (segment-masked upstream kernel, one v5e, 2026-07-31).  Operator pins
-    # (FLASH=0 / IMPL=xla) still win.  d is bounded to the swept range:
-    # unswept head dims stay on the XLA path.
-    if (jax.devices()[0].platform != "cpu"
-            and os.environ.get("DISTRIFUSER_TPU_FLASH") != "0"
-            and os.environ.get("DISTRIFUSER_TPU_FLASH_IMPL") != "xla"
-            and lk >= _FLASH_MIN_LEN and c % heads == 0
-            and d % 8 == 0 and d <= 256
-            and (lq % 128 or lk % 128)):
-        from .flash_attention import padded_flash_sdpa
-
-        return padded_flash_sdpa(q, k, v, heads=heads)
     q = q.reshape(b, lq, heads, d)
     k = k.reshape(b, lk, heads, d)
     v = v.reshape(b, lk, heads, d)

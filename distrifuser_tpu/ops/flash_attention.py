@@ -25,16 +25,15 @@ table (ops/sdpa_routing.py):
   signature, for the shapes the table leaves with it (very long sequences,
   the segment-masked padded route).
 
-``flash_sdpa`` is a drop-in for ops.attention.sdpa; attention.py routes long,
-block-aligned sequences on TPU to a flash kernel and everything else (small
-cross-attention over 77 text tokens) to the XLA softmax path.
+``flash_sdpa`` is a drop-in for ops.attention.sdpa, which runs the kernel
+ops/sdpa_routing.py names for the call's shape, with the tiles fitted to
+its lengths (``largest_dividing_tile``).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +50,21 @@ _NEG_INF = -1e30
 _KV_UNROLL = 8
 # what one kernel instance may ask of the 128 MiB of VMEM a v5e core has
 _VMEM_CEILING = 100 << 20
+
+
+def largest_dividing_tile(preferred: int, length: int):
+    """Largest power-of-2 tile <= ``preferred`` that divides ``length``.
+
+    Walks down from the power-of-2 floor of min(preferred, length) by
+    halving; returns None below 128 (the TPU lane minimum) — callers treat
+    that as "no usable tile".
+    """
+    tile = 1 << (min(preferred, length).bit_length() - 1)
+    while tile >= 128:
+        if length % tile == 0:
+            return tile
+        tile //= 2
+    return None
 
 
 def _flash_kernel(qT_ref, kT_ref, vT_ref, oT_ref, *, scale, block_k, kv_len):
@@ -110,10 +124,9 @@ def upstream_flash_sdpa(q, k, v, segment_ids=None, *, heads: int,
 
     The upstream kernel (pallas/ops/tpu/flash_attention) carries
     per-generation block-size defaults; ``block_q``/``block_k`` override
-    them (forward blocks only — inference has no backward pass), letting
-    the chip campaign's tune phase sweep this kernel the same way it
-    sweeps the in-repo one.  ``segment_ids`` is the upstream SegmentIds
-    pair (cross-segment attention masked) — padded_flash_sdpa's pad mask.
+    them (forward blocks only — inference has no backward pass).
+    ``segment_ids`` is the upstream SegmentIds pair (cross-segment attention
+    masked) — padded_flash_sdpa's pad mask.
     """
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes,
@@ -226,7 +239,7 @@ def padding_segment_ids(b: int, lq: int, lq_pad: int, lk: int, lk_pad: int):
 
 
 def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
-                      interpret: bool = False, impl: str = None):
+                      interpret: bool = False, impl: str = "upstream"):
     """Flash attention for UNALIGNED sequence lengths via pad-and-mask.
 
     Long sequences whose length is not a lane multiple (SD3's 4096+154
@@ -236,31 +249,20 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
     logits (zero softmax weight), pad query rows compute garbage and are
     sliced off.
 
-    ``impl``: "upstream" (segment-ids mask, ``padding_segment_ids``) or
-    "inrepo" (static kv_len mask).  Resolution: the ``impl`` argument,
-    else DISTRIFUSER_TPU_PADDED_IMPL, else — honoring the operator's
-    kernel-wide DISTRIFUSER_TPU_FLASH_IMPL=inrepo pin — "inrepo", else
-    "upstream" (the model-level A/B at SD3-medium 1024², 2026-07:
-    upstream 8.32 s vs inrepo 13.54 s vs chunked XLA 20.17 s, the two
-    kernels agreeing to 5e-4 on chip — against the in-repo kernel of that
-    date; the sequence-minor one that replaced it in PR 25 has not been
-    A/B'd on this route).  The resolved kernel runs or the call raises; the
-    in-repo kernel is reachable only as an explicit route, never as a
-    fallback.  ``interpret`` exists for the in-repo kernel only.
+    ``impl``: "upstream" (segment-ids mask, ``padding_segment_ids``), the
+    default, or "inrepo" (static kv_len mask).  The model-level A/B at
+    SD3-medium 1024², 2026-07: upstream 8.32 s vs inrepo 13.54 s vs chunked
+    XLA 20.17 s, the two kernels agreeing to 5e-4 on chip — against the
+    in-repo kernel of that date; the sequence-minor one that replaced it in
+    PR 25 has not been A/B'd on this route.  The named kernel runs or the
+    call raises; the in-repo kernel is reachable only as an explicit route,
+    never as a fallback.  ``interpret`` exists for the in-repo kernel only.
     """
-    # lazy import avoids a cycle: attention.py only imports this module
-    # inside function bodies
-    from .attention import _largest_dividing_tile
-
-    impl = impl or os.environ.get("DISTRIFUSER_TPU_PADDED_IMPL")
-    if impl is None and os.environ.get("DISTRIFUSER_TPU_FLASH_IMPL") == "inrepo":
-        impl = "inrepo"
-    impl = impl or "upstream"
     if impl not in ("upstream", "inrepo"):
         # loud: a typo here would silently cost SD3 its 39% (8.3 vs 13.5 s)
         raise ValueError(
-            f"DISTRIFUSER_TPU_PADDED_IMPL/impl must be 'upstream' or "
-            f"'inrepo', got {impl!r}")
+            f"padded_flash_sdpa: impl must be 'upstream' or 'inrepo', "
+            f"got {impl!r}")
     b, lq, c = q.shape
     lk = k.shape[1]
     lq_pad = -(-lq // align) * align
@@ -278,8 +280,8 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
         seg = padding_segment_ids(b, lq, lq_pad, lk, lk_pad)
         out = upstream_flash_sdpa(
             qp, kp, vp, seg, heads=heads,
-            block_q=_largest_dividing_tile(256, lq_pad),
-            block_k=_largest_dividing_tile(1024, lk_pad),
+            block_q=largest_dividing_tile(256, lq_pad),
+            block_k=largest_dividing_tile(1024, lk_pad),
         )
         return out[:, :lq]
 
@@ -287,8 +289,8 @@ def padded_flash_sdpa(q, k, v, *, heads: int, align: int = 128,
     # None here (the 128 lane minimum always divides)
     out = flash_sdpa(
         qp, kp, vp, heads=heads,
-        block_q=_largest_dividing_tile(256, lq_pad),
-        block_k=_largest_dividing_tile(256, lk_pad),
+        block_q=largest_dividing_tile(256, lq_pad),
+        block_k=largest_dividing_tile(256, lk_pad),
         interpret=interpret, kv_len=None if lk_pad == lk else lk,
     )
     return out[:, :lq]

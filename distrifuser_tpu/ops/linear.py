@@ -6,17 +6,19 @@ hit the MXU; inputs stay in the model dtype (bf16 on TPU) with XLA's native
 fp32 accumulation.
 
 Quantized kernels (`parallel.compress.QuantizedTensor`, the
-DistriConfig.weight_quant tree) dispatch here to a real low-precision
-execution path (ops/gemm_routing.py picks dequant vs int8/fp8 dot_general
-vs the Pallas tiled kernel per shape): activations quantize dynamically
-per token, the MACs run at the MXU's 2x int8 rate with
-``preferred_element_type`` accumulation, and the per-channel-tile weight
-scale applies after the accumulate.  The dequantize-to-dense path
-survives as the routed fallback (and for norm/bias/output heads, which
-never quantize).
+DistriConfig.weight_quant tree) dispatch here to one of two execution
+paths (`_quantized_matmul`): ``dot`` — activations quantize dynamically
+per token, the MACs run as a real int8/fp8 ``dot_general`` at the MXU's 2x
+int8 rate with ``preferred_element_type`` accumulation, and the
+per-channel-tile weight scale applies after the accumulate — or
+``dequant``, dequantize to the compute dtype and a dense matmul (storage
+semantics: bytes saved, no FLOPs; also what norm/bias/output heads, which
+never quantize, amount to).
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -25,46 +27,41 @@ from jax import lax
 from ..parallel.compress import QuantizedTensor, quantize
 
 
-def _quantized_matmul(x, qt: QuantizedTensor):
-    """x [..., K] @ QuantizedTensor [K, N] via the routed execution path."""
-    from .gemm_routing import resolve
+# `auto`'s minimum token count for the low-precision dot: below this the
+# per-token activation quantize (3 elementwise passes over M*K) and the M*N
+# scale apply are not paid back by halving M*K*N MAC time.  The
+# time/conditioning-embedding linears (M = batch, single digits) stay on
+# dequant; every token-stream matmul (M = B*L, thousands) runs low.
+DOT_MIN_M = 32
 
+
+def _quantized_matmul(x, qt: QuantizedTensor):
+    """x [..., K] @ QuantizedTensor [K, N] by the leaf's compute policy
+    (DistriConfig.quant_compute, the one way to force a path): "dequant"
+    and "dot" are what they say; "auto" is dequant on the CPU (XLA's CPU
+    int8 dot upcasts to int32 — all overhead, no win) and dot elsewhere
+    from DOT_MIN_M tokens up."""
     out_dtype = jnp.result_type(x.dtype, qt.dtype)
-    if qt.ndim != 2:
-        # stacked/conv layouts never reach linear() unsliced; if one does,
-        # dequant is always correct
-        return (x @ qt.__jax_array__()).astype(out_dtype)
-    k, n = qt.shape
-    m = 1
-    for d in x.shape[:-1]:
-        m *= int(d)
-    mode = "int8" if qt.payload.dtype == jnp.int8 else "fp8"
-    route = resolve(mode, m, k, n, qt.compute)
-    if route.impl == "dequant":
+    m = math.prod(x.shape[:-1])
+    use_dot = qt.compute == "dot" or (
+        qt.compute == "auto" and m >= DOT_MIN_M
+        and jax.devices()[0].platform != "cpu")
+    # stacked/conv layouts never reach linear() unsliced; if one does,
+    # dequant is always correct
+    if qt.ndim != 2 or not use_dot:
         return (x @ qt.__jax_array__()).astype(out_dtype)
 
     # dynamic per-token activation quantization (one scale per [..., K]
     # row — the reduction-axis granularity that keeps the product's error
     # per-(token, channel) bounded)
+    mode = "int8" if qt.payload.dtype == jnp.int8 else "fp8"
     xq, sx = quantize(x, mode, axis=-1)
-    sw = qt.channel_scale()  # [N] fp32, channel_tile expanded
-    if route.impl == "dot":
-        acc = lax.dot_general(
-            xq, qt.payload, (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=(jnp.int32 if mode == "int8"
-                                    else jnp.float32),
-        )
-        y = acc.astype(jnp.float32) * sx[..., None] * sw
-    else:  # pallas
-        from .quant_matmul import quant_matmul
-
-        interpret = jax.devices()[0].platform == "cpu"
-        y = quant_matmul(
-            xq.reshape(m, k), qt.payload, sw,
-            block_m=route.block_m, block_n=route.block_n,
-            block_k=route.block_k, interpret=interpret,
-        )
-        y = y.reshape(*x.shape[:-1], n) * sx[..., None]
+    acc = lax.dot_general(
+        xq, qt.payload, (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32 if mode == "int8" else jnp.float32,
+    )
+    # channel_scale(): [N] fp32, channel_tile expanded
+    y = acc.astype(jnp.float32) * sx[..., None] * qt.channel_scale()
     return y.astype(out_dtype)
 
 

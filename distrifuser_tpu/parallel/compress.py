@@ -66,12 +66,12 @@ WEIGHT_QUANT_MODES = ("none", "int8", "fp8")
 # Quantized-COMPUTE policies (DistriConfig.quant_compute / ExecKey): how a
 # QuantizedTensor kernel executes at its consuming matmul.  "off" is PR-6
 # semantics — dequantize to the compute dtype and run a dense matmul
-# (quantization buys HBM bytes, zero FLOPs).  "auto" resolves per shape
-# through ops/gemm_routing.py (env override -> measured table -> analytic
-# default); "dot" forces the low-precision dot_general path (activations
-# dynamically quantized per token, int8/fp8 MACs, fused per-channel-tile
-# scale after the accumulate); "pallas" forces the tiled Pallas kernel.
-QUANT_COMPUTE_MODES = ("off", "auto", "dot", "pallas")
+# (quantization buys HBM bytes, zero FLOPs).  "auto" resolves per call
+# in ops/linear.py (dequant on the CPU and for a handful of tokens, the
+# low-precision dot elsewhere); "dot" forces the low-precision dot_general
+# path (activations dynamically quantized per token, int8/fp8 MACs, fused
+# per-channel-tile scale after the accumulate).
+QUANT_COMPUTE_MODES = ("off", "auto", "dot")
 
 # Layer kinds (context.KIND_REGISTRY) whose stale refresh compresses.  "gn"
 # is deliberately absent (see module docstring); "stepcache" is a local
@@ -162,7 +162,7 @@ def validate_weight_mode(mode: str) -> None:
 def validate_quant_compute(policy: str, weight_quant: str = "int8") -> None:
     """Config-time validation of a quantized-compute policy, shared by
     DistriConfig, ServeConfig, and ExecKey.  Forcing a low-precision
-    execution path ("dot"/"pallas") on a full-precision key is a config
+    execution path ("dot") on a full-precision key is a config
     contradiction — there is no quantized kernel to execute — and refuses
     loudly rather than silently running dense."""
     if policy not in QUANT_COMPUTE_MODES:
@@ -170,7 +170,7 @@ def validate_quant_compute(policy: str, weight_quant: str = "int8") -> None:
             f"quant_compute must be one of {QUANT_COMPUTE_MODES}, got "
             f"{policy!r}"
         )
-    if policy in ("dot", "pallas") and weight_quant == "none":
+    if policy == "dot" and weight_quant == "none":
         raise ValueError(
             f"quant_compute={policy!r} forces a low-precision matmul path "
             "but weight_quant='none' holds no quantized kernels — set "
@@ -200,8 +200,8 @@ class QuantizedTensor:
 
     ``compute`` is the EXECUTION policy (QUANT_COMPUTE_MODES minus "off",
     which maps to the leaf-level "dequant"): ops/linear.py dispatches a
-    QuantizedTensor kernel to the low-precision dot_general / Pallas path
-    per this policy and the ops/gemm_routing.py table.  It lives in the
+    QuantizedTensor kernel to the low-precision dot_general or the
+    dequantized dense matmul per this policy.  It lives in the
     pytree AUX data (not a traced leaf), so two trees differing only in
     policy have distinct treedefs — jit retraces instead of silently
     reusing the other policy's program.  ``channel_tile`` groups output
@@ -217,10 +217,10 @@ class QuantizedTensor:
         self.payload = payload
         self.scale = scale
         self._dtype = jnp.dtype(dtype)
-        if compute not in ("dequant", "auto", "dot", "pallas"):
+        if compute not in ("dequant", "auto", "dot"):
             raise ValueError(
                 f"QuantizedTensor compute policy must be 'dequant', "
-                f"'auto', 'dot', or 'pallas', got {compute!r}"
+                f"'auto', or 'dot', got {compute!r}"
             )
         self.compute = compute
         ct = int(channel_tile)

@@ -880,9 +880,9 @@ class _GenerationMixin:
     def set_quant_compute(self, policy: str) -> None:
         """Re-tag the denoiser's quantized kernels with an EXECUTION
         policy (DistriConfig.quant_compute; docs/PERF.md "Quantized
-        compute & GEMM routing").  Unlike set_weight_quant this is
+        compute").  Unlike set_weight_quant this is
         payload-free — no values change, only which matmul path the next
-        trace routes through (ops/gemm_routing.py) — so it is safe in
+        trace takes (ops/linear.py) — so it is safe in
         both directions and the serve layer forces it per
         ExecKey.quant_compute.  Drops compiled programs: policy lives in
         the pytree aux data, so a policy change is a different traced

@@ -85,7 +85,7 @@ class ExecKey:
     refresh_fraction: float = 1.0
     weight_quant: str = "none"
     # Quantized-COMPUTE policy (DistriConfig.quant_compute semantics):
-    # storage-only ("off") and compute-routed ("auto"/"dot"/"pallas")
+    # storage-only ("off") and compute-routed ("auto"/"dot")
     # executables trace different matmul paths — int8-storage and
     # int8-compute are DISTINCT compiled programs for the same bucket, so
     # the ladder/controller can hold both and the weight ledger never

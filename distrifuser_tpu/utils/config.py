@@ -171,18 +171,16 @@ class DistriConfig:
     # decode error lands directly in output pixels (docs/PERF.md
     # "Quantized weights" for the measured tolerances).
     weight_quant_aux: str = "none"
-    # Quantized COMPUTE (ops/gemm_routing.py + ops/quant_matmul.py): how
-    # the weight_quant kernels execute at their consuming matmuls.  "off"
-    # pins PR-6 storage-only semantics (dequantize to the compute dtype,
-    # dense matmul — bytes saved, zero FLOPs).  "auto" (default) resolves
-    # per shape: env override -> the measured per-shape GEMM table ->
-    # analytic default (real int8/fp8 dot_general on TPU at the MXU's 2x
-    # int8 MAC rate, with dynamic per-token activation quantization and
-    # the per-channel-tile scale applied after the accumulate; dequant on
-    # CPU).  "dot"/"pallas" force one low-precision path (require
-    # weight_quant != "none").  Changes numerics vs "off" — activations
-    # quantize too; docs/PERF.md "Quantized compute & GEMM routing" pins
-    # the tolerances.  No effect when weight_quant="none".
+    # Quantized COMPUTE (ops/linear.py): how the weight_quant kernels
+    # execute at their consuming matmuls.  "off" pins PR-6 storage-only
+    # semantics (dequantize to the compute dtype, dense matmul — bytes
+    # saved, zero FLOPs).  "auto" (default): a real int8/fp8 dot_general on
+    # TPU at the MXU's 2x int8 MAC rate, with dynamic per-token activation
+    # quantization and the per-channel-tile scale applied after the
+    # accumulate, from 32 tokens up; dequant on CPU.  "dot" forces the
+    # low-precision path (requires weight_quant != "none").  Changes
+    # numerics vs "off" — activations quantize too; docs/PERF.md "Quantized
+    # compute" pins the tolerances.  No effect when weight_quant="none".
     quant_compute: str = "auto"
     # Sequence-parallel VAE decode over the sp axis (exact: fresh halo convs,
     # psum'd GroupNorm, ring mid attention — models/vae.py decode_sp).  The
@@ -1323,7 +1321,7 @@ class ServeConfig:
     weight_quant: str = "none"
     # Service-wide quantized-COMPUTE policy (DistriConfig.quant_compute
     # semantics): threaded into every ExecKey — storage-only ("off") and
-    # compute-routed ("auto"/"dot"/"pallas") programs trace different
+    # compute-routed ("auto"/"dot") programs trace different
     # matmul paths, so they are distinct executables.  "auto" (default)
     # means the PR-9 tier ladder's int8 rungs and the fleet inherit the
     # low-precision execution path with no further serve-layer changes.

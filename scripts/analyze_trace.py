@@ -6,7 +6,7 @@ compute — the reference's async-NCCL behavior
 (/root/reference/distrifuser/utils.py:170-190) — or did they serialize?
 
 Input: a jax.profiler trace directory captured with
-``create_perfetto_trace=True`` (scripts/chip_campaign.py trace phase).  The
+``create_perfetto_trace=True``.  The
 perfetto artifact is Chrome-trace JSON (gzip), parseable with stdlib — no
 tensorboard needed.
 

@@ -57,10 +57,10 @@ TOLERANCES = {"unet": 1.5e-2, "dit": 3e-3, "mmdit": 3e-3}
 FP8_BOUNDS = {"unet": 6e-2, "dit": 1e-2, "mmdit": 1.6e-2}
 INT8_MIN_RATIO = 1.7
 
-# Compute-path tolerances (--compute): the low-precision dot/Pallas routes
-# quantize ACTIVATIONS dynamically on top of the weight rounding, so their
+# Compute-path tolerances (--compute): the low-precision dot route
+# quantizes ACTIVATIONS dynamically on top of the weight rounding, so its
 # decoded-image budget sits above the storage-only numbers (docs/PERF.md
-# "Quantized compute & GEMM routing").  int8 gates; fp8 informative.
+# "Quantized compute").  int8 gates; fp8 informative.
 COMPUTE_TOLERANCES = {"unet": 2e-2, "dit": 6e-3, "mmdit": 8e-3}
 # Analytic FLOP-path ceiling for the routed matmuls: int8 MACs at the
 # MXU's 2x rate plus quantize/scale overhead must land at <= 0.6 of the
@@ -151,8 +151,7 @@ def _analytic_compute_ratios(pipe):
     Per kernel [K, N] at token count M: dequant costs ``2MKN`` bf16 MACs
     (+ the KN dequantize convert); the dot route costs ``MKN``
     MAC-equivalents (int8 at the MXU's 2x rate) + ``3MK`` activation
-    quantization + ``2MN`` scale application; Pallas fuses the weight
-    scale into the epilogue (``MN`` instead of ``2MN``).  The ratio is
+    quantization + ``2MN`` scale application.  The ratio is
     nearly M-independent (overhead terms go as 1/N and 1/K), so one
     representative M — this pipeline's latent token count — suffices.
     Conv kernels (4D, always dequant) are excluded from the ratio and
@@ -164,7 +163,7 @@ def _analytic_compute_ratios(pipe):
 
     cfg = pipe.distri_config
     m = cfg.latent_height * cfg.latent_width
-    cost = {"dequant": 0.0, "dot": 0.0, "pallas": 0.0}
+    cost = {"dequant": 0.0, "dot": 0.0}
     conv_flops = 0.0
     leaves = jax.tree.leaves(
         pipe.runner.params,
@@ -182,7 +181,6 @@ def _analytic_compute_ratios(pipe):
             continue
         cost["dequant"] += depth * (2.0 * m * k * n + k * n)
         cost["dot"] += depth * (m * k * n + 3.0 * m * k + 2.0 * m * n)
-        cost["pallas"] += depth * (m * k * n + 3.0 * m * k + m * n)
     if cost["dequant"] <= 0:
         return None
     routed = cost["dequant"]
@@ -190,10 +188,7 @@ def _analytic_compute_ratios(pipe):
         "m_tokens": int(m),
         "routed_matmul_flops": routed,
         "conv_dense_flops": conv_flops,
-        "flop_ratio_vs_dequant": {
-            impl: round(cost[impl] / routed, 4)
-            for impl in ("dot", "pallas")
-        },
+        "flop_ratio_vs_dequant": {"dot": round(cost["dot"] / routed, 4)},
     }
 
 
@@ -260,7 +255,7 @@ def main() -> None:
             for mode in comp_modes:
                 rows = {}
                 analytic = None
-                for impl in ("off", "dot", "pallas"):
+                for impl in ("off", "dot"):
                     pipe = _build(family, mode, compute=impl)
                     img, best = timed_gen(pipe, family)
                     delta = float(np.abs(img.astype(np.float64)

@@ -1,7 +1,7 @@
 """Single-chip latency for the beyond-reference model families.
 
-The campaign (scripts/chip_campaign.py) benches the reference-parity SDXL
-UNet; this probe takes the same campaign-style JSON lines for the round-5
+The 2026-07 campaign benched the reference-parity SDXL UNet; this probe
+takes the same campaign-style JSON lines for the round-5
 additions at their family-native sampling defaults, random weights (latency
 is weight-independent):
 

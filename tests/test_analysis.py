@@ -311,28 +311,33 @@ def test_bare_raise_fixture_flagged():
 
 
 # ---------------------------------------------------------------------------
-# route tables: seeded provenance violations (live-module monkeypatch)
+# route table: seeded violations (live-module monkeypatch)
 
 
 def test_route_tables_clean_then_seeded(monkeypatch):
     assert route_tables.check_tables() == []
     from distrifuser_tpu.ops import sdpa_routing
-
-    monkeypatch.setattr(sdpa_routing, "MEASURED_PROVENANCE", "")
-    findings = route_tables.check_tables()
-    assert any(f.identity == "sdpa:provenance-missing" for f in findings)
-
-
-def test_route_tables_malformed_entry(monkeypatch):
-    from distrifuser_tpu.ops import gemm_routing
+    from distrifuser_tpu.ops.sdpa_routing import Route, Row
 
     monkeypatch.setattr(
-        gemm_routing, "MEASURED_ROUTES",
-        {("int4", 5): next(iter(gemm_routing.MEASURED_ROUTES.values()))}
-        if gemm_routing.MEASURED_ROUTES else
-        {("int4", 5): gemm_routing.GemmRoute("dot")})
+        sdpa_routing, "TABLE",
+        {64: (Row(2944, 5760, Route("inrepo", 1024, 512), "  "),)})
     findings = route_tables.check_tables()
-    assert any(f.identity.startswith("gemm:key") for f in findings)
+    assert [f.identity for f in findings] == ["sdpa:origin:64:(2944, 5760)"]
+
+
+def test_route_tables_overlapping_ranges(monkeypatch):
+    from distrifuser_tpu.ops import sdpa_routing
+    from distrifuser_tpu.ops.sdpa_routing import Route, Row
+
+    for second in (Row(2816, 4096, Route("xla"), "test"),   # shares an edge
+                   Row(128, 640, Route("xla"), "test")):    # descends
+        monkeypatch.setattr(
+            sdpa_routing, "TABLE",
+            {72: (Row(1536, 2816, Route("xla"), "test"), second)})
+        findings = route_tables.check_tables()
+        assert [f.identity.rsplit(":", 2)[0] for f in findings] == [
+            "sdpa:order"], findings
 
 
 # ---------------------------------------------------------------------------

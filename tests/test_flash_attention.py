@@ -1,13 +1,12 @@
 """Pallas flash attention vs the XLA softmax oracle (interpret mode on CPU)."""
 
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from distrifuser_tpu.ops.attention import _resolve_route, sdpa
+from distrifuser_tpu.ops.attention import sdpa
 from distrifuser_tpu.ops.flash_attention import flash_sdpa
 
 
@@ -50,18 +49,16 @@ def test_flash_numerical_stability_large_logits():
     )
 
 
-def test_routing_gates():
-    q = jnp.zeros((1, 256, 32))
-    k = jnp.zeros((1, 256, 32))
+def test_routing_gates(monkeypatch):
+    from distrifuser_tpu.ops.sdpa_routing import route
+
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH", raising=False)
     # CPU default: no flash
-    assert _resolve_route(q, k, heads=2).impl == "xla"
-    os.environ["DISTRIFUSER_TPU_FLASH"] = "1"
-    try:
-        assert _resolve_route(q, k, heads=2).impl != "xla"
-        # unaligned length -> never
-        assert _resolve_route(jnp.zeros((1, 200, 32)), k, heads=2).impl == "xla"
-    finally:
-        del os.environ["DISTRIFUSER_TPU_FLASH"]
+    assert route(256, 256, 32, 2, "cpu").impl == "xla"
+    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "1")
+    assert route(256, 256, 32, 2, "cpu").impl == "inrepo"
+    # unaligned length -> never
+    assert route(200, 256, 32, 2, "cpu").impl == "xla"
 
 
 def test_forced_flash_on_cpu_uses_interpret(monkeypatch):
@@ -187,8 +184,8 @@ def test_padding_segment_ids_match_kv_len_semantics():
 def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
     """The resolved kernel runs or the call raises: a failing upstream
     kernel is never replaced by the in-repo one (or by XLA softmax) behind
-    the caller's back, and DISTRIFUSER_TPU_FLASH_IMPL=inrepo keeps
-    padded_flash_sdpa off the upstream segment-ids path."""
+    the caller's back, and impl="inrepo" keeps padded_flash_sdpa off the
+    upstream segment-ids path."""
     import importlib
 
     attn_mod = importlib.import_module("distrifuser_tpu.ops.attention")
@@ -217,8 +214,7 @@ def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
 
     monkeypatch.setattr(fa, "upstream_flash_sdpa", failing_upstream)
     monkeypatch.setattr(fa, "flash_sdpa", spy_inrepo)
-    monkeypatch.delenv("DISTRIFUSER_TPU_PADDED_IMPL", raising=False)
-    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL", raising=False)
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH", raising=False)
 
     # 1) default route = upstream: its failure propagates, nothing else runs
     with pytest.raises(MosaicRefused):
@@ -229,11 +225,13 @@ def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
     with pytest.raises(ValueError, match="impl='inrepo'"):
         fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=True)
 
-    # 3) the kernel-wide inrepo pin is an explicit route to the in-repo kernel
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "inrepo")
-    out = fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=True)
+    # 3) impl="inrepo" is an explicit route to the in-repo kernel; any
+    # other name is refused, never read as one of the two
+    out = fa.padded_flash_sdpa(q, k, v, heads=heads, interpret=True,
+                               impl="inrepo")
     assert out.shape == (b, lq, c) and len(inrepo_calls) == 1
-    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL")
+    with pytest.raises(ValueError, match="'upstream' or 'inrepo'"):
+        fa.padded_flash_sdpa(q, k, v, heads=heads, impl="xla")
 
     # 4) sdpa on a TPU platform: the padded route raises through sdpa (no
     # XLA-softmax fall-through), and so does the aligned table route
@@ -241,10 +239,10 @@ def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
         platform = "tpu"
 
     monkeypatch.setattr(jax, "devices", lambda: [_Dev()])
-    long_q = jnp.zeros((1, 1100, c))  # unaligned and >= _FLASH_MIN_LEN
+    long_q = jnp.zeros((1, 1100, c))  # unaligned and >= FLASH_MIN_LEN
     with pytest.raises(MosaicRefused):
         attn_mod.sdpa(long_q, long_q, long_q, heads=heads)
-    far = jnp.zeros((1, 16384, 2 * 64))  # d=64, bucket 14: upstream's
+    far = jnp.zeros((1, 16384, 2 * 64))  # d=64, 8320..32768: upstream's
     with pytest.raises(MosaicRefused):
         attn_mod.sdpa(far, far, far, heads=2)
     assert len(inrepo_calls) == 1
@@ -260,7 +258,7 @@ def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
                         lambda *a, **kw: upstream_calls.append(kw))
     monkeypatch.setattr(attn_mod, "_sdpa_xla",
                         lambda *a, **kw: upstream_calls.append(kw))
-    aligned = jnp.zeros((1, 1024, 2 * 64))  # d=64, bucket 10
+    aligned = jnp.zeros((1, 1024, 2 * 64))  # d=64, 768..1408
     with pytest.raises(MosaicRefused, match="in-repo"):
         attn_mod.sdpa(aligned, aligned, aligned, heads=2)
     assert not upstream_calls
@@ -269,8 +267,11 @@ def test_padded_flash_runs_the_resolved_kernel_or_raises(monkeypatch):
 def test_upstream_route_on_cpu_is_an_error(monkeypatch):
     """The upstream Mosaic kernel cannot run on the CPU platform; asking
     for it there raises instead of quietly running the in-repo kernel."""
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "1")
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "upstream")
+    from distrifuser_tpu.ops import sdpa_routing
+    from distrifuser_tpu.ops.sdpa_routing import Route
+
+    monkeypatch.setattr(sdpa_routing, "route",
+                        lambda *a: Route("upstream"))
     x = jnp.zeros((1, 128, 32))
     with pytest.raises(ValueError, match="needs a TPU"):
         sdpa(x, x, x, heads=2)

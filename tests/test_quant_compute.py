@@ -1,16 +1,15 @@
 """Quantized COMPUTE (ISSUE 12): int8/fp8 matmuls as an execution path.
 
-Covers: per-family parity of the low-precision dot/Pallas paths vs the
-PR-6 dequant-bf16 fallback (pinned tolerances), the HLO-level guarantee
-that a compute-routed transformer block runs an int8 ``dot`` with NO
-dequantize-to-float convert feeding it, GEMM routing resolution order
-(env override -> forced policy -> measured table with backend gating ->
-analytic default), the Pallas kernel's bit-parity with the XLA dot route,
-channel-tile scale grouping, and ExecKey distinctness across
-(none / int8-storage / int8-compute).
+Covers: per-family parity of the low-precision dot path vs the PR-6
+dequant-bf16 fallback (pinned tolerances), the HLO-level guarantee that a
+compute-routed transformer block runs an int8 ``dot`` with NO
+dequantize-to-float convert feeding it, which path a leaf's policy takes
+(forced policy -> platform -> token count), channel-tile scale grouping,
+and ExecKey distinctness across (none / int8-storage / int8-compute).
 """
 
 import dataclasses
+import importlib
 import re
 
 import numpy as np
@@ -23,18 +22,15 @@ from distrifuser_tpu.models import dit as dit_mod
 from distrifuser_tpu.models import mmdit as mmdit_mod
 from distrifuser_tpu.models import unet as unet_mod
 from distrifuser_tpu.models.weights import quantize_params, set_quant_compute
-from distrifuser_tpu.ops import gemm_routing
-from distrifuser_tpu.ops.gemm_routing import GemmRoute, resolve
-from distrifuser_tpu.ops.linear import linear
-from distrifuser_tpu.ops.quant_matmul import quant_matmul
 from distrifuser_tpu.parallel.compress import (
     QuantizedTensor,
     fp8_supported,
-    quantize,
     quantize_weight,
     validate_quant_compute,
 )
 from distrifuser_tpu.serve import ExecKey
+
+linear_mod = importlib.import_module("distrifuser_tpu.ops.linear")
 
 MODES = ["int8"] + (["fp8"] if fp8_supported() else [])
 
@@ -106,35 +102,6 @@ def test_family_compute_path_parity(family, mode):
         f"{family}/{mode}: compute path error {err_dot} is more than 2x "
         f"the storage-only error {err_dq}"
     )
-
-
-@pytest.mark.parametrize("mode", MODES)
-def test_pallas_route_matches_dot_route_bitwise(mode):
-    """The Pallas kernel is the SAME arithmetic as the XLA dot route
-    (int32/fp32 accumulate, scales after) — on the DiT family forward the
-    two routes agree bit-for-bit in fp32."""
-    params, fwd = _family_forward("dit")
-    dot = np.asarray(fwd(quantize_params(params, mode, compute="dot")))
-    pal = np.asarray(fwd(quantize_params(params, mode, compute="pallas")))
-    np.testing.assert_allclose(pal, dot, atol=2e-6)
-
-
-def test_quant_matmul_kernel_parity_and_padding():
-    """Direct kernel check: odd M/K/N (forcing the pad path) and partial
-    channel tiles still reproduce the reference int8 GEMM exactly."""
-    rng = np.random.RandomState(3)
-    for m, k, n, ct in [(64, 64, 48, 1), (33, 72, 50, 16), (128, 256, 130, 64)]:
-        w = jnp.asarray(rng.randn(k, n).astype(np.float32))
-        qt = quantize_weight(w, "int8", channel_tile=ct)
-        x = jnp.asarray(rng.randn(m, k).astype(np.float32))
-        xq, sx = quantize(x, "int8", axis=-1)
-        sw = qt.channel_scale()
-        ref = jax.lax.dot_general(
-            xq, qt.payload, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        ).astype(jnp.float32) * sw
-        got = quant_matmul(xq, qt.payload, sw, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
 def test_channel_tile_partial_last_tile_roundtrip():
@@ -282,42 +249,38 @@ def test_block_hlo_int8_dot_and_no_dequant_convert():
 
 
 def test_resolve_order_env_policy_table_analytic(monkeypatch):
-    # forced policies win over everything but env
-    assert resolve("int8", 4096, 64, 64, "dequant").impl == "dequant"
-    assert resolve("int8", 4096, 64, 64, "dot").impl == "dot"
-    assert resolve("int8", 4096, 64, 64, "pallas").impl == "pallas"
-    # env overrides even a forced policy (the operator escape hatch)
-    monkeypatch.setenv("DISTRIFUSER_TPU_GEMM", "0")
-    assert resolve("int8", 4096, 64, 64, "dot").impl == "dequant"
-    monkeypatch.setenv("DISTRIFUSER_TPU_GEMM", "pallas")
-    monkeypatch.setenv("DISTRIFUSER_TPU_GEMM_BM", "64")
-    r = resolve("int8", 4096, 64, 64, "dequant")
-    assert r.impl == "pallas" and r.block_m == 64
-    monkeypatch.setenv("DISTRIFUSER_TPU_GEMM", "nope")
-    with pytest.raises(ValueError, match="DISTRIFUSER_TPU_GEMM"):
-        resolve("int8", 4096, 64, 64, "auto")
-    monkeypatch.delenv("DISTRIFUSER_TPU_GEMM")
-    monkeypatch.delenv("DISTRIFUSER_TPU_GEMM_BM")
-    # analytic defaults: dequant on cpu; dot on tpu above the M floor
-    assert resolve("int8", 4096, 64, 64, "auto", platform="cpu").impl == "dequant"
-    assert resolve("int8", 4096, 64, 64, "auto", platform="tpu").impl == "dot"
-    assert resolve("int8", 2, 64, 64, "auto", platform="tpu").impl == "dequant"
+    """Which path `_quantized_matmul` takes: a forced policy first, then
+    (auto) the platform, then the token count.  The int8 path's output
+    differs from the dequantized one (activations quantize too), so the
+    path shows in the lowered program: an s8 x s8 dot, or none."""
 
+    class _Dev:
+        def __init__(self, platform):
+            self.platform = platform
 
-def test_measured_table_governs_only_its_backend(monkeypatch):
-    """A table baked from one platform's campaign must never govern
-    another platform's routing (a CPU structural campaign would pin
-    dequant fleet-wide on TPU)."""
-    monkeypatch.setattr(gemm_routing, "MEASURED_BACKEND", "tpu")
-    monkeypatch.setattr(
-        gemm_routing, "MEASURED_ROUTES",
-        {("int8", 12): GemmRoute("pallas", 128, 256, 512)})
-    r = resolve("int8", 4096, 64, 64, "auto", platform="tpu")
-    assert r.impl == "pallas" and r.block_k == 512
-    # same table consulted from CPU: backend mismatch -> analytic default
-    assert resolve("int8", 4096, 64, 64, "auto", platform="cpu").impl == "dequant"
-    # nearest-bucket generalization is bounded (MAX_BUCKET_DISTANCE)
-    assert resolve("int8", 64, 64, 64, "auto", platform="tpu").impl == "dot"
+    w = jax.random.normal(jax.random.PRNGKey(0), (64, 64))
+
+    def takes_dot(policy, m, platform):
+        monkeypatch.setattr(jax, "devices", lambda: [_Dev(platform)])
+        qt = quantize_weight(w, "int8", compute=policy)
+        hlo = jax.jit(lambda x: linear_mod.linear({"kernel": qt}, x)).lower(
+            jnp.zeros((m, 64))).as_text(dialect="hlo")
+        return _int8_dot_present(hlo)
+
+    # forced policies hold on every platform and at every token count
+    for platform in ("cpu", "tpu"):
+        for m in (2, 4096):
+            assert not takes_dot("dequant", m, platform)
+            assert takes_dot("dot", m, platform)
+    # auto: dequant on the cpu; dot on the chip from DOT_MIN_M tokens up
+    assert not takes_dot("auto", 4096, "cpu")
+    assert takes_dot("auto", 4096, "tpu")
+    assert not takes_dot("auto", 2, "tpu")
+    assert takes_dot("auto", linear_mod.DOT_MIN_M, "tpu")
+    assert not takes_dot("auto", linear_mod.DOT_MIN_M - 1, "tpu")
+    # an unknown policy never reaches a matmul: the leaf refuses it
+    with pytest.raises(ValueError, match="compute policy"):
+        quantize_weight(w, "int8", compute="pallas")
 
 
 def test_set_quant_compute_retags_without_touching_payloads():
@@ -340,8 +303,12 @@ def test_set_quant_compute_retags_without_touching_payloads():
 
 
 def test_validate_quant_compute():
-    for p in ("off", "auto", "dot", "pallas"):
+    for p in ("off", "auto", "dot"):
         validate_quant_compute(p, "int8")
+    # a kernel route that is gone is input from outside like any other bad
+    # value: the same typed error, naming what is left
+    with pytest.raises(ValueError, match=r"\('off', 'auto', 'dot'\)"):
+        validate_quant_compute("pallas", "int8")
     validate_quant_compute("auto", "none")
     with pytest.raises(ValueError, match="quant_compute"):
         validate_quant_compute("dequant", "int8")  # leaf-level name
@@ -362,13 +329,15 @@ def test_exec_key_distinct_none_storage_compute():
     compute = dataclasses.replace(base, weight_quant="int8",
                                   quant_compute="auto")
     forced = dataclasses.replace(base, weight_quant="int8",
-                                 quant_compute="pallas")
+                                 quant_compute="dot")
     keys = {base, storage, compute, forced}
     assert len(keys) == 4
     tags = {k.short() for k in keys}
     assert len(tags) == 4, tags
     assert "qc-off" in storage.short()
-    assert "qc-pallas" in forced.short()
+    assert "qc-dot" in forced.short()
+    with pytest.raises(ValueError, match="quant_compute"):
+        dataclasses.replace(base, weight_quant="int8", quant_compute="pallas")
     # the fleet default ("auto") needs no tag — PR-9/PR-10 rungs that set
     # weight_quant="int8" inherit the compute path without a key change
     assert "qc-" not in compute.short()

@@ -1,59 +1,39 @@
-"""Measured routing tables (sdpa + gemm) are reviewable DATA: they must
-parse on import and carry provenance — the lint scripts/lint_route_tables.py
-enforces in CI, run here under pytest so a local `pytest tests/` catches a
-bad bake before the workflow does."""
+"""The attention route table (ops/sdpa_routing.py TABLE) is reviewable DATA:
+it must parse on import, its ranges must not overlap, and every row carries
+the origin of its verdict — the `route-tables` checker of distrilint, run
+here under pytest so a local `pytest tests/` catches a bad row before
+`python -m distrifuser_tpu.analysis --strict` does."""
 
-import os
-import subprocess
-import sys
+import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from distrifuser_tpu.analysis.checkers import route_tables
+from distrifuser_tpu.ops import sdpa_routing
+from distrifuser_tpu.ops.sdpa_routing import Route, Row
 
 
 def test_route_tables_lint_clean():
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        import lint_route_tables
-    finally:
-        sys.path.pop(0)
-    assert lint_route_tables.check_tables() == []
-
-
-def test_lint_script_runs_as_tooling():
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "lint_route_tables.py")],
-        capture_output=True, text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-    )
-    assert proc.returncode == 0, proc.stderr
-    assert "clean" in proc.stdout
-
-
-def test_gemm_table_backend_declared_when_measured():
-    from distrifuser_tpu.ops import gemm_routing
-
-    if gemm_routing.MEASURED_ROUTES:
-        assert gemm_routing.MEASURED_BACKEND in ("cpu", "tpu", "gpu")
-    # provenance is never empty, measured or not
-    assert gemm_routing.MEASURED_PROVENANCE.strip()
-
-
-def test_override_table_is_linted_like_the_measured_one(monkeypatch):
-    """MODEL_VALIDATED_OVERRIDES is what routes the benchmark's cells: a
-    malformed key, an unknown impl or a tile that cannot divide its bucket's
-    length is a finding there too."""
-    from distrifuser_tpu.analysis.checkers import route_tables
-    from distrifuser_tpu.ops import sdpa_routing
-    from distrifuser_tpu.ops.sdpa_routing import Route
-
     assert route_tables.check_tables() == []
-    for bad, ident in [
-        ({(72, 12): Route("inrepo", 1000, 512)}, "sdpa:tile:"),
-        ({(72, 12): Route("inrepo", 1024, 64)}, "sdpa:tile:"),
-        ({(64, 10): Route("inrepo", 2048, 512)}, "sdpa:tile:"),
-        ({(72, 12): Route("mosaic", 256, 512)}, "sdpa:value:"),
-        ({(72, "12"): Route("inrepo", 256, 512)}, "sdpa:key:"),
-    ]:
-        monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES", bad)
-        found = route_tables.check_tables()
-        assert any(f.identity.startswith(ident) for f in found), (bad, found)
+
+
+@pytest.mark.parametrize("bad,ident", [
+    ({72: (Row(2944, 5760, Route("inrepo", 1000, 512), "t"),)}, "sdpa:tile:"),
+    ({72: (Row(2944, 5760, Route("inrepo", 1024, 64), "t"),)}, "sdpa:tile:"),
+    ({64: (Row(768, 1408, Route("inrepo", 2048, 512), "t"),)}, "sdpa:tile:"),
+    ({72: (Row(2944, 5760, Route("mosaic", 256, 512), "t"),)}, "sdpa:value:"),
+    ({72: (Row(2944, 5760, Route("padded", kernel="upstream"), "t"),)},
+     "sdpa:value:"),
+    ({72: (Row(2944, "5760", Route("inrepo", 256, 512), "t"),)}, "sdpa:key:"),
+    ({72: (Row(5760, 2944, Route("inrepo", 256, 512), "t"),)}, "sdpa:key:"),
+    ({"72": (Row(2944, 5760, Route("inrepo", 256, 512), "t"),)}, "sdpa:key:"),
+    ({72: (Row(2944, 5760, Route("inrepo", 256, 512), ""),)}, "sdpa:origin:"),
+], ids=["tile-not-pow2", "tile-under-128", "tile-over-range", "impl-unknown",
+        "padded-is-no-row", "bound-not-int", "range-reversed",
+        "head-dim-not-int", "origin-empty"])
+def test_override_table_is_linted_like_the_measured_one(monkeypatch, bad,
+                                                        ident):
+    """The one table is what routes the benchmark's cells: a malformed key,
+    an unknown impl, a tile that no length of its range could have run or a
+    row without its origin is a finding."""
+    monkeypatch.setattr(sdpa_routing, "TABLE", bad)
+    found = route_tables.check_tables()
+    assert found and all(f.identity.startswith(ident) for f in found), found

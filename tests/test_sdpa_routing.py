@@ -1,30 +1,29 @@
-"""SDPA routing: env overrides > measured table > analytic default.
+"""SDPA routing: one function (`ops/sdpa_routing.py route`) decides, one table.
 
-The reference always runs fused SDPA (modules/pp/attn.py:153); our backend
-choice is a checked-in measured table (ops/sdpa_routing.py) with env vars
-demoted to operator overrides. These tests pin the resolution order and the
-log -> table updater round trip."""
+The reference always runs fused SDPA (modules/pp/attn.py:153); here the
+kernel is chosen per shape.  `NAMED_SHAPES` and `RANGE_EDGES` were recorded
+at the parent of PR 29 (commit f8bee82: two tables competing in `lookup()`,
+four environment variables, the padded gate inside `sdpa`) with the harness
+below, platform patched to "tpu", the shipped tables in place: what
+`_resolve_route` returned and which kernel `sdpa` then entered with which
+tiles.  The one-table `route()` has to reproduce every line of it."""
 
-import json
+import ast
+import importlib
 import os
-import sys
+import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
-import importlib
+from distrifuser_tpu.ops import sdpa_routing
+from distrifuser_tpu.ops.sdpa_routing import Route, Row
 
 attention = importlib.import_module("distrifuser_tpu.ops.attention")
-from distrifuser_tpu.ops import sdpa_routing
-from distrifuser_tpu.ops.sdpa_routing import Route
+fa = importlib.import_module("distrifuser_tpu.ops.flash_attention")
 
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts"))
-
-
-# the autouse fixture below empties the shipped override table for the
-# tests of the lookup rules; the tests of what ships get it back from here
-SHIPPED_OVERRIDES = dict(sdpa_routing.MODEL_VALIDATED_OVERRIDES)
+PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(attention.__file__)))
 
 
 class _Dev:
@@ -37,393 +36,285 @@ def _clean_flash_env(monkeypatch):
     """Isolate routing tests from env leaked by other test files —
     __graft_entry__ setdefaults DISTRIFUSER_TPU_FLASH=0 process-wide when
     test_graft_entry runs earlier in the session.  Runs before each test
-    body, so tests that set these vars intentionally still win."""
-    for var in ("DISTRIFUSER_TPU_FLASH", "DISTRIFUSER_TPU_FLASH_IMPL",
-                "DISTRIFUSER_TPU_FLASH_BQ", "DISTRIFUSER_TPU_FLASH_BK"):
-        monkeypatch.delenv(var, raising=False)
-    # the shipped model-validated override would shadow every monkeypatched
-    # MEASURED_ROUTES below; tests that exercise overrides set their own
-    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES", {})
+    body, so tests that set the variable intentionally still win."""
+    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH", raising=False)
 
 
-def _route(monkeypatch, platform="tpu", lq=4096, lk=4096, c=640, heads=10):
-    import jax.numpy as jnp
+def _route(platform="tpu", lq=4096, lk=4096, c=640, heads=10):
+    return sdpa_routing.route(lq, lk, c, heads, platform)
 
+
+def _runs(monkeypatch, platform, d, heads, lq, lk):
+    """What `sdpa` enters for one call, traced abstractly: (kernel, block_q,
+    block_k, interpret, padded) — the tiles as fitted to the call."""
     monkeypatch.setattr(jax, "devices", lambda: [_Dev(platform)])
-    q = jax.ShapeDtypeStruct((2, lq, c), jnp.bfloat16)
-    k = jax.ShapeDtypeStruct((2, lk, c), jnp.bfloat16)
-    return attention._resolve_route(q, k, heads)
+    seen, padded = [], []
+
+    def spy(name):
+        def kernel(q, k, v, *args, **kw):
+            seen.append((name, kw.get("block_q"), kw.get("block_k"),
+                         kw.get("interpret", False)))
+            return q
+        return kernel
+
+    real_padded = fa.padded_flash_sdpa
+
+    def padded_spy(*args, **kw):
+        padded.append(True)
+        return real_padded(*args, **kw)
+
+    monkeypatch.setattr(fa, "flash_sdpa", spy("inrepo"))
+    monkeypatch.setattr(fa, "upstream_flash_sdpa", spy("upstream"))
+    monkeypatch.setattr(fa, "padded_flash_sdpa", padded_spy)
+    monkeypatch.setattr(
+        attention, "_sdpa_xla",
+        lambda q, k, v, scale: (seen.append(("xla", None, None, False)), q)[1])
+    shape = lambda l: jax.ShapeDtypeStruct((2, l, d * heads), jnp.bfloat16)  # noqa: E731
+    jax.eval_shape(lambda q, k, v: attention.sdpa(q, k, v, heads=heads),
+                   shape(lq), shape(lk), shape(lk))
+    assert len(set(seen)) == 1, seen  # the chunked XLA path enters it n times
+    return seen[0] + (bool(padded),)
+
+
+def _assert_as_recorded(monkeypatch, platform, shape, resolved, ran):
+    d, heads, lq, lk = shape
+    route = sdpa_routing.route(lq, lk, d * heads, heads, platform)
+    if ran[-1]:
+        # the parent's resolver said xla here and sdpa took the padded route
+        # on its own; route() now says so itself
+        assert resolved == ("xla", None, None)
+        assert route == Route("padded", kernel=ran[0])
+    else:
+        assert (route.impl, route.block_q, route.block_k) == resolved
+        assert route.kernel is None
+    assert _runs(monkeypatch, platform, d, heads, lq, lk) == ran
+
+
+# id, (head dim, heads, lq, lk), platform, DISTRIFUSER_TPU_FLASH, the
+# parent's _resolve_route, and what its sdpa then ran: (kernel, block_q,
+# block_k, interpret, padded)
+NAMED_SHAPES = [
+    ("sdxl-1024-self-64x64", (64, 10, 4096, 4096), "tpu", None,
+     ("inrepo", 1024, 512), ("inrepo", 1024, 512, False, False)),
+    ("sdxl-1024-self-32x32", (64, 20, 1024, 1024), "tpu", None,
+     ("inrepo", 1024, 1024), ("inrepo", 1024, 1024, False, False)),
+    ("sdxl-1024-cross-64x64", (64, 10, 4096, 77), "tpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("sdxl-1024-cross-32x32", (64, 20, 1024, 77), "tpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("sdxl-512-self-32x32", (64, 10, 1024, 1024), "tpu", None,
+     ("inrepo", 1024, 1024), ("inrepo", 1024, 1024, False, False)),
+    ("sdxl-512-self-16x16", (64, 20, 256, 256), "tpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("sdxl-768-self-48x48", (64, 10, 2304, 2304), "tpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("sdxl-768-self-24x24", (64, 20, 576, 576), "tpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("sdxl-2048-self-128x128", (64, 10, 16384, 16384), "tpu", None,
+     ("upstream", 512, 1024), ("upstream", 512, 1024, False, False)),
+    ("sdxl-2048-self-64x64", (64, 20, 4096, 4096), "tpu", None,
+     ("inrepo", 1024, 512), ("inrepo", 1024, 512, False, False)),
+    ("sdxl-3840-self-240x240", (64, 10, 57600, 57600), "tpu", None,
+     ("upstream", 256, 256), ("upstream", 256, 256, False, False)),
+    ("sdxl-3840-self-120x120-unaligned", (64, 20, 14400, 14400), "tpu", None,
+     ("xla", None, None), ("upstream", 128, 128, False, True)),
+    ("sdxl-1024-patch4-64x64", (64, 10, 1024, 4096), "tpu", None,
+     ("inrepo", 1024, 512), ("inrepo", 1024, 512, False, False)),
+    ("sdxl-1024-patch4-32x32", (64, 20, 256, 1024), "tpu", None,
+     ("inrepo", 1024, 1024), ("inrepo", 256, 1024, False, False)),
+    ("sd15-512-d40", (40, 8, 4096, 4096), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("sd15-512-d80", (80, 8, 1024, 1024), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("sd15-512-d160", (160, 8, 256, 256), "tpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("pixart-512", (72, 16, 1024, 1024), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("pixart-1024", (72, 16, 4096, 4096), "tpu", None,
+     ("inrepo", 1024, 512), ("inrepo", 1024, 512, False, False)),
+    ("pixart-2048", (72, 16, 16384, 16384), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("sd3-1024-joint-unaligned", (64, 24, 4250, 4250), "tpu", None,
+     ("xla", None, None), ("upstream", 256, 256, False, True)),
+    ("vae-mid-512", (512, 1, 4096, 4096), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("vae-mid-1024", (512, 1, 16384, 16384), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("vae-mid-2048", (512, 1, 65536, 65536), "tpu", None,
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("sdxl-1024-tp-5heads-64x64", (64, 5, 4096, 4096), "tpu", None,
+     ("inrepo", 1024, 512), ("inrepo", 1024, 512, False, False)),
+    ("sdxl-1024-tp-10heads-32x32", (64, 10, 1024, 1024), "tpu", None,
+     ("inrepo", 1024, 1024), ("inrepo", 1024, 1024, False, False)),
+    ("flash0-aligned", (64, 10, 4096, 4096), "tpu", "0",
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("flash0-unaligned", (64, 24, 4250, 4250), "tpu", "0",
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("flash1-aligned", (64, 10, 4096, 4096), "tpu", "1",
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("flash1-aligned-short", (64, 20, 256, 256), "tpu", "1",
+     ("upstream", None, None), ("upstream", None, None, False, False)),
+    ("flash1-unaligned", (64, 24, 4250, 4250), "tpu", "1",
+     ("xla", None, None), ("upstream", 256, 256, False, True)),
+    ("cpu-aligned", (64, 10, 4096, 4096), "cpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("cpu-unaligned", (64, 24, 4250, 4250), "cpu", None,
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("cpu-flash0-aligned", (64, 10, 4096, 4096), "cpu", "0",
+     ("xla", None, None), ("xla", None, None, False, False)),
+    ("cpu-flash1-aligned", (64, 10, 4096, 4096), "cpu", "1",
+     ("inrepo", None, None), ("inrepo", 128, 128, True, False)),
+    ("cpu-flash1-unaligned", (64, 24, 4250, 4250), "cpu", "1",
+     ("xla", None, None), ("xla", None, None, False, False)),
+]
+
+# both edges of every range of ISSUE 29"s table, lq = lk = kv_len:
+# (head dim, kv_len, the parent"s _resolve_route, what its sdpa ran)
+RANGE_EDGES = [
+    (64, 128, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (64, 640, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (64, 768, ("inrepo", 1024, 1024),
+     ("inrepo", 256, 256, False, False)),
+    (64, 1408, ("inrepo", 1024, 1024),
+     ("inrepo", 128, 128, False, False)),
+    (64, 1536, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (64, 2816, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (64, 2944, ("inrepo", 1024, 512),
+     ("inrepo", 128, 128, False, False)),
+    (64, 5760, ("inrepo", 1024, 512),
+     ("inrepo", 128, 128, False, False)),
+    (64, 5888, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (64, 8192, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (64, 8320, ("upstream", 512, 1024),
+     ("upstream", 128, 128, False, False)),
+    (64, 32768, ("upstream", 512, 1024),
+     ("upstream", 512, 1024, False, False)),
+    (64, 32896, ("upstream", 256, 256),
+     ("upstream", 128, 128, False, False)),
+    (64, 185344, ("upstream", 256, 256),
+     ("upstream", 256, 256, False, False)),
+    (64, 185472, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (64, 1048576, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (72, 128, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (72, 896, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (72, 1024, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (72, 1408, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (72, 1536, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (72, 2816, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (72, 2944, ("inrepo", 1024, 512),
+     ("inrepo", 128, 128, False, False)),
+    (72, 5760, ("inrepo", 1024, 512),
+     ("inrepo", 128, 128, False, False)),
+    (72, 5888, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (72, 11520, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (72, 11648, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (72, 1048576, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (128, 128, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (128, 896, ("xla", None, None),
+     ("xla", None, None, False, False)),
+    (128, 1024, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+    (128, 1048576, ("upstream", None, None),
+     ("upstream", None, None, False, False)),
+]
+
+@pytest.mark.parametrize(
+    "shape,platform,flash_env,resolved,ran",
+    [case[1:] for case in NAMED_SHAPES], ids=[case[0] for case in NAMED_SHAPES])
+def test_named_shape_routes(monkeypatch, shape, platform, flash_env, resolved,
+                            ran):
+    if flash_env is not None:
+        monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", flash_env)
+    _assert_as_recorded(monkeypatch, platform, shape, resolved, ran)
+
+
+@pytest.mark.parametrize(
+    "d,kv_len,resolved,ran", RANGE_EDGES,
+    ids=[f"{d}-{kv_len}" for d, kv_len, _, _ in RANGE_EDGES])
+def test_range_edges_route_as_before(monkeypatch, d, kv_len, resolved, ran):
+    heads = 16 if d == 72 else 10
+    _assert_as_recorded(monkeypatch, "tpu", (d, heads, kv_len, kv_len),
+                        resolved, ran)
 
 
 def test_env_off_wins_over_everything(monkeypatch):
     monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "0")
-    monkeypatch.setattr(sdpa_routing, "MEASURED_ROUTES",
-                        {(64, 12): Route("inrepo", 256, 512)})
-    assert _route(monkeypatch) == Route("xla")
+    monkeypatch.setattr(
+        sdpa_routing, "TABLE",
+        {64: (Row(128, 8192, Route("inrepo", 256, 512), "test"),)})
+    assert _route() == Route("xla")
+    assert _route(lq=4250, lk=4250) == Route("xla")
 
 
-def test_unaligned_always_xla(monkeypatch):
-    assert _route(monkeypatch, lq=4095, lk=4095) == Route("xla")
+def test_unaligned_is_padded_on_the_chip_and_xla_elsewhere():
+    """The gate that stood in `sdpa` behind the resolver's back: an unaligned
+    length takes the padded upstream kernel from 1024 keys on the chip, head
+    dim a multiple of 8 up to 256; everything else unaligned is XLA."""
+    padded = Route("padded", kernel="upstream")
+    assert _route(lq=4095, lk=4095) == padded
+    assert _route(lq=4095, lk=4096) == padded
+    assert _route(lq=4096, lk=1000) == Route("xla")
+    assert _route(lq=4095, lk=4095, c=512, heads=1) == Route("xla")
+    assert _route(lq=4095, lk=4095, c=60, heads=10) == Route("xla")
+    assert _route(lq=4096, lk=4096, c=60, heads=10) == Route("xla")
+    assert _route(platform="cpu", lq=4095, lk=4095) == Route("xla")
 
 
-def test_cpu_defaults_to_xla(monkeypatch):
-    assert _route(monkeypatch, platform="cpu") == Route("xla")
+def test_cpu_defaults_to_xla():
+    assert _route(platform="cpu") == Route("xla")
 
 
 def test_force_on_cpu_is_inrepo_interpret_path(monkeypatch):
     monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "1")
-    assert _route(monkeypatch, platform="cpu").impl == "inrepo"
+    assert _route(platform="cpu").impl == "inrepo"
 
 
-def test_measured_table_drives_default_route(monkeypatch):
-    monkeypatch.setattr(sdpa_routing, "MEASURED_ROUTES",
-                        {(64, 12): Route("inrepo", 256, 512),
-                         (64, 16): Route("xla")})
-    # L=4096 -> bucket 12 -> measured inrepo with tuned tiles
-    assert _route(monkeypatch) == Route("inrepo", 256, 512)
-    # L=57600 -> bucket ~15.8 -> nearest measured is 16 -> xla beats flash
-    assert _route(monkeypatch, lq=57600 // 8 * 8, lk=57344) == Route("xla")
-
-
-def test_env_tiles_override_measured_tiles(monkeypatch):
-    monkeypatch.setattr(sdpa_routing, "MEASURED_ROUTES",
-                        {(64, 12): Route("inrepo", 256, 512)})
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_BQ", "128")
-    assert _route(monkeypatch) == Route("inrepo", 128, 512)
-
-
-def test_explicit_impl_wins_over_table(monkeypatch):
-    monkeypatch.setattr(sdpa_routing, "MEASURED_ROUTES",
-                        {(64, 12): Route("xla")})
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "upstream")
-    assert _route(monkeypatch).impl == "upstream"
-
-
-def test_unmeasured_falls_to_analytic_default(monkeypatch):
-    monkeypatch.setattr(sdpa_routing, "MEASURED_ROUTES", {})
-    assert _route(monkeypatch).impl == "upstream"  # long seq on TPU
-    assert _route(monkeypatch, lq=512, lk=512).impl == "xla"  # short
-
-
-def test_lookup_requires_matching_head_dim():
-    # shipped table contents change with every campaign re-bake; pin only
-    # the lookup semantics against a controlled table
-    table = {(64, 12): Route("upstream")}
-    old = sdpa_routing.MEASURED_ROUTES
-    sdpa_routing.MEASURED_ROUTES = table
-    try:
-        assert sdpa_routing.lookup(5000, 64) == Route("upstream")
-        assert sdpa_routing.lookup(5000, 160) is None
-    finally:
-        sdpa_routing.MEASURED_ROUTES = old
-
-
-def test_lookup_distance_cap():
-    """A lone long-L measurement must not govern short sequences:
-    beyond MAX_BUCKET_DISTANCE log2 steps lookup falls through to the
-    analytic default."""
-    table = {(64, 14): Route("inrepo", 256, 512)}  # L=16384 only
-    old = sdpa_routing.MEASURED_ROUTES
-    sdpa_routing.MEASURED_ROUTES = table
-    try:
-        assert sdpa_routing.lookup(16384, 64) == Route("inrepo", 256, 512)
-        assert sdpa_routing.lookup(8192, 64) is not None   # 1 step away
-        assert sdpa_routing.lookup(1024, 64) is None       # 4 steps away
-        assert sdpa_routing.lookup(2**20, 64) is None      # far the other way
-    finally:
-        sdpa_routing.MEASURED_ROUTES = old
-
-
-def test_updater_tiles_keyed_by_head_dim(tmp_path):
-    """Tuned tiles for one head_dim must not leak onto another head_dim's
-    route at the same L."""
-    import json as _json
-
-    import update_sdpa_table as upd
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        {"phase": "attn", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"xla": 2.0, "inrepo": 1.5}},
-        {"phase": "attn", "L": 4096, "heads": 16, "head_dim": 72,
-         "ms": {"xla": 2.2, "inrepo": 1.8}},
-        {"phase": "tune", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"256x512": 1.2}},
-        {"phase": "tune", "L": 4096, "heads": 16, "head_dim": 72,
-         "ms": {"128x128": 1.6}},
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    routes = upd.build_routes(attn, tune)
-    assert routes[(64, 12)][:3] == ("inrepo", 256, 512)
-    assert routes[(72, 12)][:3] == ("inrepo", 128, 128)
-
-
-def test_updater_upstream_tune_can_win(tmp_path):
-    """A tuned upstream sweep that beats the default-tile attn comparison
-    flips the route to upstream and carries its tiles."""
-    import json as _json
-
-    import update_sdpa_table as upd
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        {"phase": "attn", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"xla": 2.0, "inrepo": 1.5, "upstream": 1.8}},
-        {"phase": "tune", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"256x512": 1.4}},
-        {"phase": "tune_upstream", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"512x1024": 1.1, "256x512": 1.3}},
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    routes = upd.build_routes(attn, tune)
-    assert routes[(64, 12)][:3] == ("upstream", 512, 1024)
-
-
-def test_updater_round_trip(tmp_path):
-    import update_sdpa_table as upd
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        {"phase": "attn", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"xla": 2.0, "inrepo": 1.5, "upstream": 1.0}},
-        {"phase": "attn", "L": 16384, "heads": 10, "head_dim": 64,
-         "ms": {"xla": 9.0, "inrepo": 8.0, "upstream": "failed:XlaError"}},
-        # 7.5 ms sits just above the L=16384 roofline floor (~6.98 ms at
-        # 100% bf16 peak) — the sanity guard must keep it
-        {"phase": "tune", "L": 16384, "heads": 10, "head_dim": 64,
-         "ms": {"128x128": 8.0, "256x512": 7.5}},
-        {"phase": "b1024", "size": 1024, "s": 7.0},  # ignored: no ms dict
-    ]
-    log.write_text("non-json noise\n"
-                   + "\n".join(json.dumps(rec) for rec in lines) + "\n")
-
-    attn, tune = upd.parse_log(str(log))
-    assert len(attn) == 2 and len(tune) == 1
-    routes = upd.build_routes(attn, tune)
-    assert routes[(64, 12)][0] == "upstream"
-    impl, bq, bk, _comment = routes[(64, 14)]
-    assert (impl, bq, bk) == ("inrepo", 256, 512)  # tuned tiles attached
-
-    block = upd.render_block(routes, "unit-test")
-    ns = {"Route": Route}
-    exec(block.replace(upd.BEGIN, "").replace(upd.END, ""), ns)
-    assert ns["MEASURED_ROUTES"][(64, 14)] == Route("inrepo", 256, 512)
-    assert ns["MEASURED_PROVENANCE"] == "unit-test"
-
-
-def test_updater_drops_subroofline_timings(tmp_path):
-    """Campaign r5 regression: upstream-flash tune entries of ~0.02 ms at
-    L=16384 (350x above bf16 peak — the kernel degenerates at those tiles
-    instead of failing) must not reach the table; the sane sub-peak tiles
-    of the same sweep still win."""
-    import json as _json
-
-    import update_sdpa_table as upd
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        {"phase": "attn", "L": 16384, "heads": 10, "head_dim": 64,
-         "ms": {"xla": "failed:JaxRuntimeError", "inrepo": 184.9,
-                "upstream": 161.8}},
-        {"phase": "tune", "L": 16384, "heads": 10, "head_dim": 64,
-         "ms": {"512x1024": 25.9}},
-        {"phase": "tune_upstream", "L": 16384, "heads": 10, "head_dim": 64,
-         "ms": {"256x2048": 23.2, "512x512": 0.022, "1024x512": 0.019}},
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    routes = upd.build_routes(attn, tune)
-    impl, bq, bk, _comment = routes[(64, 14)]
-    assert (impl, bq, bk) == ("upstream", 256, 2048)  # not the 0.02ms tiles
-    # an attn record that is ENTIRELY sub-floor contributes nothing
-    attn2 = [{"phase": "attn", "L": 16384, "heads": 10, "head_dim": 64,
-              "ms": {"xla": 0.01, "upstream": 0.02}}]
-    assert upd.build_routes(attn2, []) == {}
-
-
-def test_updater_tiles_require_matching_head_count(tmp_path):
-    """Campaign r5 regression: an h=10 tuned sweep must not fold into an
-    h=24 attn record at the same (L, head_dim) — mixed-head comparison
-    flipped the route to a kernel that loses at both head counts.  A
-    heads-less record (pre-r5 logs) still matches any sweep (wildcard)."""
-    import json as _json
-
-    import update_sdpa_table as upd
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        # h=10 record first, h=24 record last (owns the route slot)
-        {"phase": "attn", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"xla": 7.1, "inrepo": 13.8, "upstream": 12.2}},
-        {"phase": "attn", "L": 4096, "heads": 24, "head_dim": 64,
-         "ms": {"xla": 12.2, "inrepo": 29.4, "upstream": 26.3}},
-        {"phase": "tune", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"512x1024": 8.2}},
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    routes = upd.build_routes(attn, tune)
-    # the h=10 sweep (8.2ms) must NOT beat the h=24 record's xla (12.2ms)
-    assert routes[(64, 12)][:3] == ("xla", None, None)
-
-    # wildcard: heads-less attn record accepts the sweep
-    lines2 = [
-        {"phase": "attn", "L": 4096, "head_dim": 64,
-         "ms": {"xla": 12.2, "inrepo": 13.8}},
-        {"phase": "tune", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"512x1024": 8.2}},
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines2) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    routes = upd.build_routes(attn, tune)
-    assert routes[(64, 12)][:3] == ("inrepo", 512, 1024)
-
-
-def test_model_validated_override_wins_and_scopes():
-    """MODEL_VALIDATED_OVERRIDES outranks MEASURED_ROUTES at its bucket but
-    obeys the same bucket-distance discipline elsewhere."""
-    old_m = sdpa_routing.MEASURED_ROUTES
-    old_o = sdpa_routing.MODEL_VALIDATED_OVERRIDES
-    sdpa_routing.MEASURED_ROUTES = {(64, 12): Route("xla")}
-    sdpa_routing.MODEL_VALIDATED_OVERRIDES = {
-        (64, 12): Route("upstream", 256, 1024)}
-    try:
-        assert sdpa_routing.lookup(4096, 64) == Route("upstream", 256, 1024)
-        # far buckets fall through the override to the measured table rules
-        assert sdpa_routing.lookup(2**20, 64) is None
-        # other head_dims see neither
-        assert sdpa_routing.lookup(4096, 160) is None
-        # a STRICTLY CLOSER measured entry beats the override: the override
-        # is model-validated at ITS bucket only, not at lengths a nearer
-        # measurement covers (L=1536 is 0.58 buckets from the (64,10) XLA
-        # entry, 1.42 from the (64,12) override)
-        sdpa_routing.MEASURED_ROUTES = {(64, 10): Route("xla"),
-                                        (64, 12): Route("xla")}
-        assert sdpa_routing.lookup(1536, 64) == Route("xla")
-        assert sdpa_routing.lookup(4096, 64) == Route("upstream", 256, 1024)
-    finally:
-        sdpa_routing.MEASURED_ROUTES = old_m
-        sdpa_routing.MODEL_VALIDATED_OVERRIDES = old_o
-
-
-def test_updater_skips_tiles_slower_than_default(tmp_path):
-    """A tuned sweep whose best time LOSES to the winner's default-tile
-    time must not pin its tiles onto the route (the comment would claim a
-    time those tiles never achieved)."""
-    import json as _json
-
-    import update_sdpa_table as upd
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        {"phase": "attn", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"xla": 9.0, "upstream": 7.0}},
-        {"phase": "tune_upstream", "L": 4096, "heads": 10, "head_dim": 64,
-         "ms": {"512x1024": 8.5}},  # tuned WORSE than default-tile 7.0
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    routes = upd.build_routes(attn, tune)
-    assert routes[(64, 12)][:3] == ("upstream", None, None)
+def test_table_rows_are_inclusive_and_keyed_by_head_dim(monkeypatch):
+    """A row governs its own head dim from kv_lo to kv_hi inclusive, whatever
+    lq is; outside it the default decides (xla under 1024 keys, upstream
+    with its own tiles from there)."""
+    row = Route("inrepo", 256, 512)
+    monkeypatch.setattr(sdpa_routing, "TABLE",
+                        {64: (Row(512, 2048, row, "test"),)})
+    for lk in (512, 1280, 2048):
+        assert _route(lq=128, lk=lk) == row
+    assert _route(lq=384, lk=384) == Route("xla")
+    assert _route(lq=2176, lk=2176) == Route("upstream")
+    assert _route(lq=1280, lk=1280, c=1280) == Route("upstream")
+    assert _route(lq=512, lk=512, c=1280) == Route("xla")
 
 
 def test_sdpa_still_computes_on_cpu(monkeypatch):
     """End to end: routing lands on a working path whatever the table says."""
-    import jax.numpy as jnp
     import numpy as np
 
-    monkeypatch.setattr(sdpa_routing, "MEASURED_ROUTES",
-                        {(64, 7): Route("inrepo", 64, 64)})
+    monkeypatch.setattr(
+        sdpa_routing, "TABLE",
+        {64: (Row(128, 128, Route("inrepo", 64, 64), "test"),)})
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (1, 128, 128), jnp.float32)
     out = attention.sdpa(q, q, q, heads=2)
     assert out.shape == (1, 128, 128)
     assert np.isfinite(np.asarray(out)).all()
-
-
-def test_updater_accepts_bench_attention_lines(tmp_path):
-    import update_sdpa_table as upd
-
-    log = tmp_path / "bench_attention.log"
-    lines = [
-        {"impl": "xla", "L": 4096, "heads": 10, "ms": 2.0},
-        {"impl": "pallas_inrepo", "L": 4096, "heads": 10, "ms": 1.4},
-        {"impl": "pallas_upstream", "L": 4096, "heads": 10,
-         "ms": "failed: XlaRuntimeError"},
-    ]
-    log.write_text("\n".join(json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    assert len(attn) == 1 and not tune
-    routes = upd.build_routes(attn, tune)
-    assert routes[(64, 12)][0] == "inrepo"  # failed upstream excluded
-
-
-def test_updater_accepts_batch2_campaign_records(tmp_path):
-    """chip_campaign.py emits ``batch=2`` (the CFG pair) in attn/tune
-    records: the updater must carry them end to end — the roofline floor
-    doubles (4*B*h*L^2*d flops), the batch lands in the table comment, and
-    the rendered block round-trips."""
-    import json as _json
-
-    import update_sdpa_table as upd
-
-    # b=2 floor at L=16384 h=10 d=64: 4*2*10*16384^2*64/197e12 ~= 6.98 ms
-    floor_b2 = upd._roofline_floor_ms(
-        {"L": 16384, "heads": 10, "head_dim": 64, "batch": 2})
-    floor_b1 = upd._roofline_floor_ms(
-        {"L": 16384, "heads": 10, "head_dim": 64})
-    assert floor_b2 == pytest.approx(2 * floor_b1)
-
-    log = tmp_path / "campaign.log"
-    lines = [
-        {"phase": "attn", "L": 16384, "heads": 10, "head_dim": 64,
-         "batch": 2, "ms": {"xla": 30.0, "inrepo": 20.0, "upstream": 12.0}},
-        # 5 ms sits ABOVE the b=1 floor (~3.5 ms) but BELOW the b=2 floor
-        # (~6.98 ms): a b=2 record must drop it as a timing escape
-        {"phase": "tune_upstream", "L": 16384, "heads": 10, "head_dim": 64,
-         "batch": 2, "ms": {"512x512": 5.0, "256x1024": 10.0}},
-    ]
-    log.write_text("\n".join(_json.dumps(rec) for rec in lines) + "\n")
-    attn, tune = upd.parse_log(str(log))
-    assert attn[0]["batch"] == 2 and tune[0]["batch"] == 2
-    routes = upd.build_routes(attn, tune)
-    impl, bq, bk, comment = routes[(64, 14)]
-    assert (impl, bq, bk) == ("upstream", 256, 1024)  # not the 5 ms escape
-    assert "b=2" in comment
-    block = upd.render_block(routes, "unit-test-b2")
-    ns = {"Route": Route}
-    exec(block.replace(upd.BEGIN, "").replace(upd.END, ""), ns)
-    assert ns["MEASURED_ROUTES"][(64, 14)] == Route("upstream", 256, 1024)
-
-
-def test_lookup_nearest_shape_fallback_for_missing_key():
-    """The table is keyed by (head_dim, log2 L) — a query whose exact
-    (batch, seq, heads) combination was never measured still routes via
-    the NEAREST measured bucket at its head_dim (within
-    MAX_BUCKET_DISTANCE), and falls through to the analytic default
-    beyond it.  Batch and head count deliberately do not partition the
-    table: the campaign measures the CFG pair at the model's head counts,
-    and the latency ordering tracks sequence-length scale."""
-    table = {(64, 12): Route("upstream", 256, 1024),
-             (64, 14): Route("inrepo", 512, 512)}
-    old = sdpa_routing.MEASURED_ROUTES
-    sdpa_routing.MEASURED_ROUTES = table
-    try:
-        # L=6000 (bucket ~12.55) was never measured: nearest is 12
-        assert sdpa_routing.lookup(6000, 64) == Route("upstream", 256, 1024)
-        # L=11585 (bucket ~13.5): ties resolve to a measured neighbor,
-        # never to None, as long as one is in range
-        assert sdpa_routing.lookup(11585, 64) in table.values()
-        # L=23000 (bucket ~14.5): nearest is 14
-        assert sdpa_routing.lookup(23000, 64) == Route("inrepo", 512, 512)
-        # missing head_dim: no fallback across head_dims
-        assert sdpa_routing.lookup(6000, 128) is None
-        # far outside every measured bucket: analytic default decides
-        assert sdpa_routing.lookup(240, 64) is None
-    finally:
-        sdpa_routing.MEASURED_ROUTES = old
 
 
 def test_largest_dividing_tile():
@@ -432,7 +323,7 @@ def test_largest_dividing_tile():
     divisor instead of being dropped (which would mix in the kernel's
     hardcoded 512/1024 defaults — themselves non-dividing for shapes like
     Lk=57600)."""
-    fit = attention._largest_dividing_tile
+    fit = fa.largest_dividing_tile
     assert fit(512, 4096) == 512          # already divides
     assert fit(1024, 57600) == 256        # 1024, 512 fail; 256 divides
     assert fit(512, 57600) == 256
@@ -447,32 +338,28 @@ CELL_SHAPES = [(72, 16, 4096), (64, 10, 4096), (64, 20, 1024)]
 
 
 @pytest.mark.parametrize("d,heads,l", CELL_SHAPES)
-def test_cell_shapes_resolve_to_the_seq_minor_kernel(monkeypatch, d, heads, l):
+def test_cell_shapes_resolve_to_the_seq_minor_kernel(d, heads, l):
     """PR 25: the three shapes of the benchmark's cells route to the in-repo
     (sequence-minor) kernel, with tiles that divide the cell's length and
     the local Q lengths of the patch path (L/2, L/4 rows, KV gathered)."""
-    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
-                        SHIPPED_OVERRIDES)
-    route = _route(monkeypatch, lq=l, lk=l, c=heads * d, heads=heads)
+    route = _route(lq=l, lk=l, c=heads * d, heads=heads)
     assert route.impl == "inrepo", route
     for tile in (route.block_q, route.block_k):
         assert tile and tile >= 128 and tile & (tile - 1) == 0
     assert l % route.block_q == 0 and l % route.block_k == 0
-    # the patch path keys on kv_len, so it inherits the entry
+    # the patch path keys on kv_len, so it inherits the row
     for n in (2, 4):
-        assert _route(monkeypatch, lq=l // n, lk=l, c=heads * d,
+        assert _route(lq=l // n, lk=l, c=heads * d,
                       heads=heads) == route
 
 
 def test_inrepo_route_tiles_are_fitted_to_the_call(monkeypatch):
     """`sdpa` cuts a route's tiles down to what divides this call's lengths
-    (a bucket holds lengths its tiles do not divide; the patch path's local
+    (a row holds lengths its tiles do not divide; the patch path's local
     Lq is a fraction of the cell's) and hands them to `flash_sdpa`."""
-    import jax.numpy as jnp
-
-    fa = importlib.import_module("distrifuser_tpu.ops.flash_attention")
-    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
-                        {(64, 12): Route("inrepo", 1024, 512)})
+    monkeypatch.setattr(
+        sdpa_routing, "TABLE",
+        {64: (Row(2944, 5760, Route("inrepo", 1024, 512), "test"),)})
     monkeypatch.setattr(jax, "devices", lambda: [_Dev("tpu")])
     seen = []
 
@@ -489,36 +376,58 @@ def test_inrepo_route_tiles_are_fitted_to_the_call(monkeypatch):
     assert all(kw["interpret"] is False for kw in seen)
 
 
-def test_env_overrides_govern_the_moved_entries(monkeypatch):
-    """The documented hatches against the shipped table: FLASH=0 and
-    IMPL=xla pin XLA, IMPL=upstream pins the upstream kernel, and BQ / BK
-    replace the entry's tiles one axis at a time."""
-    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
-                        SHIPPED_OVERRIDES)
-    shipped = _route(monkeypatch, c=16 * 72, heads=16)
-    assert shipped.impl == "inrepo"
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_BK", "256")
-    assert _route(monkeypatch, c=16 * 72, heads=16) == Route(
-        "inrepo", shipped.block_q, 256)
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_BQ", "128")
-    assert _route(monkeypatch, c=16 * 72, heads=16) == Route(
-        "inrepo", 128, 256)
-    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_BQ")
-    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_BK")
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "upstream")
-    assert _route(monkeypatch, c=16 * 72, heads=16).impl == "upstream"
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH_IMPL", "xla")
-    assert _route(monkeypatch, c=16 * 72, heads=16) == Route("xla")
-    monkeypatch.delenv("DISTRIFUSER_TPU_FLASH_IMPL")
-    monkeypatch.setenv("DISTRIFUSER_TPU_FLASH", "0")
-    assert _route(monkeypatch, c=16 * 72, heads=16) == Route("xla")
-
-
-def test_unmoved_entries_stay_where_they_were(monkeypatch):
-    """(64, 14), (64, 16) keep the upstream kernel; a length between the
-    buckets of a moved entry and an unmoved one goes to the nearer."""
-    monkeypatch.setattr(sdpa_routing, "MODEL_VALIDATED_OVERRIDES",
-                        SHIPPED_OVERRIDES)
-    assert _route(monkeypatch, lq=16384, lk=16384).impl == "upstream"
-    assert _route(monkeypatch, lq=57600 // 128 * 128,
+def test_unmoved_entries_stay_where_they_were():
+    """d=64 at 16384 and 57600 keys keeps the upstream kernel."""
+    assert _route(lq=16384, lk=16384).impl == "upstream"
+    assert _route(lq=57600 // 128 * 128,
                   lk=57600 // 128 * 128).impl == "upstream"
+
+
+def test_kernels_and_table_import_nothing_from_attention():
+    """The arrows point one way: `attention.py` imports the kernels and the
+    table; neither reaches back up into its caller, lazily or otherwise."""
+    for fname in ("flash_attention.py", "sdpa_routing.py"):
+        with open(os.path.join(PACKAGE, "ops", fname)) as f:
+            tree = ast.parse(f.read())
+        names = set()
+        for node in ast.walk(tree):  # function bodies included
+            if isinstance(node, ast.Import):
+                names.update(a.name.rsplit(".", 1)[-1] for a in node.names)
+            elif isinstance(node, ast.ImportFrom):
+                names.add((node.module or "").rsplit(".", 1)[-1])
+                names.update(a.name for a in node.names)
+        assert "attention" not in names, (fname, sorted(names))
+
+
+def test_one_env_hatch_read_in_one_module():
+    """Under distrifuser_tpu/ the environment is asked for one
+    DISTRIFUSER_TPU_ name, in one module; no other such name is so much as
+    mentioned."""
+    reads, mentioned = set(), set()
+    for root, _dirs, files in os.walk(PACKAGE):
+        for fname in files:
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(root, fname)
+            with open(path) as f:
+                src = f.read()
+            rel = os.path.relpath(path, PACKAGE)
+            mentioned.update(re.findall(r"DISTRIFUSER_TPU_[A-Z0-9_]*", src))
+            for node in ast.walk(ast.parse(src)):
+                # os.environ.get(X) / os.getenv(X) / environ.setdefault(X, ..)
+                # / os.environ[X] / X in os.environ
+                if isinstance(node, ast.Call) and node.args:
+                    target, arg = ast.unparse(node.func), node.args[0]
+                elif isinstance(node, ast.Subscript):
+                    target, arg = ast.unparse(node.value), node.slice
+                elif isinstance(node, ast.Compare) and node.comparators:
+                    target, arg = ast.unparse(node.comparators[0]), node.left
+                else:
+                    continue
+                if "environ" in target or "getenv" in target:
+                    name = ast.unparse(arg)
+                    if "DISTRIFUSER_TPU_" in name:
+                        reads.add((rel, name.strip("'\"")))
+    assert reads == {(os.path.join("ops", "sdpa_routing.py"),
+                      "DISTRIFUSER_TPU_FLASH")}, reads
+    assert mentioned == {"DISTRIFUSER_TPU_FLASH"}, mentioned
