@@ -5,6 +5,7 @@ device side.  One profiler session per server kind serves the span, clock
 and `Tracer` assertions alike."""
 
 import glob
+import math
 import os
 import re
 
@@ -401,6 +402,78 @@ def test_flash_custom_call_carries_the_attn_scope(topo, monkeypatch):
     assert calls and all(
         re.search(r'op_name="[^"]*/down_1/attn/[^"]*pallas_call', ln)
         for ln in calls), calls
+
+
+def _entry_instructions(text):
+    """(result part, op_name) of every instruction of the ENTRY computation
+    of a compiled program's HLO text."""
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines) if ln.startswith("ENTRY "))
+    for ln in lines[start + 1:]:
+        if ln.startswith("}"):
+            return
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) [\w\-]+\(", ln)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', ln)
+            yield m.group(1), op.group(1) if op else ""
+
+
+def test_two_row_groupnorm_writes_no_float32_activation(topo, monkeypatch):
+    """The guided step's GroupNorm compiled for the described chip (PR 30):
+    the UNet's last up block at the cell's size, its three skips and six
+    norms at two rows.  Where a reduction takes each row's moments over the
+    pixels alone the compiler writes the activation out in float32
+    (`f32[128,16,17,640]`, 89 MB, and the moments as broadcasts of that
+    size: 12 such results in this cut on PR 29's tree); with every reduction
+    run through the batch axis none is left.  And what the norm became is
+    found by `groupnorm_ms_per_step`: its arithmetic sits under the
+    `groupnorm` scope and nowhere else."""
+    from jax.sharding import SingleDeviceSharding
+
+    from distrifuser_tpu.models.unet import (DenseDispatch, init_unet_params,
+                                             sdxl_config)
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: topo.devices)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    cfg = sdxl_config()
+    params = jax.eval_shape(
+        lambda k: init_unet_params(k, cfg, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    bp = jax.tree.map(lambda x: sds(x.shape, x.dtype), params["up_blocks"][2])
+
+    def up_2(bp, x, skips, temb):
+        d = DenseDispatch()
+        with jax.named_scope("up_2"):
+            for j, skip in enumerate(skips):
+                x = jnp.concatenate([x, skip], axis=-1)
+                x = d.resnet(bp["resnets"][j], x, temb, f"resnets.{j}",
+                             groups=cfg.norm_num_groups)
+        return x
+
+    rows, size, c = 2, 128, cfg.block_out_channels[0]
+    text = jax.jit(up_2).lower(
+        bp, sds((rows, size, size, 2 * c)),
+        [sds((rows, size, size, c))] * 3, sds((rows, 4 * c))
+    ).compile().as_text()
+
+    ops = list(_entry_instructions(text))
+    assert any("/groupnorm/" in op for _, op in ops), \
+        "no instruction carries the groupnorm scope"
+    large = [(op, f"f32[{dims}]") for result, op in ops
+             for dims in re.findall(r"\bf32\[([\d,]+)\]", result)
+             if 4 * math.prod(int(d) for d in dims.split(",")) >= 20e6]
+    assert not large, large
+    # the norm's own arithmetic (nothing else in a resnet divides, takes a
+    # root or sums over pixels) is named by the scope wherever it survived
+    # as an instruction of its own
+    own = re.compile(r"/(rsqrt|reduce_sum|div|integer_pow|square)$")
+    strays = [op for _, op in ops
+              if own.search(op) and "/groupnorm/" not in op]
+    assert not strays, strays
 
 
 def _attention_patterns():
