@@ -1,0 +1,342 @@
+"""EvaByte: a byte-level causal language model whose attention is EVA
+(`ops/eva.py`) - an exact 2048-byte window beside pooled 16-byte chunk
+summaries of everything before it, in one softmax - with prefill, a one-byte
+step through a BOUNDED decode state, and a greedy decode loop that stays on
+the device.
+
+    h <- h + Attn(RMSNorm(h))                       h in float32
+    h <- h + W_down(silu(W_gate x) * W_up x),       x = RMSNorm(h)
+    logits = RMSNorm(h) W_head                      float32, 8 x 320 columns
+
+``RMSNorm(x) = x / sqrt(mean(x^2) + eps) * (1 + w)`` on the served-dtype
+cast of ``h`` (the residual stream stays float32 under bf16 layers:
+``fp32_skip_add``); no bias anywhere.  ``Attn``: q, k, v = x W_qkv, 32 heads
+of 128, as many KV heads; rotary embedding on q and k by absolute position
+(theta 1e5, whole head, rotate-half) BEFORE the pooling; EVA; then W_o.
+The head predicts eight bytes a position: block ``i`` of its columns is
+byte ``t + 1 + i``.  Greedy decoding takes block 0, one byte a step; the
+other seven are computed and returned with the logits (self-speculative
+multi-byte decoding is not run: ROADMAP R7c).
+
+State across calls, a layer: a ring ``k``, ``v`` [window, H, D] (rotated,
+served dtype), written at ``t % window``, of which rows ``0 .. t % window``
+are visible - so a window boundary empties it without a write - and a
+summary table ``ks``, ``vs`` [ceil(max_len / chunk), H, D], row ``j``
+written when position ``chunk * j + chunk - 1`` is, of which the rows of
+earlier windows are visible.  One sequence at a time (no batch axis).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..ops import eva
+from .language_model import LanguageModel
+from .weights import params_nbytes
+
+F32 = jnp.float32
+
+# counters the generation returns with its ids
+COUNTERS = ("bytes_prefilled", "bytes_decoded", "summaries_written",
+            "windows_rolled", "state_bytes")
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaByteConfig:
+    num_hidden_layers: int = 32
+    vocab_size: int = 320
+    byte_offset: int = 64  # byte b is id b + 64; ids 0-63 are special
+    hidden_size: int = 4096
+    num_attention_heads: int = 32
+    intermediate_size: int = 11008
+    window_size: int = 2048
+    chunk_size: int = 16
+    num_pred_heads: int = 8
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 100000.0
+    fp32_skip_add: bool = True
+
+    def __post_init__(self):
+        if self.hidden_size % self.num_attention_heads or self.head_dim % 2:
+            raise ValueError("hidden_size must divide into heads of even size")
+        if self.window_size % self.chunk_size:
+            raise ValueError("a chunk never straddles a window: window_size "
+                             "must be a multiple of chunk_size")
+        if self.byte_offset + 256 > self.vocab_size:
+            raise ValueError("the vocabulary must hold byte_offset + 256 ids")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    def language_model(self) -> LanguageModel:
+        """This model as the rewrite stage takes it."""
+        return LanguageModel(self, prefill, decode, COUNTERS, self.chunk_size,
+                             self.vocab_size, self.byte_offset)
+
+
+def evabyte_config_from_json(d: Dict[str, Any]) -> EvaByteConfig:
+    """From the published config.json keys (``byte_offset`` is ours: the
+    config does not give the tokenizer's)."""
+    built = {"attention_class": "eva", "hidden_act": "silu",
+             "norm_add_unit_offset": True, "fp32_logits": True,
+             "mixedp_attn": True, "attention_bias": False, "fp32_ln": False,
+             "rope_scaling": None, "tie_word_embeddings": False}
+    for key, want in built.items():
+        if d.get(key, want) != want:
+            raise ValueError(f"only {key} = {want!r} is built, the "
+                             f"configuration says {d[key]!r}")
+    if d.get("num_key_value_heads", d["num_attention_heads"]) != d[
+            "num_attention_heads"]:
+        raise ValueError("EVA is built with as many KV heads as query heads")
+    names = {f.name for f in dataclasses.fields(EvaByteConfig)}
+    return EvaByteConfig(**{k: d[k] for k in names & set(d)})
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def param_shapes(cfg: EvaByteConfig) -> Dict[str, Any]:
+    """The parameter tree with a shape tuple at every leaf.  q | k | v and
+    gate | up are each held as one fused kernel: the same parameters and
+    arithmetic."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    h, hd = cfg.num_attention_heads, cfg.head_dim
+    layer = {
+        "attn_norm": {"scale": (d,)},
+        "attn": {"qkv": {"kernel": (d, 3 * d)}, "o_proj": {"kernel": (d, d)},
+                 "phi": (h, hd), "mu": (h, hd)},
+        "mlp_norm": {"scale": (d,)},
+        "mlp": {"gate_up": {"kernel": (d, 2 * f)}, "down": {"kernel": (f, d)}},
+    }
+    return {
+        "embed": (cfg.vocab_size, d),
+        "layers": [layer] * cfg.num_hidden_layers,
+        "final_norm": {"scale": (d,)},
+        "head": {"kernel": (d, cfg.num_pred_heads * cfg.vocab_size)},
+    }
+
+
+def init_leaf(key, name: str, shape, cfg: EvaByteConfig, dtype):
+    """One leaf by its name: norm offsets ``w`` zeros (the scale is 1 + w),
+    the embedding N(0, 0.02^2), the pooling vectors phi and mu
+    N(0, 1 / head_dim) (so that phi . k is of order one and the pooling is
+    neither uniform nor one-hot), kernels N(0, 1 / fan_in)."""
+    if name == "scale":
+        return jnp.zeros(shape, dtype)
+    if name == "embed":
+        return (0.02 * jax.random.normal(key, shape, F32)).astype(dtype)
+    fan_in = cfg.head_dim if name in ("phi", "mu") else shape[-2]
+    return (jax.random.normal(key, shape, F32) / math.sqrt(fan_in)
+            ).astype(dtype)
+
+
+def named_leaves(cfg: EvaByteConfig):
+    """([(a leaf's own name, its shape)], the tree's structure)."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    return [(str(getattr(path[-1], "key", path[-1])), shape)
+            for path, shape in leaves], treedef
+
+
+def init_evabyte_params(key, cfg: EvaByteConfig, dtype=F32):
+    leaves, treedef = named_leaves(cfg)
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree_util.tree_unflatten(treedef, [
+        init_leaf(k, name, shape, cfg, dtype)
+        for k, (name, shape) in zip(keys, leaves)])
+
+
+# -- layers -------------------------------------------------------------------
+
+
+def rms_norm(w, x, eps: float):
+    """x / sqrt(mean(x^2) + eps) * (1 + w), computed in float32 over the
+    last axis; the result in ``x``'s dtype."""
+    xf = x.astype(F32)
+    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf * (1.0 + w.astype(F32))).astype(x.dtype)
+
+
+def rotary(x, positions, theta: float):
+    """Rotary embedding over the whole head, rotate-half: x [T, H, D] at
+    ``positions`` [T]; float32 inside, the result in ``x``'s dtype."""
+    half = x.shape[-1] // 2
+    freqs = positions.astype(F32)[:, None] * theta ** (
+        -jnp.arange(half, dtype=F32) / half)
+    cos, sin = jnp.cos(freqs)[:, None, :], jnp.sin(freqs)[:, None, :]
+    x1, x2 = x[..., :half].astype(F32), x[..., half:].astype(F32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+@jax.named_scope("lm.eva.proj")
+def _qkv(p, cfg: EvaByteConfig, x, positions):
+    """x [T, d] -> q, k (rotated), v [T, H, D]."""
+    t = x.shape[0]
+    q, k, v = (a.reshape(t, cfg.num_attention_heads, cfg.head_dim)
+               for a in jnp.split(x @ p["qkv"]["kernel"], 3, axis=-1))
+    return (rotary(q, positions, cfg.rope_theta),
+            rotary(k, positions, cfg.rope_theta), v)
+
+
+@jax.named_scope("lm.eva.proj")
+def _out(p, attended):
+    return attended.reshape(attended.shape[0], -1) @ p["o_proj"]["kernel"]
+
+
+def attention_prefill(p, cfg: EvaByteConfig, x, max_len: int):
+    """A whole prompt from position 0 (x [T, d], T a multiple of
+    ``chunk_size``) -> (out [T, d], the layer's decode state with room for
+    ``max_len`` positions)."""
+    t = x.shape[0]
+    window, chunk = cfg.window_size, cfg.chunk_size
+    q, k, v = _qkv(p, cfg, x, jnp.arange(t))
+    with jax.named_scope("lm.eva.pool"):
+        ks, vs = eva.chunk_summaries(k, v, p["phi"], p["mu"], chunk=chunk)
+    with jax.named_scope("lm.eva.attn"):
+        out = eva.prefill_attention(q, k, v, ks, vs, window=window,
+                                    chunk=chunk)
+    # what the last, unfinished window leaves in the ring; every summary
+    held = t - t // window * window
+    rows = -(-max_len // chunk) - ks.shape[0]
+    state = {
+        "k": jnp.pad(k[t - held:], ((0, window - held), (0, 0), (0, 0))),
+        "v": jnp.pad(v[t - held:], ((0, window - held), (0, 0), (0, 0))),
+        "ks": jnp.pad(ks, ((0, rows), (0, 0), (0, 0))),
+        "vs": jnp.pad(vs, ((0, rows), (0, 0), (0, 0)))}
+    return _out(p, out), state
+
+
+def attention_step(p, cfg: EvaByteConfig, x, state, position):
+    """One byte (x [1, d]) at ``position`` through the layer's state: its
+    key and value go into the ring first; if it completes a chunk, the
+    chunk's summary goes into the table; then the query reads both."""
+    window, chunk = cfg.window_size, cfg.chunk_size
+    position = jnp.asarray(position, jnp.int32)
+    q, k, v = _qkv(p, cfg, x, position[None])
+    at = position % window
+    ring_k = lax.dynamic_update_slice_in_dim(state["k"], k, at, axis=0)
+    ring_v = lax.dynamic_update_slice_in_dim(state["v"], v, at, axis=0)
+    with jax.named_scope("lm.eva.pool"):
+        start, row = at // chunk * chunk, position // chunk
+        ks, vs = eva.chunk_summaries(
+            lax.dynamic_slice_in_dim(ring_k, start, chunk),
+            lax.dynamic_slice_in_dim(ring_v, start, chunk),
+            p["phi"], p["mu"], chunk=chunk)
+        complete = position % chunk == chunk - 1
+        table_k, table_v = (
+            lax.dynamic_update_slice_in_dim(table, jnp.where(
+                complete, new, lax.dynamic_slice_in_dim(table, row, 1)),
+                row, axis=0)
+            for table, new in ((state["ks"], ks), (state["vs"], vs)))
+    with jax.named_scope("lm.eva.attn"):
+        out = eva.decode_attention(q[0], ring_k, ring_v, table_k, table_v,
+                                   position=position, window=window,
+                                   chunk=chunk)
+    return _out(p, out[None]), {"k": ring_k, "v": ring_v, "ks": table_k,
+                                "vs": table_v}
+
+
+@jax.named_scope("lm.mlp")
+def mlp(p, x):
+    gate, up = jnp.split(x @ p["gate_up"]["kernel"], 2, axis=-1)
+    hidden = jax.nn.silu(gate.astype(F32)) * up.astype(F32)
+    return hidden.astype(x.dtype) @ p["down"]["kernel"]
+
+
+@jax.named_scope("lm.head")
+def head(params, cfg: EvaByteConfig, h):
+    """h [T, d] -> float32 logits [T, num_pred_heads * vocab_size]."""
+    x = rms_norm(params["final_norm"]["scale"],
+                 h.astype(params["head"]["kernel"].dtype), cfg.rms_norm_eps)
+    return jnp.dot(x, params["head"]["kernel"], preferred_element_type=F32)
+
+
+# -- prefill, step, generation ------------------------------------------------
+
+
+def _forward(params, cfg: EvaByteConfig, ids, state, position, max_len=None):
+    """The stack over ids [T] -> (the residual stream [T, d], the new
+    state, one entry a layer).  ``state`` None: a whole prompt from
+    position 0; else one byte at ``position`` through the state."""
+    dtype = params["embed"].dtype
+    # fp32_skip_add false keeps the stream where the published code keeps
+    # it without the switch, in bfloat16: a precision below the stated one
+    h = params["embed"][ids].astype(F32 if cfg.fp32_skip_add else jnp.bfloat16)
+    new_state = []
+    for i, lp in enumerate(params["layers"]):
+        x = rms_norm(lp["attn_norm"]["scale"], h.astype(dtype),
+                     cfg.rms_norm_eps)
+        if state is None:
+            out, st = attention_prefill(lp["attn"], cfg, x, max_len)
+        else:
+            out, st = attention_step(lp["attn"], cfg, x, state[i], position)
+        new_state.append(st)
+        h = h + out.astype(h.dtype)
+        x = rms_norm(lp["mlp_norm"]["scale"], h.astype(dtype),
+                     cfg.rms_norm_eps)
+        h = h + mlp(lp["mlp"], x).astype(h.dtype)
+    return h, new_state
+
+
+def prefill(params, cfg: EvaByteConfig, ids, *, max_len: int):
+    """A prompt (ids [T], T a multiple of ``chunk_size``) computed in full
+    -> (float32 logits after its last byte [8 * V], the decode state with
+    room for ``max_len`` positions, the `COUNTERS` so far [5] int32, ())."""
+    t = ids.shape[0]
+    if t % cfg.chunk_size:
+        raise ValueError(f"a prompt of {t} bytes is not whole chunks of "
+                         f"{cfg.chunk_size}")
+    h, state = _forward(params, cfg, ids, None, 0, max_len)
+    counters = jnp.asarray([t, 0, t // cfg.chunk_size, t // cfg.window_size,
+                            params_nbytes(state)], jnp.int32)
+    return head(params, cfg, h[-1:])[0], state, counters, ()
+
+
+def decode(params, cfg: EvaByteConfig, logits, state, counters, *,
+           position: int, new_tokens: int):
+    """Greedy decoding through the state, on the device from first byte to
+    last: ``new_tokens`` times the largest logit of block 0 is taken and
+    the byte goes through the stack.  ``logits`` follow the byte at
+    ``position - 1``.  -> (ids [new_tokens] int32, the float32 logits each
+    was chosen from, all eight blocks [new_tokens, 8 * V], (), the state,
+    the counters)."""
+    window, chunk = cfg.window_size, cfg.chunk_size
+
+    def body(i, carry):
+        logits, state, ids, chosen_from, counters = carry
+        token = jnp.argmax(logits[:cfg.vocab_size]).astype(jnp.int32)
+        ids = ids.at[i].set(token)
+        chosen_from = lax.dynamic_update_slice_in_dim(
+            chosen_from, logits[None], i, axis=0)
+        at = position + i
+        h, state = _forward(params, cfg, token[None], state, at)
+        counters = counters + jnp.stack([
+            0, 1, at % chunk == chunk - 1, at % window == window - 1, 0]
+        ).astype(jnp.int32)
+        return head(params, cfg, h)[0], state, ids, chosen_from, counters
+
+    _, state, ids, chosen_from, counters = lax.fori_loop(
+        0, new_tokens, body,
+        (logits, state, jnp.zeros((new_tokens,), jnp.int32),
+         jnp.zeros((new_tokens,) + logits.shape, F32), counters))
+    return ids, chosen_from, (), state, counters
+
+
+def generate(params, cfg: EvaByteConfig, ids, new_tokens: int):
+    """Prefill, then greedy decoding -> (new ids, the logits they were
+    chosen from, the counters, the state)."""
+    t = ids.shape[0]
+    logits, state, counters, _ = prefill(params, cfg, ids,
+                                         max_len=t + new_tokens)
+    new_ids, chosen_from, _, state, counters = decode(
+        params, cfg, logits, state, counters, position=t,
+        new_tokens=new_tokens)
+    return new_ids, chosen_from, counters, state

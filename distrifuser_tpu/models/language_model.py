@@ -1,0 +1,33 @@
+"""A causal language model as the rewrite stage takes it: a value.
+
+`pipelines.PromptRewriter` names no model.  It is handed a configuration
+whose ``language_model()`` returns this record, and calls nothing else of
+the model's module:
+
+    prefill(params, config, ids [T], max_len=)
+        -> (float32 logits after the last id, the decode state, the
+            counters so far [len(counters)] int32, what the model records
+            of the prompt beside ids and logits - () where nothing)
+    decode(params, config, logits, state, counters, position=, new_tokens=)
+        -> (new ids [new_tokens] int32, the float32 logits each was chosen
+            from [new_tokens, ...], what it records of the decoded ids, the
+            state, the counters) - all ``new_tokens`` greedy steps in one
+            loop on the device
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, NamedTuple, Optional, Tuple
+
+
+class LanguageModel(NamedTuple):
+    config: Any
+    prefill: Callable
+    decode: Callable
+    counters: Tuple[str, ...]  # names of the counters the programs carry
+    prompt_multiple: int  # a prompt's length is a multiple of this
+    vocab_size: int  # ids are 0 .. vocab_size - 1
+    # a byte-level model reads text as its UTF-8 bytes, byte b the id
+    # b + byte_offset (the ids below are special and never fed); None: the
+    # model has a vocabulary of words
+    byte_offset: Optional[int] = None

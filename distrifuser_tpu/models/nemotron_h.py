@@ -46,6 +46,7 @@ from jax import lax
 
 from ..ops import moe, ssm
 from ..ops.attention import causal_gqa_sdpa
+from .language_model import LanguageModel
 
 F32 = jnp.float32
 
@@ -102,6 +103,12 @@ class NemotronHConfig:
     @property
     def conv_dim(self) -> int:
         return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+    def language_model(self) -> LanguageModel:
+        """This model as the rewrite stage takes it: ids of words; beside
+        ids and logits it records the experts every token chose."""
+        return LanguageModel(
+            self, prefill, decode, COUNTERS, self.chunk_size, self.vocab_size)
 
 
 def nemotron_h_config_from_json(d: Dict[str, Any]) -> NemotronHConfig:

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .models import clip as clip_mod
-from .models import nemotron_h as lm_mod
 from .models import unet as unet_mod
 from .models import vae as vae_mod
 from .models.weights import (
@@ -236,12 +235,13 @@ def _tokenize(tok, texts: List[str]) -> np.ndarray:
 
 @dataclasses.dataclass(frozen=True)
 class RewriteSpec:
-    """The rewrite stage's lengths: a fixed instruction of
+    """The rewrite stage's lengths, in the language model's ids (words, or
+    bytes for a byte-level model): a fixed instruction of
     ``instruction_tokens`` ids (drawn once from ``instruction_seed`` over the
-    language model's vocabulary), then ``user_tokens`` ids of the caller's
-    words, ``new_tokens`` decoded greedily with no early stop, of which the
-    last ``prompt_tokens`` are the rewritten prompt (what follows the
-    thinking trace, by position)."""
+    ids a text can hold), then ``user_tokens`` ids of the caller's text,
+    ``new_tokens`` decoded greedily with no early stop, of which the last
+    ``prompt_tokens`` are the rewritten prompt (what follows the thinking
+    trace, by position)."""
 
     instruction_tokens: int
     user_tokens: int
@@ -256,10 +256,14 @@ class ServedRewrite(NamedTuple):
 
     prompt_ids: np.ndarray  # [instruction + user] int32
     new_ids: Any  # [new_tokens] int32
-    logits: Any  # [new_tokens, vocab] float32: what each id was chosen from
-    counters: Any  # [4] int32, `models.nemotron_h.COUNTERS`
-    # the experts every token chose: the prompt's [E layers, prompt, top_k],
-    # the decoded tokens' [new_tokens, E layers, top_k]
+    # [new_tokens, ...] float32: what each id was chosen from (a head of
+    # several predictions a position: all of them)
+    logits: Any
+    counters: Any  # int32, one a name of the model's `LanguageModel.counters`
+    # what the model records beside ids and logits, of the prompt and of the
+    # decoded ids (a model with routed experts: the experts every token
+    # chose, [E layers, prompt, top_k] and [new_tokens, E layers, top_k];
+    # one with nothing to record: (), ())
     experts: Any
 
 
@@ -270,79 +274,127 @@ def word_hash(word: str, vocab_size: int) -> int:
     return zlib.crc32(word.encode()) % vocab_size
 
 
+# a byte-level model's way out: GROUP_BYTES decoded ids are one text-encoder id
+GROUP_BYTES, GROUP_BASE = 4, 331
+
+
+def byte_group_ids(ids, modulus: int):
+    """The 4-byte group rule: ids [4 n] of a byte-level model -> [n] ids
+    below ``modulus``: each group read as a number in base 331 (first byte
+    most significant), modulo ``modulus`` - reduced after every digit, so
+    that it stays inside int32 on the device and is the same number on the
+    host."""
+    groups = ids.reshape(-1, GROUP_BYTES)
+    out = groups[:, 0] % modulus
+    for digit in range(1, GROUP_BYTES):
+        out = (out * GROUP_BASE + groups[:, digit]) % modulus
+    return out
+
+
 class PromptRewriter:
     """Think, then rewrite: the stage in front of the text encoders.
 
-    A request's prompt and a fixed instruction go through the language model
-    (`models.nemotron_h`): one prefill program over exactly
-    ``instruction_tokens + user_tokens`` ids - the caller's words through the
-    word hash, cut or repeated to ``user_tokens`` so that there is one
-    compiled shape and no padding to mask out of the convolution and the
-    state - then one decode program that runs all ``new_tokens`` greedy steps
-    on the device, with no host visit a token, and ends by looking the last
-    ``prompt_tokens`` ids up in a table of each id's word hash in the text
-    encoders' vocabularies (an id's word is its decimal string: no vocabulary
-    ships with the repo).  So the ids reach the encoders without visiting the
-    host and the request path stays asynchronous up to the image's copy.
-    Prefill is computed in full on every request (no prefix cache).
+    The language model is a value: ``config.language_model()`` gives its
+    `models.language_model.LanguageModel` record (prefill, decode, the names
+    of its counters, the multiple a prompt's length must keep, whether it
+    reads words or bytes), and nothing here names a model.
 
-    The last ``keep`` requests' ids, logits, routing and counters stay
-    reachable in ``served`` (device arrays: nothing is copied until someone reads them)."""
+    A request's prompt and a fixed instruction go through the model: one
+    prefill program over exactly ``instruction_tokens + user_tokens`` ids -
+    the caller's words through the word hash, or for a byte-level model the
+    caller's text as its UTF-8 bytes (each plus the model's byte offset), cut
+    or repeated to ``user_tokens`` so that there is one compiled shape and no
+    padding to mask out of a state - then one decode program that runs all
+    ``new_tokens`` greedy steps on the device, with no host visit a token,
+    and ends by turning the last ``prompt_tokens`` ids into ids of the text
+    encoders' vocabularies: a word id through a table of its word hash there
+    (an id's word is its decimal string: no vocabulary ships with the repo),
+    bytes four at a time through `byte_group_ids`.  So the ids reach the
+    encoders without visiting the host and the request path stays
+    asynchronous up to the image's copy.  Prefill is computed in full on
+    every request (no prefix cache).
 
-    def __init__(self, config: lm_mod.NemotronHConfig, params,
-                 spec: RewriteSpec, tokenizers, keep: int = 2):
+    The last ``keep`` requests' ids, logits, counters and what else the
+    model records stay reachable in ``served`` (device arrays: nothing is
+    copied until someone reads them)."""
+
+    def __init__(self, config, params, spec: RewriteSpec, tokenizers,
+                 keep: int = 2):
         if not all(isinstance(t, SimpleTokenizer) for t in tokenizers):
             raise ValueError("the rewrite stage hands ids on through the "
                              "weightless word hash; a vocabulary-backed "
                              "tokenizer would need the model's own detokenizer")
+        lm = config.language_model()
         prompt_len = spec.instruction_tokens + spec.user_tokens
-        if prompt_len % config.chunk_size:
+        if prompt_len % lm.prompt_multiple:
             raise ValueError(
                 f"instruction_tokens + user_tokens = {prompt_len} is not a "
-                f"multiple of the scan's chunk size {config.chunk_size}")
+                f"multiple of the scan's or the pooling's chunk size "
+                f"{lm.prompt_multiple}")
         if not 0 < spec.prompt_tokens <= spec.new_tokens:
             raise ValueError("prompt_tokens must lie in 1..new_tokens")
-        self.config, self.params, self.spec = config, params, spec
+        by_bytes = lm.byte_offset is not None
+        # ids of the language model that make one id of a text encoder
+        per_id = GROUP_BYTES if by_bytes else 1
+        if spec.prompt_tokens % per_id:
+            raise ValueError(f"prompt_tokens must be whole groups of "
+                             f"{per_id} bytes")
+        self.lm, self.config, self.params, self.spec = lm, config, params, spec
         rng = np.random.default_rng(spec.instruction_seed)
-        self.instruction = rng.integers(
-            0, config.vocab_size, spec.instruction_tokens).astype(np.int32)
+        self.instruction = (rng.integers(
+            0, 256 if by_bytes else lm.vocab_size, spec.instruction_tokens)
+            + (lm.byte_offset or 0)).astype(np.int32)
         self.served = collections.deque(maxlen=keep)
         self._decode_args = None
-        # per text encoder: each language-model id's word hash there, and
-        # the row the ids are set into (BOS, n ids, EOS to the end)
-        self._tables = [jnp.asarray(
+        # a word model, per text encoder: each of its ids' word hash there
+        self._tables = [] if by_bytes else [jnp.asarray(
             [word_hash(str(i), tok.vocab_size - 2)
-             for i in range(config.vocab_size)], jnp.int32)
+             for i in range(lm.vocab_size)], jnp.int32)
             for tok in tokenizers]
+        # per text encoder, the row the ids are set into: BOS, n ids, EOS to
+        # the end
         frames = [(tok.bos, tok.eos,
-                   min(spec.prompt_tokens, tok.model_max_length - 2),
-                   tok.model_max_length) for tok in tokenizers]
+                   min(spec.prompt_tokens // per_id, tok.model_max_length - 2),
+                   tok.model_max_length, tok.vocab_size - 2)
+                  for tok in tokenizers]
 
         def rewrite_prefill(params, ids):
-            return lm_mod.prefill(params, config, ids,
-                                  max_len=prompt_len + spec.new_tokens)
+            return lm.prefill(params, config, ids,
+                              max_len=prompt_len + spec.new_tokens)
 
         def rewrite_decode(params, logits, state, counters, tables):
-            new_ids, chosen_from, experts, _, counters = lm_mod.decode(
+            new_ids, chosen_from, recorded, state, counters = lm.decode(
                 params, config, logits, state, counters, position=prompt_len,
                 new_tokens=spec.new_tokens)
             encoder_ids = []
-            for table, (bos, eos, n, length) in zip(tables, frames):
+            for i, (bos, eos, n, length, modulus) in enumerate(frames):
+                tail = new_ids[-n * per_id:]
+                ids = (byte_group_ids(tail, modulus) if by_bytes
+                       else tables[i][tail])
                 row = jnp.full((length,), eos, jnp.int32).at[0].set(bos)
-                encoder_ids.append(
-                    row.at[1:1 + n].set(table[new_ids[-n:]])[None])
-            return new_ids, chosen_from, experts, counters, encoder_ids
+                encoder_ids.append(row.at[1:1 + n].set(ids)[None])
+            return (new_ids, chosen_from, recorded, counters, encoder_ids,
+                    state)
 
         self._prefill = jax.jit(rewrite_prefill)
-        self._decode = jax.jit(rewrite_decode)
+        # the decode state is the prefill's to give away: donated and handed
+        # back, the loop carries it in place, with no second copy beside the
+        # weights (the caller drops what comes back)
+        self._decode = jax.jit(rewrite_decode, donate_argnums=2)
 
     def lm_ids(self, prompt: str) -> np.ndarray:
-        """The instruction, then the prompt's words through the word hash,
-        cut or repeated to ``user_tokens`` (an empty prompt is id 0)."""
-        words = [word_hash(w, self.config.vocab_size)
-                 for w in prompt.lower().split()] or [0]
+        """The instruction, then the prompt - its words through the word
+        hash (an empty prompt is id 0), or its UTF-8 bytes plus the model's
+        byte offset (an empty prompt is a space) - cut or repeated to
+        ``user_tokens``."""
+        if self.lm.byte_offset is None:
+            text = [word_hash(w, self.lm.vocab_size)
+                    for w in prompt.lower().split()] or [0]
+        else:
+            text = [b + self.lm.byte_offset
+                    for b in prompt.encode("utf-8") or b" "]
         n = self.spec.user_tokens
-        user = (words * -(-n // len(words)))[:n]
+        user = (text * -(-n // len(text)))[:n]
         return np.concatenate([self.instruction,
                                np.asarray(user, np.int32)])
 
@@ -353,17 +405,17 @@ class PromptRewriter:
         for prompt in prompts:
             ids = self.lm_ids(prompt)
             with span("distri.rewrite.prefill"):
-                logits, state, counters, chosen = self._prefill(
+                logits, state, counters, of_prompt = self._prefill(
                     self.params, ids)
             with span("distri.rewrite.decode"):
                 args = (self.params, logits, state, counters, self._tables)
                 if self._decode_args is None:
                     self._decode_args = jax.tree.map(
                         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-                (new_ids, chosen_from, experts, counters,
-                 encoder_ids) = self._decode(*args)
+                (new_ids, chosen_from, of_new, counters, encoder_ids,
+                 _) = self._decode(*args)
             self.served.append(ServedRewrite(
-                ids, new_ids, chosen_from, counters, (chosen, experts)))
+                ids, new_ids, chosen_from, counters, (of_prompt, of_new)))
             rows.append(encoder_ids)
         if len(rows) == 1:
             return rows[0]
@@ -1424,12 +1476,14 @@ class DistriSDXLPipeline(_DistriPipelineBase):
     def from_params(cls, distri_config, unet_config, unet_params, vae_config,
                     vae_params, text_configs, text_params, scheduler="ddim",
                     tokenizers=None, rewriter=None):
-        """``rewriter``: ``(NemotronHConfig, its params, RewriteSpec)`` puts
-        the think-then-rewrite stage in front of the text encoders."""
+        """``rewriter``: ``(a language model's configuration, its params,
+        RewriteSpec)`` puts the think-then-rewrite stage in front of the
+        text encoders (`PromptRewriter`: the configuration's
+        ``language_model()`` is the model)."""
         if rewriter is not None and distri_config.world_size != 1:
             raise NotImplementedError(
-                "the rewrite stage runs on one chip: its expert layer has no "
-                "exchange between chips")
+                "the rewrite stage runs on one chip: the language model "
+                "holds one chip's share and has no exchange between chips")
         sched = scheduler if isinstance(scheduler, BaseScheduler) else get_scheduler(scheduler)
         toks = tokenizers or [SimpleTokenizer(tc.vocab_size) for tc in text_configs]
         pipe = cls(

@@ -644,3 +644,60 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(
                            body)]
     assert len(grouped) == 2 * n_e, len(grouped)
     assert "expert_gather_matvec" not in prefill
+
+
+def test_byte_level_rewrite_programs_compile_for_the_chip(topo):
+    """The rewrite stage's two programs at EvaByte's published widths, 16
+    layers, compiled for the described v5e.  Decode: the donated state - 16
+    rings and summary tables, 608 MB - is carried in place (aliased to the
+    output, no second copy among the temporaries), the language model's
+    scopes are on its ops, weights and state fit.  Prefill: 3840 positions
+    by query block, no array of all positions squared."""
+    import json
+
+    from jax.sharding import SingleDeviceSharding
+
+    from distrifuser_tpu.models import evabyte as lm
+    from distrifuser_tpu.pipelines import (
+        PromptRewriter,
+        RewriteSpec,
+        SimpleTokenizer,
+    )
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "evabyte-sdxl-rewrite.json")) as f:
+        config = json.load(f)
+    cfg = lm.evabyte_config_from_json(config)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one),
+        lm.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    spec = RewriteSpec(**config["rewrite"])
+    rw = PromptRewriter(cfg, None, spec, [SimpleTokenizer(49408)] * 2)
+    t = spec.instruction_tokens + spec.user_tokens
+    ids = jax.ShapeDtypeStruct((t,), jnp.int32, sharding=one)
+    logits, state, counters, _ = jax.tree.map(
+        on_chip, jax.eval_shape(rw._prefill, params, ids))
+    state_bytes = 16 * 2 * 2 * 4096 * (2048 + (t + spec.new_tokens) // 16)
+    assert state_bytes == 608_174_080
+
+    compiled = rw._decode.lower(params, logits, state, counters, []).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert 7.0e9 < mem.argument_size_in_bytes < 7.2e9  # weights + state
+    text = compiled.as_text()
+    for scope in ("lm.eva.proj", "lm.eva.pool", "lm.eva.attn", "lm.mlp",
+                  "lm.head"):
+        assert f"/{scope}/" in text, scope
+
+    prefill = rw._prefill.lower(params, ids).compile()
+    shapes = {tuple(int(n) for n in dims.split(","))
+              for dims in re.findall(r"\[((?:\d+,)+\d+)\]", prefill.as_text())}
+    assert shapes and not [s for s in shapes if s.count(t) >= 2]
+    assert prefill.memory_analysis().temp_size_in_bytes < 1.0e9
