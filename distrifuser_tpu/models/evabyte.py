@@ -1,6 +1,7 @@
 """EvaByte: a byte-level causal language model whose attention is EVA
 (`ops/eva.py`) - an exact 2048-byte window beside pooled 16-byte chunk
-summaries of everything before it, in one softmax - with prefill, a one-byte
+summaries of everything before it, in one softmax - with prefill (of a
+whole prompt, or of a suffix through the state its prefix left), a one-byte
 step through a BOUNDED decode state, and a greedy decode loop that stays on
 the device.
 
@@ -42,9 +43,11 @@ from .weights import params_nbytes
 
 F32 = jnp.float32
 
-# counters the generation returns with its ids
+# counters the generation returns with its ids; ``bytes_reused``: of the
+# positions the state covers after prefill, those a state handed in already
+# covered - entered, not computed in this request
 COUNTERS = ("bytes_prefilled", "bytes_decoded", "summaries_written",
-            "windows_rolled", "state_bytes")
+            "windows_rolled", "state_bytes", "bytes_reused")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,7 +81,8 @@ class EvaByteConfig:
     def language_model(self) -> LanguageModel:
         """This model as the rewrite stage takes it."""
         return LanguageModel(self, prefill, decode, COUNTERS, self.chunk_size,
-                             self.vocab_size, self.byte_offset)
+                             self.vocab_size, self.byte_offset,
+                             prefill_from=prefill)
 
 
 def evabyte_config_from_json(d: Dict[str, Any]) -> EvaByteConfig:
@@ -191,27 +195,29 @@ def _out(p, attended):
     return attended.reshape(attended.shape[0], -1) @ p["o_proj"]["kernel"]
 
 
-def attention_prefill(p, cfg: EvaByteConfig, x, max_len: int):
-    """A whole prompt from position 0 (x [T, d], T a multiple of
-    ``chunk_size``) -> (out [T, d], the layer's decode state with room for
-    ``max_len`` positions)."""
-    t = x.shape[0]
+def empty_state(cfg: EvaByteConfig, max_len: int, dtype):
+    """A layer's decode state with nothing in it and room for ``max_len``
+    positions."""
+    heads = (cfg.num_attention_heads, cfg.head_dim)
+    ring = jnp.zeros((cfg.window_size,) + heads, dtype)
+    table = jnp.zeros((-(-max_len // cfg.chunk_size),) + heads, dtype)
+    return {"k": ring, "v": ring, "ks": table, "vs": table}
+
+
+def attention_prefill(p, cfg: EvaByteConfig, x, state, position: int):
+    """x [T, d] at ``position`` onward (both whole chunks, ``position``
+    static) through the layer's state, which covers the positions before:
+    -> (out [T, d], the state as position + T finds it)."""
     window, chunk = cfg.window_size, cfg.chunk_size
-    q, k, v = _qkv(p, cfg, x, jnp.arange(t))
+    q, k, v = _qkv(p, cfg, x, position + jnp.arange(x.shape[0]))
     with jax.named_scope("lm.eva.pool"):
         ks, vs = eva.chunk_summaries(k, v, p["phi"], p["mu"], chunk=chunk)
     with jax.named_scope("lm.eva.attn"):
-        out = eva.prefill_attention(q, k, v, ks, vs, window=window,
-                                    chunk=chunk)
-    # what the last, unfinished window leaves in the ring; every summary
-    held = t - t // window * window
-    rows = -(-max_len // chunk) - ks.shape[0]
-    state = {
-        "k": jnp.pad(k[t - held:], ((0, window - held), (0, 0), (0, 0))),
-        "v": jnp.pad(v[t - held:], ((0, window - held), (0, 0), (0, 0))),
-        "ks": jnp.pad(ks, ((0, rows), (0, 0), (0, 0))),
-        "vs": jnp.pad(vs, ((0, rows), (0, 0), (0, 0)))}
-    return _out(p, out), state
+        out, ring_k, ring_v, table_k, table_v = eva.prefill_attention(
+            q, k, v, ks, vs, state["k"], state["v"], state["ks"], state["vs"],
+            position=position, window=window, chunk=chunk)
+    return _out(p, out), {"k": ring_k, "v": ring_v, "ks": table_k,
+                          "vs": table_v}
 
 
 def attention_step(p, cfg: EvaByteConfig, x, state, position):
@@ -262,22 +268,20 @@ def head(params, cfg: EvaByteConfig, h):
 # -- prefill, step, generation ------------------------------------------------
 
 
-def _forward(params, cfg: EvaByteConfig, ids, state, position, max_len=None):
-    """The stack over ids [T] -> (the residual stream [T, d], the new
-    state, one entry a layer).  ``state`` None: a whole prompt from
-    position 0; else one byte at ``position`` through the state."""
+def _forward(params, cfg: EvaByteConfig, ids, state, position, attend):
+    """The stack over ids [T] at ``position`` onward, through the state (one
+    entry a layer) -> (the residual stream [T, d], the new state).
+    ``attend``: `attention_prefill` (T whole chunks) or `attention_step`
+    (one byte)."""
     dtype = params["embed"].dtype
     # fp32_skip_add false keeps the stream where the published code keeps
     # it without the switch, in bfloat16: a precision below the stated one
     h = params["embed"][ids].astype(F32 if cfg.fp32_skip_add else jnp.bfloat16)
     new_state = []
-    for i, lp in enumerate(params["layers"]):
+    for lp, st in zip(params["layers"], state):
         x = rms_norm(lp["attn_norm"]["scale"], h.astype(dtype),
                      cfg.rms_norm_eps)
-        if state is None:
-            out, st = attention_prefill(lp["attn"], cfg, x, max_len)
-        else:
-            out, st = attention_step(lp["attn"], cfg, x, state[i], position)
+        out, st = attend(lp["attn"], cfg, x, st, position)
         new_state.append(st)
         h = h + out.astype(h.dtype)
         x = rms_norm(lp["mlp_norm"]["scale"], h.astype(dtype),
@@ -286,17 +290,39 @@ def _forward(params, cfg: EvaByteConfig, ids, state, position, max_len=None):
     return h, new_state
 
 
-def prefill(params, cfg: EvaByteConfig, ids, *, max_len: int):
-    """A prompt (ids [T], T a multiple of ``chunk_size``) computed in full
-    -> (float32 logits after its last byte [8 * V], the decode state with
-    room for ``max_len`` positions, the `COUNTERS` so far [5] int32, ())."""
-    t = ids.shape[0]
-    if t % cfg.chunk_size:
-        raise ValueError(f"a prompt of {t} bytes is not whole chunks of "
-                         f"{cfg.chunk_size}")
-    h, state = _forward(params, cfg, ids, None, 0, max_len)
-    counters = jnp.asarray([t, 0, t // cfg.chunk_size, t // cfg.window_size,
-                            params_nbytes(state)], jnp.int32)
+def prefill(params, cfg: EvaByteConfig, ids, *, max_len: int, state=None,
+            position: int = 0, counters=None):
+    """ids [T] (T a multiple of ``chunk_size``) at ``position`` onward,
+    computed in full -> (float32 logits after the last byte [8 * V], the
+    decode state, the `COUNTERS` so far [6] int32, ()).
+
+    A prompt from position 0 enters a state with nothing in it and room for
+    ``max_len`` positions.  A suffix enters ``state`` - what a prefill of
+    the ``position`` bytes before it returned, with its ``counters`` - which
+    is read, not consumed: the state returned is a new one, and of its
+    ``bytes_prefilled`` positions ``bytes_reused`` = ``position`` came with
+    the state handed in."""
+    t, chunk = ids.shape[0], cfg.chunk_size
+    if t % chunk or position % chunk:  # before the pooling reshapes by chunk
+        raise ValueError(f"{t} bytes from position {position} on are not "
+                         f"whole chunks of {chunk}")
+    if state is None:
+        if position:
+            raise ValueError(f"position {position} needs the state of the "
+                             f"bytes before it")
+        state = [empty_state(cfg, max_len, params["embed"].dtype)
+                 ] * cfg.num_hidden_layers
+        counters = jnp.zeros((len(COUNTERS),), jnp.int32)
+    elif state[0]["ks"].shape[0] * chunk < max_len:
+        raise ValueError(f"the state handed in has no room for {max_len} "
+                         f"positions")
+    end = position + t
+    h, state = _forward(params, cfg, ids, state, position, attention_prefill)
+    counters = jnp.stack([
+        counters[0] + t, counters[1],
+        counters[2] + end // chunk - position // chunk,
+        counters[3] + end // cfg.window_size - position // cfg.window_size,
+        params_nbytes(state), position]).astype(jnp.int32)
     return head(params, cfg, h[-1:])[0], state, counters, ()
 
 
@@ -317,9 +343,10 @@ def decode(params, cfg: EvaByteConfig, logits, state, counters, *,
         chosen_from = lax.dynamic_update_slice_in_dim(
             chosen_from, logits[None], i, axis=0)
         at = position + i
-        h, state = _forward(params, cfg, token[None], state, at)
+        h, state = _forward(params, cfg, token[None], state, at,
+                            attention_step)
         counters = counters + jnp.stack([
-            0, 1, at % chunk == chunk - 1, at % window == window - 1, 0]
+            0, 1, at % chunk == chunk - 1, at % window == window - 1, 0, 0]
         ).astype(jnp.int32)
         return head(params, cfg, h)[0], state, ids, chosen_from, counters
 

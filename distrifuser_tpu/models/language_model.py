@@ -13,6 +13,18 @@ the model's module:
             from [new_tokens, ...], what it records of the decoded ids, the
             state, the counters) - all ``new_tokens`` greedy steps in one
             loop on the device
+
+and, where the model can take a prompt's suffix into the state its prefix
+left (``prefill_from`` is None where it cannot):
+
+    prefill_from(params, config, ids [T], max_len=, state=, counters=,
+                 position=)
+        -> what ``prefill`` returns, for ids at ``position`` onward
+            (static, like T a multiple of ``prompt_multiple``) through
+            ``state`` and ``counters`` as ``prefill`` returned them for the
+            ``position`` ids before.  The state handed in is read, not
+            consumed - the one returned is new, so one prefix serves many
+            suffixes - and the result is the prefill of all the ids.
 """
 
 from __future__ import annotations
@@ -31,3 +43,4 @@ class LanguageModel(NamedTuple):
     # b + byte_offset (the ids below are special and never fed); None: the
     # model has a vocabulary of words
     byte_offset: Optional[int] = None
+    prefill_from: Optional[Callable] = None

@@ -29,6 +29,7 @@ import dataclasses
 import functools
 import os
 import sys
+import weakref
 import zlib
 from typing import Any, List, NamedTuple, Optional
 
@@ -311,8 +312,19 @@ class PromptRewriter:
     (an id's word is its decimal string: no vocabulary ships with the repo),
     bytes four at a time through `byte_group_ids`.  So the ids reach the
     encoders without visiting the host and the request path stays
-    asynchronous up to the image's copy.  Prefill is computed in full on
-    every request (no prefix cache).
+    asynchronous up to the image's copy.
+
+    The instruction is the same in every request.  Where the model can take
+    a prompt's suffix into the state its prefix left
+    (`LanguageModel.prefill_from`), the instruction - as much of it as is
+    whole ``prompt_multiple``s - is prefilled ONCE, by a program of its own
+    (``rewrite_prefix``, on the first call: a server's warm-up request), and
+    its decode state and counters are kept: the **snapshot**, this
+    rewriter's, beside the weights for as long as a pipeline holds the
+    rewriter (`drop_snapshot`).  A request's prefill program then takes the
+    snapshot - read, not donated - and the remaining ids, and returns what
+    the prefill of all the ids returns.  Where the model cannot, prefill is
+    computed in full on every request.
 
     The last ``keep`` requests' ids, logits, counters and what else the
     model records stay reachable in ``served`` (device arrays: nothing is
@@ -346,6 +358,12 @@ class PromptRewriter:
             + (lm.byte_offset or 0)).astype(np.int32)
         self.served = collections.deque(maxlen=keep)
         self._decode_args = None
+        # ids of the instruction that the snapshot covers - whole multiples,
+        # and a request's own program keeps some to take; 0: no snapshot
+        prefix_len = 0 if lm.prefill_from is None else (
+            min(spec.instruction_tokens, prompt_len - 1)
+            // lm.prompt_multiple * lm.prompt_multiple)
+        self._prefix_len, self._snapshot = prefix_len, None
         # a word model, per text encoder: each of its ids' word hash there
         self._tables = [] if by_bytes else [jnp.asarray(
             [word_hash(str(i), tok.vocab_size - 2)
@@ -358,9 +376,21 @@ class PromptRewriter:
                    tok.model_max_length, tok.vocab_size - 2)
                   for tok in tokenizers]
 
-        def rewrite_prefill(params, ids):
-            return lm.prefill(params, config, ids,
-                              max_len=prompt_len + spec.new_tokens)
+        max_len = prompt_len + spec.new_tokens
+
+        def rewrite_prefix(params, ids):
+            _, state, counters, _ = lm.prefill(params, config, ids,
+                                               max_len=max_len)
+            return state, counters
+
+        def rewrite_prefill(params, ids, snapshot=None):
+            """All of a request's ids, or those after the snapshot's."""
+            if snapshot is None:
+                return lm.prefill(params, config, ids, max_len=max_len)
+            state, counters = snapshot
+            return lm.prefill_from(params, config, ids, max_len=max_len,
+                                   state=state, counters=counters,
+                                   position=prefix_len)
 
         def rewrite_decode(params, logits, state, counters, tables):
             new_ids, chosen_from, recorded, state, counters = lm.decode(
@@ -376,6 +406,7 @@ class PromptRewriter:
             return (new_ids, chosen_from, recorded, counters, encoder_ids,
                     state)
 
+        self._prefix = jax.jit(rewrite_prefix)
         self._prefill = jax.jit(rewrite_prefill)
         # the decode state is the prefill's to give away: donated and handed
         # back, the loop carries it in place, with no second copy beside the
@@ -398,15 +429,30 @@ class PromptRewriter:
         return np.concatenate([self.instruction,
                                np.asarray(user, np.int32)])
 
+    def snapshot(self):
+        """(the decode state, the counters) after the first
+        ``_prefix_len`` ids of the instruction, made on first use and kept;
+        None where the model cannot enter a state."""
+        if self._snapshot is None and self._prefix_len:
+            with span("distri.rewrite.prefix"):
+                self._snapshot = self._prefix(
+                    self.params, self.instruction[:self._prefix_len])
+        return self._snapshot
+
+    def drop_snapshot(self):
+        """Give the snapshot's memory back (the next call makes it anew)."""
+        self._snapshot = None
+
     def __call__(self, prompts: List[str]):
         """-> one [len(prompts), model_max_length] int32 device array per
         text encoder."""
         rows = []
         for prompt in prompts:
             ids = self.lm_ids(prompt)
+            snapshot = self.snapshot()
             with span("distri.rewrite.prefill"):
                 logits, state, counters, of_prompt = self._prefill(
-                    self.params, ids)
+                    self.params, ids[self._prefix_len:], snapshot)
             with span("distri.rewrite.decode"):
                 args = (self.params, logits, state, counters, self._tables)
                 if self._decode_args is None:
@@ -1492,6 +1538,10 @@ class DistriSDXLPipeline(_DistriPipelineBase):
         )
         if rewriter is not None:
             pipe.rewriter = PromptRewriter(*rewriter, toks)
+            # the snapshot is the pipeline's while it lives: whoever still
+            # holds the rewriter afterwards (its served records, its program
+            # text) does not hold that memory
+            weakref.finalize(pipe, pipe.rewriter.drop_snapshot)
         return pipe
 
     def _encode(self, prompts, negs, micro_cond=None, rewritten=None):

@@ -78,6 +78,15 @@ def qkv(t, seed=0, h=4, d=16):
             for k in jax.random.split(jax.random.PRNGKey(seed), 3))
 
 
+def from_zero(q, k, v, phi, mu, *, chunk, block):
+    """EVA attention of a whole prompt: the entering form, nothing before."""
+    ks, vs = eva.chunk_summaries(k, v, phi, mu, chunk=chunk)
+    ring, table = jnp.zeros((W,) + k.shape[1:]), jnp.zeros_like(ks)
+    return eva.prefill_attention(q, k, v, ks, vs, ring, ring, table, table,
+                                 position=0, window=W, chunk=chunk,
+                                 block=block)[0]
+
+
 def test_the_parameters_are_the_published_count():
     full = lm.evabyte_config_from_json({"num_attention_heads": 32})
     count = sum(int(np.prod(shape)) for _, shape in lm.named_leaves(full)[0])
@@ -92,7 +101,10 @@ def test_prefill_logits_against_the_reference_over_two_and_a_half_windows(
     ids = byte_ids(2 * W + W // 2)
     # every position's logits, all eight blocks: the stack's output through
     # the program's head
-    h, _ = lm._forward(params, CFG, jnp.asarray(ids), None, 0, len(ids))
+    empty = [lm.empty_state(CFG, len(ids), jnp.float32)] * len(
+        params["layers"])
+    h, _ = lm._forward(params, CFG, jnp.asarray(ids), empty, 0,
+                       lm.attention_prefill)
     got = lm.head(params, CFG, h)
     assert got.shape == (len(ids), 8 * CFG.vocab_size)
     close(got, reference_logits(params, ids), tol=5e-5)
@@ -127,18 +139,96 @@ def test_prefill_then_decode_across_a_chunk_and_a_window_boundary(params):
     assert dict(zip(lm.COUNTERS, np.asarray(counters).tolist())) == {
         "bytes_prefilled": len(prompt), "bytes_decoded": new,
         "summaries_written": total // C, "windows_rolled": total // W,
-        "state_bytes": nbytes}
+        "state_bytes": nbytes, "bytes_reused": 0}
     assert total // W == 1 and len(prompt) // W == 0  # it did roll
     # a chunk that is not complete leaves its row of the table alone
     assert total % C and not np.asarray(state[0]["ks"][total // C]).any()
     assert np.asarray(state[0]["ks"][total // C - 1]).any()
 
 
+# (position, T) of a suffix that enters the state of the bytes before it
+SPLITS = {
+    "inside one window": (W + 2 * C, 3 * C),
+    "across a window boundary": (2 * W - 2 * C, 5 * C),
+    "from a window boundary": (2 * W, 3 * C),
+    "from zero": (0, W + 4 * C),
+    "longer than a window": (W - C, 2 * W + 3 * C),
+    "to a window boundary": (W + C, W - C),
+}
+
+
+@pytest.mark.parametrize("split", SPLITS)
+def test_a_suffix_entering_the_prefixs_state_is_the_full_prefill(params,
+                                                                 split):
+    """The prompt prefilled whole, and its first ``position`` bytes
+    prefilled, then the other T through that state: the same logits, the
+    same state leaf by leaf, the same counters but for `bytes_reused`, the
+    same bytes decoded from either - and the prefix's state is still
+    there."""
+    position, t = SPLITS[split]
+    ids, new = jnp.asarray(byte_ids(position + t, seed=11)), 2 * C + 1
+    max_len = position + t + new
+    want = lm.prefill(params, CFG, ids, max_len=max_len)
+    state, counters = None, None
+    if position:
+        _, state, counters, _ = lm.prefill(params, CFG, ids[:position],
+                                           max_len=max_len)
+        before = jax.tree.map(np.asarray, state)
+    got = jax.jit(lambda p, ids, state, counters: lm.prefill(
+        p, CFG, ids, max_len=max_len, state=state, counters=counters,
+        position=position))(params, ids[position:], state, counters)
+    close(got[0], want[0], tol=1e-5)
+    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1]),
+                    strict=True):
+        close(a, b, tol=1e-5)
+    end = position + t
+    assert dict(zip(lm.COUNTERS, np.asarray(got[2]).tolist())) == {
+        "bytes_prefilled": end, "bytes_decoded": 0,
+        "summaries_written": end // C, "windows_rolled": end // W,
+        "state_bytes": lm.params_nbytes(want[1]), "bytes_reused": position}
+    assert np.array_equal(np.asarray(got[2])[:5], np.asarray(want[2])[:5])
+    decoded = [np.asarray(lm.decode(params, CFG, *out[:3], position=end,
+                                    new_tokens=new)[0]) for out in (got, want)]
+    assert np.array_equal(*decoded)
+    if position:  # read, not consumed
+        for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(before),
+                        strict=True):
+            assert np.array_equal(np.asarray(a), b)
+
+
+@pytest.mark.parametrize("position,t,match", [
+    (W + 2, C, "whole chunks"), (W, C + 1, "whole chunks"),
+    (W, C, "needs the state"), (0, W + C, "no room")])
+def test_an_entering_prefill_of_broken_chunks_or_no_state_is_refused(
+        params, position, t, match):
+    ids = jnp.asarray(byte_ids(t))
+    state, counters = None, None
+    if match != "needs the state":
+        _, state, counters, _ = lm.prefill(
+            params, CFG, jnp.asarray(byte_ids(W)),
+            max_len=W + C if match == "no room" else 2 * W + 2 * C)
+    with pytest.raises(ValueError, match=match):
+        lm.prefill(params, CFG, ids, max_len=2 * W + 2 * C, state=state,
+                   counters=counters, position=position)
+
+
+@pytest.mark.parametrize("position,t,match", [
+    (W + 2, C, "whole chunks"), (W, C + 1, "whole chunks"),
+    (W, 2 * C, "do not reach")])
+def test_the_attention_op_refuses_broken_chunks_and_a_short_table(
+        position, t, match):
+    q, k, v = qkv(t)
+    ring, table = jnp.zeros((W, 4, 16)), jnp.zeros((W // C + 1, 4, 16))
+    with pytest.raises(ValueError, match=match):
+        eva.prefill_attention(q, k, v, table[:t // C], table[:t // C], ring,
+                              ring, table, table, position=position,
+                              window=W, chunk=C)
+
+
 def test_within_one_window_eva_is_plain_causal_attention():
     q, k, v = qkv(W)
     phi, mu = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 16))
-    ks, vs = eva.chunk_summaries(k, v, phi, mu, chunk=C)
-    got = eva.prefill_attention(q, k, v, ks, vs, window=W, chunk=C, block=8)
+    got = from_zero(q, k, v, phi, mu, chunk=C, block=8)
     close(got, plain_causal(q, k, v))
 
 
@@ -149,7 +239,7 @@ def test_with_chunks_of_one_and_no_offset_eva_is_plain_causal_attention():
     phi = jax.random.normal(jax.random.PRNGKey(1), (4, 16))
     ks, vs = eva.chunk_summaries(k, v, phi, jnp.zeros((4, 16)), chunk=1)
     close(ks, k), close(vs, v)
-    got = eva.prefill_attention(q, k, v, ks, vs, window=W, chunk=1, block=16)
+    got = from_zero(q, k, v, phi, jnp.zeros((4, 16)), chunk=1, block=16)
     close(got, plain_causal(q, k, v))
     # and the one-row form, the ring and the table as a decode step has them
     t = 2 * W + 4

@@ -1,11 +1,14 @@
 """The think-then-rewrite stage on the request path: `PromptRewriter` in
 front of a tiny SDXL pipeline, through `InferenceServer` end to end."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
 
 from distrifuser_tpu import DistriConfig
+from distrifuser_tpu.models import evabyte
 from distrifuser_tpu.models import nemotron_h as lm
 from distrifuser_tpu.models.clip import (
     CLIPTextConfig,
@@ -33,6 +36,29 @@ LM = lm.NemotronHConfig(
 SPEC = RewriteSpec(instruction_tokens=10, user_tokens=6, new_tokens=12,
                    prompt_tokens=5, instruction_seed=1)
 STEPS = 2
+
+# a byte-level model that can take a suffix into its prefix's state: windows
+# of 32 bytes, chunks of 4
+BYTES = evabyte.EvaByteConfig(
+    num_hidden_layers=2, hidden_size=32, num_attention_heads=4,
+    intermediate_size=48, window_size=32, chunk_size=4)
+
+
+class FromZero(evabyte.EvaByteConfig):
+    """The same model, said to have no entering prefill."""
+
+    def language_model(self):
+        return super().language_model()._replace(prefill_from=None)
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    """The names of the programs JAX compiles, in order, from here on."""
+    names = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, seconds, **kw: names.append(kw.get("fun_name"))
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    return names
 
 
 def build(devices, rewriter, **cfg_kw):
@@ -89,6 +115,89 @@ def test_the_rewriters_ids_are_the_tokenizers_of_the_decimal_words():
     assert two[0].shape == (2, 77)
     assert np.array_equal(np.asarray(two[0][0]), np.asarray(out[0][0]))
     assert "lm.mamba" in rw.decode_program_text()
+
+
+@pytest.mark.parametrize("instruction,user,reused", [
+    (60, 24, 60),  # the suffix runs 60 -> 84 over the window boundary at 64
+    (58, 14, 56),  # an instruction of broken chunks: its whole ones
+    (32, 4, 32)])  # the snapshot ends a window: an empty ring, a grown table
+def test_the_instruction_is_prefilled_once_and_entered_by_every_request(
+        compiles, instruction, user, reused):
+    spec = RewriteSpec(instruction_tokens=instruction, user_tokens=user,
+                       new_tokens=12, prompt_tokens=8, instruction_seed=1)
+    params = evabyte.init_evabyte_params(jax.random.PRNGKey(2), BYTES)
+    toks = [SimpleTokenizer(1000), SimpleTokenizer(777)]
+    rw = PromptRewriter(BYTES, params, spec, toks)
+    full = PromptRewriter(FromZero(**dataclasses.asdict(BYTES)), params,
+                          spec, toks)
+    assert rw._snapshot is None and full.snapshot() is None
+    prompts = ["a red fox", "an old sailor by the sea", "a red fox"]
+    outs, marks = [], []
+    for prompt in prompts:
+        mark = len(compiles)
+        outs.append(jax.block_until_ready(rw([prompt])))
+        marks.append(compiles[mark:])
+        counters = dict(zip(rw.lm.counters,
+                            np.asarray(rw.served[-1].counters).tolist()))
+        assert counters["bytes_reused"] == reused
+        assert counters["bytes_prefilled"] == instruction + user
+        assert counters["bytes_decoded"] == 12
+        assert len(rw.served[-1].prompt_ids) == instruction + user
+        # ... and through the full prefill: the same bytes, the same ids
+        want = full([prompt])
+        theirs = full.served[-1]
+        assert np.array_equal(np.asarray(rw.served[-1].new_ids),
+                              np.asarray(theirs.new_ids))
+        for got, ids in zip(outs[-1], want):
+            assert np.array_equal(np.asarray(got), np.asarray(ids))
+        np.testing.assert_allclose(np.asarray(rw.served[-1].logits),
+                                   np.asarray(theirs.logits), atol=2e-5)
+        their = dict(zip(full.lm.counters,
+                         np.asarray(theirs.counters).tolist()))
+        assert their.pop("bytes_reused") == 0
+        assert their.items() <= counters.items()
+    # the snapshot: made by the first request, read by all, consumed by none
+    state, _ = rw.snapshot()
+    assert all(not leaf.is_deleted() for leaf in jax.tree.leaves(state))
+    assert sum(leaf.nbytes for leaf in jax.tree.leaves(state)) == \
+        counters["state_bytes"]
+    for a, b in zip(outs[0], outs[2]):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert not np.array_equal(np.asarray(outs[0][0]), np.asarray(outs[1][0]))
+    # one program a name, the request's still `rewrite_prefill`; after the
+    # first request nothing compiles
+    assert (rw._prefix._cache_size(), rw._prefill._cache_size(),
+            rw._decode._cache_size()) == (1, 1, 1)
+    text = rw._prefill.lower(rw.params, rw.lm_ids("x")[reused:],
+                             rw.snapshot()).as_text()
+    assert "module @jit_rewrite_prefill " in text
+    assert {"jit(rewrite_prefix)", "jit(rewrite_prefill)",
+            "jit(rewrite_decode)"} <= set(marks[0]), marks
+    assert marks[1:] == [[], []], marks
+    rw.drop_snapshot()
+    assert rw._snapshot is None
+
+
+def test_a_model_without_the_entering_form_is_served_as_before(compiles):
+    """Nemotron's record offers no `prefill_from`: no snapshot, and the
+    prefill program is, instruction for instruction, the one a rewriter
+    without any of this lowers."""
+    params = lm.init_nemotron_h_params(jax.random.PRNGKey(9), LM)
+    rw = PromptRewriter(LM, params, SPEC, [SimpleTokenizer(1000)])
+    assert rw.lm.prefill_from is None and rw.snapshot() is None
+    before = len(compiles)
+    rw(["a red fox"])
+    assert rw._snapshot is None and rw._prefix._cache_size() == 0
+    assert "jit(rewrite_prefix)" not in compiles[before:]
+    assert "jit(rewrite_prefill)" in compiles[before:]
+    ids = rw.lm_ids("a red fox")
+
+    def rewrite_prefill(params, ids):
+        return lm.prefill(params, LM, ids, max_len=len(ids) + SPEC.new_tokens)
+
+    assert rw._prefill.lower(params, ids).as_text() == jax.jit(
+        rewrite_prefill).lower(params, ids).as_text()
+    assert "bytes_reused" not in rw.lm.counters
 
 
 def test_a_rewriter_needs_the_word_hash_and_whole_chunks():

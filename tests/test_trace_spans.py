@@ -701,3 +701,20 @@ def test_byte_level_rewrite_programs_compile_for_the_chip(topo):
               for dims in re.findall(r"\[((?:\d+,)+\d+)\]", prefill.as_text())}
     assert shapes and not [s for s in shapes if s.count(t) >= 2]
     assert prefill.memory_analysis().temp_size_in_bytes < 1.0e9
+
+    n = spec.instruction_tokens
+    assert rw._prefix_len == n == 3712
+    snapshot = jax.tree.map(on_chip, jax.eval_shape(
+        rw._prefix, params, jax.ShapeDtypeStruct((n,), jnp.int32,
+                                                 sharding=one)))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), snapshot) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), (state, counters))
+    entering = rw._prefill.lower(
+        params, jax.ShapeDtypeStruct((t - n,), jnp.int32, sharding=one),
+        snapshot).compile()
+    mem = entering.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert mem.output_size_in_bytes - state_bytes < 1e6
+    assert mem.temp_size_in_bytes < 0.5e9
+    flops = [c.cost_analysis()["flops"] for c in (prefill, entering)]
+    assert flops[1] < flops[0] / 20, flops
