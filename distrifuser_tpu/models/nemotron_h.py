@@ -327,7 +327,7 @@ def moe_layer(p, cfg: NemotronHConfig, u):
         latent = u @ p["down"]["kernel"]
         routed, held = moe.local_expert_sum(
             latent, idx, weights, p["experts"]["w1"], p["experts"]["w2"],
-            first_expert=cfg.first_local_expert)
+            first_expert=cfg.first_local_expert, activation="relu2")
         routed = routed.astype(u.dtype) @ p["up"]["kernel"]
     with jax.named_scope("lm.moe.shared"):
         hidden = jnp.square(jax.nn.relu(u @ p["shared"]["fc1"]["kernel"]))
@@ -390,21 +390,12 @@ def _balancing_layer(lp, x, *, cfg: NemotronHConfig, kind: str,
         out, _ = attention_layer(
             mixer, cfg, u, empty_cache(cfg, x.shape[0], x.dtype), 0)
     else:
-        top_k, e = cfg.num_experts_per_tok, cfg.n_routed_experts
-        share = x.shape[0] * top_k / e
         scores = jax.nn.sigmoid(jnp.dot(
             u.astype(F32), mixer["router"]["kernel"].astype(F32),
             precision=lax.Precision.HIGHEST))
-
-        def nudge(i, bias):
-            select = scores + bias
-            least = lax.top_k(select, top_k)[0][:, -1:]
-            load = jnp.sum(select >= least, axis=0).astype(F32)
-            return bias - step * (1.0 - i / rounds) * jnp.clip(
-                (load - share) / share, -1.0, 1.0)
-
-        bias = lax.fori_loop(0, rounds, nudge, jnp.zeros((e,), F32)).astype(
-            mixer["e_score_correction_bias"].dtype)
+        bias = moe.balanced_bias(
+            scores, top_k=cfg.num_experts_per_tok, rounds=rounds, step=step
+        ).astype(mixer["e_score_correction_bias"].dtype)
         out, _, _ = moe_layer(dict(mixer, e_score_correction_bias=bias),
                               cfg, u)
     return x + out, bias
@@ -419,7 +410,8 @@ def balanced_selection_bias(params, cfg: NemotronHConfig, ids, *,
     its share and up where less - and this is that rule run to its fixed
     point on one calibration sequence, layer after layer (a layer's inputs
     depend on the layers before it, so each is balanced before the next
-    sees its output).  For seeded weights: without it a random router loads
+    sees its output; the fit of one layer's bias is `ops/moe.py
+    balanced_bias`).  For seeded weights: without it a random router loads
     any fixed 64 of its 512 experts by +-4% from seed to seed, where a
     trained one loads them alike (+-1.4% after this, on other tokens).
     Returns one [n_routed_experts] bias an E layer, in the stored dtype."""

@@ -718,3 +718,92 @@ def test_byte_level_rewrite_programs_compile_for_the_chip(topo):
     assert mem.temp_size_in_bytes < 0.5e9
     flops = [c.cost_analysis()["flops"] for c in (prefill, entering)]
     assert flops[1] < flops[0] / 20, flops
+
+
+def test_latent_attention_rewrite_programs_compile_for_the_chip(
+        topo, monkeypatch):
+    """The rewrite stage's three programs at Kanana-2-30B-A3B's published
+    widths, one chip's share (24 layers, 16 of 128 experts), compiled for the
+    described v5e.  Prefix: the instruction's 8064 tokens by the materialised
+    form in query blocks - no array with the prompt's length twice among its
+    dims, the routed experts on the grouped matmul.  The request's prefill:
+    128 ids ENTERING the snapshot (read, not aliased) at a twentieth of the
+    whole prompt's FLOPs.  Decode: the donated state - 24 latent caches of
+    576 numbers a position and the record of the experts chosen - carried in
+    place, every expert layer's routed experts ONE call of the gather
+    mat-vec kernel in its gated form, the language model's scopes on its
+    ops, weights and state fit."""
+    import json
+
+    from jax.sharding import SingleDeviceSharding
+
+    from distrifuser_tpu.models import deepseek_v3 as lm
+    from distrifuser_tpu.pipelines import (
+        PromptRewriter,
+        RewriteSpec,
+        SimpleTokenizer,
+    )
+
+    # `local_expert_sum` asks the first device for its platform
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: topo.devices)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "kanana-2-30b-sdxl-rewrite.json")) as f:
+        config = json.load(f)
+    cfg = lm.deepseek_v3_config_from_json(config)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+
+    def ids(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one)
+
+    params = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one),
+        lm.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    spec = RewriteSpec(**config["rewrite"])
+    rw = PromptRewriter(cfg, None, spec, [SimpleTokenizer(49408)] * 2)
+    t, n = spec.instruction_tokens + spec.user_tokens, rw._prefix_len
+    assert (t, n, t - n) == (8192, 8064, 128)
+    max_len, n_e = t + spec.new_tokens, cfg.n_expert_layers
+    cache_bytes = 24 * max_len * 576 * 2
+    state_bytes = cache_bytes + n_e * max_len * cfg.num_experts_per_tok * 4
+    assert cache_bytes == 240_648_192
+
+    prefix = rw._prefix.lower(params, ids(n)).compile()
+    text = prefix.as_text()
+    shapes = {tuple(int(x) for x in dims.split(","))
+              for dims in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
+    assert (32, 32, n) in shapes  # one query block's logits
+    assert not [s for s in shapes if s.count(n) >= 2]
+    assert "ragged-dot" in text and "expert_gather_matvec" not in text
+    assert prefix.memory_analysis().temp_size_in_bytes < 2.0e9
+
+    snapshot = jax.tree.map(on_chip, jax.eval_shape(rw._prefix, params,
+                                                    ids(n)))
+    entering = rw._prefill.lower(params, ids(t - n), snapshot).compile()
+    mem = entering.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 0.6e9
+    flops = [c.cost_analysis()["flops"] for c in (prefix, entering)]
+    assert flops[1] < flops[0] / 10, flops
+
+    logits, state, counters, _ = jax.tree.map(on_chip, jax.eval_shape(
+        rw._prefill, params, ids(t - n), snapshot))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), (state, counters)) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), snapshot)
+    compiled = rw._decode.lower(
+        params, logits, state, counters,
+        [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32, sharding=one)] * 2
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert 5.5e9 < mem.argument_size_in_bytes < 5.8e9  # weights + state
+    text = compiled.as_text()
+    kernels = re.findall(r"%(expert_gather_matvec[\w.\-]*) = ", text)
+    assert len(kernels) == n_e and "ragged-dot" not in text
+    for scope in ("lm.mla.proj", "lm.mla.attn", "lm.moe.router",
+                  "lm.moe.experts", "lm.moe.shared", "lm.mlp", "lm.head"):
+        assert f"/{scope}/" in text, scope
