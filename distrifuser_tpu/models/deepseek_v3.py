@@ -64,9 +64,13 @@ F32 = jnp.float32
 # counters the generation returns with its ids; ``tokens_reused``: of the
 # positions the cache covers after prefill, those a cache handed in already
 # covered - entered, not computed in this request; ``state_bytes``: the
-# latent cache of every layer
+# latent cache of every layer; ``cache_rows_fetched``: the cache rows the
+# decode steps' attention fetched where it is the single-pass kernel
+# (`ops/mla.py streamed_attention`), summed over steps and layers - 0 on the
+# XLA route, which reads every row of the cache, twice, under its mask
 COUNTERS = ("tokens_prefilled", "tokens_reused", "tokens_decoded",
-            "expert_assignments", "expert_assignments_held", "state_bytes")
+            "expert_assignments", "expert_assignments_held", "state_bytes",
+            "cache_rows_fetched")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,12 +257,17 @@ def attention_layer(p, cfg: DeepseekV3Config, x, cache, position,
 
     ``visible`` None and ``position`` 0 (static): a whole prompt, the
     materialised form over its own keys.  Otherwise the absorbed form
-    against the cache - its first ``visible`` rows (static, at least
-    position + T) or, ``visible`` None, all of them under the mask."""
+    against the cache (`ops/mla.py cache_attention`: a decode step on a TPU
+    in one pass over the rows written so far; else the XLA form over the
+    cache's first ``visible`` rows - static, at least position + T - or,
+    ``visible`` None, all of them under the mask).
+    -> (the layer's output [T, d], the cache, the cache rows the single-pass
+    kernel fetched: 0 on every other route)."""
     t = x.shape[0]
     positions = position + jnp.arange(t)
     q_nope, q_pe, c, k_pe = _queries_and_latents(p, cfg, x, positions)
     materialised = visible is None and isinstance(position, int)
+    fetched = jnp.zeros((), jnp.int32)
     if materialised and position:
         raise ValueError(f"position {position} needs the cache of the "
                          f"tokens before it")
@@ -281,13 +290,13 @@ def attention_layer(p, cfg: DeepseekV3Config, x, cache, position,
         with jax.named_scope("lm.mla.proj"):
             q_lat = jnp.einsum("thd,hdc->thc", q_nope, p["k_up"])
         with jax.named_scope("lm.mla.attn"):
-            attended = mla.absorbed_attention(
-                q_lat, q_pe, cache["c"][:visible], cache["k_pe"][:visible],
-                q_positions=positions, scale=cfg.softmax_scale)
+            attended, fetched = mla.cache_attention(
+                q_lat, q_pe, cache["c"], cache["k_pe"], position,
+                scale=cfg.softmax_scale, visible=visible)
         with jax.named_scope("lm.mla.proj"):
             out = jnp.einsum("thc,hcd->thd", attended, p["v_up"])
     with jax.named_scope("lm.mla.proj"):
-        return out.reshape(t, -1) @ p["o_proj"]["kernel"], cache
+        return out.reshape(t, -1) @ p["o_proj"]["kernel"], cache, fetched
 
 
 def gated_mlp(p, x):
@@ -313,11 +322,12 @@ def moe_layer(p, cfg: DeepseekV3Config, u):
 
 
 def _attend(lp, cfg: DeepseekV3Config, x, cache, position, visible):
-    """A layer's first half -> (x + Attn(RMSNorm(x)), the cache)."""
-    out, cache = attention_layer(
+    """A layer's first half -> (x + Attn(RMSNorm(x)), the cache, the cache
+    rows its attention fetched)."""
+    out, cache, fetched = attention_layer(
         lp["attn"], cfg, rms_norm(lp["attn_norm"]["scale"], x,
                                   cfg.rms_norm_eps), cache, position, visible)
-    return x + out, cache
+    return x + out, cache, fetched
 
 
 def _feed_forward(lp, cfg: DeepseekV3Config, x):
@@ -353,13 +363,16 @@ def empty_state(cfg: DeepseekV3Config, max_len: int, dtype):
 
 def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible):
     """The stack over ids [T] at ``position`` onward through the state ->
-    (hidden [T, d], the new state, held expert assignments)."""
+    (hidden [T, d], the new state, held expert assignments, the cache rows
+    the layers' attention fetched)."""
     x = params["embed"][ids]
-    caches, chosen, held = [], [], jnp.zeros((), jnp.int32)
+    caches, chosen = [], []
+    held = fetched = jnp.zeros((), jnp.int32)
     for lp, cache in zip(params["layers"], state["cache"]):
-        x, cache = _attend(lp, cfg, x, cache, position, visible)
+        x, cache, rows = _attend(lp, cfg, x, cache, position, visible)
         x, n, idx = _feed_forward(lp, cfg, x)
         caches.append(cache)
+        fetched = fetched + rows
         if idx is not None:
             held = held + n.astype(jnp.int32)
             chosen.append(idx)
@@ -367,7 +380,7 @@ def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible):
     if chosen:
         experts = lax.dynamic_update_slice_in_dim(
             experts, jnp.stack(chosen), position, axis=1)
-    return x, {"cache": caches, "experts": experts}, held
+    return x, {"cache": caches, "experts": experts}, held, fetched
 
 
 def _assignments(cfg: DeepseekV3Config, tokens: int) -> int:
@@ -378,7 +391,7 @@ def prefill(params, cfg: DeepseekV3Config, ids, *, max_len: int, state=None,
             position: int = 0, counters=None):
     """ids [T] (T a multiple of ``prefill_block``) at ``position`` onward,
     computed in full -> (float32 logits after the last token [V], the
-    state, the `COUNTERS` so far [6] int32, the experts the T tokens chose
+    state, the `COUNTERS` so far [7] int32, the experts the T tokens chose
     [E layers, T, top_k]).
 
     A prompt from position 0 enters a state with nothing in it and room for
@@ -401,11 +414,11 @@ def prefill(params, cfg: DeepseekV3Config, ids, *, max_len: int, state=None,
         if state["cache"][0]["c"].shape[0] < max(max_len, visible):
             raise ValueError(f"the state handed in has no room for "
                              f"{max(max_len, visible)} positions")
-    x, state, held = _forward(params, cfg, ids, state, position, visible)
+    x, state, held, _ = _forward(params, cfg, ids, state, position, visible)
     counters = jnp.stack([
         counters[0] + t, position, counters[2],
         counters[3] + _assignments(cfg, t), counters[4] + held,
-        params_nbytes(state["cache"])]).astype(jnp.int32)
+        params_nbytes(state["cache"]), counters[6]]).astype(jnp.int32)
     chosen = state["experts"][:, position:position + t]
     return head(params, cfg, x[-1:])[0], state, counters, chosen
 
@@ -414,13 +427,15 @@ def decode(params, cfg: DeepseekV3Config, logits, state, counters, *,
            position: int, new_tokens: int):
     """Greedy decoding through the state, on the device from first token to
     last: ``new_tokens`` times the largest logit is taken and the token goes
-    through the stack, by the absorbed form against the whole cache under
-    its mask.  ``logits`` follow the token at ``position - 1``.
+    through the stack, by the absorbed form against the cache (on a TPU
+    the rows written so far in one pass, else the whole cache under its
+    mask).  ``logits`` follow the token at ``position - 1``.
     -> (ids [new_tokens] int32, the float32 logits each was chosen from
     [new_tokens, V], the experts EVERY position so far chose
     [E layers, max_len, top_k] - the prompt's, a snapshot's too -, the
     state, the counters)."""
-    per_token = jnp.asarray([0, 0, 1, _assignments(cfg, 1), 0, 0], jnp.int32)
+    per_token = jnp.asarray([0, 0, 1, _assignments(cfg, 1), 0, 0, 0],
+                            jnp.int32)
 
     def body(i, carry):
         logits, state, ids, chosen_from, counters = carry
@@ -428,9 +443,9 @@ def decode(params, cfg: DeepseekV3Config, logits, state, counters, *,
         ids = ids.at[i].set(token)
         chosen_from = lax.dynamic_update_slice_in_dim(
             chosen_from, logits[None], i, axis=0)
-        x, state, held = _forward(params, cfg, token[None], state,
-                                  position + i, None)
-        counters = counters + per_token.at[4].set(held)
+        x, state, held, fetched = _forward(params, cfg, token[None], state,
+                                           position + i, None)
+        counters = counters + per_token.at[4].set(held).at[6].set(fetched)
         return head(params, cfg, x)[0], state, ids, chosen_from, counters
 
     _, state, ids, chosen_from, counters = lax.fori_loop(
@@ -460,7 +475,7 @@ def generate(params, cfg: DeepseekV3Config, ids, new_tokens: int):
 def _balancing_layer(lp, x, *, cfg: DeepseekV3Config, rounds: int):
     """One layer of the calibration pass -> (its output, an expert layer's
     balanced bias or None).  One compiled program a kind of layer."""
-    x, _ = _attend(lp, cfg, x, None, 0, None)
+    x, _, _ = _attend(lp, cfg, x, None, 0, None)
     bias = None
     if "router" in lp["ffn"]:
         u = rms_norm(lp["ffn_norm"]["scale"], x, cfg.rms_norm_eps)

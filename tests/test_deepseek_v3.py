@@ -3,7 +3,9 @@ small size, seeded weights: prefill and decode through the latent cache
 against the plain reference's one full forward (`benchmark/reference`) by
 logits; the two forms of latent attention against each other; a suffix
 entering a snapshot; one chip's share of the experts against the uncut
-layer; the gated form of the expert kernels; the issue's arithmetic."""
+layer; the gated form of the expert kernels; the decode step's single-pass
+attention kernel (interpreted) against the XLA form, alone and as the
+model's route; the issue's arithmetic."""
 
 import os
 import re
@@ -126,16 +128,50 @@ def test_a_setting_that_is_not_built_is_refused(key, value):
 
 
 def served(params, ids):
-    return jax.jit(lambda p, i: lm.generate(p, CFG, i, NEW))(
-        params, jnp.asarray(ids))
+    # (the interpreted kernel's callbacks run JAX ops of their own: wait for
+    # them before this thread dispatches more)
+    return jax.block_until_ready(jax.jit(
+        lambda p, i: lm.generate(p, CFG, i, NEW))(params, jnp.asarray(ids)))
 
 
-def test_prefill_then_decode_through_the_cache_is_the_full_forward(params):
+# cache rows a block of the interpreted kernel: the decoded positions
+# T .. T + NEW - 1 cross two block boundaries
+BLOCK = 4
+
+
+@pytest.fixture(params=["xla_form", "kernel_interpreted"])
+def route(request, monkeypatch):
+    """Which form a ONE-query call of `mla.cache_attention` takes: what the
+    CPU gives it (the XLA form) or what a TPU would (the single-pass kernel,
+    interpreted here)."""
+    if request.param == "kernel_interpreted":
+        by_shape = mla.cache_attention
+
+        def kernel_for_one_query(q_lat, q_pe, c, k_pe, position, **kw):
+            if q_lat.shape[0] != 1:
+                return by_shape(q_lat, q_pe, c, k_pe, position, **kw)
+            return mla.streamed_attention(
+                q_lat, q_pe, c, k_pe, position, scale=kw["scale"],
+                block_rows=BLOCK, interpret=True)
+
+        monkeypatch.setattr(mla, "cache_attention", kernel_for_one_query)
+    return request.param
+
+
+def rows_fetched(route, steps, layers=CFG.num_hidden_layers):
+    """`cache_rows_fetched` after decode steps at the positions ``steps``."""
+    if route == "xla_form":
+        return 0
+    return sum(layers * (p // BLOCK + 1) * BLOCK for p in steps)
+
+
+def test_prefill_then_decode_through_the_cache_is_the_full_forward(
+        params, route):
     """float32, tight: the prompt by the materialised form, every decoded
-    token by the absorbed form against the cache, against ONE full forward
-    of the reference over prompt + served ids - teacher-forced over the
-    served ids, and with the reference's OWN choice of experts (in float32
-    both choose alike)."""
+    token by the absorbed form against the cache (either route), against ONE
+    full forward of the reference over prompt + served ids - teacher-forced
+    over the served ids, and with the reference's OWN choice of experts (in
+    float32 both choose alike)."""
     ids = token_ids(T)
     new_ids, chosen_from, counters, experts = served(params, ids)
     all_ids = np.concatenate([ids, np.asarray(new_ids)[:-1]])
@@ -152,7 +188,16 @@ def test_prefill_then_decode_through_the_cache_is_the_full_forward(params):
     assert np.asarray(counters).tolist() == [
         T, 0, NEW, (T + NEW) * 3 * 3, int(np.sum(
             (np.asarray(experts) >= 4) & (np.asarray(experts) < 8))),
-        4 * (T + NEW) * (32 + 8) * 4]
+        4 * (T + NEW) * (32 + 8) * 4,
+        rows_fetched(route, range(T, T + NEW))]
+
+
+def test_the_counters_keep_their_places_and_the_new_one_is_last():
+    assert lm.COUNTERS[:6] == (
+        "tokens_prefilled", "tokens_reused", "tokens_decoded",
+        "expert_assignments", "expert_assignments_held", "state_bytes")
+    assert lm.COUNTERS[6:] == ("cache_rows_fetched",)
+    assert CFG.language_model().counters == lm.COUNTERS
 
 
 def test_the_served_path_in_bfloat16_is_near_the_reference():
@@ -177,16 +222,30 @@ def test_the_served_path_in_bfloat16_is_near_the_reference():
     assert slack < 0.05
 
 
-def test_absorbed_is_materialised_on_the_same_weights(params):
+def test_absorbed_is_materialised_on_the_same_weights(params, route):
     """Every position's hidden state by the materialised form (a prompt) and
-    by the absorbed form (the same prompt ENTERING an empty cache, all its
-    rows visible under the mask)."""
+    by the absorbed form: the same prompt ENTERING an empty cache, all its
+    rows visible under the mask, and - the one-query route - its last tokens
+    going through the cache one step each."""
     ids = jnp.asarray(token_ids(T))
     state = lm.empty_state(CFG, T + 8, jnp.float32)
-    x_mat, s_mat, _ = lm._forward(params, CFG, ids, state, 0, None)
-    x_abs, s_abs, _ = lm._forward(params, CFG, ids, state, 0, T)
+    x_mat, s_mat, _, none = lm._forward(params, CFG, ids, state, 0, None)
+    x_abs, s_abs, _, entering = lm._forward(params, CFG, ids, state, 0, T)
     close(x_abs, x_mat, tol=1e-5)
     for a, b in zip(jax.tree.leaves(s_abs), jax.tree.leaves(s_mat)):
+        close(a, b, tol=1e-5)
+    assert int(none) == int(entering) == 0  # neither is a decode step
+    cut = T - 6
+    _, stepped, _, _ = lm._forward(params, CFG, ids[:cut], state, 0, None)
+    fetched = 0
+    for i in range(cut, T):
+        x, stepped, _, rows = jax.block_until_ready(jax.jit(
+            lambda p, t, s, i: lm._forward(p, CFG, t, s, i, None))(
+                params, ids[i:i + 1], stepped, i))
+        close(x[0], x_mat[i], tol=1e-5)
+        fetched += int(rows)
+    assert fetched == rows_fetched(route, range(cut, T))
+    for a, b in zip(jax.tree.leaves(stepped), jax.tree.leaves(s_mat)):
         close(a, b, tol=1e-5)
 
 
@@ -229,6 +288,84 @@ def test_the_two_forms_of_the_op_agree_and_blocks_do_not_matter():
         close(mla.absorbed_attention(
             q_lat, q_pe, c, k_pe, q_positions=jnp.arange(t), scale=scale,
             block=block), attended, tol=1e-6)
+
+
+# (heads, the query's position, the query's dtype, the cache's): a cache of
+# 512 rows in blocks of 256, the last block fetched 128 rows a copy - the
+# first row, one before / at / one after a block boundary, the same around a
+# copy's boundary, the last row; the cell's 32 heads; the cache a precision
+# below the queries
+KERNEL_CASES = {
+    "first_row": (4, 0, "float32", "float32"),
+    "one_before_a_boundary": (4, 255, "float32", "float32"),
+    "at_a_boundary": (4, 256, "float32", "float32"),
+    "one_after_a_boundary": (4, 257, "float32", "float32"),
+    "one_before_a_copys_boundary": (4, 383, "float32", "float32"),
+    "at_a_copys_boundary": (4, 384, "float32", "float32"),
+    "last_row": (4, 511, "float32", "float32"),
+    "heads_32_bfloat16": (32, 300, "bfloat16", "bfloat16"),
+    "heads_32_at_a_boundary_bfloat16": (32, 256, "bfloat16", "bfloat16"),
+    "cache_float8": (4, 300, "bfloat16", "float8_e4m3fn"),
+    "heads_32_cache_float8_last_row": (32, 511, "bfloat16", "float8_e4m3fn"),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_streamed_kernel_against_the_xla_form_and_a_dense_softmax(case):
+    """`streamed_attention` (interpreted here) is `absorbed_attention` and a
+    dense float32 softmax over the rows written so far; the rows beyond the
+    position hold NaN and never reach the result; it says how many latent
+    rows it fetched: the whole blocks before the position's and of that one
+    the copies up to the position."""
+    h, position, dtype, cache_dtype = KERNEL_CASES[case]
+    max_len, block, sub, c_dim, r, scale = 512, 256, 128, 32, 8, 0.2
+    k = iter(jax.random.split(jax.random.PRNGKey(12), 4))
+    q_lat = jax.random.normal(next(k), (1, h, c_dim)).astype(dtype)
+    q_pe = jax.random.normal(next(k), (1, h, r)).astype(dtype)
+    written = (jnp.arange(max_len) <= position)[:, None]
+    c, k_pe = (jnp.where(written, jax.random.normal(next(k), (max_len, d)),
+                         jnp.nan).astype(cache_dtype) for d in (c_dim, r))
+    assert bool(jnp.isnan(c.astype(jnp.float32)).any()) == (
+        position + 1 < max_len)
+    got, rows = jax.block_until_ready(mla.streamed_attention(
+        q_lat, q_pe, c, k_pe, position, scale=scale, block_rows=block,
+        interpret=True))
+    assert got.shape == q_lat.shape and got.dtype == q_lat.dtype
+    assert int(rows) == -(-(position + 1) // sub) * sub
+    # the XLA form multiplies the unwritten rows by a weight of 0: give it
+    # zeros there
+    c0, k_pe0 = (jnp.where(written, a.astype(jnp.float32), 0).astype(
+        cache_dtype) for a in (c, k_pe))
+    tol = 1e-6 if dtype == "float32" else 2e-2  # weights rounded to bf16
+    close(got, mla.absorbed_attention(
+        q_lat, q_pe, c0, k_pe0, q_positions=jnp.asarray([position]),
+        scale=scale), tol=tol)
+    q = np.concatenate([np.asarray(q_lat, np.float64),
+                        np.asarray(q_pe, np.float64)], -1)
+    seen = np.concatenate([np.asarray(c0, np.float64),
+                           np.asarray(k_pe0, np.float64)], -1)[:position + 1]
+    logits = np.einsum("thd,sd->ths", q, seen) * scale
+    w = np.exp(logits - logits.max(-1, keepdims=True))
+    w /= w.sum(-1, keepdims=True)
+    close(got, np.einsum("ths,sc->thc", w, seen[:, :c_dim]), tol=tol)
+
+
+def test_a_cache_no_block_divides_is_refused_and_takes_the_xla_form():
+    q_lat, q_pe = jnp.zeros((1, 4, 128)), jnp.zeros((1, 4, 8))
+    c, k_pe = jnp.zeros((100, 128)), jnp.zeros((100, 8))
+    with pytest.raises(ValueError, match="not divide"):
+        mla.streamed_attention(q_lat, q_pe, c, k_pe, 3, scale=1.0,
+                               interpret=True)
+    with pytest.raises(ValueError, match="not divide"):
+        mla.streamed_attention(q_lat, q_pe, c, k_pe, 3, scale=1.0,
+                               block_rows=48, interpret=True)
+    with pytest.raises(ValueError, match="one query"):
+        mla.streamed_attention(jnp.zeros((2, 4, 128)), jnp.zeros((2, 4, 8)),
+                               c, k_pe, 3, scale=1.0, block_rows=50,
+                               interpret=True)
+    # off the TPU every call is the XLA form, and says it fetched nothing
+    out, rows = mla.cache_attention(q_lat, q_pe, c, k_pe, 3, scale=1.0)
+    assert out.shape == q_lat.shape and int(rows) == 0
 
 
 def test_rotary_turns_pairs_and_keeps_the_relative_position():

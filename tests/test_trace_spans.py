@@ -720,20 +720,13 @@ def test_byte_level_rewrite_programs_compile_for_the_chip(topo):
     assert flops[1] < flops[0] / 20, flops
 
 
-def test_latent_attention_rewrite_programs_compile_for_the_chip(
-        topo, monkeypatch):
+@pytest.fixture(scope="module")
+def latent_programs(topo):
     """The rewrite stage's three programs at Kanana-2-30B-A3B's published
     widths, one chip's share (24 layers, 16 of 128 experts), compiled for the
-    described v5e.  Prefix: the instruction's 8064 tokens by the materialised
-    form in query blocks - no array with the prompt's length twice among its
-    dims, the routed experts on the grouped matmul.  The request's prefill:
-    128 ids ENTERING the snapshot (read, not aliased) at a twentieth of the
-    whole prompt's FLOPs.  Decode: the donated state - 24 latent caches of
-    576 numbers a position and the record of the experts chosen - carried in
-    place, every expert layer's routed experts ONE call of the gather
-    mat-vec kernel in its gated form, the language model's scopes on its
-    ops, weights and state fit."""
+    described v5e, with the shapes they were compiled from."""
     import json
+    import types
 
     from jax.sharding import SingleDeviceSharding
 
@@ -744,8 +737,6 @@ def test_latent_attention_rewrite_programs_compile_for_the_chip(
         SimpleTokenizer,
     )
 
-    # `local_expert_sum` asks the first device for its platform
-    monkeypatch.setattr(jax, "devices", lambda *a, **k: topo.devices)
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "benchmark", "configs",
                            "kanana-2-30b-sdxl-rewrite.json")) as f:
@@ -765,45 +756,119 @@ def test_latent_attention_rewrite_programs_compile_for_the_chip(
     spec = RewriteSpec(**config["rewrite"])
     rw = PromptRewriter(cfg, None, spec, [SimpleTokenizer(49408)] * 2)
     t, n = spec.instruction_tokens + spec.user_tokens, rw._prefix_len
+    # `local_expert_sum` and `cache_attention` ask the first device for its
+    # platform: answer with the described chip, while these compile
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        prefix = rw._prefix.lower(params, ids(n)).compile()
+        snapshot = jax.tree.map(on_chip, jax.eval_shape(rw._prefix, params,
+                                                        ids(n)))
+        entering = rw._prefill.lower(params, ids(t - n), snapshot).compile()
+        logits, state, counters, _ = jax.tree.map(on_chip, jax.eval_shape(
+            rw._prefill, params, ids(t - n), snapshot))
+        decode = rw._decode.lower(
+            params, logits, state, counters,
+            [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32,
+                                  sharding=one)] * 2).compile()
+    return types.SimpleNamespace(
+        cfg=cfg, spec=spec, t=t, n=n, prefix=prefix, entering=entering,
+        decode=decode, snapshot=snapshot, state=state, counters=counters)
+
+
+def test_latent_attention_rewrite_programs_compile_for_the_chip(
+        latent_programs):
+    """Prefix: the instruction's 8064 tokens by the materialised form in
+    query blocks - no array with the prompt's length twice among its dims,
+    the routed experts on the grouped matmul.  The request's prefill: 128
+    ids ENTERING the snapshot (read, not aliased) at a twentieth of the
+    whole prompt's FLOPs.  Decode: the donated state - 24 latent caches of
+    576 numbers a position and the record of the experts chosen - carried in
+    place, every expert layer's routed experts ONE call of the gather
+    mat-vec kernel in its gated form, the language model's scopes on its
+    ops, weights and state fit."""
+    lp = latent_programs
+    cfg, t, n = lp.cfg, lp.t, lp.n
     assert (t, n, t - n) == (8192, 8064, 128)
-    max_len, n_e = t + spec.new_tokens, cfg.n_expert_layers
+    max_len, n_e = t + lp.spec.new_tokens, cfg.n_expert_layers
     cache_bytes = 24 * max_len * 576 * 2
     state_bytes = cache_bytes + n_e * max_len * cfg.num_experts_per_tok * 4
     assert cache_bytes == 240_648_192
 
-    prefix = rw._prefix.lower(params, ids(n)).compile()
-    text = prefix.as_text()
+    text = lp.prefix.as_text()
     shapes = {tuple(int(x) for x in dims.split(","))
               for dims in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
     assert (32, 32, n) in shapes  # one query block's logits
     assert not [s for s in shapes if s.count(n) >= 2]
     assert "ragged-dot" in text and "expert_gather_matvec" not in text
-    assert prefix.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert lp.prefix.memory_analysis().temp_size_in_bytes < 2.0e9
 
-    snapshot = jax.tree.map(on_chip, jax.eval_shape(rw._prefix, params,
-                                                    ids(n)))
-    entering = rw._prefill.lower(params, ids(t - n), snapshot).compile()
-    mem = entering.memory_analysis()
+    mem = lp.entering.memory_analysis()
     assert mem.alias_size_in_bytes == 0
     assert mem.temp_size_in_bytes < 0.6e9
-    flops = [c.cost_analysis()["flops"] for c in (prefix, entering)]
+    flops = [c.cost_analysis()["flops"] for c in (lp.prefix, lp.entering)]
     assert flops[1] < flops[0] / 10, flops
+    # the 128 entering rows keep the XLA form, 32 queries at a time
+    assert "latent_cache_attention" not in lp.entering.as_text()
 
-    logits, state, counters, _ = jax.tree.map(on_chip, jax.eval_shape(
-        rw._prefill, params, ids(t - n), snapshot))
-    assert jax.tree.map(lambda a: (a.shape, a.dtype), (state, counters)) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), snapshot)
-    compiled = rw._decode.lower(
-        params, logits, state, counters,
-        [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32, sharding=one)] * 2
-    ).compile()
-    mem = compiled.memory_analysis()
+    assert jax.tree.map(lambda a: (a.shape, a.dtype),
+                        (lp.state, lp.counters)) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot)
+    mem = lp.decode.memory_analysis()
     assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
     assert mem.temp_size_in_bytes < 0.3e9
     assert 5.5e9 < mem.argument_size_in_bytes < 5.8e9  # weights + state
-    text = compiled.as_text()
+    text = lp.decode.as_text()
     kernels = re.findall(r"%(expert_gather_matvec[\w.\-]*) = ", text)
     assert len(kernels) == n_e and "ragged-dot" not in text
     for scope in ("lm.mla.proj", "lm.mla.attn", "lm.moe.router",
                   "lm.moe.experts", "lm.moe.shared", "lm.mlp", "lm.head"):
         assert f"/{scope}/" in text, scope
+
+
+def _loop_body(text):
+    """The largest computation of a compiled program's HLO text that is not
+    its entry: the decode loop's body."""
+    comps = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    return max((c for c in comps if not c.startswith("ENTRY ")), key=len)
+
+
+def test_decode_step_reads_each_latent_cache_through_one_kernel(
+        latent_programs):
+    """The compiled decode step: one `latent_cache_attention` custom call a
+    layer, under the `lm.mla.attn` scope (what `mla_attn_ms_per_token` reads
+    it by); the cache arrays reach it as they are carried - no copy,
+    transpose or slice with a cache's shape as its result in the loop's body
+    (a copy the compiler schedules ASYNCHRONOUSLY, `copy-start`, to keep a
+    layer's cache in VMEM is its own affair: the parent's program has those
+    too) - and no float32 array of a whole cache's logits is left."""
+    cfg = latent_programs.cfg
+    max_len = latent_programs.t + latent_programs.spec.new_tokens
+    h, layers = cfg.num_attention_heads, cfg.num_hidden_layers
+    body = _loop_body(latent_programs.decode.as_text())
+    calls = [ln for ln in body.splitlines()
+             if re.match(r"\s*%latent_cache_attention[\w.\-]* = ", ln)
+             and "custom-call(" in ln]
+    assert len(calls) == layers
+    assert all('custom_call_target="tpu_custom_call"' in ln and re.search(
+        r'op_name="[^"]*/lm\.mla\.attn/[^"]*pallas_call', ln)
+        for ln in calls)
+    def cache_shaped(dims):
+        """[max_len, 512 | 64], or the same rows as blocks of a leading
+        axis."""
+        return (len(dims) >= 2 and math.prod(dims[:-1]) == max_len
+                and dims[-1] in (cfg.kv_lora_rank, cfg.qk_rope_head_dim))
+
+    moved = []
+    for ln in body.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]+)\]\S* "
+                     r"([\w\-]+)\(", ln)
+        if m and cache_shaped([int(d) for d in m.group(2).split(",")]) and (
+                m.group(3) in ("copy", "transpose", "slice", "dynamic-slice")
+                or re.match(r"(copy|transpose|slice)", m.group(1))
+                and m.group(3) == "fusion"):
+            moved.append(ln.strip()[:160])
+    assert not moved, moved
+    logits = [ln.strip()[:160] for ln in body.splitlines() if re.search(
+        rf"= f32\[(?:{h},1,{max_len}|{max_len},1,{h}|{h},{max_len}|"
+        rf"{max_len},{h})\]", ln)]
+    assert not logits, logits
