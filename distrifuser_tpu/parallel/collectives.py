@@ -110,6 +110,7 @@ def halo_exchange(x, halo: int, n: int, axis: str = SP_AXIS):
     return exchange_boundary_rows(x[:, -halo:], x[:, :halo], n, axis)
 
 
+@jax.named_scope("out_gather")
 def gather_rows(patch, axis: str = SP_AXIS):
     """Reassemble row-sharded [B, h, W, C] patches into the full [B, H, W, C].
 
@@ -119,6 +120,7 @@ def gather_rows(patch, axis: str = SP_AXIS):
     return lax.all_gather(patch, axis, axis=1, tiled=True)
 
 
+@jax.named_scope("out_gather")
 def gather_cols(patch, axis: str = SP_AXIS):
     """Column-split variant used by naive patch parallelism (split_scheme='col',
     naive_patch_sdxl.py:119-122)."""

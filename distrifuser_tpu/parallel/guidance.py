@@ -15,6 +15,7 @@ once.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -61,6 +62,7 @@ def _per_row_gs(gs, ref):
     return gs.reshape(gs.shape + (1,) * (jnp.ndim(ref) - 1))
 
 
+@jax.named_scope("cfg_combine")
 def combine_guidance(cfg: DistriConfig, out, gs, batch):
     """Guided output from per-branch model output (full latent or chunk):
     ``u + gs * (c - u)`` with branches gathered over the cfg axis

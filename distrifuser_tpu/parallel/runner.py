@@ -347,7 +347,7 @@ class DenoiseRunner:
             x_next, sstate = sched.step(x, guided.astype(jnp.float32), i, sstate)
             return x_next, new_pstate, sstate
 
-        return step
+        return jax.named_scope(f"phase_{phase}")(step)
 
     # ------------------------------------------------------------------
     # the full loop (traced once per num_steps)
@@ -1205,7 +1205,12 @@ class DenoiseRunner:
         comm_volume_report and compiled_hlo so the two observability paths
         can never trace different programs (they once drifted on the enc
         dtype).  generate() casts its real inputs to the same dtypes, so a
-        program lowered from these specs is the program that runs.
+        program lowered from these specs is the program that runs — to the
+        argument attributes: the encoders' outputs reach generate()
+        committed to the mesh, replicated (the pipelines), latents, time ids
+        and the scale uncommitted, and the specs say the same, so that
+        compiled_hlo() is a hit in JAX's compile cache after the served
+        program's compile and not a second compile of minutes.
 
         ``per_group=False`` gives the global-batch signature of the fused
         loop (batch splits over the dp axis inside shard_map);
@@ -1220,12 +1225,14 @@ class DenoiseRunner:
         if per_group:
             b = b // cfg.dp_degree
         n_br = 2 if cfg.do_classifier_free_guidance else 1
+        replicated = jax.sharding.NamedSharding(cfg.mesh, P())
         lat = jax.ShapeDtypeStruct(
             (b, cfg.latent_height, cfg.latent_width, self.ucfg.in_channels),
             jnp.float32,
         )
         enc = jax.ShapeDtypeStruct(
-            (n_br, b, text_len, self.ucfg.cross_attention_dim), cfg.dtype
+            (n_br, b, text_len, self.ucfg.cross_attention_dim), cfg.dtype,
+            sharding=replicated,
         )
         added = None
         if self.ucfg.addition_embed_type == "text_time":
@@ -1234,7 +1241,8 @@ class DenoiseRunner:
                 - 6 * self.ucfg.addition_time_embed_dim
             )
             added = {
-                "text_embeds": jax.ShapeDtypeStruct((n_br, b, emb), cfg.dtype),
+                "text_embeds": jax.ShapeDtypeStruct(
+                    (n_br, b, emb), cfg.dtype, sharding=replicated),
                 "time_ids": jax.ShapeDtypeStruct((n_br, b, 6), jnp.float32),
             }
         gs = jax.ShapeDtypeStruct((), jnp.float32)
@@ -1250,6 +1258,20 @@ class DenoiseRunner:
         # reuses this program instead of re-compiling (jit caches by shape)
         fn = self.compiled_handle(num_inference_steps)
         return fn.lower(self.params, lat, enc, added, gs).compile().as_text()
+
+    @staticmethod
+    def exchange_report(hlo_text: str):
+        """What the COMPILED loop exchanges, from its own text (e.g.
+        ``compiled_hlo()``): per phase (``phase_sync`` / ``phase_stale``,
+        the scopes `_make_step` puts its step under) and exchange kind
+        (``halo``, ``stale_kv``, ``gn_stats``, ``stale_gather``,
+        ``out_gather``, ``cfg_combine``) ``{"collectives": instructions,
+        "inline": those this iteration computes with, "bytes": wire bytes
+        per device and step}``.  comm_volume_report / comm_plan are the
+        model of these bytes; this is the program's count."""
+        from ..utils.overlap import exchange_report
+
+        return exchange_report(hlo_text)
 
     def generate(
         self,

@@ -29,6 +29,21 @@ inline collectives ONLY for the per-step full-output gather + CFG combine
 tests/test_overlap.py asserts this, with the sync path as negative control.
 `python -m distrifuser_tpu.utils.overlap <file.hlo>` prints the report for
 any dumped module (e.g. from a real-chip run with XLA dump flags).
+
+Each collective also carries what its `op_name` metadata says of it — the
+exchange it belongs to (the innermost of the `EXCHANGE_KINDS` named scopes)
+and the loop phase (`phase_sync` / `phase_stale`, parallel/runner.py) — and
+its wire bytes per device by `comm_plan`'s gathered-buffer convention (an
+all-gather: its result; a permute: the rows sent).  `exchange_report` sums
+them per phase and kind: the compiled program's own count of what
+`comm_volume_report` models.  TPU text is read as well as CPU text: a
+`-start` / `-done` pair counts once (at the start), an `async-start`
+wrapper or a fusion that holds a collective counts as that collective, and
+the start / overlapped-compute / done fusions the TPU compiler splits one
+all-gather into (one `channel_id`) count once, classified at the done.  The
+TPU compiler's own form of `concatenate` (each part padded with -inf, then
+`maximum`) is data movement, recognised by what the fusion holds and not by
+its name.
 """
 
 from __future__ import annotations
@@ -37,11 +52,14 @@ import dataclasses
 import re
 from typing import Dict, List
 
-_COLLECTIVES = (
-    "all-gather(", "collective-permute(", "all-reduce(", "reduce-scatter(",
-    "all-gather-start(", "collective-permute-start(", "all-reduce-start(",
-    "all-to-all(",
-)
+_COLLECTIVE_OPS = frozenset({
+    "all-gather", "collective-permute", "all-reduce", "reduce-scatter",
+    "all-to-all", "collective-broadcast", "ragged-all-to-all",
+    "all-gather-start", "collective-permute-start", "all-reduce-start",
+})
+# instructions that may hold a collective in the computation they call: the
+# generic async wrapper, and the fusions a TPU compiler puts one into
+_WRAPPER_OPS = frozenset({"async-start", "fusion", "call"})
 # pure data movement: consuming a value through these does not compute with it
 _DM_OPS = frozenset({
     "copy", "bitcast", "bitcast-convert", "convert", "reshape", "transpose",
@@ -49,6 +67,10 @@ _DM_OPS = frozenset({
     "broadcast", "reverse", "tuple", "get-tuple-element",
     "all-gather-done", "collective-permute-done", "all-reduce-done",
     "optimization-barrier",
+    # the other halves of async ops (a TPU compiler's own copies and slices,
+    # the generic wrapper's update and done)
+    "async-update", "async-done", "copy-start", "copy-done", "slice-start",
+    "slice-done",
 })
 # ops that may appear in a data-movement fusion without consuming anything
 _DM_SOURCES = frozenset({"parameter", "constant", "iota"})
@@ -66,12 +88,24 @@ _EW_OPS = frozenset({
     "round-nearest-even", "round-nearest-afz",
 })
 
+# the exchanges' named scopes (ops/conv.py, ops/attention.py,
+# ops/normalization.py, parallel/context.py, collectives.py, guidance.py)
+EXCHANGE_KINDS = ("halo", "stale_kv", "gn_stats", "stale_gather",
+                  "out_gather", "cfg_combine")
+PHASES = ("phase_sync", "phase_stale")
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+             "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+             "f64": 8, "c64": 8, "c128": 16}
+_LEAF = re.compile(r"\b([a-z]+\d+[a-z0-9]*|pred)\[([\d,]*)\]")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CHANNEL = re.compile(r"channel_id=(\d+)")
+
 _ATTR_REF = re.compile(r"(?:condition|body)=%[\w.\-]+")
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _TOKEN = re.compile(r"%([\w.\-]+)")
 _DEF = re.compile(r"^(ROOT )?%?([\w.\-]+) = ")
 _BLOCK_HEAD = re.compile(r"^(?:ENTRY )?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{$")
-_OPCODE = re.compile(r"([\w\-]+)\(")
+_OPCODE = re.compile(r"([\w\-]+)\(")  # at the start of what follows the result
 
 
 def parse_computations(hlo_text: str) -> Dict[str, List[str]]:
@@ -93,9 +127,131 @@ def parse_computations(hlo_text: str) -> Dict[str, List[str]]:
     return blocks
 
 
+def _split_result(line: str):
+    """(result type, the rest from the opcode on) of an instruction line.
+    The result type is skipped whole, because a TPU layout holds parentheses
+    of its own (`bf16[8,128]{1,0:T(8,128)(2,1)S(1)}`) and an async start
+    returns a tuple."""
+    rhs = line.split(" = ", 1)[1]
+    if not rhs.startswith("("):
+        result, _, rest = rhs.partition(" ")
+        return result, rest
+    depth = 0
+    for i, ch in enumerate(rhs):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return rhs[: i + 1], rhs[i + 1 :].lstrip()
+    return rhs, ""
+
+
 def _opcode(line: str) -> str:
-    m = _OPCODE.search(line.split(" = ", 1)[1])
+    m = _OPCODE.match(_split_result(line)[1])
     return m.group(1) if m else "?"
+
+
+def _tuple_elements(result: str) -> List[str]:
+    """Top-level elements of a tuple result type (itself, if no tuple)."""
+    if not result.startswith("("):
+        return [result]
+    out, depth, start = [], 0, 1
+    for i, ch in enumerate(result):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        if (ch == "," and depth == 1) or (ch == ")" and depth == 0):
+            out.append(result[start:i].strip())
+            start = i + 1
+    return out
+
+
+def _nbytes(result: str) -> int:
+    """Logical bytes of every array in a result type (layout padding is the
+    compiler's, not the wire's)."""
+    total = 0
+    for dtype, dims in _LEAF.findall(result):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += n * _ITEMSIZE.get(dtype, 1)
+    return total
+
+
+def _wire_bytes(opcode: str, result: str) -> int:
+    """Wire bytes per device of one collective instruction, gathered-buffer
+    convention.  An async start returns (operands, results, context...):
+    the results are what moves."""
+    if opcode.endswith("-start"):
+        parts = _tuple_elements(result)
+        return _nbytes(parts[1] if len(parts) > 1 else parts[0])
+    return _nbytes(result)
+
+
+def _scoped(op_name: str, names) -> str:
+    """The innermost path component of ``op_name`` that is one of ``names``."""
+    for part in reversed(op_name.split("/")):
+        if part in names:
+            return part
+    return ""
+
+
+# what may stand between the parts of a padded concatenate and its result
+_CONCAT_GLUE = frozenset({"convert", "bitcast", "copy", "reshape"})
+
+
+def _is_padded_concatenate(lines: List[str]) -> bool:
+    """A TPU compiler writes `concatenate` as a fusion that pads each part
+    with -inf out to the result's shape and takes the `maximum` of the
+    padded parts: arithmetic by opcode, data movement by what it does.  True
+    for a computation that is exactly that and nothing else: parameters, one
+    kind of constant (-inf), `pad` with that constant, `maximum` over pads
+    (through converts of the float-normalisation pass) and other such
+    maxima.  A multiply, an add, a pad with another value or a maximum
+    against a live value makes it arithmetic like any other."""
+    defs = {}
+    for ln in lines:
+        m = _DEF.match(ln)
+        if m:
+            defs[m.group(2)] = (_opcode(ln), ln)
+
+    def operands(ln):
+        return [t for t in _TOKEN.findall(ln.split(" = ", 1)[1]) if t in defs]
+
+    def source(name):  # the op a value comes from, glue looked through
+        op, ln = defs[name]
+        while op in _CONCAT_GLUE and operands(ln):
+            op, ln = defs[operands(ln)[0]]
+        return op
+
+    n_max = 0
+    for op, ln in defs.values():
+        if op == "parameter" or op in _CONCAT_GLUE:
+            continue
+        if op == "constant":
+            if "constant(-inf)" not in ln:
+                return False
+        elif op == "pad":
+            ops = operands(ln)
+            if len(ops) != 2 or defs[ops[1]][0] != "constant":
+                return False
+        elif op == "maximum":
+            n_max += 1
+            if any(source(o) not in ("pad", "maximum") for o in operands(ln)):
+                return False
+        else:
+            return False
+    return n_max > 0
+
+
+@dataclasses.dataclass(frozen=True)
+class Collective:
+    """One collective of a loop body, as its instruction states it."""
+
+    opcode: str  # all-gather, collective-permute-start, ...
+    kind: str  # one of EXCHANGE_KINDS, "" where op_name names none
+    phase: str  # one of PHASES, "" where op_name names none
+    nbytes: int  # wire bytes per device
+    inline: bool  # computed with in this iteration (not carry-only)
 
 
 @dataclasses.dataclass
@@ -109,6 +265,8 @@ class LoopReport:
     # classification keeps them in ``inline``, preserving the strict
     # pure-data-movement invariant of the uncompressed program.
     deferred_compute: Dict[str, str] = dataclasses.field(default_factory=dict)
+    # instruction name -> what its own line says of it (kind, phase, bytes)
+    collectives: Dict[str, Collective] = dataclasses.field(default_factory=dict)
 
     @property
     def n_deferred(self) -> int:
@@ -128,6 +286,7 @@ class _Analyzer:
         self.blocks = parse_computations(hlo_text)
         self._dm_comp: Dict[str, bool] = {}
         self._ew_comp: Dict[str, bool] = {}
+        self._inner: Dict[str, str | None] = {}
 
     def _computation_is_dm(self, name: str) -> bool:
         """True if a (fusion) computation contains no arithmetic at all."""
@@ -193,7 +352,9 @@ class _Analyzer:
                     return False
                 if allow_ew:
                     return self._computation_is_ew(m.group(1))
-                return self._computation_is_dm(m.group(1))
+                return (self._computation_is_dm(m.group(1))
+                        or _is_padded_concatenate(
+                            self.blocks.get(m.group(1), ())))
             return False
 
         def deferred(coll: str, allow_ew: bool = False) -> bool:
@@ -215,18 +376,64 @@ class _Analyzer:
                         return False
             return True
 
-        d, dc, i = {}, {}, {}
+        # name -> the line that states the collective (its own, or the one
+        # inside the computation a wrapper calls)
+        found: Dict[str, str] = {}
+        wrapped: Dict[tuple, str] = {}  # (opcode, channel) -> last wrapper
         for n, ln in defs.items():
-            if any(c in ln for c in _COLLECTIVES):
-                if deferred(n):
-                    d[n] = _opcode(ln)
-                elif elementwise_carry and deferred(n, allow_ew=True):
-                    dc[n] = _opcode(ln)
-                else:
-                    i[n] = _opcode(ln)
-        if d or dc or i:
-            return LoopReport(body, d, i, dc)
+            op = _opcode(ln)
+            if op in _COLLECTIVE_OPS:
+                found[n] = ln
+            elif op in _WRAPPER_OPS:
+                inner = self._inner_collective(ln)
+                if inner is None:
+                    continue
+                ch = _CHANNEL.search(inner)
+                key = (_opcode(inner), ch.group(1) if ch else n)
+                # one all-gather split into start / overlapped compute /
+                # done fusions: the last of them holds the value
+                found.pop(wrapped.get(key), None)
+                wrapped[key] = n
+                found[n] = inner
+        d, dc, i, info = {}, {}, {}, {}
+        for n, stated in found.items():
+            op = _opcode(stated)
+            if deferred(n):
+                d[n] = op
+            elif elementwise_carry and deferred(n, allow_ew=True):
+                dc[n] = op
+            else:
+                i[n] = op
+            m = _OP_NAME.search(defs[n]) or _OP_NAME.search(stated)
+            op_name = m.group(1) if m else ""
+            info[n] = Collective(
+                opcode=op, kind=_scoped(op_name, EXCHANGE_KINDS),
+                phase=_scoped(op_name, PHASES),
+                nbytes=_wire_bytes(op, _split_result(stated)[0]),
+                inline=n in i)
+        if info:
+            return LoopReport(body, d, i, dc, info)
         return None
+
+    def _inner_collective(self, line: str) -> str | None:
+        """The collective instruction inside the computation ``line`` calls
+        (through nested wrappers), or None; found once a computation."""
+        m = _CALLS.search(line)
+        if not m:
+            return None
+        comp = m.group(1)
+        if comp not in self._inner:
+            self._inner[comp] = None  # cycle guard
+            for ln in self.blocks.get(comp, ()):
+                if " = " not in ln:
+                    continue
+                op = _opcode(ln)
+                inner = ln if op in _COLLECTIVE_OPS else (
+                    self._inner_collective(ln) if op in _WRAPPER_OPS else None)
+                if inner is not None:
+                    self._inner[comp] = inner
+                    break
+        return self._inner[comp]
 
 
 def analyze_loop_collectives(
@@ -251,6 +458,26 @@ def analyze_loop_collectives(
     return reports
 
 
+def exchange_report(hlo_text: str) -> Dict[str, Dict[str, Dict[str, int]]]:
+    """{phase: {kind: {"collectives", "inline", "bytes"}}} over every
+    while-body collective of a compiled program: how many instructions each
+    exchange became, how many of them this iteration computes with, and the
+    wire bytes per device of one pass through the body.  A collective whose
+    `op_name` names no phase or no exchange is filed under "".  Inline is the
+    strict reading (pure data movement to the carry): through cheap
+    elementwise arithmetic alone the CFG combine, too, reaches only the
+    carry - by way of the scheduler's update - and it is no hidden exchange."""
+    out: Dict[str, Dict[str, Dict[str, int]]] = {}
+    for r in analyze_loop_collectives(hlo_text):
+        for c in r.collectives.values():
+            row = out.setdefault(c.phase, {}).setdefault(
+                c.kind, {"collectives": 0, "inline": 0, "bytes": 0})
+            row["collectives"] += 1
+            row["inline"] += c.inline
+            row["bytes"] += c.nbytes
+    return out
+
+
 def format_report(reports: List[LoopReport]) -> str:
     from collections import Counter
 
@@ -271,6 +498,18 @@ def format_report(reports: List[LoopReport]) -> str:
             )
         if r.inline:
             out.append(f"  inline (serializing):    {dict(Counter(r.inline.values()))}")
+        rows: Dict[tuple, List[int]] = {}
+        for c in r.collectives.values():
+            row = rows.setdefault(
+                (c.phase or "-", c.kind or "-", c.opcode), [0, 0, 0])
+            row[0] += 1
+            row[1] += c.inline
+            row[2] += c.nbytes
+        out.append(f"  {'phase':<12}{'kind':<13}{'opcode':<25}"
+                   f"{'n':>5}{'inline':>8}{'bytes':>14}")
+        for (phase, kind, opcode), (n, inline, nbytes) in sorted(rows.items()):
+            out.append(f"  {phase:<12}{kind:<13}{opcode:<25}{n:>5}"
+                       f"{inline:>8}{nbytes:>14}")
     return "\n".join(out) if out else "no while-loop collectives found"
 
 
