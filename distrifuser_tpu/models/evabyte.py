@@ -45,9 +45,13 @@ F32 = jnp.float32
 
 # counters the generation returns with its ids; ``bytes_reused``: of the
 # positions the state covers after prefill, those a state handed in already
-# covered - entered, not computed in this request
+# covered - entered, not computed in this request; ``state_rows_read``: the
+# ring and summary rows the decode steps' attention read, summed over layers
+# and steps (`ops/eva.py step_attention`: the rows in view on a TPU, every
+# row held elsewhere)
 COUNTERS = ("bytes_prefilled", "bytes_decoded", "summaries_written",
-            "windows_rolled", "state_bytes", "bytes_reused")
+            "windows_rolled", "state_bytes", "bytes_reused",
+            "state_rows_read")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,7 +211,8 @@ def empty_state(cfg: EvaByteConfig, max_len: int, dtype):
 def attention_prefill(p, cfg: EvaByteConfig, x, state, position: int):
     """x [T, d] at ``position`` onward (both whole chunks, ``position``
     static) through the layer's state, which covers the positions before:
-    -> (out [T, d], the state as position + T finds it)."""
+    -> (out [T, d], the state as position + T finds it, 0: no row read by
+    a decode step)."""
     window, chunk = cfg.window_size, cfg.chunk_size
     q, k, v = _qkv(p, cfg, x, position + jnp.arange(x.shape[0]))
     with jax.named_scope("lm.eva.pool"):
@@ -217,13 +222,16 @@ def attention_prefill(p, cfg: EvaByteConfig, x, state, position: int):
             q, k, v, ks, vs, state["k"], state["v"], state["ks"], state["vs"],
             position=position, window=window, chunk=chunk)
     return _out(p, out), {"k": ring_k, "v": ring_v, "ks": table_k,
-                          "vs": table_v}
+                          "vs": table_v}, 0
 
 
 def attention_step(p, cfg: EvaByteConfig, x, state, position):
     """One byte (x [1, d]) at ``position`` through the layer's state: its
     key and value go into the ring first; if it completes a chunk, the
-    chunk's summary goes into the table; then the query reads both."""
+    chunk's summary goes into the table; then the query reads both
+    (`ops/eva.py step_attention`: on a TPU the rows in view in one pass,
+    else every row under a mask) -> (out [1, d], the state, the ring +
+    summary rows read)."""
     window, chunk = cfg.window_size, cfg.chunk_size
     position = jnp.asarray(position, jnp.int32)
     q, k, v = _qkv(p, cfg, x, position[None])
@@ -243,11 +251,11 @@ def attention_step(p, cfg: EvaByteConfig, x, state, position):
                 row, axis=0)
             for table, new in ((state["ks"], ks), (state["vs"], vs)))
     with jax.named_scope("lm.eva.attn"):
-        out = eva.decode_attention(q[0], ring_k, ring_v, table_k, table_v,
-                                   position=position, window=window,
-                                   chunk=chunk)
+        out, rows = eva.step_attention(q[0], ring_k, ring_v, table_k, table_v,
+                                       position=position, window=window,
+                                       chunk=chunk)
     return _out(p, out[None]), {"k": ring_k, "v": ring_v, "ks": table_k,
-                                "vs": table_v}
+                                "vs": table_v}, rows
 
 
 @jax.named_scope("lm.mlp")
@@ -270,31 +278,32 @@ def head(params, cfg: EvaByteConfig, h):
 
 def _forward(params, cfg: EvaByteConfig, ids, state, position, attend):
     """The stack over ids [T] at ``position`` onward, through the state (one
-    entry a layer) -> (the residual stream [T, d], the new state).
-    ``attend``: `attention_prefill` (T whole chunks) or `attention_step`
-    (one byte)."""
+    entry a layer) -> (the residual stream [T, d], the new state, the state
+    rows the layers' decode attention read).  ``attend``:
+    `attention_prefill` (T whole chunks) or `attention_step` (one byte)."""
     dtype = params["embed"].dtype
     # fp32_skip_add false keeps the stream where the published code keeps
     # it without the switch, in bfloat16: a precision below the stated one
     h = params["embed"][ids].astype(F32 if cfg.fp32_skip_add else jnp.bfloat16)
-    new_state = []
+    new_state, rows_read = [], 0
     for lp, st in zip(params["layers"], state):
         x = rms_norm(lp["attn_norm"]["scale"], h.astype(dtype),
                      cfg.rms_norm_eps)
-        out, st = attend(lp["attn"], cfg, x, st, position)
+        out, st, rows = attend(lp["attn"], cfg, x, st, position)
         new_state.append(st)
+        rows_read = rows_read + rows
         h = h + out.astype(h.dtype)
         x = rms_norm(lp["mlp_norm"]["scale"], h.astype(dtype),
                      cfg.rms_norm_eps)
         h = h + mlp(lp["mlp"], x).astype(h.dtype)
-    return h, new_state
+    return h, new_state, rows_read
 
 
 def prefill(params, cfg: EvaByteConfig, ids, *, max_len: int, state=None,
             position: int = 0, counters=None):
     """ids [T] (T a multiple of ``chunk_size``) at ``position`` onward,
     computed in full -> (float32 logits after the last byte [8 * V], the
-    decode state, the `COUNTERS` so far [6] int32, ()).
+    decode state, the `COUNTERS` so far [7] int32, ()).
 
     A prompt from position 0 enters a state with nothing in it and room for
     ``max_len`` positions.  A suffix enters ``state`` - what a prefill of
@@ -317,12 +326,13 @@ def prefill(params, cfg: EvaByteConfig, ids, *, max_len: int, state=None,
         raise ValueError(f"the state handed in has no room for {max_len} "
                          f"positions")
     end = position + t
-    h, state = _forward(params, cfg, ids, state, position, attention_prefill)
+    h, state, _ = _forward(params, cfg, ids, state, position,
+                           attention_prefill)
     counters = jnp.stack([
         counters[0] + t, counters[1],
         counters[2] + end // chunk - position // chunk,
         counters[3] + end // cfg.window_size - position // cfg.window_size,
-        params_nbytes(state), position]).astype(jnp.int32)
+        params_nbytes(state), position, counters[6]]).astype(jnp.int32)
     return head(params, cfg, h[-1:])[0], state, counters, ()
 
 
@@ -343,11 +353,11 @@ def decode(params, cfg: EvaByteConfig, logits, state, counters, *,
         chosen_from = lax.dynamic_update_slice_in_dim(
             chosen_from, logits[None], i, axis=0)
         at = position + i
-        h, state = _forward(params, cfg, token[None], state, at,
-                            attention_step)
+        h, state, rows_read = _forward(params, cfg, token[None], state, at,
+                                       attention_step)
         counters = counters + jnp.stack([
-            0, 1, at % chunk == chunk - 1, at % window == window - 1, 0, 0]
-        ).astype(jnp.int32)
+            0, 1, at % chunk == chunk - 1, at % window == window - 1, 0, 0,
+            rows_read]).astype(jnp.int32)
         return head(params, cfg, h)[0], state, ids, chosen_from, counters
 
     _, state, ids, chosen_from, counters = lax.fori_loop(

@@ -103,8 +103,8 @@ def test_prefill_logits_against_the_reference_over_two_and_a_half_windows(
     # the program's head
     empty = [lm.empty_state(CFG, len(ids), jnp.float32)] * len(
         params["layers"])
-    h, _ = lm._forward(params, CFG, jnp.asarray(ids), empty, 0,
-                       lm.attention_prefill)
+    h, *_ = lm._forward(params, CFG, jnp.asarray(ids), empty, 0,
+                        lm.attention_prefill)
     got = lm.head(params, CFG, h)
     assert got.shape == (len(ids), 8 * CFG.vocab_size)
     close(got, reference_logits(params, ids), tol=5e-5)
@@ -139,7 +139,9 @@ def test_prefill_then_decode_across_a_chunk_and_a_window_boundary(params):
     assert dict(zip(lm.COUNTERS, np.asarray(counters).tolist())) == {
         "bytes_prefilled": len(prompt), "bytes_decoded": new,
         "summaries_written": total // C, "windows_rolled": total // W,
-        "state_bytes": nbytes, "bytes_reused": 0}
+        "state_bytes": nbytes, "bytes_reused": 0,
+        # off the TPU a step reads every row the state holds
+        "state_rows_read": 3 * new * (W + -(-total // C))}
     assert total // W == 1 and len(prompt) // W == 0  # it did roll
     # a chunk that is not complete leaves its row of the table alone
     assert total % C and not np.asarray(state[0]["ks"][total // C]).any()
@@ -185,7 +187,8 @@ def test_a_suffix_entering_the_prefixs_state_is_the_full_prefill(params,
     assert dict(zip(lm.COUNTERS, np.asarray(got[2]).tolist())) == {
         "bytes_prefilled": end, "bytes_decoded": 0,
         "summaries_written": end // C, "windows_rolled": end // W,
-        "state_bytes": lm.params_nbytes(want[1]), "bytes_reused": position}
+        "state_bytes": lm.params_nbytes(want[1]), "bytes_reused": position,
+        "state_rows_read": 0}
     assert np.array_equal(np.asarray(got[2])[:5], np.asarray(want[2])[:5])
     decoded = [np.asarray(lm.decode(params, CFG, *out[:3], position=end,
                                     new_tokens=new)[0]) for out in (got, want)]

@@ -646,14 +646,13 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(
     assert "expert_gather_matvec" not in prefill
 
 
-def test_byte_level_rewrite_programs_compile_for_the_chip(topo):
-    """The rewrite stage's two programs at EvaByte's published widths, 16
-    layers, compiled for the described v5e.  Decode: the donated state - 16
-    rings and summary tables, 608 MB - is carried in place (aliased to the
-    output, no second copy among the temporaries), the language model's
-    scopes are on its ops, weights and state fit.  Prefill: 3840 positions
-    by query block, no array of all positions squared."""
+@pytest.fixture(scope="module")
+def byte_programs(topo):
+    """The byte-level rewrite stage at EvaByte's published widths, 16
+    layers: the rewriter, the shapes its programs take on the described v5e,
+    and the decode program compiled for it."""
     import json
+    import types
 
     from jax.sharding import SingleDeviceSharding
 
@@ -683,10 +682,31 @@ def test_byte_level_rewrite_programs_compile_for_the_chip(topo):
     ids = jax.ShapeDtypeStruct((t,), jnp.int32, sharding=one)
     logits, state, counters, _ = jax.tree.map(
         on_chip, jax.eval_shape(rw._prefill, params, ids))
+    # `step_attention` asks the first device for its platform: answer with
+    # the described chip, while the decode program compiles
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        decode = rw._decode.lower(params, logits, state, counters,
+                                  []).compile()
+    return types.SimpleNamespace(
+        cfg=cfg, spec=spec, rw=rw, t=t, one=one, on_chip=on_chip,
+        params=params, ids=ids, state=state, counters=counters, decode=decode)
+
+
+def test_byte_level_rewrite_programs_compile_for_the_chip(byte_programs):
+    """The rewrite stage's two programs at EvaByte's published widths, 16
+    layers, compiled for the described v5e.  Decode: the donated state - 16
+    rings and summary tables, 608 MB - is carried in place (aliased to the
+    output, no second copy among the temporaries), the language model's
+    scopes are on its ops, weights and state fit.  Prefill: 3840 positions
+    by query block, no array of all positions squared."""
+    bp = byte_programs
+    rw, spec, t, one, on_chip = bp.rw, bp.spec, bp.t, bp.one, bp.on_chip
+    params, ids, state, counters = bp.params, bp.ids, bp.state, bp.counters
     state_bytes = 16 * 2 * 2 * 4096 * (2048 + (t + spec.new_tokens) // 16)
     assert state_bytes == 608_174_080
 
-    compiled = rw._decode.lower(params, logits, state, counters, []).compile()
+    compiled = bp.decode
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == state_bytes
     assert mem.temp_size_in_bytes < 0.1e9
@@ -871,4 +891,49 @@ def test_decode_step_reads_each_latent_cache_through_one_kernel(
     logits = [ln.strip()[:160] for ln in body.splitlines() if re.search(
         rf"= f32\[(?:{h},1,{max_len}|{max_len},1,{h}|{h},{max_len}|"
         rf"{max_len},{h})\]", ln)]
+    assert not logits, logits
+
+
+def test_decode_step_reads_each_ring_through_one_kernel(byte_programs):
+    """The compiled byte-level decode step: one `eva_state_attention` custom
+    call a layer, under the `lm.eva.attn` scope (what `eva_attn_ms_per_byte`
+    reads it by); ring and summary table reach it as the loop carries them,
+    in HBM - no copy, transpose or slice with a ring's or a table's shape
+    in the loop's body, and none the compiler schedules ASYNCHRONOUSLY
+    either (`copy-start` / `slice-start`: the program before the kernel
+    moved one layer's rings and tables into VMEM for the row's write and
+    back, 67 MB a step; `streamed_decode_attention` holds its operands to
+    the HBM) - and no float32 array of a whole ring's logits is left."""
+    cfg = byte_programs.cfg
+    h, d, window = cfg.num_attention_heads, cfg.head_dim, cfg.window_size
+    table = (byte_programs.t + byte_programs.spec.new_tokens) // cfg.chunk_size
+    body = _loop_body(byte_programs.decode.as_text())
+    calls = [ln for ln in body.splitlines()
+             if re.match(r"\s*%eva_state_attention[\w.\-]* = ", ln)
+             and "custom-call(" in ln]
+    assert len(calls) == cfg.num_hidden_layers == 16
+    assert all('custom_call_target="tpu_custom_call"' in ln and re.search(
+        r'op_name="[^"]*/lm\.eva\.attn/[^"]*pallas_call', ln)
+        for ln in calls)
+    moved = []
+    for ln in body.splitlines():
+        # (an asynchronous copy's result is a tuple: its first array; the
+        # opcode stands in front of the first operand)
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(*\w+\[([\d,]+)\]", ln)
+        op = re.search(r" ([\w\-]+)\(%", ln)
+        if not m or not op:
+            continue
+        dims = [int(x) for x in m.group(2).split(",")]
+        state_shaped = (len(dims) >= 2 and dims[-1] == d and math.prod(
+            dims[:-1]) in (window * h, table * h))
+        if state_shaped and (
+                op.group(1) in ("copy", "transpose", "slice", "dynamic-slice",
+                                "copy-start", "slice-start")
+                or re.match(r"(copy|transpose|slice)", m.group(1))
+                and op.group(1) == "fusion"):
+            moved.append(ln.strip()[:160])
+    assert not moved, moved
+    logits = [ln.strip()[:160] for ln in body.splitlines() if re.search(
+        rf"= f32\[(?:{h},1,{window}|{window},1,{h}|{h},{window}|"
+        rf"{window},{h})\]", ln)]
     assert not logits, logits
