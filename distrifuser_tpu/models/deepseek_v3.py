@@ -20,6 +20,9 @@ float32; ``x <- x + concat_h(o) W_o``.  A PROMPT takes the materialised
 form by query block, a SUFFIX entering a cache and a DECODE STEP the
 absorbed form (``q_nope W_UK^T`` against the cached latents themselves, the
 attended latents through ``W_UV``): the same numbers, nothing expanded.
+Under ``mla_use_nope`` (Kimi Linear's full layers: `models/kimi_linear.py`
+calls `attention_layer` with its own configuration) nothing is rotated and
+the 64-wide parts are used as projected.
 ``kv_b_proj`` [512, H * (128 + 128)] is held as its two per-head halves,
 ``k_up`` [H, 128, 512] and ``v_up`` [H, 512, 128] - the layout the absorbed
 form multiplies by, so a decode step slices no weight.
@@ -86,6 +89,9 @@ class DeepseekV3Config:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 1000000.0
+    # the 64-wide part of queries and keys is left as projected: no rotary
+    # embedding, no position in the attention at all (``mla_use_nope``)
+    mla_use_nope: bool = False
     # feed-forward
     first_k_dense_replace: int = 1
     intermediate_size: int = 6144
@@ -158,7 +164,7 @@ def _gated_mlp_shapes(d: int, f: int) -> Dict[str, Any]:
     return {"gate_up": {"kernel": (d, 2 * f)}, "down": {"kernel": (f, d)}}
 
 
-def _layer_shapes(cfg: DeepseekV3Config, dense: bool) -> Dict[str, Any]:
+def layer_shapes(cfg: DeepseekV3Config, dense: bool) -> Dict[str, Any]:
     d, h = cfg.hidden_size, cfg.num_attention_heads
     lat, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     attn = {
@@ -191,7 +197,7 @@ def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Any]:
     d = cfg.hidden_size
     return {
         "embed": (cfg.vocab_size, d),
-        "layers": [_layer_shapes(cfg, i < cfg.first_k_dense_replace)
+        "layers": [layer_shapes(cfg, i < cfg.first_k_dense_replace)
                    for i in range(cfg.num_hidden_layers)],
         "final_norm": {"scale": (d,)},
         "head": {"kernel": (d, cfg.vocab_size)},
@@ -239,13 +245,16 @@ def rms_norm(scale, x, eps: float):
 
 @jax.named_scope("lm.mla.proj")
 def _queries_and_latents(p, cfg: DeepseekV3Config, x, positions):
-    """x [T, d] -> q_nope [T, H, 128], q_pe [T, H, 64] (rotated), the
-    normalised latents c [T, 512] and the shared k_pe [T, 64] (rotated)."""
+    """x [T, d] -> q_nope [T, H, 128], q_pe [T, H, 64], the normalised
+    latents c [T, 512] and the shared k_pe [T, 64]; q_pe and k_pe rotated
+    unless the configuration says ``mla_use_nope``."""
     t = x.shape[0]
     q = (x @ p["q"]["kernel"]).reshape(t, cfg.num_attention_heads, -1)
     q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
     c, k_pe = jnp.split(x @ p["kv_a"]["kernel"], [cfg.kv_lora_rank], axis=-1)
     c = rms_norm(p["kv_norm"]["scale"], c, cfg.rms_norm_eps)
+    if cfg.mla_use_nope:
+        return q_nope, q_pe, c, k_pe
     return (q_nope, mla.rotary_interleaved(q_pe, positions, cfg.rope_theta),
             c, mla.rotary_interleaved(k_pe, positions, cfg.rope_theta))
 
@@ -330,7 +339,7 @@ def _attend(lp, cfg: DeepseekV3Config, x, cache, position, visible):
     return x + out, cache, fetched
 
 
-def _feed_forward(lp, cfg: DeepseekV3Config, x):
+def feed_forward(lp, cfg: DeepseekV3Config, x):
     """A layer's second half -> (x + FFN(RMSNorm(x)), held assignments or
     None, the experts chosen [T, top_k] or None)."""
     u = rms_norm(lp["ffn_norm"]["scale"], x, cfg.rms_norm_eps)
@@ -370,7 +379,7 @@ def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible):
     held = fetched = jnp.zeros((), jnp.int32)
     for lp, cache in zip(params["layers"], state["cache"]):
         x, cache, rows = _attend(lp, cfg, x, cache, position, visible)
-        x, n, idx = _feed_forward(lp, cfg, x)
+        x, n, idx = feed_forward(lp, cfg, x)
         caches.append(cache)
         fetched = fetched + rows
         if idx is not None:
@@ -383,7 +392,7 @@ def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible):
     return x, {"cache": caches, "experts": experts}, held, fetched
 
 
-def _assignments(cfg: DeepseekV3Config, tokens: int) -> int:
+def assignments(cfg: DeepseekV3Config, tokens: int) -> int:
     return tokens * cfg.n_expert_layers * cfg.num_experts_per_tok
 
 
@@ -417,7 +426,7 @@ def prefill(params, cfg: DeepseekV3Config, ids, *, max_len: int, state=None,
     x, state, held, _ = _forward(params, cfg, ids, state, position, visible)
     counters = jnp.stack([
         counters[0] + t, position, counters[2],
-        counters[3] + _assignments(cfg, t), counters[4] + held,
+        counters[3] + assignments(cfg, t), counters[4] + held,
         params_nbytes(state["cache"]), counters[6]]).astype(jnp.int32)
     chosen = state["experts"][:, position:position + t]
     return head(params, cfg, x[-1:])[0], state, counters, chosen
@@ -434,7 +443,7 @@ def decode(params, cfg: DeepseekV3Config, logits, state, counters, *,
     [new_tokens, V], the experts EVERY position so far chose
     [E layers, max_len, top_k] - the prompt's, a snapshot's too -, the
     state, the counters)."""
-    per_token = jnp.asarray([0, 0, 1, _assignments(cfg, 1), 0, 0, 0],
+    per_token = jnp.asarray([0, 0, 1, assignments(cfg, 1), 0, 0, 0],
                             jnp.int32)
 
     def body(i, carry):
@@ -476,6 +485,13 @@ def _balancing_layer(lp, x, *, cfg: DeepseekV3Config, rounds: int):
     """One layer of the calibration pass -> (its output, an expert layer's
     balanced bias or None).  One compiled program a kind of layer."""
     x, _, _ = _attend(lp, cfg, x, None, 0, None)
+    return balanced_feed_forward(lp, cfg, x, rounds)
+
+
+def balanced_feed_forward(lp, cfg, x, rounds: int):
+    """A layer's second half in the calibration pass, x [T, d] after its
+    mixer -> (the layer's output with its router balanced over these T
+    tokens, the balanced bias; None where the layer has no router)."""
     bias = None
     if "router" in lp["ffn"]:
         u = rms_norm(lp["ffn_norm"]["scale"], x, cfg.rms_norm_eps)
@@ -486,7 +502,7 @@ def _balancing_layer(lp, x, *, cfg: DeepseekV3Config, rounds: int):
             scores, top_k=cfg.num_experts_per_tok, rounds=rounds).astype(
                 lp["ffn"]["e_score_correction_bias"].dtype)
         lp = dict(lp, ffn=dict(lp["ffn"], e_score_correction_bias=bias))
-    x, _, _ = _feed_forward(lp, cfg, x)
+    x, _, _ = feed_forward(lp, cfg, x)
     return x, bias
 
 

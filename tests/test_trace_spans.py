@@ -937,3 +937,97 @@ def test_decode_step_reads_each_ring_through_one_kernel(byte_programs):
         rf"= f32\[(?:{h},1,{window}|{window},1,{h}|{h},{window}|"
         rf"{window},{h})\]", ln)]
     assert not logits, logits
+
+
+def test_linear_attention_rewrite_programs_compile_for_the_chip(topo):
+    """The rewrite stage's three programs at Kimi-Linear-48B-A3B's published
+    widths, one chip's share (12 layers, 32 of 256 experts), compiled for the
+    described v5e.  Prefix: the instruction's 8064 tokens - the chunked KDA
+    form `SPAN_CHUNKS` chunks at a time, so that its temporaries fit beside
+    13.3 GB of weights, the full layers by query block.  The request's
+    prefill: 128 ids ENTERING the snapshot (read, not aliased), whose state
+    is of two kinds side by side.  Decode: the donated state carried in
+    place, the full layers' attention the single-pass kernel, every expert
+    layer's routed experts one gather mat-vec call, every scope on its ops."""
+    import json
+
+    from jax.sharding import SingleDeviceSharding
+
+    from distrifuser_tpu.models import kimi_linear as lm
+    from distrifuser_tpu.pipelines import (
+        PromptRewriter,
+        RewriteSpec,
+        SimpleTokenizer,
+    )
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "kimi-linear-48b-sdxl-rewrite.json")) as f:
+        config = json.load(f)
+    cfg = lm.kimi_linear_config_from_json(config)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
+
+    def ids(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one)
+
+    params = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one),
+        lm.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    spec = RewriteSpec(**config["rewrite"])
+    rw = PromptRewriter(cfg, None, spec, [SimpleTokenizer(49408)] * 2)
+    t, n = spec.instruction_tokens + spec.user_tokens, rw._prefix_len
+    assert (t, n, t - n) == (8192, 8064, 128)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: topo.devices)
+        prefix = rw._prefix.lower(params, ids(n)).compile()
+        snapshot = jax.tree.map(on_chip, jax.eval_shape(rw._prefix, params,
+                                                        ids(n)))
+        entering = rw._prefill.lower(params, ids(t - n), snapshot).compile()
+        logits, state, counters, _ = jax.tree.map(on_chip, jax.eval_shape(
+            rw._prefill, params, ids(t - n), snapshot))
+        decode = rw._decode.lower(
+            params, logits, state, counters,
+            [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32,
+                                  sharding=one)] * 2).compile()
+
+    max_len = t + spec.new_tokens
+    kinds = [sorted(layer) for layer in state["layers"]]
+    assert kinds == [["conv", "s"]] * 3 + [["c", "k_pe"]] + kinds[4:]
+    kda_bytes = 9 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    cache_bytes = 3 * max_len * 576 * 2
+    state_bytes = (kda_bytes + cache_bytes
+                   + cfg.n_expert_layers * max_len * 8 * 4)
+    assert (kda_bytes, cache_bytes) == (19_537_920, 30_081_024)
+    assert prefix.memory_analysis().temp_size_in_bytes < 1.6e9
+    text = prefix.as_text()
+    assert "ragged-dot" in text and "expert_gather_matvec" not in text
+    assert "triangular_solve" in text  # one forward substitution a chunk
+
+    mem = entering.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 0.3e9
+    flops = [c.cost_analysis()["flops"] for c in (prefix, entering)]
+    assert flops[1] < flops[0] / 10, flops
+    assert "latent_cache_attention" not in entering.as_text()
+
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), (state, counters)) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), snapshot)
+    mem = decode.memory_analysis()
+    assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert 6.35e9 < mem.argument_size_in_bytes < 6.5e9  # weights + state
+    text = decode.as_text()
+    assert len(re.findall(r"%(expert_gather_matvec[\w.\-]*) = ", text)) \
+        == cfg.n_expert_layers == 11
+    assert len([ln for ln in text.splitlines() if re.match(
+        r"\s*%latent_cache_attention[\w.\-]* = ", ln)
+        and "custom-call(" in ln]) == 3
+    assert "ragged-dot" not in text
+    for scope in ("lm.kda.proj", "lm.kda.conv", "lm.kda.gate", "lm.kda.recur",
+                  "lm.kda.norm", "lm.mla.proj", "lm.mla.attn",
+                  "lm.moe.router", "lm.moe.experts", "lm.moe.shared",
+                  "lm.mlp", "lm.head"):
+        assert f"/{scope}/" in text, scope
