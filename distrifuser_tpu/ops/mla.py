@@ -322,8 +322,12 @@ def streamed_attention(q_lat, q_pe, c, k_pe, position, *, scale: float,
         # how the benchmark's `mla_attn_ms_per_token` finds it
         name="latent_cache_attention",
     )(jnp.asarray(position, jnp.int32).reshape(1), q_lat[0], q_pe[0],
-      # a block is one index of a leading axis (a bitcast: whole tiles)
-      c.reshape(-1, block, c_dim), k_pe.reshape(-1, block, r))
+      # a block is one index of a leading axis (a bitcast: whole tiles); held
+      # to the HBM: left to itself the compiler moves some layers' whole
+      # caches into VMEM for the row's write in front of the call and copies
+      # them back (31 / 53 MB a step in the served Kimi / Kanana programs)
+      *(pltpu.with_memory_space_constraint(a, pltpu.HBM)
+        for a in (c.reshape(-1, block, c_dim), k_pe.reshape(-1, block, r))))
     return out[None], rows[0]
 
 

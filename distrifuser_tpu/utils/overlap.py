@@ -49,6 +49,7 @@ its name.
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from typing import Dict, List
 
@@ -475,6 +476,81 @@ def exchange_report(hlo_text: str) -> Dict[str, Dict[str, Dict[str, int]]]:
             row["collectives"] += 1
             row["inline"] += c.inline
             row["bytes"] += c.nbytes
+    return out
+
+
+# -- where a loop's carried state lives while a row is written into it --------
+
+_MEMORY_SPACE = re.compile(r"S\((\d+)\)")
+
+
+def _arrays(result: str):
+    """[(dims, lane-padded bytes, memory space)] of the arrays a result type
+    names, in order.  A TPU layout pads the last axis to whole 128-lane
+    tiles (a 64-wide bf16 row moves as 128) and names its memory space
+    `S(n)`: none is the HBM, 1 the VMEM."""
+    out = []
+    for m in _LEAF.finditer(result):
+        dims = [int(d) for d in filter(None, m.group(2).split(","))]
+        if not dims:
+            continue
+        layout = result[m.end():].split("}", 1)[0] \
+            if result[m.end():m.end() + 1] == "{" else ""
+        space = _MEMORY_SPACE.search(layout)
+        n = math.prod(dims[:-1]) * -(-dims[-1] // 128) * 128
+        out.append((tuple(dims), n * _ITEMSIZE.get(m.group(1), 1),
+                    int(space.group(1)) if space else 0))
+    return out
+
+
+def cache_staging(hlo_text: str, rows: int = 0, shapes=()) -> Dict[str, int]:
+    """What a compiled TPU program's while bodies do with the state they
+    carry, beside what the program asked for: {"staged_bytes": the bytes one
+    pass moves BETWEEN memory spaces in asynchronous copies (`copy-start`,
+    `slice-start`) of which source or destination is cache-shaped, lane
+    padding counted; "staged_copies": how many; "writes": the cache-shaped
+    `dynamic-update-slice` (a row's write, alone or as a fusion's root);
+    "writes_outside_hbm": those whose result lies in another memory space}.
+
+    Cache-shaped: an array of two or more axes whose leading axes multiply
+    to ``rows`` (a cache [rows, C], or the same rows as blocks of a leading
+    axis), or one whose dims are in ``shapes``.  A program whose memory-space
+    assignment moves a whole cache into VMEM for one row's write and back
+    reads its size twice a layer here; one that writes the row in place, 0."""
+    shapes = {tuple(s) for s in shapes}
+
+    def cached(dims):
+        return dims in shapes or bool(rows) and len(dims) >= 2 and (
+            math.prod(dims[:-1]) == rows)
+
+    comps = parse_computations(hlo_text)
+    out = {"staged_bytes": 0, "staged_copies": 0, "writes": 0,
+           "writes_outside_hbm": 0}
+    for body in sorted(set(re.findall(r"body=%?([\w.\-]+)", hlo_text))):
+        for ln in comps.get(body, ()):
+            if " = " not in ln:
+                continue
+            result, rest = _split_result(ln)
+            op = _opcode(ln)
+            if op in ("copy-start", "slice-start"):
+                # (destination, source, context) / ((source), destination,
+                # context): what moves is the destination's size
+                ends = _arrays(result)[:2]
+                if len(ends) == 2 and ends[0][2] != ends[1][2] and any(
+                        cached(dims) for dims, _, _ in ends):
+                    out["staged_bytes"] += ends[op == "slice-start"][1]
+                    out["staged_copies"] += 1
+                continue
+            called = _CALLS.search(rest) if op == "fusion" else None
+            if called:
+                root = [l for l in comps.get(called.group(1), ())
+                        if l.startswith("ROOT ")]
+                op = _opcode(root[0]) if root else op
+            if op == "dynamic-update-slice":
+                for dims, _, space in _arrays(result)[:1]:
+                    if cached(dims):
+                        out["writes"] += 1
+                        out["writes_outside_hbm"] += space != 0
     return out
 
 

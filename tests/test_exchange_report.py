@@ -268,3 +268,76 @@ def test_an_instructions_opcode_and_wire_bytes(line, opcode, nbytes):
     result, rest = overlap._split_result(line)
     assert rest.startswith(opcode + "(") and overlap._opcode(line) == opcode
     assert overlap._wire_bytes(opcode, result) == nbytes
+
+
+# A decode loop's body as a TPU compiler leaves it around two layers' caches
+# of 64 rows: the first layer's `c` comes into VMEM whole, has its row
+# written there and goes back, its 64-wide `k_pe` comes in two slices (joined
+# by a bitcast) and goes back in one copy; the second layer's two rows are
+# written in place; a weight prefetch of another shape stands beside them.
+STAGED_TEXT = '''
+HloModule jit_rewrite_decode, is_scheduled=true
+
+%write_row (p0: bf16[64,64], p1: bf16[1,64], p2: s32[]) -> bf16[64,64] {
+  %p0 = bf16[64,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[1,64]{1,0:T(2,128)(2,1)} parameter(1)
+  %p2 = s32[]{:T(128)} parameter(2)
+  %zero = s32[]{:T(128)} constant(0)
+  ROOT %dynamic-update-slice.9 = bf16[64,64]{1,0:T(8,128)(2,1)} dynamic-update-slice(%p0, %p1, %p2, %zero)
+}
+
+%body (carry: (s32[], bf16[64,512], bf16[64,64], bf16[64,512], bf16[64,64])) -> (s32[], bf16[64,512], bf16[64,64], bf16[64,512], bf16[64,64]) {
+  %carry = (s32[]{:T(128)}, bf16[64,512]{1,0:T(8,128)(2,1)}, bf16[64,64]{1,0:T(8,128)(2,1)}, bf16[64,512]{1,0:T(8,128)(2,1)}, bf16[64,64]{1,0:T(8,128)(2,1)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%carry), index=0
+  %zero = s32[]{:T(128)} constant(0)
+  %c0 = bf16[64,512]{1,0:T(8,128)(2,1)} get-tuple-element(%carry), index=1
+  %k0 = bf16[64,64]{1,0:T(8,128)(2,1)} get-tuple-element(%carry), index=2
+  %c1 = bf16[64,512]{1,0:T(8,128)(2,1)} get-tuple-element(%carry), index=3
+  %k1 = bf16[64,64]{1,0:T(8,128)(2,1)} get-tuple-element(%carry), index=4
+  %copy-start.1 = (bf16[64,512]{1,0:T(8,128)(2,1)S(1)}, bf16[64,512]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%c0)
+  %slice-start.1 = ((bf16[64,64]{1,0:T(8,128)(2,1)}), bf16[32,64]{1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%k0), slice={[0:32], [0:64]}
+  %slice-start.2 = ((bf16[64,64]{1,0:T(8,128)(2,1)}), bf16[32,64]{1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%k0), slice={[32:64], [0:64]}
+  %copy-start.7 = (bf16[2048]{0:T(1024)(128)(2,1)S(1)}, bf16[2048]{0:T(1024)(128)(2,1)}, u32[]{:S(2)}) copy-start(%weight)
+  %copy-done.1 = bf16[64,512]{1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.1)
+  %slice-done.1 = bf16[32,64]{1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start.1)
+  %slice-done.2 = bf16[32,64]{1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start.2)
+  %joined = bf16[64,64]{1,0:T(8,128)(2,1)S(1)} custom-call(%slice-done.1, %slice-done.2), custom_call_target="ConcatBitcast"
+  %dynamic_update_slice.1 = bf16[64,512]{1,0:T(8,128)(2,1)S(1)} dynamic-update-slice(%copy-done.1, %c_row, %i, %zero), metadata={op_name="jit(rewrite_decode)/while/body/closed_call/lm.mla.attn/dynamic_update_slice"}
+  %dynamic_update_slice.2 = bf16[64,64]{1,0:T(8,128)(2,1)S(1)} dynamic-update-slice(%joined, %k_row, %i, %zero)
+  %latent_cache_attention.1 = (bf16[32,512]{1,0:T(8,128)(2,1)}, s32[1]{0:T(128)S(6)}) custom-call(%i, %q_lat, %q_pe, %dynamic_update_slice.1, %dynamic_update_slice.2), custom_call_target="tpu_custom_call"
+  %copy-start.2 = (bf16[64,512]{1,0:T(8,128)(2,1)}, bf16[64,512]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(%dynamic_update_slice.1)
+  %copy-start.3 = (bf16[64,64]{1,0:T(8,128)(2,1)}, bf16[64,64]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(%dynamic_update_slice.2)
+  %copy-done.2 = bf16[64,512]{1,0:T(8,128)(2,1)} copy-done(%copy-start.2)
+  %copy-done.3 = bf16[64,64]{1,0:T(8,128)(2,1)} copy-done(%copy-start.3)
+  %dynamic_update_slice.3 = bf16[64,512]{1,0:T(8,128)(2,1)} dynamic-update-slice(%c1, %c_row, %i, %zero)
+  %row_fusion.4 = bf16[64,64]{1,0:T(8,128)(2,1)} fusion(%k1, %k_row, %i), kind=kLoop, calls=%write_row
+  %latent_cache_attention.2 = (bf16[32,512]{1,0:T(8,128)(2,1)}, s32[1]{0:T(128)S(6)}) custom-call(%i, %q_lat, %q_pe, %dynamic_update_slice.3, %row_fusion.4), custom_call_target="tpu_custom_call"
+  ROOT %tuple.9 = (s32[]{:T(128)}, bf16[64,512]{1,0:T(8,128)(2,1)}, bf16[64,64]{1,0:T(8,128)(2,1)}, bf16[64,512]{1,0:T(8,128)(2,1)}, bf16[64,64]{1,0:T(8,128)(2,1)}) tuple(%i, %copy-done.2, %copy-done.3, %dynamic_update_slice.3, %row_fusion.4)
+}
+
+ENTRY %main (x: s32[]) -> s32[] {
+  %x = s32[] parameter(0)
+  %while.1 = (s32[], bf16[64,512]{1,0}, bf16[64,64]{1,0}, bf16[64,512]{1,0}, bf16[64,64]{1,0}) while(%init), condition=%cond, body=%body
+  ROOT %r = s32[] get-tuple-element(%while.1), index=0
+}
+'''
+
+
+def test_a_staged_cache_is_counted_and_one_written_in_place_is_not():
+    c, k_pe = 64 * 512 * 2, 64 * 128 * 2  # a 64-wide row moves as 128 lanes
+    assert overlap.cache_staging(STAGED_TEXT, 64) == {
+        "staged_bytes": 2 * c + 2 * k_pe, "staged_copies": 5,
+        "writes": 4, "writes_outside_hbm": 2}
+    # by shape, where the state's leading axes are not its rows alone
+    assert overlap.cache_staging(STAGED_TEXT, shapes=[(64, 512)]) == {
+        "staged_bytes": 2 * c, "staged_copies": 2,
+        "writes": 2, "writes_outside_hbm": 1}
+    # another length's caches, and a program with no loop: nothing to count
+    nothing = {"staged_bytes": 0, "staged_copies": 0, "writes": 0,
+               "writes_outside_hbm": 0}
+    assert overlap.cache_staging(STAGED_TEXT, 8704) == nothing
+    in_place = STAGED_TEXT.replace("S(1)", "")
+    assert overlap.cache_staging(in_place, 64) == {
+        **nothing, "writes": 4}
+    assert overlap.cache_staging(
+        STAGED_TEXT.replace("body=%body", ""), 64) == nothing

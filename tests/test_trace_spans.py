@@ -740,17 +740,15 @@ def test_byte_level_rewrite_programs_compile_for_the_chip(byte_programs):
     assert flops[1] < flops[0] / 20, flops
 
 
-@pytest.fixture(scope="module")
-def latent_programs(topo):
-    """The rewrite stage's three programs at Kanana-2-30B-A3B's published
-    widths, one chip's share (24 layers, 16 of 128 experts), compiled for the
-    described v5e, with the shapes they were compiled from."""
+def _rewrite_programs(topo, lm, from_json, config_file):
+    """The rewrite stage's three programs of one language model at its
+    published widths, one chip's share, compiled for the described v5e, with
+    the shapes they were compiled from."""
     import json
     import types
 
     from jax.sharding import SingleDeviceSharding
 
-    from distrifuser_tpu.models import deepseek_v3 as lm
     from distrifuser_tpu.pipelines import (
         PromptRewriter,
         RewriteSpec,
@@ -758,10 +756,9 @@ def latent_programs(topo):
     )
 
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmark", "configs",
-                           "kanana-2-30b-sdxl-rewrite.json")) as f:
+    with open(os.path.join(here, "benchmark", "configs", config_file)) as f:
         config = json.load(f)
-    cfg = lm.deepseek_v3_config_from_json(config)
+    cfg = from_json(config)
     one = SingleDeviceSharding(topo.devices[0])
 
     def on_chip(a):
@@ -793,6 +790,24 @@ def latent_programs(topo):
     return types.SimpleNamespace(
         cfg=cfg, spec=spec, t=t, n=n, prefix=prefix, entering=entering,
         decode=decode, snapshot=snapshot, state=state, counters=counters)
+
+
+@pytest.fixture(scope="module")
+def latent_programs(topo):
+    """Kanana-2-30B-A3B: 24 layers, 16 of 128 experts."""
+    from distrifuser_tpu.models import deepseek_v3 as lm
+
+    return _rewrite_programs(topo, lm, lm.deepseek_v3_config_from_json,
+                             "kanana-2-30b-sdxl-rewrite.json")
+
+
+@pytest.fixture(scope="module")
+def linear_programs(topo):
+    """Kimi-Linear-48B-A3B: 12 layers, 32 of 256 experts."""
+    from distrifuser_tpu.models import kimi_linear as lm
+
+    return _rewrite_programs(topo, lm, lm.kimi_linear_config_from_json,
+                             "kimi-linear-48b-sdxl-rewrite.json")
 
 
 def test_latent_attention_rewrite_programs_compile_for_the_chip(
@@ -939,7 +954,8 @@ def test_decode_step_reads_each_ring_through_one_kernel(byte_programs):
     assert not logits, logits
 
 
-def test_linear_attention_rewrite_programs_compile_for_the_chip(topo):
+def test_linear_attention_rewrite_programs_compile_for_the_chip(
+        linear_programs):
     """The rewrite stage's three programs at Kimi-Linear-48B-A3B's published
     widths, one chip's share (12 layers, 32 of 256 experts), compiled for the
     described v5e.  Prefix: the instruction's 8064 tokens - the chunked KDA
@@ -949,50 +965,11 @@ def test_linear_attention_rewrite_programs_compile_for_the_chip(topo):
     is of two kinds side by side.  Decode: the donated state carried in
     place, the full layers' attention the single-pass kernel, every expert
     layer's routed experts one gather mat-vec call, every scope on its ops."""
-    import json
-
-    from jax.sharding import SingleDeviceSharding
-
-    from distrifuser_tpu.models import kimi_linear as lm
-    from distrifuser_tpu.pipelines import (
-        PromptRewriter,
-        RewriteSpec,
-        SimpleTokenizer,
-    )
-
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(here, "benchmark", "configs",
-                           "kimi-linear-48b-sdxl-rewrite.json")) as f:
-        config = json.load(f)
-    cfg = lm.kimi_linear_config_from_json(config)
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def on_chip(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
-
-    def ids(n):
-        return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one)
-
-    params = jax.tree.map(
-        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one),
-        lm.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    spec = RewriteSpec(**config["rewrite"])
-    rw = PromptRewriter(cfg, None, spec, [SimpleTokenizer(49408)] * 2)
-    t, n = spec.instruction_tokens + spec.user_tokens, rw._prefix_len
+    lp = linear_programs
+    cfg, spec, t, n = lp.cfg, lp.spec, lp.t, lp.n
+    prefix, entering, decode = lp.prefix, lp.entering, lp.decode
+    snapshot, state, counters = lp.snapshot, lp.state, lp.counters
     assert (t, n, t - n) == (8192, 8064, 128)
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(jax, "devices", lambda *a, **k: topo.devices)
-        prefix = rw._prefix.lower(params, ids(n)).compile()
-        snapshot = jax.tree.map(on_chip, jax.eval_shape(rw._prefix, params,
-                                                        ids(n)))
-        entering = rw._prefill.lower(params, ids(t - n), snapshot).compile()
-        logits, state, counters, _ = jax.tree.map(on_chip, jax.eval_shape(
-            rw._prefill, params, ids(t - n), snapshot))
-        decode = rw._decode.lower(
-            params, logits, state, counters,
-            [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32,
-                                  sharding=one)] * 2).compile()
-
     max_len = t + spec.new_tokens
     kinds = [sorted(layer) for layer in state["layers"]]
     assert kinds == [["conv", "s"]] * 3 + [["c", "k_pe"]] + kinds[4:]
@@ -1031,3 +1008,26 @@ def test_linear_attention_rewrite_programs_compile_for_the_chip(topo):
                   "lm.moe.router", "lm.moe.experts", "lm.moe.shared",
                   "lm.mlp", "lm.head"):
         assert f"/{scope}/" in text, scope
+
+
+@pytest.mark.parametrize("programs, latent_layers", [
+    ("linear_programs", 3), ("latent_programs", 24)])
+def test_decode_step_writes_each_cache_row_in_hbm(request, programs,
+                                                  latent_layers):
+    """The compiled decode step leaves every latent cache where the loop
+    carries it: no asynchronous copy (`copy-start` / `slice-start`) moves a
+    cache-shaped array between memory spaces in the loop's body, and every
+    row's `dynamic-update-slice` - one into `c`, one into `k_pe` a layer -
+    lands in the HBM.  (Before `streamed_attention` held its two cache
+    operands there, the compiler moved whole caches into VMEM for the row's
+    write and back: 31 MB a token in Kimi's program, 53 in Kanana's.)"""
+    from distrifuser_tpu.utils.overlap import cache_staging
+
+    lp = request.getfixturevalue(programs)
+    max_len = lp.t + lp.spec.new_tokens
+    caches = [a for a in jax.tree.leaves(lp.state)
+              if a.ndim == 2 and a.shape[0] == max_len]
+    assert len(caches) == 2 * latent_layers
+    staging = cache_staging(lp.decode.as_text(), max_len)
+    assert staging == {"staged_bytes": 0, "staged_copies": 0,
+                       "writes": 2 * latent_layers, "writes_outside_hbm": 0}
