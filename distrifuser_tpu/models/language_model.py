@@ -12,7 +12,14 @@ the model's module:
         -> (new ids [new_tokens] int32, the float32 logits each was chosen
             from [new_tokens, ...], what it records of the decoded ids, the
             state, the counters) - all ``new_tokens`` greedy steps in one
-            loop on the device
+            loop on the device.  ``logits`` is what ``prefill`` returned:
+            "what follows the prompt" only for a model that predicts the
+            NEXT position - one whose logits at a position predict that
+            position (it decodes by unmasking) ignores them.  A trip of the
+            loop need not yield one id: a model that decodes a block of
+            positions together takes ``new_tokens`` in multiples of its
+            ``decode_multiple``, and records (third result) what of a
+            block's passes the ids and logits alone do not say
 
 and, where the model can take a prompt's suffix into the state its prefix
 left (``prefill_from`` is None where it cannot):
@@ -44,3 +51,6 @@ class LanguageModel(NamedTuple):
     # model has a vocabulary of words
     byte_offset: Optional[int] = None
     prefill_from: Optional[Callable] = None
+    # ``new_tokens`` is a multiple of this: the ids a trip of the decode
+    # loop yields (a block, for a model that decodes by blocks)
+    decode_multiple: int = 1

@@ -21,6 +21,7 @@ for long sequences (ops/flash_attention.py).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -252,3 +253,51 @@ def causal_gqa_sdpa(q, k, v, *, q_positions):
     logits = jnp.where(visible[None, None], logits, -jnp.inf)
     w = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("kgts,skd->tkgd", w, v).reshape(t, hq, d)
+
+
+# Queries a block of `gqa_sdpa_by_query_block`: `ops/mla.py QUERY_BLOCK`'s
+# timings at 32 query heads against 8192 keys hold here (a block's
+# [32, rows, S] float32 logits go through HBM at every pass of the softmax)
+GQA_QUERY_BLOCK = 32
+
+
+def gqa_sdpa_by_query_block(q, k, v, *, q_positions,
+                            block: int = GQA_QUERY_BLOCK):
+    """`causal_gqa_sdpa` without the [Hq, T, S] array: the queries go
+    ``block`` at a time (the largest divisor of T that ``block`` holds), so
+    that at most [Hq, block, S] float32 logits are alive - a prompt of
+    thousands of rows, a suffix entering a cache, or the few rows of a
+    decode pass (T <= ``block``: one block, no loop).
+
+    ``q`` [T, Hq, D]; ``k`` / ``v`` [Hkv, S, D], KV-HEAD MAJOR - the layout
+    of a cache whose rows are a position's [D] numbers, so that a row of few
+    KV heads pads no tile; ``q_positions`` [T]: query ``i`` sees keys
+    ``0 .. q_positions[i]`` - its own position for causal attention, the
+    last position of its block for attention by blocks (both directions
+    inside a block, everything before it) - so rows of a cache not written
+    yet are never read into the result.  ``k`` / ``v`` may be held a
+    precision below ``q``: they are read in ``q``'s dtype.  Softmax in
+    float32, the MXU fed the model dtype.  -> [T, Hq, D]."""
+    t, hq, d = q.shape
+    hkv, s, _ = k.shape
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads over {hkv} KV heads")
+    k, v = k.astype(q.dtype), v.astype(q.dtype)
+    keys = jnp.arange(s)
+
+    def one(args):
+        qb, positions = args
+        qb = qb.reshape(qb.shape[0], hkv, hq // hkv, d)
+        logits = jnp.einsum("tkgd,ksd->kgts", qb, k,
+                            preferred_element_type=jnp.float32) / d**0.5
+        visible = keys[None, :] <= positions[:, None]
+        w = jax.nn.softmax(jnp.where(visible[None, None], logits, -jnp.inf),
+                           axis=-1).astype(v.dtype)
+        return jnp.einsum("kgts,ksd->tkgd", w, v).reshape(-1, hq, d)
+
+    block = math.gcd(t, block)
+    if block == t:
+        return one((q, q_positions))
+    out = lax.map(one, (q.reshape(t // block, block, hq, d),
+                        q_positions.reshape(t // block, block)))
+    return out.reshape(t, hq, d)

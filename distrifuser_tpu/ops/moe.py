@@ -61,18 +61,28 @@ _W1_GROUPS = {"relu2": 1, "silu": 2}
 MIN_GROUPED_ROWS = 64
 
 
-def route(u, router_kernel, score_bias, *, top_k: int, scale: float):
-    """Sigmoid router with a selection-only bias (DeepSeek-V3 style,
-    ``n_group`` 1: no group limit).
+def route(u, router_kernel, score_bias=None, *, top_k: int,
+          scale: float = 1.0, scoring: str = "sigmoid"):
+    """A router over ALL experts, in float32 throughout.
 
-    ``u`` [T, D]; ``router_kernel`` [D, E]; ``score_bias`` [E].  In float32
-    throughout: s = sigmoid(u W); the ``top_k`` largest of s + bias are
-    chosen; their weights are ``scale * s_i / sum of the chosen s``.
+    ``u`` [T, D]; ``router_kernel`` [D, E].  ``scoring`` "sigmoid"
+    (DeepSeek-V3 style, ``n_group`` 1: no group limit): s = sigmoid(u W), a
+    selection-only ``score_bias`` [E] added for the choice alone; "softmax"
+    (Qwen3-MoE style, ``norm_topk_prob``): s = softmax(u W) over all E, no
+    bias.  The ``top_k`` largest of s (+ bias) are chosen; their weights are
+    ``scale * s_i / sum of the chosen s``.
     Returns (expert ids [T, top_k] int32, weights [T, top_k] float32)."""
     logits = jnp.dot(u.astype(F32), router_kernel.astype(F32),
                      precision=lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
-    _, idx = lax.top_k(s + score_bias.astype(F32), top_k)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"a router scores by sigmoid or softmax, not "
+                         f"{scoring!r}")
+    select = s if score_bias is None else s + score_bias.astype(F32)
+    _, idx = lax.top_k(select, top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), weights
@@ -269,8 +279,11 @@ def local_expert_sum(x, idx, weights, w1, w2, *, first_expert: int,
     Returns ([T, d] float32, how many of the T * k assignments fell on held
     experts).
 
-    Fewer than `MIN_GROUPED_ROWS` assignments (a decode step) on a TPU go
-    through `gather_expert_sum`; everything else is the grouped matmul."""
+    Fewer than `MIN_GROUPED_ROWS` assignments (a decode step, or the few
+    rows of a block-diffusion decode pass) on a TPU go through
+    `gather_expert_sum` - which fetches per held (token, expert)
+    ASSIGNMENT: an expert two rows of the call chose crosses the HBM twice
+    -; everything else is the grouped matmul."""
     t, k = idx.shape
     _, f, d = w2.shape
     if (t * k < MIN_GROUPED_ROWS and d % 128 == 0 and f % 128 == 0

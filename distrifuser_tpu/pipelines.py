@@ -240,7 +240,8 @@ class RewriteSpec:
     bytes for a byte-level model): a fixed instruction of
     ``instruction_tokens`` ids (drawn once from ``instruction_seed`` over the
     ids a text can hold), then ``user_tokens`` ids of the caller's text,
-    ``new_tokens`` decoded greedily with no early stop, of which the last
+    ``new_tokens`` decoded greedily with no early stop (whole trips of the
+    model's decode loop: `LanguageModel.decode_multiple`), of which the last
     ``prompt_tokens`` are the rewritten prompt (what follows the thinking
     trace, by position)."""
 
@@ -264,7 +265,9 @@ class ServedRewrite(NamedTuple):
     # what the model records beside ids and logits, of the prompt and of the
     # decoded ids (a model with routed experts: the experts every token
     # chose, [E layers, prompt, top_k] and [new_tokens, E layers, top_k];
-    # one with nothing to record: (), ())
+    # one that decodes a block of positions in several passes: in which
+    # pass each id was fixed, and the experts every pass's rows chose; one
+    # with nothing to record: (), ())
     experts: Any
 
 
@@ -345,6 +348,11 @@ class PromptRewriter:
                 f"{lm.prompt_multiple}")
         if not 0 < spec.prompt_tokens <= spec.new_tokens:
             raise ValueError("prompt_tokens must lie in 1..new_tokens")
+        if spec.new_tokens % lm.decode_multiple:
+            raise ValueError(
+                f"new_tokens = {spec.new_tokens} is not a multiple of the "
+                f"{lm.decode_multiple} ids a trip of the model's decode "
+                f"loop yields")
         by_bytes = lm.byte_offset is not None
         # ids of the language model that make one id of a text encoder
         per_id = GROUP_BYTES if by_bytes else 1

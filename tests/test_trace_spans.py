@@ -1031,3 +1031,70 @@ def test_decode_step_writes_each_cache_row_in_hbm(request, programs,
     staging = cache_staging(lp.decode.as_text(), max_len)
     assert staging == {"staged_bytes": 0, "staged_copies": 0,
                        "writes": 2 * latent_layers, "writes_outside_hbm": 0}
+
+
+@pytest.fixture(scope="module")
+def block_programs(topo):
+    """SDAR-30B-A3B-Chat: 24 layers, 16 of 128 experts, blocks of 4."""
+    from distrifuser_tpu.models import sdar as lm
+
+    return _rewrite_programs(topo, lm, lm.sdar_config_from_json,
+                             "sdar-30b-a3b-sdxl-rewrite.json")
+
+
+def test_block_diffusion_rewrite_programs_compile_for_the_chip(
+        block_programs):
+    """Prefix: the instruction's 8064 tokens under the block rule, the
+    grouped-query attention by query blocks of 32 - no array with the
+    prompt's length twice among its dims -, the experts on the grouped
+    matmul.  The request's prefill: 128 ids ENTERING the snapshot (read, not
+    aliased) at a tenth of the whole prompt's FLOPs.  Decode: the donated
+    state - 24 KV caches [4, 8704, 128] twice and the record of the experts
+    chosen - carried in place; the pass traced TWICE (the denoise passes an
+    inner loop with the head, the commit pass without), so every layer's
+    experts are two calls of the gather kernel over a pass's four rows and
+    the head is there once; the language model's scopes on its ops; weights
+    and state fit."""
+    lp = block_programs
+    cfg, t, n = lp.cfg, lp.t, lp.n
+    assert (t, n, t - n) == (8192, 8064, 128)
+    max_len, layers = t + lp.spec.new_tokens, cfg.num_hidden_layers
+    cache_bytes = layers * 2 * 4 * max_len * 128 * 2
+    state_bytes = cache_bytes + layers * max_len * 8 * 4
+    assert cache_bytes == 427_819_008
+
+    text = lp.prefix.as_text()
+    shapes = {tuple(int(x) for x in dims.split(","))
+              for dims in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
+    assert (4, 8, 32, n) in shapes  # one query block's logits
+    assert not [s for s in shapes if s.count(n) >= 2]
+    assert "ragged-dot" in text and "expert_gather_matvec" not in text
+    assert lp.prefix.memory_analysis().temp_size_in_bytes < 2.0e9
+
+    mem = lp.entering.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 0.3e9
+    flops = [c.cost_analysis()["flops"] for c in (lp.prefix, lp.entering)]
+    assert flops[1] < flops[0] / 10, flops
+
+    assert jax.tree.map(lambda a: (a.shape, a.dtype),
+                        (lp.state, lp.counters)) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot)
+    mem = lp.decode.memory_analysis()
+    assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert 5.1e9 < mem.argument_size_in_bytes < 5.2e9  # weights + state
+    text = lp.decode.as_text()
+    kernels = re.findall(r"%(expert_gather_matvec[\w.\-]*) = ", text)
+    assert len(kernels) == 2 * layers and "ragged-dot" not in text
+    # the head's matmul [4, 2048] x [2048, 18992]: in the denoise passes'
+    # body alone
+    assert len(re.findall(r"= f32\[4,18992\]\S* (?:fusion|convolution|dot)\(",
+                          text)) >= 1
+    comps = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    bodies = [c for c in comps if "expert_gather_matvec" in c]
+    assert len(bodies) == 2
+    assert sorted("f32[4,18992]" in c for c in bodies) == [False, True]
+    for scope in ("lm.attn.proj", "lm.attn", "lm.moe.router",
+                  "lm.moe.experts", "lm.head", "lm.sdar.unmask"):
+        assert f"/{scope}/" in text, scope
