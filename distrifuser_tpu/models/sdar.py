@@ -58,10 +58,26 @@ block of B positions after the prompt:
 A pass writes its block's keys and values into the cache rows the block
 will own and reads rows ``0 .. end of the block``: no position before the
 commit pass's write reads those rows, so a denoise pass leaves nothing in
-the cache that anything sees - one pass program (traced with the head and
-without it), the T denoise passes an inner loop, all blocks one loop on the
-device.  Departures, each also in the benchmark configuration's
-``assumed``: the MASK id is the LAST id of the held vocabulary (published:
+the cache that anything sees.  **A pass is a block's B rows through the
+stack; a SWEEP is a trip over the stack's weights, and two passes may share
+one**: a block's commit pass and its successor's first denoise pass are the
+2B rows of one sweep at the finished block's position.  Under the block
+rule the first B see keys up to their block's end and the last B - MASK ids
+- up to theirs, which includes the first B's keys and values OF THE SAME
+LAYER, written into the cache before that layer's attention reads it:
+layer by layer the first B rows compute what a commit pass alone computes
+and the last B what a first denoise pass alone computes, and weights and
+cache cross the memory once for both (the experts in a call a block:
+`moe_layer`).  The head, the unmasking and the record take the last B rows.
+Block 0's first denoise pass and the last block's commit pass have no
+partner: ``blocks * T + 1`` sweeps for ``blocks * (T + 1)`` passes.  The
+stack is traced three times - a denoise pass alone (the inner loop over a
+block's passes, which block 0 enters at pass 0 and every later block at
+pass 1), the shared sweep, the last commit pass (a conditional's branches)
+-, all blocks one loop on the device.
+
+Departures, each also in the benchmark configuration's ``assumed``: the
+MASK id is the LAST id of the held vocabulary (published:
 151669, which a slice does not hold); it is never chosen - its logit is
 left out of candidate and confidence (a trained model does not emit it; a
 seeded one would once in a vocabulary's worth of tokens, and that block
@@ -95,8 +111,11 @@ F32 = jnp.float32
 
 # counters the generation returns with its ids; ``tokens_reused``: of the
 # positions the cache covers after prefill, those a cache handed in already
-# covered; ``denoise_passes`` / ``commit_passes``: trips of the stack over a
-# block's rows, with the head and without; ``expert_assignments`` (and
+# covered; ``denoise_passes`` / ``commit_passes``: a block's rows through the
+# stack, with the head and without; ``stack_sweeps``: trips over the stack's
+# weights - the passes less the sweeps two passes share (with B rows a pass
+# and ``blocks`` blocks: (passes - sweeps) / (blocks - 1) of the commit
+# passes rode a denoise pass); ``expert_assignments`` (and
 # ``_held``: those on experts held here): (row, layer, chosen expert)
 # triples of the prefill's rows and of every pass's; ``experts_fetched``:
 # expert weight blocks the DECODE PASSES' expert calls fetched - one per
@@ -106,7 +125,8 @@ F32 = jnp.float32
 # ``kv_cache_bytes``: every layer's keys and values
 COUNTERS = ("tokens_prefilled", "tokens_reused", "tokens_decoded",
             "denoise_passes", "commit_passes", "expert_assignments",
-            "expert_assignments_held", "experts_fetched", "kv_cache_bytes")
+            "expert_assignments_held", "experts_fetched", "kv_cache_bytes",
+            "stack_sweeps")
 _C = {name: i for i, name in enumerate(COUNTERS)}
 
 
@@ -303,9 +323,9 @@ def attention_layer(p, cfg: SdarConfig, x, cache, position,
     are written into ``cache`` {"k", "v"} [Hkv, max_len, D] first.
 
     ``visible`` None and ``position`` 0 (static): a whole prompt, over its
-    own keys.  Otherwise against the cache: its first ``visible`` rows
-    (static, at least position + T: a suffix entering it) or, ``visible``
-    None, all of them under the mask (a pass of the decode loop).
+    own keys.  Otherwise against the cache's first ``visible`` rows (static,
+    at least position + T): a suffix entering it, or - all of them, under
+    the mask - a sweep of the decode loop.
     -> (the layer's output [T, d], the cache)."""
     t = x.shape[0]
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -327,7 +347,7 @@ def attention_layer(p, cfg: SdarConfig, x, cache, position,
                 cache["k"], k.astype(cache["k"].dtype), position, axis=1),
             "v": lax.dynamic_update_slice_in_dim(
                 cache["v"], v.astype(cache["v"].dtype), position, axis=1)}
-        if visible is None and isinstance(position, int):
+        if visible is None:
             if position:
                 raise ValueError(f"position {position} needs the cache of "
                                  f"the tokens before it")
@@ -341,18 +361,24 @@ def attention_layer(p, cfg: SdarConfig, x, cache, position,
         return out.reshape(t, hq * hd) @ p["o_proj"]["kernel"], cache
 
 
-def moe_layer(p, cfg: SdarConfig, u):
+def moe_layer(p, cfg: SdarConfig, u, calls: int = 1):
     """-> (out [T, d] float32, how many of the T * top_k assignments fell on
-    experts held here, the experts each row chose [T, top_k])."""
+    experts held here, the experts each row chose [T, top_k]).  The rows go
+    to the experts in ``calls`` equal parts, a call each: the two blocks of
+    a shared sweep each take the kernel a pass's few rows take
+    (`ops/moe.py MIN_GROUPED_ROWS`), as they did a pass apiece."""
     with jax.named_scope("lm.moe.router"):
         idx, weights = moe.route(u, p["router"]["kernel"],
                                  top_k=cfg.num_experts_per_tok,
                                  scoring="softmax")
     with jax.named_scope("lm.moe.experts"):
-        routed, held = moe.local_expert_sum(
-            u, idx, weights, p["experts"]["w1"], p["experts"]["w2"],
+        parts = [moe.local_expert_sum(
+            *part, p["experts"]["w1"], p["experts"]["w2"],
             first_expert=cfg.first_local_expert, activation="silu")
-    return routed, held, idx
+            for part in zip(*(jnp.split(a, calls)
+                              for a in (u, idx, weights)))]
+    return (jnp.concatenate([routed for routed, _ in parts]),
+            sum(held for _, held in parts), idx)
 
 
 @jax.named_scope("lm.head")
@@ -374,10 +400,12 @@ def empty_state(cfg: SdarConfig, max_len: int, dtype):
                                   cfg.num_experts_per_tok), jnp.int32)}
 
 
-def _forward(params, cfg: SdarConfig, ids, state, position, visible):
+def _forward(params, cfg: SdarConfig, ids, state, position, visible,
+             expert_calls: int = 1):
     """The stack over ids [T] (whole blocks) at ``position`` onward through
-    the state -> (hidden [T, d], the new state, held expert assignments,
-    the experts the rows chose [layers, T, top_k])."""
+    the state, every layer's experts in ``expert_calls`` calls (`moe_layer`)
+    -> (hidden [T, d], the new state, held expert assignments, the experts
+    the rows chose [layers, T, top_k])."""
     x = params["embed"][ids]
     caches, chosen = [], []
     held = jnp.zeros((), jnp.int32)
@@ -389,7 +417,7 @@ def _forward(params, cfg: SdarConfig, ids, state, position, visible):
         x = x + out
         out, n, idx = moe_layer(
             lp["ffn"], cfg, rms_norm(lp["ffn_norm"]["scale"], x,
-                                     cfg.rms_norm_eps))
+                                     cfg.rms_norm_eps), expert_calls)
         x = x + out.astype(x.dtype)
         caches.append(cache)
         chosen.append(idx)
@@ -473,9 +501,10 @@ def decode(params, cfg: SdarConfig, logits, state, counters, *,
     """Generation by diffusion over blocks through the state, on the device
     from first block to last (the module's docstring has the procedure):
     ``new_tokens / block_length`` blocks, each ``denoising_steps`` denoise
-    passes and one commit pass.  ``logits`` - what the prefill returned -
-    are NOT read: a block starts from MASK ids, and a masked position's
-    logits predict that position.
+    passes and one commit pass, a block's commit pass and its successor's
+    first denoise pass ONE sweep of the stack over both blocks' rows.
+    ``logits`` - what the prefill returned - are NOT read: a block starts
+    from MASK ids, and a masked position's logits predict that position.
     -> (ids [new_tokens] int32, the float32 logits each was fixed from
     [new_tokens, V], the record {"fixed_in_pass" [new_tokens]: the denoise
     pass of its block that fixed each id; "denoise_experts"
@@ -489,55 +518,88 @@ def decode(params, cfg: SdarConfig, logits, state, counters, *,
         raise ValueError(f"new_tokens {new_tokens} is not whole blocks of "
                          f"{size}")
     blocks = new_tokens // size
-    a_pass = assignments(cfg, size)
+    masks = jnp.full((size,), cfg.mask_id, jnp.int32)
 
-    def one_pass(block_ids, state, counters, start, **passes):
+    def sweep(rows, state, counters, start, **passes):
+        """One trip over the stack's weights: ``rows`` the ids of one block
+        (a pass) or of two (a commit pass and the next block's first denoise
+        pass), each block's experts a call of their own - a pass's few rows
+        take the gather kernel, which fetches an expert's weights per held
+        ASSIGNMENT."""
+        n = rows.shape[0] // size
         x, state, held, chosen = _forward(
-            params, cfg, block_ids, state, start, None)
-        # a pass's few rows take the gather kernel, which fetches an
-        # expert's weights per held ASSIGNMENT
+            params, cfg, rows, state, start,
+            state["cache"][0]["k"].shape[1], expert_calls=n)
         return x, state, chosen, _count(
-            counters, expert_assignments=a_pass, expert_assignments_held=held,
-            experts_fetched=held, **passes)
+            counters, stack_sweeps=1,
+            expert_assignments=assignments(cfg, n * size),
+            expert_assignments_held=held, experts_fetched=held, **passes)
+
+    def fix(b, t, block_ids, x, chosen, out):
+        """What denoise pass ``t`` of block ``b`` does with its rows out of
+        the stack: the head, the positions it fixes, the record."""
+        fixed_from, fixed_in, denoise_experts = out
+        logits = head(params, cfg, x)
+        block_ids, fixed = unmask(cfg, logits, block_ids)
+        with jax.named_scope("lm.sdar.unmask"):
+            fixed_from = fixed_from.at[b * size + fixed].set(logits[fixed])
+            fixed_in = fixed_in.at[b * size + fixed].set(t)
+        denoise_experts = lax.dynamic_update_slice(
+            denoise_experts, chosen.swapaxes(0, 1)[None, None],
+            (b, t, 0, 0, 0))
+        return block_ids, (fixed_from, fixed_in, denoise_experts)
+
+    def shared(b, done, state, out, counters):
+        """The finished block ``b``'s commit pass and its successor's first
+        denoise pass, one sweep: the successor's rows see the keys and
+        values the same sweep commits, layer by layer."""
+        x, state, chosen, counters = sweep(
+            jnp.concatenate([done, masks]), state, counters,
+            position + b * size, commit_passes=1, denoise_passes=1)
+        block_ids, out = fix(b + 1, 0, masks, x[size:], chosen[:, size:], out)
+        return block_ids, state, out, counters
+
+    def alone(b, done, state, out, counters):
+        """The last block's commit pass: no successor, and no head."""
+        _, state, _, counters = sweep(done, state, counters,
+                                      position + b * size, commit_passes=1)
+        return masks, state, out, counters
 
     def block(b, carry):
-        state, ids, fixed_from, fixed_in, denoise_experts, counters = carry
-        start = position + b * size
+        """``block_ids``: block ``b`` as the sweep it shared with the block
+        before it left it - its first denoise pass made - or, block 0 and
+        every block of the control, all MASK ids."""
+        block_ids, state, ids, out, counters = carry
 
         def denoise(t, inner):
-            block_ids, state, fixed_from, fixed_in, denoise_experts, \
-                counters = inner
-            x, state, chosen, counters = one_pass(
-                block_ids, state, counters, start, denoise_passes=1)
-            logits = head(params, cfg, x)
-            block_ids, fixed = unmask(cfg, logits, block_ids)
-            with jax.named_scope("lm.sdar.unmask"):
-                fixed_from = fixed_from.at[b * size + fixed].set(logits[fixed])
-                fixed_in = fixed_in.at[b * size + fixed].set(t)
-            denoise_experts = lax.dynamic_update_slice(
-                denoise_experts, chosen.swapaxes(0, 1)[None, None],
-                (b, t, 0, 0, 0))
-            return (block_ids, state, fixed_from, fixed_in, denoise_experts,
-                    counters)
+            """A denoise pass alone."""
+            block_ids, state, out, counters = inner
+            x, state, chosen, counters = sweep(
+                block_ids, state, counters, position + b * size,
+                denoise_passes=1)
+            block_ids, out = fix(b, t, block_ids, x, chosen, out)
+            return block_ids, state, out, counters
 
-        block_ids, state, fixed_from, fixed_in, denoise_experts, counters = \
-            lax.fori_loop(0, steps, denoise, (
-                jnp.full((size,), cfg.mask_id, jnp.int32), state, fixed_from,
-                fixed_in, denoise_experts, counters))
-        if cfg.commit_pass:
-            _, state, _, counters = one_pass(block_ids, state, counters,
-                                             start, commit_passes=1)
+        first = jnp.where(b == 0, 0, 1) if cfg.commit_pass else 0
+        block_ids, state, out, counters = lax.fori_loop(
+            first, steps, denoise, (block_ids, state, out, counters))
         ids = lax.dynamic_update_slice_in_dim(ids, block_ids, b * size, axis=0)
-        return (state, ids, fixed_from, fixed_in, denoise_experts,
-                _count(counters, tokens_decoded=size))
+        counters = _count(counters, tokens_decoded=size)
+        if cfg.commit_pass:
+            block_ids, state, out, counters = lax.cond(
+                b < blocks - 1, shared, alone, b, block_ids, state, out,
+                counters)
+        else:
+            block_ids = masks
+        return block_ids, state, ids, out, counters
 
-    state, ids, fixed_from, fixed_in, denoise_experts, counters = \
-        lax.fori_loop(0, blocks, block, (
-            state, jnp.zeros((new_tokens,), jnp.int32),
-            jnp.zeros((new_tokens, cfg.vocab_size), F32),
-            jnp.zeros((new_tokens,), jnp.int32),
-            jnp.zeros((blocks, steps, size, cfg.num_hidden_layers,
-                       cfg.num_experts_per_tok), jnp.int32), counters))
+    _, state, ids, out, counters = lax.fori_loop(0, blocks, block, (
+        masks, state, jnp.zeros((new_tokens,), jnp.int32),
+        (jnp.zeros((new_tokens, cfg.vocab_size), F32),
+         jnp.zeros((new_tokens,), jnp.int32),
+         jnp.zeros((blocks, steps, size, cfg.num_hidden_layers,
+                    cfg.num_experts_per_tok), jnp.int32)), counters))
+    fixed_from, fixed_in, denoise_experts = out
     record = {"fixed_in_pass": fixed_in, "denoise_experts": denoise_experts,
               "experts": state["experts"]}
     return ids, fixed_from, record, state, counters

@@ -3,8 +3,10 @@ small size, seeded weights: prefill and generation by diffusion over blocks
 through the KV cache against the plain reference's one cache-less forward
 over the served trajectory (`benchmark/reference`) - logits of every fixed
 id, the position fixed in every pass, the experts chosen; a suffix entering
-a snapshot; the block rule; the commit pass; the MASK id; one chip's share
-of the experts against the uncut layer; what the configuration refuses."""
+a snapshot; the block rule; the commit pass, alone and sharing a sweep of the
+stack with the next block's first denoise pass; the MASK id; one chip's
+share of the experts against the uncut layer; what the configuration
+refuses."""
 
 import os
 import sys
@@ -252,6 +254,100 @@ def test_without_the_commit_pass_the_cache_is_not_the_final_blocks(params):
     assert readings["lm_logit_rel_rmse_median"] > 1e-2, readings
     c = dict(zip(lm.COUNTERS, counters.tolist()))
     assert (c["denoise_passes"], c["commit_passes"]) == (12, 0)
+
+
+def pass_by_pass(params, cfg, prompt, new_tokens):
+    """The procedure with nothing shared: every denoise pass and every
+    commit pass a trip of the stack of its own over its block's rows,
+    ``denoising_steps + 1`` a block -> (ids, the logits each was fixed from,
+    the pass that fixed each)."""
+    size, max_len = cfg.block_length, len(prompt) + new_tokens
+    _, state, _, _ = lm.prefill(params, cfg, jnp.asarray(prompt),
+                                max_len=max_len)
+    ids, fixed_from, fixed_in = [], {}, {}
+    for start in range(len(prompt), max_len, size):
+        block = jnp.full((size,), cfg.mask_id, jnp.int32)
+        for t in range(cfg.denoising_steps):
+            x, state, _, _ = lm._forward(params, cfg, block, state, start,
+                                         max_len)
+            logits = lm.head(params, cfg, x)
+            block, fixed = lm.unmask(cfg, logits, block)
+            for j in np.asarray(fixed).tolist():
+                fixed_from[start + j], fixed_in[start + j] = logits[j], t
+        _, state, _, _ = lm._forward(params, cfg, block, state, start, max_len)
+        ids += np.asarray(block).tolist()
+    order = sorted(fixed_from)
+    return (ids, np.stack([fixed_from[i] for i in order]),
+            [fixed_in[i] for i in order])
+
+
+@pytest.mark.parametrize("blocks_of, prompt_len, new_tokens", [
+    (4, T, NEW), (1, 16, 5)])
+def test_a_shared_sweep_is_the_two_passes_it_holds(blocks_of, prompt_len,
+                                                   new_tokens):
+    """Ids, the logits they were fixed from and the pass that fixed each
+    against T + 1 sweeps a block: the last rows of a shared sweep see the
+    keys and values the same sweep commits, layer by layer.  With blocks of
+    one in one pass every sweep but the first and the last commits one id
+    and predicts the next."""
+    cfg = lm.sdar_config_from_json(dict(JSON, block_length=blocks_of,
+                                        denoising_steps=blocks_of))
+    params = init(cfg)
+    prompt = token_ids(prompt_len, seed=19)
+    new_ids, logits, counters, record = jax.jit(
+        lambda p, i: lm.generate(p, cfg, i, new_tokens))(
+            params, jnp.asarray(prompt))
+    ids, fixed_from, fixed_in = pass_by_pass(params, cfg, prompt, new_tokens)
+    assert np.asarray(new_ids).tolist() == ids
+    close(logits, fixed_from, tol=1e-5)
+    assert np.asarray(record["fixed_in_pass"]).tolist() == fixed_in
+    c = dict(zip(lm.COUNTERS, np.asarray(counters).tolist()))
+    assert (c["denoise_passes"], c["commit_passes"], c["stack_sweeps"]) == (
+        new_tokens, new_tokens // blocks_of, new_tokens + 1)
+
+
+@pytest.mark.parametrize("new_tokens", [4, 12])
+def test_the_cache_after_generation_is_the_whole_sequences_prefill(
+        params, new_tokens):
+    """A shared sweep's first rows ARE a commit - and the last block's commit
+    pass, alone, is one: every layer's keys and values of prompt + new ids,
+    row for row, and the experts the rows chose.  One block: no sweep is
+    shared."""
+    prompt = jnp.asarray(token_ids(T, seed=23))
+    max_len = T + new_tokens
+    logits, state, counters, _ = lm.prefill(params, CFG, prompt,
+                                            max_len=max_len)
+    new_ids, _, record, state, counters = jax.jit(
+        lambda p, s, c: lm.decode(p, CFG, logits, s, c, position=T,
+                                  new_tokens=new_tokens))(
+            params, state, counters)
+    _, whole, _, chosen = lm.prefill(
+        params, CFG, jnp.concatenate([prompt, new_ids]), max_len=max_len)
+    for got, want in zip(state["cache"], whole["cache"]):
+        close(got["k"], want["k"], tol=1e-5)
+        close(got["v"], want["v"], tol=1e-5)
+    assert np.array_equal(record["experts"], chosen)
+    c = dict(zip(lm.COUNTERS, np.asarray(counters).tolist()))
+    assert c["stack_sweeps"] == new_tokens + 1
+
+
+@pytest.mark.parametrize("commit, commits, sweeps", [
+    (True, 3, 13), (False, 0, 12)])
+def test_passes_are_counted_as_before_and_sweeps_beside_them(
+        params, commit, commits, sweeps):
+    """A pass is a block's rows through the stack, whatever it shares:
+    T a block with the head and one without; the sweeps are one fewer a
+    block with a successor - and as many as the passes in the control."""
+    cfg = lm.sdar_config_from_json(dict(JSON, commit_pass=commit))
+    _, _, counters, record = jax.jit(
+        lambda p, i: lm.generate(p, cfg, i, NEW))(
+            params, jnp.asarray(token_ids(T, seed=29)))
+    c = dict(zip(lm.COUNTERS, np.asarray(counters).tolist()))
+    assert (c["denoise_passes"], c["commit_passes"],
+            c["stack_sweeps"]) == (12, commits, sweeps)
+    assert c["tokens_decoded"] == NEW
+    assert c["expert_assignments"] == (T + (12 + commits) * 4) * 3 * 3
+    assert np.asarray(record["denoise_experts"]).shape == (3, 4, 4, 3, 3)
 
 
 def test_the_mask_id_is_never_chosen(params):

@@ -1050,11 +1050,16 @@ def test_block_diffusion_rewrite_programs_compile_for_the_chip(
     matmul.  The request's prefill: 128 ids ENTERING the snapshot (read, not
     aliased) at a tenth of the whole prompt's FLOPs.  Decode: the donated
     state - 24 KV caches [4, 8704, 128] twice and the record of the experts
-    chosen - carried in place; the pass traced TWICE (the denoise passes an
-    inner loop with the head, the commit pass without), so every layer's
-    experts are two calls of the gather kernel over a pass's four rows and
-    the head is there once; the language model's scopes on its ops; weights
-    and state fit."""
+    chosen - carried in place; the stack traced THREE times - a denoise
+    pass alone (four rows, the head; an inner loop), the sweep a commit pass
+    shares with the next block's first denoise pass (eight rows, the head
+    on the last four) and the last block's commit pass alone (four rows, no
+    head), the two a conditional's branches -, so every layer's experts are
+    four calls of the gather kernel, each over FOUR rows (eight rows in one
+    call would be 64 assignments: the grouped matmul's); the language
+    model's scopes on its ops; weights and state fit; and a block's sweeps
+    stage no more of the caches through VMEM than the parent's five did.
+    """
     lp = block_programs
     cfg, t, n = lp.cfg, lp.t, lp.n
     assert (t, n, t - n) == (8192, 8064, 128)
@@ -1085,16 +1090,40 @@ def test_block_diffusion_rewrite_programs_compile_for_the_chip(
     assert mem.temp_size_in_bytes < 0.3e9
     assert 5.1e9 < mem.argument_size_in_bytes < 5.2e9  # weights + state
     text = lp.decode.as_text()
-    kernels = re.findall(r"%(expert_gather_matvec[\w.\-]*) = ", text)
-    assert len(kernels) == 2 * layers and "ragged-dot" not in text
-    # the head's matmul [4, 2048] x [2048, 18992]: in the denoise passes'
-    # body alone
-    assert len(re.findall(r"= f32\[4,18992\]\S* (?:fusion|convolution|dot)\(",
-                          text)) >= 1
-    comps = re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
-    bodies = [c for c in comps if "expert_gather_matvec" in c]
-    assert len(bodies) == 2
-    assert sorted("f32[4,18992]" in c for c in bodies) == [False, True]
+    kernel = r"%expert_gather_matvec[\w.\-]* = "
+    assert len(re.findall(kernel, text)) == 4 * layers == len(re.findall(
+        kernel + r"\(f32\[4,2048\]", text))  # four rows a call
+    assert "ragged-dot" not in text
+    comps = {m.group(1): c for c in re.split(
+        r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+        if (m := re.match(r"(?:ENTRY )?%([\w.\-]+) \(", c))}
+    # the traces of the stack by the kernel calls they hold: alone a layer's
+    # one, shared its two; the head's matmul [4, 2048] x [2048, 18992] in
+    # the denoise pass's and the shared sweep's
+    traces = sorted(
+        (len(re.findall(kernel, c)) // layers, bool(re.search(
+            r"= f32\[4,18992\]\S* (?:fusion|convolution|dot)\(", c)), name)
+        for name, c in comps.items() if re.search(kernel, c))
+    assert [tr[:2] for tr in traces] == [(1, False), (1, True), (2, True)]
+    (_, _, alone), (_, _, denoise), (_, _, shared) = traces
+    assert f"body=%{denoise}" in text
+    branches = re.search(r"conditional\(.*branch_computations=\{([^}]*)\}",
+                         text).group(1).replace("%", "").split(", ")
+    assert sorted(branches) == sorted([alone, shared])
+    # what one trip of each moves between the HBM and VMEM of the caches,
+    # whole (`cache_staging` reads loop bodies: each is handed to it as one)
+    from distrifuser_tpu.utils.overlap import cache_staging
+
+    staged = {name: cache_staging(
+        f"%w = () while(), body=%{name}\n{comps[name]}\n",
+        shapes=[(4, max_len, 128)]) for name in (alone, denoise, shared)}
+    assert all(s["writes"] == 2 * layers for s in staged.values())
+    a_block = ((cfg.denoising_steps - 1) * staged[denoise]["staged_bytes"]
+               + staged[shared]["staged_bytes"])
+    # the parent's program, compiled here the same way: 4 x 98.0 MB (its
+    # denoise passes) + 17.8 MB (its commit pass) = 410 MB a block - half
+    # of them copies back to the HBM
+    assert a_block <= 0.31e9, staged
     for scope in ("lm.attn.proj", "lm.attn", "lm.moe.router",
                   "lm.moe.experts", "lm.head", "lm.sdar.unmask"):
         assert f"/{scope}/" in text, scope
