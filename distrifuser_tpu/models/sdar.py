@@ -21,9 +21,11 @@ rotate-half rotary embedding over all D (theta 1e6), ``Hq / Hkv`` query
 heads a KV head, the softmax in float32.  **Visibility is by block, not by
 position**: with ``B = block_length``, position ``i`` sees position ``j``
 iff ``j // B <= i // B`` - both directions inside its own block, everything
-before it (`ops/attention.py gqa_sdpa_by_query_block`, with the last
-position of a query's block as what it sees up to).  The per-head norm has
-no key in the published config: it is the family's (Qwen3-MoE).
+before it (`ops/gqa_cache.py cache_attention`, with the last position of a
+query's block as each row's limit: a decode sweep's few rows against the
+whole cache take one single-pass kernel on a TPU, a prompt or a suffix
+entering a cache `ops/attention.py gqa_sdpa_by_query_block`).  The per-head
+norm has no key in the published config: it is the family's (Qwen3-MoE).
 
 ``Experts``: ``p = softmax(u W_g)`` over ALL experts in float32, the
 ``num_experts_per_tok`` largest chosen, weights ``p_e / sum of the chosen
@@ -102,7 +104,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops import moe
-from ..ops.attention import gqa_sdpa_by_query_block
+from ..ops.gqa_cache import cache_attention
 from .deepseek_v3 import rms_norm
 from .language_model import LanguageModel
 from .weights import params_nbytes
@@ -122,11 +124,15 @@ F32 = jnp.float32
 # held assignment (`ops/moe.py gather_expert_sum`), so an expert two rows
 # of a block chose counts twice: what the calls moved, beside the DISTINCT
 # held experts a pass's rows chose, which the record gives;
-# ``kv_cache_bytes``: every layer's keys and values
+# ``kv_cache_bytes``: every layer's keys and values; ``kv_rows_fetched``:
+# cache rows of each KV head that the decode sweeps' attention fetched
+# through `ops/gqa_cache.py streamed_gqa_attention`, summed over layers and
+# sweeps (over ``stack_sweeps`` x layers: the rows in view, to a copy's 128;
+# 0 on the XLA route, which reads every row the cache holds under its mask)
 COUNTERS = ("tokens_prefilled", "tokens_reused", "tokens_decoded",
             "denoise_passes", "commit_passes", "expert_assignments",
             "expert_assignments_held", "experts_fetched", "kv_cache_bytes",
-            "stack_sweeps")
+            "stack_sweeps", "kv_rows_fetched")
 _C = {name: i for i, name in enumerate(COUNTERS)}
 
 
@@ -325,8 +331,11 @@ def attention_layer(p, cfg: SdarConfig, x, cache, position,
     ``visible`` None and ``position`` 0 (static): a whole prompt, over its
     own keys.  Otherwise against the cache's first ``visible`` rows (static,
     at least position + T): a suffix entering it, or - all of them, under
-    the mask - a sweep of the decode loop.
-    -> (the layer's output [T, d], the cache)."""
+    the mask - a sweep of the decode loop, whose few rows take the
+    single-pass kernel where there is a TPU (`ops/gqa_cache.py
+    cache_attention` routes by the call's shape).
+    -> (the layer's output [T, d], the cache, the cache rows of each KV head
+    the kernel fetched: 0 on the XLA route)."""
     t = x.shape[0]
     hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
@@ -353,12 +362,13 @@ def attention_layer(p, cfg: SdarConfig, x, cache, position,
                                  f"the tokens before it")
             keys, values = k, v
         else:
-            keys, values = (cache["k"][:, :visible], cache["v"][:, :visible])
-        out = gqa_sdpa_by_query_block(
-            q, keys, values,
-            q_positions=sees_up_to(positions, cfg.block_length))
+            keys, values = cache["k"], cache["v"]
+        out, fetched = cache_attention(
+            q, keys, values, limits=sees_up_to(positions, cfg.block_length),
+            visible=visible)
     with jax.named_scope("lm.attn.proj"):
-        return out.reshape(t, hq * hd) @ p["o_proj"]["kernel"], cache
+        return (out.reshape(t, hq * hd) @ p["o_proj"]["kernel"], cache,
+                fetched)
 
 
 def moe_layer(p, cfg: SdarConfig, u, calls: int = 1):
@@ -405,12 +415,13 @@ def _forward(params, cfg: SdarConfig, ids, state, position, visible,
     """The stack over ids [T] (whole blocks) at ``position`` onward through
     the state, every layer's experts in ``expert_calls`` calls (`moe_layer`)
     -> (hidden [T, d], the new state, held expert assignments, the experts
-    the rows chose [layers, T, top_k])."""
+    the rows chose [layers, T, top_k], the cache rows the layers' attention
+    fetched: `attention_layer`)."""
     x = params["embed"][ids]
     caches, chosen = [], []
-    held = jnp.zeros((), jnp.int32)
+    held = fetched = jnp.zeros((), jnp.int32)
     for lp, cache in zip(params["layers"], state["cache"]):
-        out, cache = attention_layer(
+        out, cache, rows = attention_layer(
             lp["attn"], cfg, rms_norm(lp["attn_norm"]["scale"], x,
                                       cfg.rms_norm_eps), cache, position,
             visible)
@@ -422,10 +433,11 @@ def _forward(params, cfg: SdarConfig, ids, state, position, visible,
         caches.append(cache)
         chosen.append(idx)
         held = held + n.astype(jnp.int32)
+        fetched = fetched + rows
     chosen = jnp.stack(chosen)
     experts = lax.dynamic_update_slice_in_dim(state["experts"], chosen,
                                               position, axis=1)
-    return x, {"cache": caches, "experts": experts}, held, chosen
+    return x, {"cache": caches, "experts": experts}, held, chosen, fetched
 
 
 def assignments(cfg: SdarConfig, rows: int) -> int:
@@ -467,8 +479,8 @@ def prefill(params, cfg: SdarConfig, ids, *, max_len: int, state=None,
         if state["cache"][0]["k"].shape[1] < max(max_len, visible):
             raise ValueError(f"the state handed in has no room for "
                              f"{max(max_len, visible)} positions")
-    x, state, held, chosen = _forward(params, cfg, ids, state, position,
-                                      visible)
+    x, state, held, chosen, _ = _forward(params, cfg, ids, state, position,
+                                         visible)
     counters = _count(
         counters.at[_C["tokens_reused"]].set(position).at[
             _C["kv_cache_bytes"]].set(params_nbytes(state["cache"])),
@@ -527,11 +539,11 @@ def decode(params, cfg: SdarConfig, logits, state, counters, *,
         take the gather kernel, which fetches an expert's weights per held
         ASSIGNMENT."""
         n = rows.shape[0] // size
-        x, state, held, chosen = _forward(
+        x, state, held, chosen, fetched = _forward(
             params, cfg, rows, state, start,
             state["cache"][0]["k"].shape[1], expert_calls=n)
         return x, state, chosen, _count(
-            counters, stack_sweeps=1,
+            counters, stack_sweeps=1, kv_rows_fetched=fetched,
             expert_assignments=assignments(cfg, n * size),
             expert_assignments_held=held, experts_fetched=held, **passes)
 

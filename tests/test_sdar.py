@@ -268,13 +268,13 @@ def pass_by_pass(params, cfg, prompt, new_tokens):
     for start in range(len(prompt), max_len, size):
         block = jnp.full((size,), cfg.mask_id, jnp.int32)
         for t in range(cfg.denoising_steps):
-            x, state, _, _ = lm._forward(params, cfg, block, state, start,
+            x, state, *_ = lm._forward(params, cfg, block, state, start,
                                          max_len)
             logits = lm.head(params, cfg, x)
             block, fixed = lm.unmask(cfg, logits, block)
             for j in np.asarray(fixed).tolist():
                 fixed_from[start + j], fixed_in[start + j] = logits[j], t
-        _, state, _, _ = lm._forward(params, cfg, block, state, start, max_len)
+        _, state, *_ = lm._forward(params, cfg, block, state, start, max_len)
         ids += np.asarray(block).tolist()
     order = sorted(fixed_from)
     return (ids, np.stack([fixed_from[i] for i in order]),
@@ -348,6 +348,56 @@ def test_passes_are_counted_as_before_and_sweeps_beside_them(
     assert c["tokens_decoded"] == NEW
     assert c["expert_assignments"] == (T + (12 + commits) * 4) * 3 * 3
     assert np.asarray(record["denoise_experts"]).shape == (3, 4, 4, 3, 3)
+
+
+@pytest.mark.parametrize("commit, sweeps", [(True, 13), (False, 12)])
+def test_generation_through_the_kernels_route_is_the_xla_routes(
+        params, monkeypatch, commit, sweeps):
+    """What a TPU gives a decode sweep - `ops/gqa_cache.py
+    streamed_gqa_attention`, interpreted here, blocks of 4 cache rows - in
+    the place of `cache_attention` for every call against a cache with few
+    rows (a pass's 4, a shared sweep's 8; the prompt keeps the XLA form):
+    the same ids fixed in the same passes from the same logits, and the
+    counter `kv_rows_fetched` the rows in view - a sweep at position p
+    fetches rows 0 .. the end of its last block, in every layer; 0 on the
+    XLA route."""
+    from distrifuser_tpu.ops import gqa_cache
+
+    cfg = lm.sdar_config_from_json(dict(JSON, commit_pass=commit))
+    prompt = jnp.asarray(token_ids(T, seed=31))
+    want_ids, want_logits, counters, want = jax.jit(
+        lambda p, i: lm.generate(p, cfg, i, NEW))(params, prompt)
+    c = dict(zip(lm.COUNTERS, np.asarray(counters).tolist()))
+    assert c["kv_rows_fetched"] == 0 and c["stack_sweeps"] == sweeps
+
+    def as_on_a_tpu(q, k, v, *, limits, visible=None):
+        if visible is None or q.shape[0] > 8:
+            return gqa_cache.cache_attention(q, k, v, limits=limits,
+                                             visible=visible)
+        assert k.shape[1] == T + NEW  # the whole cache, never a slice
+        return gqa_cache.streamed_gqa_attention(q, k, v, limits,
+                                                block_rows=4, interpret=True)
+
+    monkeypatch.setattr(lm, "cache_attention", as_on_a_tpu)
+    ids, logits, counters, record = jax.block_until_ready(jax.jit(
+        lambda p, i: lm.generate(p, cfg, i, NEW))(params, prompt))
+    assert np.array_equal(ids, want_ids)
+    close(logits, want_logits, tol=1e-5)
+    for name in ("fixed_in_pass", "denoise_experts", "experts"):
+        assert np.array_equal(record[name], want[name]), name
+    c = dict(zip(lm.COUNTERS, np.asarray(counters).tolist()))
+    # block b's sweeps start at T + 4 b; the one it shares with block b + 1
+    # reaches four rows further
+    starts = [T + 4 * b for b in range(NEW // 4)]
+    if commit:
+        # (block 0 makes four denoise passes alone, the later ones three)
+        rows = (4 * (starts[0] + 4) + (starts[0] + 8)
+                + sum(3 * (p + 4) + (p + 8) for p in starts[1:-1])
+                + 3 * (starts[-1] + 4) + (starts[-1] + 4))
+    else:
+        rows = sum(4 * (p + 4) for p in starts)
+    assert c["stack_sweeps"] == sweeps
+    assert c["kv_rows_fetched"] == rows * cfg.num_hidden_layers
 
 
 def test_the_mask_id_is_never_chosen(params):

@@ -1057,8 +1057,11 @@ def test_block_diffusion_rewrite_programs_compile_for_the_chip(
     head), the two a conditional's branches -, so every layer's experts are
     four calls of the gather kernel, each over FOUR rows (eight rows in one
     call would be 64 assignments: the grouped matmul's); the language
-    model's scopes on its ops; weights and state fit; and a block's sweeps
-    stage no more of the caches through VMEM than the parent's five did.
+    model's scopes on its ops; weights and state fit; every sweep's
+    attention is the single-pass kernel, once a layer in each trace, and no
+    sweep stages any of the caches through VMEM: every row is written where
+    the loop carries its cache.  The prompt and the entering suffix keep the
+    XLA form.
     """
     lp = block_programs
     cfg, t, n = lp.cfg, lp.t, lp.n
@@ -1117,13 +1120,28 @@ def test_block_diffusion_rewrite_programs_compile_for_the_chip(
     staged = {name: cache_staging(
         f"%w = () while(), body=%{name}\n{comps[name]}\n",
         shapes=[(4, max_len, 128)]) for name in (alone, denoise, shared)}
-    assert all(s["writes"] == 2 * layers for s in staged.values())
-    a_block = ((cfg.denoising_steps - 1) * staged[denoise]["staged_bytes"]
-               + staged[shared]["staged_bytes"])
-    # the parent's program, compiled here the same way: 4 x 98.0 MB (its
-    # denoise passes) + 17.8 MB (its commit pass) = 410 MB a block - half
-    # of them copies back to the HBM
-    assert a_block <= 0.31e9, staged
+    # every row written in place: `streamed_gqa_attention` holds its two
+    # cache operands to the HBM.  (With the sweeps' attention XLA's einsums
+    # a block's sweeps staged 3 x 98.0 + 8.9 = 303 MB of whole caches
+    # through VMEM round the rows' writes - PR 42's program, compiled here
+    # the same way; PR 41's five passes 410 MB; other schedules of the same
+    # mathematics anything from 0 to 2.2 GB.)
+    assert all(s == {"staged_bytes": 0, "staged_copies": 0,
+                     "writes": 2 * layers, "writes_outside_hbm": 0}
+               for s in staged.values()), staged
+    # each trace of the stack: the single-pass attention kernel once a
+    # layer, under the `lm.attn` scope (what `sdar_attn_ms_per_token` reads
+    # it by), and no float32 array of a whole cache's logits left
+    for name in (alone, denoise, shared):
+        calls = [ln for ln in comps[name].splitlines()
+                 if re.match(r"\s*%gqa_cache_attention[\w.\-]* = ", ln)
+                 and "custom-call(" in ln]
+        assert len(calls) == layers, (name, len(calls))
+        assert all('custom_call_target="tpu_custom_call"' in ln and re.search(
+            r'op_name="[^"]*/lm\.attn/[^"]*pallas_call', ln) for ln in calls)
+        assert not re.search(rf"= f32\[4,8,[48],{max_len}\]", comps[name])
+    assert "gqa_cache_attention" not in lp.entering.as_text()
+    assert "gqa_cache_attention" not in lp.prefix.as_text()
     for scope in ("lm.attn.proj", "lm.attn", "lm.moe.router",
                   "lm.moe.experts", "lm.head", "lm.sdar.unmask"):
         assert f"/{scope}/" in text, scope
