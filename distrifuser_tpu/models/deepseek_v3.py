@@ -51,18 +51,19 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..ops import mla, moe
+from . import lm_common
 from .language_model import LanguageModel
+from .lm_common import F32, gated_mlp, rms_norm
 from .weights import params_nbytes
-
-F32 = jnp.float32
 
 # counters the generation returns with its ids; ``tokens_reused``: of the
 # positions the cache covers after prefill, those a cache handed in already
@@ -137,24 +138,15 @@ def deepseek_v3_config_from_json(d: Dict[str, Any]) -> DeepseekV3Config:
     (``{"chips": n, "index": i}``) says of how many shares this is which, so
     the router is ``chips`` times as wide; ``num_hidden_layers`` layers from
     the first are served; ``prefill_block`` and ``cache_dtype`` are ours."""
-    built = {"model_type": "deepseek_v3", "q_lora_rank": None,
-             "rope_scaling": None, "rope_interleave": True, "n_group": 1,
-             "topk_group": 1, "scoring_func": "sigmoid",
-             "norm_topk_prob": True, "hidden_act": "silu",
-             "attention_bias": False, "tie_word_embeddings": False,
-             "moe_layer_freq": 1}
-    for key, want in built.items():
-        if d.get(key, want) != want:
-            raise ValueError(f"only {key} = {want!r} is built, the "
-                             f"configuration says {d[key]!r}")
-    ep = d.get("expert_parallel", {"chips": 1, "index": 0})
-    held = int(d["n_routed_experts"])
-    names = {f.name for f in dataclasses.fields(DeepseekV3Config)}
-    kw = {k: d[k] for k in names & set(d) if k not in (
-        "n_routed_experts", "n_local_experts", "first_local_expert")}
-    return DeepseekV3Config(
-        n_routed_experts=held * int(ep["chips"]), n_local_experts=held,
-        first_local_expert=held * int(ep["index"]), **kw)
+    lm_common.refuse_unbuilt(d, {
+        "model_type": "deepseek_v3", "q_lora_rank": None,
+        "rope_scaling": None, "rope_interleave": True, "n_group": 1,
+        "topk_group": 1, "scoring_func": "sigmoid", "norm_topk_prob": True,
+        "hidden_act": "silu", "attention_bias": False,
+        "tie_word_embeddings": False, "moe_layer_freq": 1})
+    return DeepseekV3Config(**{
+        **lm_common.config_fields(DeepseekV3Config, d),
+        **lm_common.expert_share(d, "n_routed_experts")})
 
 
 # -- parameters ---------------------------------------------------------------
@@ -219,28 +211,15 @@ def init_leaf(key, name: str, shape, cfg: DeepseekV3Config, dtype):
 
 def named_leaves(cfg: DeepseekV3Config):
     """([(a leaf's own name, its shape)], the tree's structure)."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    return [(str(getattr(path[-1], "key", path[-1])), shape)
-            for path, shape in leaves], treedef
+    return lm_common.named_leaves(param_shapes(cfg))
 
 
 def init_deepseek_v3_params(key, cfg: DeepseekV3Config, dtype=F32):
-    leaves, treedef = named_leaves(cfg)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        init_leaf(k, name, shape, cfg, dtype)
-        for k, (name, shape) in zip(keys, leaves)])
+    return lm_common.init_params(key, cfg, dtype, named_leaves=named_leaves,
+                                 init_leaf=init_leaf)
 
 
 # -- layers -------------------------------------------------------------------
-
-
-def rms_norm(scale, x, eps: float):
-    """RMSNorm in float32 over the last axis; the result in ``x``'s dtype."""
-    xf = x.astype(F32)
-    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (xf * scale.astype(F32)).astype(x.dtype)
 
 
 @jax.named_scope("lm.mla.proj")
@@ -308,12 +287,6 @@ def attention_layer(p, cfg: DeepseekV3Config, x, cache, position,
         return out.reshape(t, -1) @ p["o_proj"]["kernel"], cache, fetched
 
 
-def gated_mlp(p, x):
-    gate, up = jnp.split(x @ p["gate_up"]["kernel"], 2, axis=-1)
-    hidden = jax.nn.silu(gate.astype(F32)) * up.astype(F32)
-    return hidden.astype(x.dtype) @ p["down"]["kernel"]
-
-
 def moe_layer(p, cfg: DeepseekV3Config, u):
     """-> (out [T, d], how many of the T * top_k assignments fell on experts
     held here, the experts each token chose [T, top_k])."""
@@ -350,11 +323,9 @@ def feed_forward(lp, cfg: DeepseekV3Config, x):
         return x + gated_mlp(lp["ffn"], u), None, None
 
 
-@jax.named_scope("lm.head")
 def head(params, cfg: DeepseekV3Config, x):
     """x [T, d] -> float32 logits [T, V] over the held vocabulary."""
-    x = rms_norm(params["final_norm"]["scale"], x, cfg.rms_norm_eps)
-    return jnp.dot(x, params["head"]["kernel"], preferred_element_type=F32)
+    return lm_common.head(params, x, cfg.rms_norm_eps)
 
 
 # -- prefill, step, generation ------------------------------------------------
@@ -370,17 +341,35 @@ def empty_state(cfg: DeepseekV3Config, max_len: int, dtype):
                                   cfg.num_experts_per_tok), jnp.int32)}
 
 
-def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible):
+class Stack(NamedTuple):
+    """What `prefill` and `decode` drive: this module's stack (`STACK`), or a
+    sibling's that keeps the feed-forward half, the record and these
+    counters and puts mixers of its own in (`models/kimi_linear.py`)."""
+    counters: Tuple[str, ...]  # `COUNTERS`, more names after them
+    empty_state: Callable  # (cfg, max_len, dtype) -> {layers: [...], "experts"}
+    layers: str = "cache"  # the state's key of what the mixers carry
+    # cfg -> a layer's first half each, of `_attend`'s signature
+    mixers: Callable = lambda cfg: itertools.repeat(_attend)
+    # (cfg, t) -> what else a prefill of t tokens moves the counters by
+    prefilled: Callable = lambda cfg, t: {}
+
+
+STACK = Stack(COUNTERS, empty_state)
+
+
+def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible,
+             stack: Stack = STACK):
     """The stack over ids [T] at ``position`` onward through the state ->
     (hidden [T, d], the new state, held expert assignments, the cache rows
     the layers' attention fetched)."""
     x = params["embed"][ids]
-    caches, chosen = [], []
+    layers, chosen = [], []
     held = fetched = jnp.zeros((), jnp.int32)
-    for lp, cache in zip(params["layers"], state["cache"]):
-        x, cache, rows = _attend(lp, cfg, x, cache, position, visible)
+    for lp, layer, mix in zip(params["layers"], state[stack.layers],
+                              stack.mixers(cfg)):
+        x, layer, rows = mix(lp, cfg, x, layer, position, visible)
         x, n, idx = feed_forward(lp, cfg, x)
-        caches.append(cache)
+        layers.append(layer)
         fetched = fetched + rows
         if idx is not None:
             held = held + n.astype(jnp.int32)
@@ -389,7 +378,7 @@ def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible):
     if chosen:
         experts = lax.dynamic_update_slice_in_dim(
             experts, jnp.stack(chosen), position, axis=1)
-    return x, {"cache": caches, "experts": experts}, held, fetched
+    return x, {stack.layers: layers, "experts": experts}, held, fetched
 
 
 def assignments(cfg: DeepseekV3Config, tokens: int) -> int:
@@ -397,43 +386,37 @@ def assignments(cfg: DeepseekV3Config, tokens: int) -> int:
 
 
 def prefill(params, cfg: DeepseekV3Config, ids, *, max_len: int, state=None,
-            position: int = 0, counters=None):
+            position: int = 0, counters=None, stack: Stack = STACK):
     """ids [T] (T a multiple of ``prefill_block``) at ``position`` onward,
     computed in full -> (float32 logits after the last token [V], the
     state, the `COUNTERS` so far [7] int32, the experts the T tokens chose
     [E layers, T, top_k]).
 
-    A prompt from position 0 enters a state with nothing in it and room for
-    ``max_len`` positions, by the materialised form.  A suffix enters
-    ``state`` - what a prefill of the ``position`` tokens before it
-    returned, with its ``counters`` - by the absorbed form against the
-    cache's first ``position + T`` rows; the state is read, not consumed:
-    the one returned is new, and of its ``tokens_prefilled`` positions
-    ``tokens_reused`` = ``position`` came with the state handed in."""
+    `models/language_model.py`'s ``prefill`` and ``prefill_from`` both: a
+    prompt from position 0 takes the materialised form, a suffix entering
+    ``state`` the absorbed form against the cache's first ``position + T``
+    rows (of ``tokens_prefilled``, ``tokens_reused`` = ``position``)."""
     t = ids.shape[0]
-    if state is None:
-        if position:
-            raise ValueError(f"position {position} needs the state of the "
-                             f"tokens before it")
-        state = empty_state(cfg, max_len, params["embed"].dtype)
-        counters = jnp.zeros((len(COUNTERS),), jnp.int32)
-        visible = None
-    else:
-        visible = position + t
-        if state["cache"][0]["c"].shape[0] < max(max_len, visible):
-            raise ValueError(f"the state handed in has no room for "
-                             f"{max(max_len, visible)} positions")
-    x, state, held, _ = _forward(params, cfg, ids, state, position, visible)
-    counters = jnp.stack([
-        counters[0] + t, position, counters[2],
-        counters[3] + assignments(cfg, t), counters[4] + held,
-        params_nbytes(state["cache"]), counters[6]]).astype(jnp.int32)
+    visible = None if state is None else position + t
+    state, counters = lm_common.enter_state(
+        state, counters, stack.counters, position=position,
+        empty=lambda: stack.empty_state(cfg, max_len, params["embed"].dtype),
+        room=lambda state: state["experts"].shape[1],
+        needed=max(max_len, position + t))
+    x, state, held, _ = _forward(params, cfg, ids, state, position, visible,
+                                 stack)
+    counters = lm_common.count(
+        stack.counters, counters, put={
+            "tokens_reused": position,
+            "state_bytes": params_nbytes(state[stack.layers])},
+        tokens_prefilled=t, expert_assignments=assignments(cfg, t),
+        expert_assignments_held=held, **stack.prefilled(cfg, t))
     chosen = state["experts"][:, position:position + t]
     return head(params, cfg, x[-1:])[0], state, counters, chosen
 
 
 def decode(params, cfg: DeepseekV3Config, logits, state, counters, *,
-           position: int, new_tokens: int):
+           position: int, new_tokens: int, stack: Stack = STACK):
     """Greedy decoding through the state, on the device from first token to
     last: ``new_tokens`` times the largest logit is taken and the token goes
     through the stack, by the absorbed form against the cache (on a TPU
@@ -443,24 +426,16 @@ def decode(params, cfg: DeepseekV3Config, logits, state, counters, *,
     [new_tokens, V], the experts EVERY position so far chose
     [E layers, max_len, top_k] - the prompt's, a snapshot's too -, the
     state, the counters)."""
-    per_token = jnp.asarray([0, 0, 1, assignments(cfg, 1), 0, 0, 0],
-                            jnp.int32)
+    def step(token, state, at):
+        x, state, held, fetched = _forward(params, cfg, token, state, at,
+                                           None, stack)
+        return head(params, cfg, x)[0], state, dict(
+            tokens_decoded=1, expert_assignments=assignments(cfg, 1),
+            expert_assignments_held=held, cache_rows_fetched=fetched), None
 
-    def body(i, carry):
-        logits, state, ids, chosen_from, counters = carry
-        token = jnp.argmax(logits).astype(jnp.int32)
-        ids = ids.at[i].set(token)
-        chosen_from = lax.dynamic_update_slice_in_dim(
-            chosen_from, logits[None], i, axis=0)
-        x, state, held, fetched = _forward(params, cfg, token[None], state,
-                                           position + i, None)
-        counters = counters + per_token.at[4].set(held).at[6].set(fetched)
-        return head(params, cfg, x)[0], state, ids, chosen_from, counters
-
-    _, state, ids, chosen_from, counters = lax.fori_loop(
-        0, new_tokens, body,
-        (logits, state, jnp.zeros((new_tokens,), jnp.int32),
-         jnp.zeros((new_tokens,) + logits.shape, F32), counters))
+    ids, chosen_from, _, state, counters = lm_common.greedy_decode(
+        step, logits, state, counters, names=stack.counters,
+        position=position, new_tokens=new_tokens)
     return ids, chosen_from, state["experts"], state, counters
 
 
@@ -468,13 +443,8 @@ def generate(params, cfg: DeepseekV3Config, ids, new_tokens: int):
     """Prefill, then greedy decoding -> (new ids, the logits they were
     chosen from, the counters, the experts every position chose
     [E layers, T + new_tokens, top_k])."""
-    t = ids.shape[0]
-    logits, state, counters, _ = prefill(params, cfg, ids,
-                                         max_len=t + new_tokens)
-    new_ids, chosen_from, experts, _, counters = decode(
-        params, cfg, logits, state, counters, position=t,
-        new_tokens=new_tokens)
-    return new_ids, chosen_from, counters, experts
+    return lm_common.generate(cfg.language_model(), params, ids,
+                              new_tokens)[:4]
 
 
 # -- the routers' balance, for seeded weights -----------------------------------
@@ -514,10 +484,6 @@ def balanced_selection_bias(params, cfg: DeepseekV3Config, ids, *,
     over the calibration sequence ``ids`` [T], each balanced before the
     next sees its output.  Returns one [n_routed_experts] bias an expert
     layer, in the stored dtype."""
-    x = params["embed"][ids]
-    biases = []
-    for lp in params["layers"]:
-        x, bias = _balancing_layer(lp, x, cfg=cfg, rounds=rounds)
-        if bias is not None:
-            biases.append(bias)
-    return biases
+    return lm_common.balanced_biases(params["embed"][ids], (
+        functools.partial(_balancing_layer, lp, cfg=cfg, rounds=rounds)
+        for lp in params["layers"]))

@@ -38,10 +38,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops import eva
+from . import lm_common
 from .language_model import LanguageModel
+from .lm_common import F32
 from .weights import params_nbytes
-
-F32 = jnp.float32
 
 # counters the generation returns with its ids; ``bytes_reused``: of the
 # positions the state covers after prefill, those a state handed in already
@@ -92,19 +92,15 @@ class EvaByteConfig:
 def evabyte_config_from_json(d: Dict[str, Any]) -> EvaByteConfig:
     """From the published config.json keys (``byte_offset`` is ours: the
     config does not give the tokenizer's)."""
-    built = {"attention_class": "eva", "hidden_act": "silu",
-             "norm_add_unit_offset": True, "fp32_logits": True,
-             "mixedp_attn": True, "attention_bias": False, "fp32_ln": False,
-             "rope_scaling": None, "tie_word_embeddings": False}
-    for key, want in built.items():
-        if d.get(key, want) != want:
-            raise ValueError(f"only {key} = {want!r} is built, the "
-                             f"configuration says {d[key]!r}")
+    lm_common.refuse_unbuilt(d, {
+        "attention_class": "eva", "hidden_act": "silu",
+        "norm_add_unit_offset": True, "fp32_logits": True,
+        "mixedp_attn": True, "attention_bias": False, "fp32_ln": False,
+        "rope_scaling": None, "tie_word_embeddings": False})
     if d.get("num_key_value_heads", d["num_attention_heads"]) != d[
             "num_attention_heads"]:
         raise ValueError("EVA is built with as many KV heads as query heads")
-    names = {f.name for f in dataclasses.fields(EvaByteConfig)}
-    return EvaByteConfig(**{k: d[k] for k in names & set(d)})
+    return EvaByteConfig(**lm_common.config_fields(EvaByteConfig, d))
 
 
 # -- parameters ---------------------------------------------------------------
@@ -147,29 +143,21 @@ def init_leaf(key, name: str, shape, cfg: EvaByteConfig, dtype):
 
 def named_leaves(cfg: EvaByteConfig):
     """([(a leaf's own name, its shape)], the tree's structure)."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    return [(str(getattr(path[-1], "key", path[-1])), shape)
-            for path, shape in leaves], treedef
+    return lm_common.named_leaves(param_shapes(cfg))
 
 
 def init_evabyte_params(key, cfg: EvaByteConfig, dtype=F32):
-    leaves, treedef = named_leaves(cfg)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        init_leaf(k, name, shape, cfg, dtype)
-        for k, (name, shape) in zip(keys, leaves)])
+    return lm_common.init_params(key, cfg, dtype, named_leaves=named_leaves,
+                                 init_leaf=init_leaf)
 
 
 # -- layers -------------------------------------------------------------------
 
 
-def rms_norm(w, x, eps: float):
-    """x / sqrt(mean(x^2) + eps) * (1 + w), computed in float32 over the
-    last axis; the result in ``x``'s dtype."""
-    xf = x.astype(F32)
-    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (xf * (1.0 + w.astype(F32))).astype(x.dtype)
+def unit_offset_norm(w, x, eps: float):
+    """x / sqrt(mean(x^2) + eps) * (1 + w): the stored ``w`` is the scale's
+    offset from one."""
+    return lm_common.rms_norm(1.0 + w.astype(F32), x, eps)
 
 
 def rotary(x, positions, theta: float):
@@ -258,18 +246,15 @@ def attention_step(p, cfg: EvaByteConfig, x, state, position):
                                 "vs": table_v}, rows
 
 
-@jax.named_scope("lm.mlp")
-def mlp(p, x):
-    gate, up = jnp.split(x @ p["gate_up"]["kernel"], 2, axis=-1)
-    hidden = jax.nn.silu(gate.astype(F32)) * up.astype(F32)
-    return hidden.astype(x.dtype) @ p["down"]["kernel"]
+mlp = jax.named_scope("lm.mlp")(lm_common.gated_mlp)
 
 
 @jax.named_scope("lm.head")
 def head(params, cfg: EvaByteConfig, h):
     """h [T, d] -> float32 logits [T, num_pred_heads * vocab_size]."""
-    x = rms_norm(params["final_norm"]["scale"],
-                 h.astype(params["head"]["kernel"].dtype), cfg.rms_norm_eps)
+    x = unit_offset_norm(params["final_norm"]["scale"],
+                         h.astype(params["head"]["kernel"].dtype),
+                         cfg.rms_norm_eps)
     return jnp.dot(x, params["head"]["kernel"], preferred_element_type=F32)
 
 
@@ -287,14 +272,14 @@ def _forward(params, cfg: EvaByteConfig, ids, state, position, attend):
     h = params["embed"][ids].astype(F32 if cfg.fp32_skip_add else jnp.bfloat16)
     new_state, rows_read = [], 0
     for lp, st in zip(params["layers"], state):
-        x = rms_norm(lp["attn_norm"]["scale"], h.astype(dtype),
-                     cfg.rms_norm_eps)
+        x = unit_offset_norm(lp["attn_norm"]["scale"], h.astype(dtype),
+                             cfg.rms_norm_eps)
         out, st, rows = attend(lp["attn"], cfg, x, st, position)
         new_state.append(st)
         rows_read = rows_read + rows
         h = h + out.astype(h.dtype)
-        x = rms_norm(lp["mlp_norm"]["scale"], h.astype(dtype),
-                     cfg.rms_norm_eps)
+        x = unit_offset_norm(lp["mlp_norm"]["scale"], h.astype(dtype),
+                             cfg.rms_norm_eps)
         h = h + mlp(lp["mlp"], x).astype(h.dtype)
     return h, new_state, rows_read
 
@@ -305,34 +290,27 @@ def prefill(params, cfg: EvaByteConfig, ids, *, max_len: int, state=None,
     computed in full -> (float32 logits after the last byte [8 * V], the
     decode state, the `COUNTERS` so far [7] int32, ()).
 
-    A prompt from position 0 enters a state with nothing in it and room for
-    ``max_len`` positions.  A suffix enters ``state`` - what a prefill of
-    the ``position`` bytes before it returned, with its ``counters`` - which
-    is read, not consumed: the state returned is a new one, and of its
-    ``bytes_prefilled`` positions ``bytes_reused`` = ``position`` came with
-    the state handed in."""
+    `models/language_model.py`'s ``prefill`` and ``prefill_from`` both: of
+    the ``bytes_prefilled`` positions the state returned covers,
+    ``bytes_reused`` = ``position`` came with the state handed in."""
     t, chunk = ids.shape[0], cfg.chunk_size
     if t % chunk or position % chunk:  # before the pooling reshapes by chunk
         raise ValueError(f"{t} bytes from position {position} on are not "
                          f"whole chunks of {chunk}")
-    if state is None:
-        if position:
-            raise ValueError(f"position {position} needs the state of the "
-                             f"bytes before it")
-        state = [empty_state(cfg, max_len, params["embed"].dtype)
-                 ] * cfg.num_hidden_layers
-        counters = jnp.zeros((len(COUNTERS),), jnp.int32)
-    elif state[0]["ks"].shape[0] * chunk < max_len:
-        raise ValueError(f"the state handed in has no room for {max_len} "
-                         f"positions")
+    state, counters = lm_common.enter_state(
+        state, counters, COUNTERS, position=position, of="bytes",
+        empty=lambda: [empty_state(cfg, max_len, params["embed"].dtype)
+                       ] * cfg.num_hidden_layers,
+        room=lambda state: state[0]["ks"].shape[0] * chunk, needed=max_len)
     end = position + t
     h, state, _ = _forward(params, cfg, ids, state, position,
                            attention_prefill)
-    counters = jnp.stack([
-        counters[0] + t, counters[1],
-        counters[2] + end // chunk - position // chunk,
-        counters[3] + end // cfg.window_size - position // cfg.window_size,
-        params_nbytes(state), position, counters[6]]).astype(jnp.int32)
+    counters = lm_common.count(
+        COUNTERS, counters,
+        put={"state_bytes": params_nbytes(state), "bytes_reused": position},
+        bytes_prefilled=t,
+        summaries_written=end // chunk - position // chunk,
+        windows_rolled=end // cfg.window_size - position // cfg.window_size)
     return head(params, cfg, h[-1:])[0], state, counters, ()
 
 
@@ -346,34 +324,24 @@ def decode(params, cfg: EvaByteConfig, logits, state, counters, *,
     the counters)."""
     window, chunk = cfg.window_size, cfg.chunk_size
 
-    def body(i, carry):
-        logits, state, ids, chosen_from, counters = carry
-        token = jnp.argmax(logits[:cfg.vocab_size]).astype(jnp.int32)
-        ids = ids.at[i].set(token)
-        chosen_from = lax.dynamic_update_slice_in_dim(
-            chosen_from, logits[None], i, axis=0)
-        at = position + i
-        h, state, rows_read = _forward(params, cfg, token[None], state, at,
+    def step(token, state, at):
+        h, state, rows_read = _forward(params, cfg, token, state, at,
                                        attention_step)
-        counters = counters + jnp.stack([
-            0, 1, at % chunk == chunk - 1, at % window == window - 1, 0, 0,
-            rows_read]).astype(jnp.int32)
-        return head(params, cfg, h)[0], state, ids, chosen_from, counters
+        return head(params, cfg, h)[0], state, dict(
+            bytes_decoded=1, summaries_written=at % chunk == chunk - 1,
+            windows_rolled=at % window == window - 1,
+            state_rows_read=rows_read), None
 
-    _, state, ids, chosen_from, counters = lax.fori_loop(
-        0, new_tokens, body,
-        (logits, state, jnp.zeros((new_tokens,), jnp.int32),
-         jnp.zeros((new_tokens,) + logits.shape, F32), counters))
+    ids, chosen_from, _, state, counters = lm_common.greedy_decode(
+        step, logits, state, counters, names=COUNTERS, position=position,
+        new_tokens=new_tokens,
+        pick=lambda logits: jnp.argmax(logits[:cfg.vocab_size]))
     return ids, chosen_from, (), state, counters
 
 
 def generate(params, cfg: EvaByteConfig, ids, new_tokens: int):
     """Prefill, then greedy decoding -> (new ids, the logits they were
     chosen from, the counters, the state)."""
-    t = ids.shape[0]
-    logits, state, counters, _ = prefill(params, cfg, ids,
-                                         max_len=t + new_tokens)
-    new_ids, chosen_from, _, state, counters = decode(
-        params, cfg, logits, state, counters, position=t,
-        new_tokens=new_tokens)
+    new_ids, chosen_from, counters, _, state, _ = lm_common.generate(
+        cfg.language_model(), params, ids, new_tokens)
     return new_ids, chosen_from, counters, state

@@ -69,10 +69,9 @@ from jax import lax
 
 from ..ops import kda, ssm
 from . import deepseek_v3 as dsv3
+from . import lm_common
 from .language_model import LanguageModel
-from .weights import params_nbytes
-
-F32 = jnp.float32
+from .lm_common import F32
 
 # counters the generation returns with its ids: `models/deepseek_v3.py
 # COUNTERS` under their names there (``state_bytes``: the whole decode state,
@@ -169,28 +168,20 @@ def kimi_linear_config_from_json(d: Dict[str, Any]) -> KimiLinearConfig:
     the first are served, their kinds read from ``linear_attn_config``'s
     published lists as far as that; ``prefill_block``, ``kda_chunk``,
     ``state_dtype`` and ``cache_dtype`` are ours."""
-    built = {"model_type": "kimi_linear", "q_lora_rank": None,
-             "rope_scaling": None, "num_expert_group": 1, "topk_group": 1,
-             "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
-             "hidden_act": "silu", "tie_word_embeddings": False,
-             "moe_layer_freq": 1, "num_nextn_predict_layers": 0}
-    for key, want in built.items():
-        if d.get(key, want) != want:
-            raise ValueError(f"only {key} = {want!r} is built, the "
-                             f"configuration says {d[key]!r}")
-    ep = d.get("expert_parallel", {"chips": 1, "index": 0})
-    held = int(d["num_experts"])
+    lm_common.refuse_unbuilt(d, {
+        "model_type": "kimi_linear", "q_lora_rank": None,
+        "rope_scaling": None, "num_expert_group": 1, "topk_group": 1,
+        "moe_router_activation_func": "sigmoid", "moe_renormalize": True,
+        "hidden_act": "silu", "tie_word_embeddings": False,
+        "moe_layer_freq": 1, "num_nextn_predict_layers": 0})
     linear = d["linear_attn_config"]
-    names = {f.name for f in dataclasses.fields(KimiLinearConfig)}
-    kw = {k: d[k] for k in names & set(d) if k not in (
-        "num_experts", "n_local_experts", "first_local_expert")}
     return KimiLinearConfig(
         kda_layers=tuple(linear["kda_layers"]),
         full_attn_layers=tuple(linear["full_attn_layers"]),
         kda_num_heads=linear["num_heads"], kda_head_dim=linear["head_dim"],
         short_conv_kernel_size=linear["short_conv_kernel_size"],
-        num_experts=held * int(ep["chips"]), n_local_experts=held,
-        first_local_expert=held * int(ep["index"]), **kw)
+        **{**lm_common.config_fields(KimiLinearConfig, d),
+           **lm_common.expert_share(d, "num_experts")})
 
 
 # -- parameters ---------------------------------------------------------------
@@ -245,18 +236,12 @@ def init_leaf(key, name: str, shape, cfg: KimiLinearConfig, dtype):
 
 def named_leaves(cfg: KimiLinearConfig):
     """([(a leaf's own name, its shape)], the tree's structure)."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    return [(str(getattr(path[-1], "key", path[-1])), shape)
-            for path, shape in leaves], treedef
+    return lm_common.named_leaves(param_shapes(cfg))
 
 
 def init_kimi_linear_params(key, cfg: KimiLinearConfig, dtype=F32):
-    leaves, treedef = named_leaves(cfg)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        init_leaf(k, name, shape, cfg, dtype)
-        for k, (name, shape) in zip(keys, leaves)])
+    return lm_common.init_params(key, cfg, dtype, named_leaves=named_leaves,
+                                 init_leaf=init_leaf)
 
 
 # -- layers -------------------------------------------------------------------
@@ -308,10 +293,10 @@ def kda_layer(p, cfg: KimiLinearConfig, x, state):
     return out, {"s": s, "conv": tail.astype(state["conv"].dtype)}
 
 
-def _mix(lp, cfg: KimiLinearConfig, kind: str, x, state, position, visible):
+def _mix(lp, cfg: KimiLinearConfig, x, state, position, visible, *, kind: str):
     """A layer's first half -> (x + Mixer(RMSNorm(x)), the layer's state,
     the cache rows a full layer's attention fetched)."""
-    u = dsv3.rms_norm(lp["attn_norm"]["scale"], x, cfg.rms_norm_eps)
+    u = lm_common.rms_norm(lp["attn_norm"]["scale"], x, cfg.rms_norm_eps)
     if kind == "kda":
         out, state = kda_layer(lp["attn"], cfg, u, state)
         return x + out, state, jnp.zeros((), jnp.int32)
@@ -331,122 +316,37 @@ def _empty_kda(cfg: KimiLinearConfig, dtype):
 
 
 def empty_state(cfg: KimiLinearConfig, max_len: int, dtype):
-    """The state with nothing in it and room for ``max_len`` positions."""
-    cache_dtype = jnp.dtype(cfg.cache_dtype or dtype)
-    cache = {"c": jnp.zeros((max_len, cfg.kv_lora_rank), cache_dtype),
-             "k_pe": jnp.zeros((max_len, cfg.qk_rope_head_dim), cache_dtype)}
+    """The state with nothing in it and room for ``max_len`` positions:
+    `models/deepseek_v3.py`'s, a KDA layer's in the place of its cache."""
+    state = dsv3.empty_state(cfg, max_len, dtype)
     return {"layers": [_empty_kda(cfg, dtype) if kind == "kda" else cache
-                       for kind in cfg.kinds],
-            "experts": jnp.zeros((cfg.n_expert_layers, max_len,
-                                  cfg.num_experts_per_token), jnp.int32)}
+                       for kind, cache in zip(cfg.kinds, state["cache"])],
+            "experts": state["experts"]}
 
 
-def _forward(params, cfg: KimiLinearConfig, ids, state, position, visible):
-    """The stack over ids [T] at ``position`` onward through the state ->
-    (hidden [T, d], the new state, held expert assignments, the cache rows
-    the full layers' attention fetched)."""
-    x = params["embed"][ids]
-    layers, chosen = [], []
-    held = fetched = jnp.zeros((), jnp.int32)
-    for lp, kind, layer in zip(params["layers"], cfg.kinds, state["layers"]):
-        x, layer, rows = _mix(lp, cfg, kind, x, layer, position, visible)
-        x, n, idx = dsv3.feed_forward(lp, cfg, x)
-        layers.append(layer)
-        fetched = fetched + rows
-        if idx is not None:
-            held = held + n.astype(jnp.int32)
-            chosen.append(idx)
-    experts = state["experts"]
-    if chosen:
-        experts = lax.dynamic_update_slice_in_dim(
-            experts, jnp.stack(chosen), position, axis=1)
-    return x, {"layers": layers, "experts": experts}, held, fetched
-
-
-def prefill(params, cfg: KimiLinearConfig, ids, *, max_len: int, state=None,
-            position: int = 0, counters=None):
-    """ids [T] (T a multiple of ``prefill_block``) at ``position`` onward,
-    computed in full -> (float32 logits after the last token [V], the
-    state, the `COUNTERS` so far [8] int32, the experts the T tokens chose
-    [E layers, T, top_k]).
-
-    A prompt from position 0 enters a state with nothing in it and room for
-    ``max_len`` positions: the KDA layers' chunked form from zero states,
-    the full layers' materialised form.  A suffix enters ``state`` - what a
-    prefill of the ``position`` tokens before it returned, with its
-    ``counters``: its first chunk starts from each KDA layer's matrix state
-    and tail, and the full layers take the absorbed form against the
-    cache's first ``position + T`` rows.  The state is read, not consumed:
-    the one returned is new, and of its ``tokens_prefilled`` positions
-    ``tokens_reused`` = ``position`` came with the state handed in."""
-    t = ids.shape[0]
-    if state is None:
-        if position:
-            raise ValueError(f"position {position} needs the state of the "
-                             f"tokens before it")
-        state = empty_state(cfg, max_len, params["embed"].dtype)
-        counters = jnp.zeros((len(COUNTERS),), jnp.int32)
-        visible = None
-    else:
-        visible = position + t
-        if state["experts"].shape[1] < max(max_len, visible):
-            raise ValueError(f"the state handed in has no room for "
-                             f"{max(max_len, visible)} positions")
-    x, state, held, _ = _forward(params, cfg, ids, state, position, visible)
-    counters = jnp.stack([
-        counters[0] + t, position, counters[2],
-        counters[3] + dsv3.assignments(cfg, t), counters[4] + held,
-        params_nbytes(state["layers"]), counters[6],
-        counters[7] + t // cfg.kda_chunk * cfg.kinds.count("kda")]).astype(
-            jnp.int32)
-    chosen = state["experts"][:, position:position + t]
-    return dsv3.head(params, cfg, x[-1:])[0], state, counters, chosen
-
-
-def decode(params, cfg: KimiLinearConfig, logits, state, counters, *,
-           position: int, new_tokens: int):
-    """Greedy decoding through the state, on the device from first token to
-    last: ``new_tokens`` times the largest logit is taken and the token goes
-    through the stack - the KDA layers' recurrence, the full layers'
-    absorbed form against the cache.  ``logits`` follow the token at
-    ``position - 1``.
-    -> (ids [new_tokens] int32, the float32 logits each was chosen from
-    [new_tokens, V], the experts EVERY position so far chose
-    [E layers, max_len, top_k] - the prompt's, a snapshot's too -, the
-    state, the counters)."""
-    per_token = jnp.zeros((len(COUNTERS),), jnp.int32).at[2].set(1).at[3].set(
-        dsv3.assignments(cfg, 1))
-
-    def body(i, carry):
-        logits, state, ids, chosen_from, counters = carry
-        token = jnp.argmax(logits).astype(jnp.int32)
-        ids = ids.at[i].set(token)
-        chosen_from = lax.dynamic_update_slice_in_dim(
-            chosen_from, logits[None], i, axis=0)
-        x, state, held, fetched = _forward(params, cfg, token[None], state,
-                                           position + i, None)
-        counters = counters + per_token.at[4].set(held).at[6].set(fetched)
-        return (dsv3.head(params, cfg, x)[0], state, ids, chosen_from,
-                counters)
-
-    _, state, ids, chosen_from, counters = lax.fori_loop(
-        0, new_tokens, body,
-        (logits, state, jnp.zeros((new_tokens,), jnp.int32),
-         jnp.zeros((new_tokens,) + logits.shape, F32), counters))
-    return ids, chosen_from, state["experts"], state, counters
+# `models/deepseek_v3.py`'s stack, prefill and decode with this module's
+# mixers in: a PROMPT from position 0 takes the KDA layers' chunked form from
+# zero states and the full layers' materialised form; a SUFFIX's first chunk
+# starts from each KDA layer's matrix state and tail, and the full layers
+# take the absorbed form against the cache's first ``position + T`` rows; a
+# DECODE STEP the KDA layers' recurrence and the absorbed form.  The
+# counters are `COUNTERS` [8].
+STACK = dsv3.Stack(
+    COUNTERS, empty_state, layers="layers",
+    mixers=lambda cfg: [functools.partial(_mix, kind=kind)
+                        for kind in cfg.kinds],
+    prefilled=lambda cfg, t: {
+        "kda_chunks": t // cfg.kda_chunk * cfg.kinds.count("kda")})
+prefill = functools.partial(dsv3.prefill, stack=STACK)
+decode = functools.partial(dsv3.decode, stack=STACK)
 
 
 def generate(params, cfg: KimiLinearConfig, ids, new_tokens: int):
     """Prefill, then greedy decoding -> (new ids, the logits they were
     chosen from, the counters, the experts every position chose
     [E layers, T + new_tokens, top_k])."""
-    t = ids.shape[0]
-    logits, state, counters, _ = prefill(params, cfg, ids,
-                                         max_len=t + new_tokens)
-    new_ids, chosen_from, experts, _, counters = decode(
-        params, cfg, logits, state, counters, position=t,
-        new_tokens=new_tokens)
-    return new_ids, chosen_from, counters, experts
+    return lm_common.generate(cfg.language_model(), params, ids,
+                              new_tokens)[:4]
 
 
 # -- the routers' balance, for seeded weights --------------------------------
@@ -458,7 +358,7 @@ def _balancing_layer(lp, x, *, cfg: KimiLinearConfig, kind: str, rounds: int):
     -> (its output, an expert layer's balanced bias or None).  One compiled
     program a kind of layer."""
     state = _empty_kda(cfg, x.dtype) if kind == "kda" else None
-    x, _, _ = _mix(lp, cfg, kind, x, state, 0, None)
+    x, _, _ = _mix(lp, cfg, x, state, 0, None, kind=kind)
     return dsv3.balanced_feed_forward(lp, cfg, x, rounds)
 
 
@@ -469,10 +369,7 @@ def balanced_selection_bias(params, cfg: KimiLinearConfig, ids, *,
     stack, layer after layer over the calibration sequence ``ids`` [T] (whole
     chunks of the KDA form).  Returns one [num_experts] bias an expert layer,
     in the stored dtype."""
-    x = params["embed"][ids]
-    biases = []
-    for lp, kind in zip(params["layers"], cfg.kinds):
-        x, bias = _balancing_layer(lp, x, cfg=cfg, kind=kind, rounds=rounds)
-        if bias is not None:
-            biases.append(bias)
-    return biases
+    return lm_common.balanced_biases(params["embed"][ids], (
+        functools.partial(_balancing_layer, lp, cfg=cfg, kind=kind,
+                          rounds=rounds)
+        for lp, kind in zip(params["layers"], cfg.kinds)))

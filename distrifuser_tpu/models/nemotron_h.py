@@ -46,9 +46,9 @@ from jax import lax
 
 from ..ops import moe, ssm
 from ..ops.attention import causal_gqa_sdpa
+from . import lm_common
 from .language_model import LanguageModel
-
-F32 = jnp.float32
+from .lm_common import F32, rms_norm
 
 # counters the generation returns with its ids
 COUNTERS = ("tokens_prefilled", "tokens_decoded", "expert_assignments",
@@ -118,8 +118,6 @@ def nemotron_h_config_from_json(d: Dict[str, Any]) -> NemotronHConfig:
     the router is ``chips`` times as wide; ``layer_offset`` is where in
     ``hybrid_override_pattern`` the ``num_hidden_layers`` served layers
     start."""
-    ep = d.get("expert_parallel", {"chips": 1, "index": 0})
-    held = int(d["n_routed_experts"])
     start = int(d.get("layer_offset", 0))
     pattern = d["hybrid_override_pattern"][start:start + int(
         d["num_hidden_layers"])]
@@ -131,14 +129,9 @@ def nemotron_h_config_from_json(d: Dict[str, Any]) -> NemotronHConfig:
         raise ValueError("only relu2 experts and silu Mamba are built")
     if int(d.get("n_group", 1)) != 1 or int(d.get("n_shared_experts", 1)) != 1:
         raise ValueError("only n_group 1 and one shared expert are built")
-    names = {f.name for f in dataclasses.fields(NemotronHConfig)}
-    kw = {k: d[k] for k in names & set(d) if k not in (
-        "pattern", "n_routed_experts", "n_local_experts",
-        "first_local_expert")}
-    return NemotronHConfig(
-        pattern=pattern, n_routed_experts=held * int(ep["chips"]),
-        n_local_experts=held, first_local_expert=held * int(ep["index"]),
-        **kw)
+    return NemotronHConfig(**{
+        **lm_common.config_fields(NemotronHConfig, d),
+        **lm_common.expert_share(d, "n_routed_experts"), "pattern": pattern})
 
 
 # -- parameters ---------------------------------------------------------------
@@ -214,30 +207,15 @@ def init_leaf(key, name: str, shape, cfg: NemotronHConfig, dtype):
 
 def named_leaves(cfg: NemotronHConfig):
     """([(a leaf's own name, its shape)], the tree's structure)."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-    return [(str(getattr(path[-1], "key", path[-1])), shape)
-            for path, shape in leaves], treedef
+    return lm_common.named_leaves(param_shapes(cfg))
 
 
 def init_nemotron_h_params(key, cfg: NemotronHConfig, dtype=F32):
-    leaves, treedef = named_leaves(cfg)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        init_leaf(k, name, shape, cfg, dtype)
-        for k, (name, shape) in zip(keys, leaves)])
+    return lm_common.init_params(key, cfg, dtype, named_leaves=named_leaves,
+                                 init_leaf=init_leaf)
 
 
 # -- layers -------------------------------------------------------------------
-
-
-def rms_norm(scale, x, eps: float, groups: int = 1):
-    """RMSNorm in float32 over the last axis, or over each of ``groups``
-    equal parts of it; the result in ``x``'s dtype."""
-    shape = x.shape
-    xf = x.astype(F32).reshape(shape[:-1] + (groups, shape[-1] // groups))
-    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (xf.reshape(shape) * scale.astype(F32)).astype(x.dtype)
 
 
 def _mamba_inputs(p, cfg, u):
@@ -335,11 +313,9 @@ def moe_layer(p, cfg: NemotronHConfig, u):
     return routed + shared, held, idx
 
 
-@jax.named_scope("lm.head")
 def head(params, cfg: NemotronHConfig, x):
     """x [T, D] -> float32 logits [T, V] over the held vocabulary."""
-    x = rms_norm(params["final_norm"]["scale"], x, cfg.norm_eps)
-    return jnp.dot(x, params["head"]["kernel"], preferred_element_type=F32)
+    return lm_common.head(params, x, cfg.norm_eps)
 
 
 # -- prefill, step, generation ------------------------------------------------
@@ -415,13 +391,10 @@ def balanced_selection_bias(params, cfg: NemotronHConfig, ids, *,
     any fixed 64 of its 512 experts by +-4% from seed to seed, where a
     trained one loads them alike (+-1.4% after this, on other tokens).
     Returns one [n_routed_experts] bias an E layer, in the stored dtype."""
-    x = params["embed"][ids]
-    biases = []
-    for kind, lp in zip(cfg.pattern, params["layers"]):
-        x, bias = _balancing_layer(lp, x, cfg=cfg, kind=kind, rounds=rounds)
-        if bias is not None:
-            biases.append(bias)
-    return biases
+    return lm_common.balanced_biases(params["embed"][ids], (
+        functools.partial(_balancing_layer, lp, cfg=cfg, kind=kind,
+                          rounds=rounds)
+        for kind, lp in zip(cfg.pattern, params["layers"])))
 
 
 def _assignments(cfg: NemotronHConfig, tokens: int) -> int:
@@ -438,8 +411,9 @@ def prefill(params, cfg: NemotronHConfig, ids, *, max_len: int):
              for kind in cfg.pattern]
     x, state, held, chosen = _forward(params, cfg, ids, state, 0)
     t = ids.shape[0]
-    counters = jnp.stack([jnp.int32(t), jnp.int32(0),
-                          jnp.int32(_assignments(cfg, t)), held])
+    counters = lm_common.count(
+        COUNTERS, jnp.zeros((len(COUNTERS),), jnp.int32), tokens_prefilled=t,
+        expert_assignments=_assignments(cfg, t), expert_assignments_held=held)
     return head(params, cfg, x[-1:])[0], state, counters, chosen
 
 
@@ -451,40 +425,25 @@ def decode(params, cfg: NemotronHConfig, logits, state, counters, *,
     -> (ids [new_tokens] int32, the float32 logits each was chosen from
     [new_tokens, V], the experts each chose on its way through the stack
     [new_tokens, E layers, top_k], the state, the counters)."""
-    per_token = jnp.asarray([0, 1, _assignments(cfg, 1), 0], jnp.int32)
     n_e, k = cfg.pattern.count("E"), cfg.num_experts_per_tok
 
-    def body(i, carry):
-        logits, state, ids, chosen_from, experts, counters = carry
-        token = jnp.argmax(logits).astype(jnp.int32)
-        ids = ids.at[i].set(token)
-        chosen_from = lax.dynamic_update_slice_in_dim(
-            chosen_from, logits[None], i, axis=0)
-        x, state, held, chosen = _forward(params, cfg, token[None], state,
-                                          position + i)
-        experts = lax.dynamic_update_slice_in_dim(
-            experts, chosen.reshape(1, n_e, k), i, axis=0)
-        counters = counters + per_token.at[3].set(held)
-        return (head(params, cfg, x)[0], state, ids, chosen_from, experts,
-                counters)
+    def step(token, state, at):
+        x, state, held, chosen = _forward(params, cfg, token, state, at)
+        return head(params, cfg, x)[0], state, dict(
+            tokens_decoded=1, expert_assignments=_assignments(cfg, 1),
+            expert_assignments_held=held), chosen.reshape(n_e, k)
 
-    _, state, ids, chosen_from, experts, counters = lax.fori_loop(
-        0, new_tokens, body,
-        (logits, state, jnp.zeros((new_tokens,), jnp.int32),
-         jnp.zeros((new_tokens,) + logits.shape, F32),
-         jnp.zeros((new_tokens, n_e, k), jnp.int32), counters))
-    return ids, chosen_from, experts, state, counters
+    return lm_common.greedy_decode(
+        step, logits, state, counters, names=COUNTERS, position=position,
+        new_tokens=new_tokens,
+        record=jnp.zeros((new_tokens, n_e, k), jnp.int32))
 
 
 def generate(params, cfg: NemotronHConfig, ids, new_tokens: int):
     """Prefill, then greedy decoding -> (new ids, the logits they were
     chosen from, the counters, the experts every token but the last new one
     chose [E layers, T + new_tokens - 1, top_k])."""
-    t = ids.shape[0]
-    logits, state, counters, chosen = prefill(params, cfg, ids,
-                                              max_len=t + new_tokens)
-    new_ids, chosen_from, experts, _, counters = decode(
-        params, cfg, logits, state, counters, position=t,
-        new_tokens=new_tokens)
+    new_ids, chosen_from, counters, experts, _, chosen = lm_common.generate(
+        cfg.language_model(), params, ids, new_tokens)
     chosen = jnp.concatenate([chosen, experts[:-1].swapaxes(0, 1)], axis=1)
     return new_ids, chosen_from, counters, chosen

@@ -96,6 +96,7 @@ every layer [layers, max_len, top_k].  One sequence at a time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -105,11 +106,10 @@ from jax import lax
 
 from ..ops import moe
 from ..ops.gqa_cache import cache_attention
-from .deepseek_v3 import rms_norm
+from . import lm_common
 from .language_model import LanguageModel
+from .lm_common import F32, rms_norm
 from .weights import params_nbytes
-
-F32 = jnp.float32
 
 # counters the generation returns with its ids; ``tokens_reused``: of the
 # positions the cache covers after prefill, those a cache handed in already
@@ -133,7 +133,6 @@ COUNTERS = ("tokens_prefilled", "tokens_reused", "tokens_decoded",
             "denoise_passes", "commit_passes", "expert_assignments",
             "expert_assignments_held", "experts_fetched", "kv_cache_bytes",
             "stack_sweeps", "kv_rows_fetched")
-_C = {name: i for i, name in enumerate(COUNTERS)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,23 +202,14 @@ def sdar_config_from_json(d: Dict[str, Any]) -> SdarConfig:
     the router is ``chips`` times as wide; ``num_hidden_layers`` layers from
     the first are served; ``block_length``, ``denoising_steps``,
     ``prefill_block``, ``cache_dtype`` and ``commit_pass`` are ours."""
-    built = {"model_type": "sdar_moe", "mlp_only_layers": [],
-             "decoder_sparse_step": 1, "use_sliding_window": False,
-             "rope_scaling": None, "attention_bias": False,
-             "norm_topk_prob": True, "hidden_act": "silu",
-             "tie_word_embeddings": False}
-    for key, want in built.items():
-        if d.get(key, want) != want:
-            raise ValueError(f"only {key} = {want!r} is built, the "
-                             f"configuration says {d[key]!r}")
-    ep = d.get("expert_parallel", {"chips": 1, "index": 0})
-    held = int(d["num_experts"])
-    names = {f.name for f in dataclasses.fields(SdarConfig)}
-    kw = {k: d[k] for k in names & set(d) if k not in (
-        "num_experts", "n_local_experts", "first_local_expert")}
-    return SdarConfig(
-        num_experts=held * int(ep["chips"]), n_local_experts=held,
-        first_local_expert=held * int(ep["index"]), **kw)
+    lm_common.refuse_unbuilt(d, {
+        "model_type": "sdar_moe", "mlp_only_layers": [],
+        "decoder_sparse_step": 1, "use_sliding_window": False,
+        "rope_scaling": None, "attention_bias": False,
+        "norm_topk_prob": True, "hidden_act": "silu",
+        "tie_word_embeddings": False})
+    return SdarConfig(**{**lm_common.config_fields(SdarConfig, d),
+                         **lm_common.expert_share(d, "num_experts")})
 
 
 # -- parameters ---------------------------------------------------------------
@@ -282,22 +272,14 @@ def init_leaf(key, name: str, shape, cfg: SdarConfig, dtype):
 def named_leaves(cfg: SdarConfig):
     """([(a leaf's name - its own key; its norm's for a scale -, its
     shape)], the tree's structure)."""
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
-
-    def name(path):
-        keys = [str(getattr(k, "key", k)) for k in path]
-        return keys[-2] if keys[-1] == "scale" else keys[-1]
-
-    return [(name(path), shape) for path, shape in leaves], treedef
+    return lm_common.named_leaves(
+        param_shapes(cfg),
+        name=lambda keys: keys[-2] if keys[-1] == "scale" else keys[-1])
 
 
 def init_sdar_params(key, cfg: SdarConfig, dtype=F32):
-    leaves, treedef = named_leaves(cfg)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree_util.tree_unflatten(treedef, [
-        init_leaf(k, name, shape, cfg, dtype)
-        for k, (name, shape) in zip(keys, leaves)])
+    return lm_common.init_params(key, cfg, dtype, named_leaves=named_leaves,
+                                 init_leaf=init_leaf)
 
 
 # -- layers -------------------------------------------------------------------
@@ -391,11 +373,9 @@ def moe_layer(p, cfg: SdarConfig, u, calls: int = 1):
             sum(held for _, held in parts), idx)
 
 
-@jax.named_scope("lm.head")
 def head(params, cfg: SdarConfig, x):
     """x [T, d] -> float32 logits [T, V] over the held vocabulary."""
-    x = rms_norm(params["final_norm"]["scale"], x, cfg.rms_norm_eps)
-    return jnp.dot(x, params["head"]["kernel"], preferred_element_type=F32)
+    return lm_common.head(params, x, cfg.rms_norm_eps)
 
 
 # -- prefill, pass, generation -------------------------------------------------
@@ -444,12 +424,7 @@ def assignments(cfg: SdarConfig, rows: int) -> int:
     return rows * cfg.num_hidden_layers * cfg.num_experts_per_tok
 
 
-def _count(counters, **add):
-    """``counters`` with each named one moved by its amount."""
-    for name, amount in add.items():
-        counters = counters.at[_C[name]].add(
-            jnp.asarray(amount).astype(jnp.int32))
-    return counters
+_count = functools.partial(lm_common.count, COUNTERS)
 
 
 def prefill(params, cfg: SdarConfig, ids, *, max_len: int, state=None,
@@ -459,31 +434,22 @@ def prefill(params, cfg: SdarConfig, ids, *, max_len: int, state=None,
     to `decode`, which starts from MASK ids -, the state, the `COUNTERS` so
     far int32, the experts the T ids chose [layers, T, top_k]).
 
-    A prompt from position 0 enters a state with nothing in it and room for
-    ``max_len`` positions, over its own keys.  A suffix enters ``state`` -
-    what a prefill of the ``position`` ids before it returned, with its
-    ``counters`` - against the cache's first ``position + T`` rows; the
-    state is read, not consumed: the one returned is new, and of its
-    ``tokens_prefilled`` positions ``tokens_reused`` = ``position`` came
-    with the state handed in."""
+    `models/language_model.py`'s ``prefill`` and ``prefill_from`` both: a
+    prompt from position 0 attends over its own keys, a suffix entering
+    ``state`` against the cache's first ``position + T`` rows (of
+    ``tokens_prefilled``, ``tokens_reused`` = ``position``)."""
     t = ids.shape[0]
-    if state is None:
-        if position:
-            raise ValueError(f"position {position} needs the state of the "
-                             f"tokens before it")
-        state = empty_state(cfg, max_len, params["embed"].dtype)
-        counters = jnp.zeros((len(COUNTERS),), jnp.int32)
-        visible = None
-    else:
-        visible = position + t
-        if state["cache"][0]["k"].shape[1] < max(max_len, visible):
-            raise ValueError(f"the state handed in has no room for "
-                             f"{max(max_len, visible)} positions")
+    visible = None if state is None else position + t
+    state, counters = lm_common.enter_state(
+        state, counters, COUNTERS, position=position,
+        empty=lambda: empty_state(cfg, max_len, params["embed"].dtype),
+        room=lambda state: state["cache"][0]["k"].shape[1],
+        needed=max(max_len, position + t))
     x, state, held, chosen, _ = _forward(params, cfg, ids, state, position,
                                          visible)
     counters = _count(
-        counters.at[_C["tokens_reused"]].set(position).at[
-            _C["kv_cache_bytes"]].set(params_nbytes(state["cache"])),
+        counters, put={"tokens_reused": position,
+                       "kv_cache_bytes": params_nbytes(state["cache"])},
         tokens_prefilled=t, expert_assignments=assignments(cfg, t),
         expert_assignments_held=held)
     return head(params, cfg, x[-1:])[0], state, counters, chosen
@@ -620,11 +586,6 @@ def decode(params, cfg: SdarConfig, logits, state, counters, *,
 def generate(params, cfg: SdarConfig, ids, new_tokens: int):
     """Prefill, then generation by blocks -> (new ids, the logits each was
     fixed from, the counters, the record `decode` returns)."""
-    t = ids.shape[0]
-    logits, state, counters, _ = prefill(params, cfg, ids,
-                                         max_len=t + new_tokens)
-    new_ids, fixed_from, record, _, counters = decode(
-        params, cfg, logits, state, counters, position=t,
-        new_tokens=new_tokens)
-    return new_ids, fixed_from, counters, record
+    return lm_common.generate(cfg.language_model(), params, ids,
+                              new_tokens)[:4]
 

@@ -1,14 +1,14 @@
 """The DeepSeek-V3-style language model (Kanana-2's published keys) at a
 small size, seeded weights: prefill and decode through the latent cache
 against the plain reference's one full forward (`benchmark/reference`) by
-logits; the two forms of latent attention against each other; a suffix
-entering a snapshot; one chip's share of the experts against the uncut
+logits; the two forms of latent attention against each other; the rewriter's
+snapshot (a suffix entering one: `tests/test_language_models.py`, every
+model's); one chip's share of the experts against the uncut
 layer; the gated form of the expert kernels; the decode step's single-pass
 attention kernel (interpreted) against the XLA form, alone and as the
 model's route; the issue's arithmetic."""
 
 import os
-import re
 import sys
 
 import jax
@@ -385,49 +385,7 @@ def test_rotary_turns_pairs_and_keeps_the_relative_position():
     close(jnp.sum(q[1] * k[3]), jnp.sum(q[2] * k[4]), tol=1e-5)
 
 
-# -- a suffix entering a snapshot ---------------------------------------------
-
-
-def test_prefill_from_a_snapshot_is_the_prefill_of_all_the_ids(params):
-    ids, cut, room = jnp.asarray(token_ids(T)), 24, T + NEW
-    whole = lm.prefill(params, CFG, ids, max_len=room)
-    _, state, counters, _ = lm.prefill(params, CFG, ids[:cut], max_len=room)
-    before = jax.tree.map(np.asarray, (state, counters))
-    entered = jax.jit(lambda p, i, s, c: lm.prefill(
-        p, CFG, i, max_len=room, state=s, counters=c, position=cut))(
-            params, ids[cut:], state, counters)
-    close(entered[0], whole[0], tol=1e-5)
-    for a, b in zip(jax.tree.leaves(entered[1]), jax.tree.leaves(whole[1])):
-        close(a, b, tol=1e-5)
-    assert np.array_equal(entered[3], whole[3][:, cut:])
-    got, want = np.asarray(entered[2]).tolist(), np.asarray(whole[2]).tolist()
-    assert got[1] == cut and want[1] == 0  # tokens_reused
-    assert got[:1] + got[2:] == want[:1] + want[2:]
-    # the snapshot is not consumed: a second suffix enters the same state
-    for a, b in zip(jax.tree.leaves((state, counters)),
-                    jax.tree.leaves(before)):
-        assert np.array_equal(np.asarray(a), b)
-    other = jnp.asarray(token_ids(T - cut, seed=9))
-    again = lm.prefill(params, CFG, other, max_len=room, state=state,
-                       counters=counters, position=cut)
-    close(again[0], lm.prefill(params, CFG, jnp.concatenate(
-        [ids[:cut], other]), max_len=room)[0], tol=1e-5)
-    # ... and decoding goes on from the entered state as from the whole
-    a = lm.decode(params, CFG, *entered[:3], position=T, new_tokens=4)
-    b = lm.decode(params, CFG, *whole[:3], position=T, new_tokens=4)
-    assert np.array_equal(a[0], b[0])
-    close(a[1], b[1], tol=1e-5)
-
-
-def test_a_state_without_room_and_a_position_without_a_state_are_refused(
-        params):
-    ids = jnp.asarray(token_ids(16))
-    with pytest.raises(ValueError, match="needs the state"):
-        lm.prefill(params, CFG, ids, max_len=32, position=8)
-    _, state, counters, _ = lm.prefill(params, CFG, ids, max_len=16)
-    with pytest.raises(ValueError, match="no room"):
-        lm.prefill(params, CFG, ids, max_len=32, state=state,
-                   counters=counters, position=16)
+# -- the snapshot (a suffix entering one: `tests/test_language_models.py`) -----
 
 
 def test_the_rewriter_snapshots_the_instruction_for_this_model_too(params):
@@ -598,19 +556,3 @@ def test_balanced_selection_bias_evens_the_held_experts_load(params):
     for lp, b in zip(balanced["layers"][CFG.first_k_dense_replace:], biases):
         lp["ffn"] = dict(lp["ffn"], e_score_correction_bias=b)
     assert spread(balanced) < 1.1 < spread(params)
-
-
-# -- the compiled prompt program ----------------------------------------------
-
-
-def test_no_array_of_all_positions_squared_in_the_prompts_program(params):
-    """A 1024-token prompt by query blocks of `mla.QUERY_BLOCK`: the compiled
-    program's text holds no array with the prompt's length twice among its
-    dims."""
-    t = 1024
-    text = jax.jit(lambda p, i: lm.prefill(p, CFG, i, max_len=t)[0]).lower(
-        params, jnp.zeros((t,), jnp.int32)).compile().as_text()
-    shapes = {tuple(int(n) for n in dims.split(","))
-              for dims in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
-    assert (CFG.num_attention_heads, mla.QUERY_BLOCK, t) in shapes  # a block's
-    assert not [s for s in shapes if s.count(t) >= 2]

@@ -148,71 +148,17 @@ def test_prefill_then_decode_across_a_chunk_and_a_window_boundary(params):
     assert np.asarray(state[0]["ks"][total // C - 1]).any()
 
 
-# (position, T) of a suffix that enters the state of the bytes before it
-SPLITS = {
-    "inside one window": (W + 2 * C, 3 * C),
-    "across a window boundary": (2 * W - 2 * C, 5 * C),
-    "from a window boundary": (2 * W, 3 * C),
-    "from zero": (0, W + 4 * C),
-    "longer than a window": (W - C, 2 * W + 3 * C),
-    "to a window boundary": (W + C, W - C),
-}
-
-
-@pytest.mark.parametrize("split", SPLITS)
-def test_a_suffix_entering_the_prefixs_state_is_the_full_prefill(params,
-                                                                 split):
-    """The prompt prefilled whole, and its first ``position`` bytes
-    prefilled, then the other T through that state: the same logits, the
-    same state leaf by leaf, the same counters but for `bytes_reused`, the
-    same bytes decoded from either - and the prefix's state is still
-    there."""
-    position, t = SPLITS[split]
-    ids, new = jnp.asarray(byte_ids(position + t, seed=11)), 2 * C + 1
-    max_len = position + t + new
-    want = lm.prefill(params, CFG, ids, max_len=max_len)
-    state, counters = None, None
-    if position:
-        _, state, counters, _ = lm.prefill(params, CFG, ids[:position],
-                                           max_len=max_len)
-        before = jax.tree.map(np.asarray, state)
-    got = jax.jit(lambda p, ids, state, counters: lm.prefill(
-        p, CFG, ids, max_len=max_len, state=state, counters=counters,
-        position=position))(params, ids[position:], state, counters)
-    close(got[0], want[0], tol=1e-5)
-    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1]),
-                    strict=True):
-        close(a, b, tol=1e-5)
-    end = position + t
-    assert dict(zip(lm.COUNTERS, np.asarray(got[2]).tolist())) == {
-        "bytes_prefilled": end, "bytes_decoded": 0,
-        "summaries_written": end // C, "windows_rolled": end // W,
-        "state_bytes": lm.params_nbytes(want[1]), "bytes_reused": position,
-        "state_rows_read": 0}
-    assert np.array_equal(np.asarray(got[2])[:5], np.asarray(want[2])[:5])
-    decoded = [np.asarray(lm.decode(params, CFG, *out[:3], position=end,
-                                    new_tokens=new)[0]) for out in (got, want)]
-    assert np.array_equal(*decoded)
-    if position:  # read, not consumed
-        for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(before),
-                        strict=True):
-            assert np.array_equal(np.asarray(a), b)
-
-
-@pytest.mark.parametrize("position,t,match", [
-    (W + 2, C, "whole chunks"), (W, C + 1, "whole chunks"),
-    (W, C, "needs the state"), (0, W + C, "no room")])
-def test_an_entering_prefill_of_broken_chunks_or_no_state_is_refused(
-        params, position, t, match):
-    ids = jnp.asarray(byte_ids(t))
-    state, counters = None, None
-    if match != "needs the state":
-        _, state, counters, _ = lm.prefill(
-            params, CFG, jnp.asarray(byte_ids(W)),
-            max_len=W + C if match == "no room" else 2 * W + 2 * C)
-    with pytest.raises(ValueError, match=match):
-        lm.prefill(params, CFG, ids, max_len=2 * W + 2 * C, state=state,
-                   counters=counters, position=position)
+@pytest.mark.parametrize("position,t", [(W + 2, C), (W, C + 1)])
+def test_an_entering_prefill_of_broken_chunks_is_refused(params, position,
+                                                         t):
+    """(A position without a state and a state without room: every model's,
+    `tests/test_language_models.py`.)"""
+    _, state, counters, _ = lm.prefill(params, CFG, jnp.asarray(byte_ids(W)),
+                                       max_len=2 * W + 2 * C)
+    with pytest.raises(ValueError, match="whole chunks"):
+        lm.prefill(params, CFG, jnp.asarray(byte_ids(t)),
+                   max_len=2 * W + 2 * C, state=state, counters=counters,
+                   position=position)
 
 
 @pytest.mark.parametrize("position,t,match", [
@@ -251,20 +197,6 @@ def test_with_chunks_of_one_and_no_offset_eva_is_plain_causal_attention():
     row = eva.decode_attention(q[t], ring, ring_v, ks, vs, position=t,
                                window=W, chunk=1)
     close(row, plain_causal(q[:t + 1], k[:t + 1], v[:t + 1])[t])
-
-
-def test_no_array_of_all_positions_squared_in_the_compiled_prefill(params):
-    """Blocked by queries: the compiled program's text holds no
-    [heads, T, T] array, of any layout."""
-    import re
-
-    t = 4 * W
-    text = jax.jit(lambda p, ids: lm.prefill(p, CFG, ids, max_len=t)).lower(
-        params, jnp.zeros((t,), jnp.int32)).compile().as_text()
-    shapes = {tuple(int(n) for n in dims.split(","))
-              for dims in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
-    assert shapes and not [s for s in shapes if s.count(t) >= 2]
-    assert any(W in s for s in shapes)  # a window's keys are there
 
 
 def test_a_residual_stream_in_bfloat16_is_another_result(params):

@@ -2,8 +2,9 @@
 a small size, seeded weights: prefill and decode through BOTH kinds of state
 - the KDA layers' matrix states and convolution tails, the full layers'
 latent caches - against the plain reference's one full forward
-(`benchmark/reference`) by logits; a suffix entering a snapshot, which holds
-a recurrent state and a latent cache side by side; latent attention without
+(`benchmark/reference`) by logits; the rewriter's snapshot, which holds
+a recurrent state and a latent cache side by side (a suffix entering one:
+`tests/test_language_models.py`, every model's); latent attention without
 its rotary embedding, and Kanana's path left as it was; one chip's share of
 the experts against the uncut layer; the issue's arithmetic."""
 
@@ -244,55 +245,6 @@ def test_a_matrix_state_in_bfloat16_moves_the_logits(params):
 
 
 # -- the snapshot ------------------------------------------------------------
-
-
-def test_prefill_from_a_snapshot_is_the_prefill_of_all_the_ids(params):
-    """The snapshot is a VALUE the suffix's first chunk starts from - nine
-    numbers of tail and a matrix a KDA layer - beside rows before a length."""
-    ids, cut, room = jnp.asarray(token_ids(T)), 24, T + NEW
-    whole = lm.prefill(params, CFG, ids, max_len=room)
-    _, state, counters, _ = lm.prefill(params, CFG, ids[:cut], max_len=room)
-    before = jax.tree.map(np.asarray, (state, counters))
-    entered = jax.jit(lambda p, i, s, c: lm.prefill(
-        p, CFG, i, max_len=room, state=s, counters=c, position=cut))(
-            params, ids[cut:], state, counters)
-    close(entered[0], whole[0], tol=1e-5)
-    for a, b in zip(jax.tree.leaves(entered[1]), jax.tree.leaves(whole[1])):
-        close(a, b, tol=1e-5)
-    assert np.array_equal(entered[3], whole[3][:, cut:])
-    got, want = np.asarray(entered[2]).tolist(), np.asarray(whole[2]).tolist()
-    assert got[1] == cut and want[1] == 0  # tokens_reused
-    assert got[:1] + got[2:] == want[:1] + want[2:]
-    # the snapshot is unchanged afterwards: a second suffix enters the same
-    for a, b in zip(jax.tree.leaves((state, counters)),
-                    jax.tree.leaves(before)):
-        assert np.array_equal(np.asarray(a), b)
-    other = jnp.asarray(token_ids(T - cut, seed=9))
-    again = lm.prefill(params, CFG, other, max_len=room, state=state,
-                       counters=counters, position=cut)
-    close(again[0], lm.prefill(params, CFG, jnp.concatenate(
-        [ids[:cut], other]), max_len=room)[0], tol=1e-5)
-    # a suffix that ignored the state it enters would not be the prefill
-    zeros = jax.tree.map(jnp.zeros_like, state)
-    wrong = lm.prefill(params, CFG, ids[cut:], max_len=room, state=zeros,
-                       counters=counters, position=cut)
-    assert float(jnp.abs(wrong[0] - whole[0]).max()) > 1e-2
-    # ... and decoding goes on from the entered state as from the whole
-    a = lm.decode(params, CFG, *entered[:3], position=T, new_tokens=4)
-    b = lm.decode(params, CFG, *whole[:3], position=T, new_tokens=4)
-    assert np.array_equal(a[0], b[0])
-    close(a[1], b[1], tol=1e-5)
-
-
-def test_a_state_without_room_and_a_position_without_a_state_are_refused(
-        params):
-    ids = jnp.asarray(token_ids(16))
-    with pytest.raises(ValueError, match="needs the state"):
-        lm.prefill(params, CFG, ids, max_len=32, position=8)
-    _, state, counters, _ = lm.prefill(params, CFG, ids, max_len=16)
-    with pytest.raises(ValueError, match="no room"):
-        lm.prefill(params, CFG, ids, max_len=32, state=state,
-                   counters=counters, position=16)
 
 
 def test_the_rewriter_snapshots_the_instruction_for_this_model_too(params):

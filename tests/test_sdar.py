@@ -2,8 +2,8 @@
 small size, seeded weights: prefill and generation by diffusion over blocks
 through the KV cache against the plain reference's one cache-less forward
 over the served trajectory (`benchmark/reference`) - logits of every fixed
-id, the position fixed in every pass, the experts chosen; a suffix entering
-a snapshot; the block rule; the commit pass, alone and sharing a sweep of the
+id, the position fixed in every pass, the experts chosen (a suffix entering
+a snapshot: `tests/test_language_models.py`, every model's); the block rule; the commit pass, alone and sharing a sweep of the
 stack with the next block's first denoise pass; the MASK id; one chip's
 share of the experts against the uncut layer; what the configuration
 refuses."""
@@ -173,32 +173,6 @@ def test_the_reference_alone_generates_the_same_ids(params):
             fixed_in += [order[j] for j in range(4)]
     assert seq[T:] == np.asarray(new_ids).tolist()
     assert fixed_in == np.asarray(record["fixed_in_pass"]).tolist()
-
-
-def test_a_suffix_entering_a_snapshot_is_the_prefill_of_the_whole(params):
-    ids = jnp.asarray(token_ids(T, seed=7))
-    whole = lm.prefill(params, CFG, ids, max_len=T + NEW)
-    _, state, counters, _ = lm.prefill(params, CFG, ids[:24],
-                                       max_len=T + NEW)
-    kept = jax.tree.map(np.asarray, state)
-    entered = lm.prefill(params, CFG, ids[24:], max_len=T + NEW, state=state,
-                         counters=counters, position=24)
-    close(entered[0], whole[0])
-    for got, want in zip(jax.tree.leaves(entered[1]),
-                         jax.tree.leaves(whole[1])):
-        close(got, want)
-    assert np.array_equal(entered[3], np.asarray(whole[3])[:, 24:])
-    c = dict(zip(lm.COUNTERS, np.asarray(entered[2]).tolist()))
-    assert (c["tokens_prefilled"], c["tokens_reused"]) == (T, 24)
-    assert c["expert_assignments"] == T * 3 * 3
-    # the state handed in is read, not consumed
-    for before, after in zip(jax.tree.leaves(kept), jax.tree.leaves(state)):
-        assert np.array_equal(before, after)
-    with pytest.raises(ValueError, match="needs the state"):
-        lm.prefill(params, CFG, ids[24:], max_len=T + NEW, position=24)
-    with pytest.raises(ValueError, match="no room"):
-        lm.prefill(params, CFG, ids[24:], max_len=T + NEW + 8, state=state,
-                   counters=counters, position=24)
 
 
 def test_a_position_sees_its_whole_block_and_nothing_after_it(params):
