@@ -343,8 +343,9 @@ def empty_state(cfg: DeepseekV3Config, max_len: int, dtype):
 
 class Stack(NamedTuple):
     """What `prefill` and `decode` drive: this module's stack (`STACK`), or a
-    sibling's that keeps the feed-forward half, the record and these
-    counters and puts mixers of its own in (`models/kimi_linear.py`)."""
+    sibling's that keeps the record and these counters and puts mixers of
+    its own in (`models/kimi_linear.py`) - and a feed-forward half of its
+    own too (`models/lfm2.py`)."""
     counters: Tuple[str, ...]  # `COUNTERS`, more names after them
     empty_state: Callable  # (cfg, max_len, dtype) -> {layers: [...], "experts"}
     layers: str = "cache"  # the state's key of what the mixers carry
@@ -352,6 +353,8 @@ class Stack(NamedTuple):
     mixers: Callable = lambda cfg: itertools.repeat(_attend)
     # (cfg, t) -> what else a prefill of t tokens moves the counters by
     prefilled: Callable = lambda cfg, t: {}
+    # a layer's second half, of `feed_forward`'s signature
+    feed_forward: Callable = feed_forward
 
 
 STACK = Stack(COUNTERS, empty_state)
@@ -368,7 +371,7 @@ def _forward(params, cfg: DeepseekV3Config, ids, state, position, visible,
     for lp, layer, mix in zip(params["layers"], state[stack.layers],
                               stack.mixers(cfg)):
         x, layer, rows = mix(lp, cfg, x, layer, position, visible)
-        x, n, idx = feed_forward(lp, cfg, x)
+        x, n, idx = stack.feed_forward(lp, cfg, x)
         layers.append(layer)
         fetched = fetched + rows
         if idx is not None:

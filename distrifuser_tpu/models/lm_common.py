@@ -1,7 +1,7 @@
 """What every language model's module has, written once.
 
 A model's module (`nemotron_h`, `evabyte`, `deepseek_v3`, `kimi_linear`,
-`sdar`) writes its configuration, ``param_shapes``, ``init_leaf``, its
+`sdar`, `lfm2`) writes its configuration, ``param_shapes``, ``init_leaf``, its
 layers, the stack (``_forward``), its one-token step and the NAMES of its
 counters.  From here it takes the seeded tree, the norm, the head, the
 counters' arithmetic by name, the greedy loop, the entry into a state,
@@ -98,8 +98,13 @@ def gated_mlp(p, x):
 
 @jax.named_scope("lm.head")
 def head(params, x, eps: float):
-    """x [T, d] -> float32 logits [T, V] over the held vocabulary."""
+    """x [T, d] -> float32 logits [T, V] over the held vocabulary.  A tree
+    with no ``head`` ties it to the embedding: ``embed`` [V, d] contracted
+    over its SECOND axis, no transposed copy in memory."""
     x = rms_norm(params["final_norm"]["scale"], x, eps)
+    if "head" not in params:
+        return lax.dot_general(x, params["embed"], (((1,), (1,)), ((), ())),
+                               preferred_element_type=F32)
     return jnp.dot(x, params["head"]["kernel"], preferred_element_type=F32)
 
 

@@ -62,7 +62,8 @@ MIN_GROUPED_ROWS = 64
 
 
 def route(u, router_kernel, score_bias=None, *, top_k: int,
-          scale: float = 1.0, scoring: str = "sigmoid"):
+          scale: float = 1.0, scoring: str = "sigmoid",
+          denominator_eps: float = 0.0):
     """A router over ALL experts, in float32 throughout.
 
     ``u`` [T, D]; ``router_kernel`` [D, E].  ``scoring`` "sigmoid"
@@ -70,7 +71,8 @@ def route(u, router_kernel, score_bias=None, *, top_k: int,
     selection-only ``score_bias`` [E] added for the choice alone; "softmax"
     (Qwen3-MoE style, ``norm_topk_prob``): s = softmax(u W) over all E, no
     bias.  The ``top_k`` largest of s (+ bias) are chosen; their weights are
-    ``scale * s_i / sum of the chosen s``.
+    ``scale * s_i / (sum of the chosen s + denominator_eps)`` - the constant
+    static, and with the default 0.0 not in the program at all.
     Returns (expert ids [T, top_k] int32, weights [T, top_k] float32)."""
     logits = jnp.dot(u.astype(F32), router_kernel.astype(F32),
                      precision=lax.Precision.HIGHEST)
@@ -84,8 +86,10 @@ def route(u, router_kernel, score_bias=None, *, top_k: int,
     select = s if score_bias is None else s + score_bias.astype(F32)
     _, idx = lax.top_k(select, top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), weights
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    if denominator_eps:
+        total = total + denominator_eps
+    return idx.astype(jnp.int32), scale * chosen / total
 
 
 def balanced_bias(scores, *, top_k: int, rounds: int, step: float = 0.02):

@@ -34,14 +34,16 @@ def causal_conv1d(x, kernel, bias, tail):
 
     ``x`` [T, C]; ``kernel`` [K, C] (tap ``i`` multiplies the input K-1-i
     steps back, torch ``Conv1d(groups=C, padding=K-1)`` order); ``bias``
-    [C]; ``tail`` [K-1, C], the inputs that came before ``x`` (zeros at the
-    start of a sequence).  Returns (y [T, C] float32, the new tail).  The
-    same code serves a prefill (T tokens) and a decode step (T = 1)."""
+    [C] (None: the convolution has none); ``tail`` [K-1, C], the inputs
+    that came before ``x`` (zeros at the start of a sequence).  Returns
+    (y [T, C] float32, the new tail).  The same code serves a prefill (T
+    tokens) and a decode step (T = 1)."""
     k, t = kernel.shape[0], x.shape[0]
     padded = jnp.concatenate([tail.astype(x.dtype), x], axis=0)
-    y = bias.astype(F32)
+    y = None if bias is None else bias.astype(F32)
     for i in range(k):
-        y = y + padded[i:i + t].astype(F32) * kernel[i].astype(F32)
+        tap = padded[i:i + t].astype(F32) * kernel[i].astype(F32)
+        y = tap if y is None else y + tap
     return y, padded[t:]
 
 

@@ -33,6 +33,7 @@ from distrifuser_tpu.models import (  # noqa: E402
     deepseek_v3,
     evabyte,
     kimi_linear,
+    lfm2,
     nemotron_h,
     sdar,
 )
@@ -87,6 +88,14 @@ MODELS = {
         num_key_value_heads=2, head_dim=16, num_experts=16,
         n_local_experts=4, first_local_expert=4, num_experts_per_tok=3,
         prefill_block=8), sdar.init_sdar_params),
+    "lfm2": Model(lfm2.Lfm2Config(
+        num_hidden_layers=5, vocab_size=96, hidden_size=64,
+        intermediate_size=96, moe_intermediate_size=32, num_dense_layers=1,
+        num_attention_heads=4, num_key_value_heads=2, num_experts=16,
+        n_local_experts=4, first_local_expert=4, num_experts_per_tok=3,
+        prefill_block=8), lfm2.init_lfm2_params, splits=(
+            (24, 16),
+            (37, 3))),  # a suffix inside the convolution's 3-tap window
 }
 ENTERING = [name for name, m in MODELS.items() if m.splits]
 SPLITS = [pytest.param(name, *split, id=f"{name}-{split[0]}+{split[1]}")

@@ -1145,3 +1145,81 @@ def test_block_diffusion_rewrite_programs_compile_for_the_chip(
     for scope in ("lm.attn.proj", "lm.attn", "lm.moe.router",
                   "lm.moe.experts", "lm.head", "lm.sdar.unmask"):
         assert f"/{scope}/" in text, scope
+
+
+@pytest.fixture(scope="module")
+def conv_programs(topo):
+    """LFM2-24B-A2B: 20 layers (15 conv, 5 attention), 16 of 64 experts."""
+    from distrifuser_tpu.models import lfm2 as lm
+
+    return _rewrite_programs(topo, lm, lm.lfm2_config_from_json,
+                             "lfm2-24b-a2b-sdxl-rewrite.json")
+
+
+def test_convolution_attention_rewrite_programs_compile_for_the_chip(
+        conv_programs):
+    """Prefix: the instruction's 8064 tokens, the convolutions from zero
+    tails, the five attention layers by query blocks of 32 against rows of
+    128 - no array with the prompt's length twice among its dims -, the
+    experts on the grouped matmul.  The request's prefill: 128 ids ENTERING
+    the snapshot (read, not aliased).  Decode: the donated state - five KV
+    caches [4, 8704, 128] twice (two KV heads of 64 a row: nothing padded),
+    fifteen tails [2, 2048] and the record of the experts chosen - carried
+    in place; one gather kernel an expert layer over one row; every step's
+    attention the single-pass kernel, once an attention layer, under the
+    `lm.attn` scope, and no cache staged through VMEM; the head a
+    contraction over the embedding's second axis with no transposed copy of
+    it; the language model's scopes on its ops; weights and state fit."""
+    lp = conv_programs
+    cfg, t, n = lp.cfg, lp.t, lp.n
+    assert (t, n, t - n) == (8192, 8064, 128)
+    max_len = t + lp.spec.new_tokens
+    convs, attns = (cfg.kinds.count(k) for k in ("conv", "full_attention"))
+    assert (convs, attns, cfg.n_expert_layers, cfg.kv_pack) == (15, 5, 18, 2)
+    cache_bytes = attns * 2 * 4 * max_len * 128 * 2
+    state_bytes = (cache_bytes + convs * 2 * 2048 * 2
+                   + cfg.n_expert_layers * max_len * 4 * 4)
+    assert cache_bytes == 89_128_960
+
+    text = lp.prefix.as_text()
+    shapes = {tuple(int(x) for x in dims.split(","))
+              for dims in re.findall(r"\[((?:\d+,)+\d+)\]", text)}
+    assert (4, 8, 32, n) in shapes  # one query block's logits
+    assert not [s for s in shapes if s.count(n) >= 2]
+    assert "ragged-dot" in text and "expert_gather_matvec" not in text
+    assert lp.prefix.memory_analysis().temp_size_in_bytes < 2.0e9
+
+    mem = lp.entering.memory_analysis()
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < 0.3e9
+
+    assert jax.tree.map(lambda a: (a.shape, a.dtype),
+                        (lp.state, lp.counters)) == \
+        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot)
+    mem = lp.decode.memory_analysis()
+    assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert 6.4e9 < mem.argument_size_in_bytes < 6.6e9  # weights + state
+    text = lp.decode.as_text()
+    kernel = r"%expert_gather_matvec[\w.\-]* = "
+    assert len(re.findall(kernel, text)) == cfg.n_expert_layers == len(
+        re.findall(kernel + r"\(f32\[1,2048\]", text))  # one row a call
+    assert "ragged-dot" not in text
+    calls = [ln for ln in text.splitlines()
+             if re.match(r"\s*%gqa_cache_attention[\w.\-]* = ", ln)
+             and "custom-call(" in ln]
+    assert len(calls) == attns
+    assert all('custom_call_target="tpu_custom_call"' in ln and re.search(
+        r'op_name="[^"]*/lm\.attn/[^"]*pallas_call', ln) for ln in calls)
+    assert "gqa_cache_attention" not in lp.entering.as_text()
+    assert "gqa_cache_attention" not in lp.prefix.as_text()
+    from distrifuser_tpu.utils.overlap import cache_staging
+
+    assert cache_staging(text, shapes=[(4, max_len, 128)]) == {
+        "staged_bytes": 0, "staged_copies": 0, "writes": 2 * attns,
+        "writes_outside_hbm": 0}
+    # the tied head: no [2048, 16384] copy of the embedding anywhere
+    assert not re.search(r"bf16\[2048,16384\]", text)
+    for scope in ("lm.conv.proj", "lm.conv", "lm.attn.proj", "lm.attn",
+                  "lm.mlp", "lm.moe.router", "lm.moe.experts", "lm.head"):
+        assert f"/{scope}/" in text, scope
