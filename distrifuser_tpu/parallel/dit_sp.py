@@ -42,6 +42,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -958,7 +959,7 @@ class DiTDenoiseRunner:
         use_cuda_graph=False, via ordered io_callback inside the compiled
         loop otherwise."""
         self.scheduler.set_timesteps(num_inference_steps)
-        gs = jnp.asarray(guidance_scale, jnp.float32)
+        gs = np.float32(guidance_scale)
         if cap_mask is None:
             cap_mask = jnp.ones(enc.shape[:3], jnp.float32)
         cap_mask = jnp.asarray(cap_mask, jnp.float32)

@@ -44,6 +44,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -963,7 +964,7 @@ class MMDiTDenoiseRunner:
         assert end_step is None or start_step < end_step <= num_inference_steps, (
             start_step, end_step, num_inference_steps)
         self.scheduler.set_timesteps(num_inference_steps)
-        gs = jnp.asarray(guidance_scale, jnp.float32)
+        gs = np.float32(guidance_scale)
         if not self.cfg.use_compiled_step:
             return self._generate_stepwise(
                 jnp.asarray(latents), enc, pooled, gs, num_inference_steps,

@@ -809,7 +809,7 @@ class PipeFusionRunner:
         # re-trace later and must not read tables left by a different step
         # count (see DenoiseRunner.generate).
         self.scheduler.set_timesteps(num_inference_steps)
-        gs = jnp.asarray(guidance_scale, jnp.float32)
+        gs = np.float32(guidance_scale)
         if cap_mask is None:
             cap_mask = jnp.ones(enc.shape[:3], jnp.float32)
         cap_mask = jnp.asarray(cap_mask, jnp.float32)

@@ -1328,7 +1328,7 @@ class DenoiseRunner:
                 # without teaching the io_callback program a third body.
                 return self._generate_stepwise(
                     jnp.asarray(latents), prompt_embeds, added,
-                    jnp.asarray(guidance_scale, jnp.float32),
+                    np.float32(guidance_scale),
                     num_inference_steps, start_step, end_step, callback,
                 )
             # fused/hybrid modes: the callback rides io_callback inside a
@@ -1348,7 +1348,7 @@ class DenoiseRunner:
                     jnp.asarray(latents),
                     prompt_embeds,
                     added,
-                    jnp.asarray(guidance_scale, jnp.float32),
+                    np.float32(guidance_scale),
                 )
                 # block_until_ready only waits on the OUTPUT buffer; host
                 # callbacks drain on a separate thread, so without this
@@ -1364,7 +1364,7 @@ class DenoiseRunner:
                 jnp.asarray(latents),
                 jnp.asarray(prompt_embeds),
                 added,
-                jnp.asarray(guidance_scale, jnp.float32),
+                np.float32(guidance_scale),
                 num_inference_steps,
                 start_step,
                 end_step,
@@ -1374,7 +1374,7 @@ class DenoiseRunner:
                 and start_step == 0 and end_step is None):
             return self._generate_hybrid(
                 jnp.asarray(latents), jnp.asarray(prompt_embeds), added,
-                jnp.asarray(guidance_scale, jnp.float32), num_inference_steps,
+                np.float32(guidance_scale), num_inference_steps,
             )
         # Re-pin the scheduler tables on every call, not just at build time:
         # a cached jitted loop can RE-trace later (new input shapes), and the
@@ -1387,7 +1387,7 @@ class DenoiseRunner:
             jnp.asarray(latents),
             jnp.asarray(prompt_embeds),
             added,
-            jnp.asarray(guidance_scale, jnp.float32),
+            np.float32(guidance_scale),
         )
 
 

@@ -551,7 +551,14 @@ def _mk_output(images, tokenizers) -> PipelineOutput:
 def _build_decoder(cfg: DistriConfig, vae_config: vae_mod.VAEConfig):
     """(jitted decode fn, parallel?) for the config's geometry: sequence-
     parallel over sp when the latent divides, row-tiled above 2048px, plain
-    whole-latent otherwise (shared by the UNet and DiT pipelines)."""
+    whole-latent otherwise (shared by the UNet and DiT pipelines).
+
+    The decode fn is ``(vae params, latent, scaling, shift) -> float32
+    image [N, H, W * C]`` (`_for_the_host`): the VAE's descale, ``latent /
+    scaling + shift``, is the program's first op and not two eager ones in
+    front of it.  Both are run-time scalars (Python floats, weakly typed as
+    they were in the eager form), so the division stays a division by a
+    number the compiler cannot see."""
     parallel = (
         cfg.is_sp and cfg.vae_sp
         and cfg.latent_height % cfg.n_device_per_batch == 0
@@ -568,8 +575,8 @@ def _build_decoder(cfg: DistriConfig, vae_config: vae_mod.VAEConfig):
 
         n = cfg.n_device_per_batch
 
-        def _dec(p, l):
-            return shard_map(
+        def _dec(p, l, scaling, shift):
+            return _for_the_host(shard_map(
                 lambda p_, l_: gather_rows(
                     vae_mod.decode_sp(p_, vae_config, l_, n)
                 ),
@@ -577,15 +584,30 @@ def _build_decoder(cfg: DistriConfig, vae_config: vae_mod.VAEConfig):
                 in_specs=(P(), P(DP_AXIS, SP_AXIS)),
                 out_specs=P(DP_AXIS),
                 check_vma=False,
-            )(p, l)
+            )(p, l / scaling + shift))
 
         return jax.jit(_dec), True
     # Above 2048px the whole-latent decode's activations dominate HBM on one
     # chip; switch to the row-tiled decoder (models/vae.py).
     tile = 64 if cfg.latent_height > 128 else 0
     return jax.jit(
-        lambda p, l: vae_mod.decode(p, vae_config, l, tile=tile)
+        lambda p, l, scaling, shift: _for_the_host(vae_mod.decode(
+            p, vae_config, l / scaling + shift, tile=tile))
     ), False
+
+
+def _for_the_host(image):
+    """The decode programs' last op: the image ``[N, H, W, C]`` as float32
+    ``[N, H, W * C]``.  Widening on the device is exact and costs it
+    microseconds; on the chip machine's host the same cast took 12-13 ms an
+    image, `ml_dtypes`' loop or integer shifts alike (scripts/
+    to_host_forms.py; root PERF.md section 5).  The barrier keeps the
+    decoder's own rounding to its dtype where it was - a convert to float32
+    right behind it would let XLA drop the pair - and the minor dimension
+    of W * C numbers instead of C leaves no lane padding to carry."""
+    n, h, w, c = image.shape
+    image = jax.lax.optimization_barrier(image).astype(jnp.float32)
+    return image.reshape(n, h, w * c)
 
 
 def _normalize_prompts(prompt, negative_prompt):
@@ -631,6 +653,35 @@ def _pad_chunks(total: int, bs: int):
         yield i, i + n, bs - n
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _seeded_latents(seeds, shape, sigma):
+    noise = lambda s: jax.random.normal(  # noqa: E731
+        jax.random.PRNGKey(s), shape, jnp.float32)
+    for _ in range(seeds.ndim):
+        noise = jax.vmap(noise)
+    # the barrier keeps the scale a multiply of its own, as the eager op
+    # was: without it XLA folds sigma into the normal's last constant and
+    # the low bits move
+    return jax.lax.optimization_barrier(noise(seeds)) * sigma
+
+
+def seeded_latents(seeds, shape, sigma):
+    """Initial noise scaled by the scheduler's ``sigma``, float32, as ONE
+    cached program: ``seeds`` an int -> ``shape`` from that one key (the
+    pipelines' ``seed=``); a sequence of ints -> ``[len(seeds), *shape]``,
+    row r from ``PRNGKey(seeds[r])`` alone (the serve plane's seed a
+    request; threefry counts depend on a row's element count, not on the
+    leading axis).  Bit for bit what ``PRNGKey(seed)`` + ``normal`` +
+    an eager multiply give for every seed ``PRNGKey`` takes: the seeds go in
+    as int64, which jit narrows exactly as ``PRNGKey`` narrows a Python
+    int, and the keys are built inside.  The host does no tracing here
+    after the first call of a shape: stacking the keys and vmapping the
+    draw eagerly traced it on every request, with the device idle (17-20
+    ms under the profiler, 3-4 ms of a window's request: root PERF.md
+    section 6, PR 46)."""
+    return _seeded_latents(np.asarray(seeds, np.int64), tuple(shape), sigma)
+
+
 def _batched_generate(cfg, scheduler, prompts, negs, num_images_per_prompt,
                       seed, latents, in_channels, run_chunk):
     """Arbitrary prompt counts over the fixed-batch jitted denoise loop.
@@ -652,12 +703,16 @@ def _batched_generate(cfg, scheduler, prompts, negs, num_images_per_prompt,
     lat_shape = (total, cfg.latent_height, cfg.latent_width, in_channels)
     if latents is None:
         with span("distri.pipe.latents"):
-            latents = jax.random.normal(jax.random.PRNGKey(seed), lat_shape,
-                                        jnp.float32)
-            latents = latents * scheduler.init_noise_sigma
+            latents = seeded_latents(seed, lat_shape,
+                                     scheduler.init_noise_sigma)
     else:
         latents = jnp.asarray(latents, jnp.float32)
         assert latents.shape == lat_shape, (latents.shape, lat_shape)
+    if total == bs:
+        # one whole chunk (the serve plane's every call): the slice, the
+        # padding and the concatenate below would each be an identity, and
+        # an eager one is a program the device waits for
+        return run_chunk(prompts, negs, latents, bs)
     outs = []
     for i, stop, pad in _pad_chunks(total, bs):
         cp, cn = prompts[i:stop], negs[i:stop]
@@ -676,11 +731,14 @@ def _decode_chunked(decode, vae_params, latent, bs, scaling, shift=0.0):
     rows): the jitted decoder traces once per shape, and the sequence-
     parallel decode's shard_map needs its dp-divisible batch — an arbitrary
     total from _batched_generate must not reach it directly.  ``shift`` is
-    the SD3-family latent re-centering (VAEConfig.shift_factor)."""
+    the SD3-family latent re-centering (VAEConfig.shift_factor); the
+    descale by both is the decode program's own (`_build_decoder`)."""
+    if latent.shape[0] == bs:  # one whole chunk: nothing to cut or join
+        return decode(vae_params, latent, scaling, shift)
     outs = []
     for i, stop, pad in _pad_chunks(latent.shape[0], bs):
         cl = _pad_rows(latent[i:stop], pad)
-        img = decode(vae_params, cl / scaling + shift)
+        img = decode(vae_params, cl, scaling, shift)
         outs.append(img[:bs - pad] if pad else img)
     return jnp.concatenate(outs, axis=0)
 
@@ -1079,19 +1137,23 @@ class _GenerationMixin:
                     self.distri_config.batch_size,
                     self.vae_config.scaling_factor, self._vae_shift,
                 )
+            # the copy home starts when the decode ends, not when this
+            # thread next runs
+            image.copy_to_host_async()
             # the wait np.asarray made anyway, split from its copy
             ph.next("distri.pipe.wait_device", stage="device_wait")
             jax.block_until_ready(image)
             ph.next("distri.pipe.to_host", stage="to_host")
-            # into host memory this pipeline has used before (see
-            # `_HostImages`), widened on the way
-            host = np.asarray(image)
-            image = self._host_images.take(host.shape)
-            np.copyto(image, host, casting="unsafe")
+            # float32 already, [N, H, W * C] (`_for_the_host`)
+            host = np.asarray(image).reshape(
+                *image.shape[:2], -1, self.vae_config.out_channels)
             ph.next("distri.pipe.post", stage="post")
-            # clip(image / 2 + 0.5) in place: the same bits, no second and
-            # third image-sized array for the host to page in
-            image *= 0.5
+            # clip(image / 2 + 0.5), the first pass writing into host memory
+            # this pipeline has used before (see `_HostImages`) and the rest
+            # in place: the same bits, no second and third image-sized array
+            # for the host to page in
+            image = self._host_images.take(host.shape)
+            np.multiply(host, 0.5, out=image)
             image += 0.5
             return np.clip(image, 0.0, 1.0, out=image)
 
@@ -1218,6 +1280,13 @@ class _DistriPipelineBase(_GenerationMixin):
             jax.jit(lambda prm, ids, _cfg=ccfg: clip_mod.clip_text_forward(prm, _cfg, ids))
             for ccfg, _ in self.text_encoders
         ]
+        # and ONE program behind them for the joining, reshaping and dtype
+        # pinning that make the denoiser's conditioning of their outputs:
+        # op by op, each of those was a program the device waited for (root
+        # PERF.md section 6, PR 46).  The forwards stay programs of their
+        # own: inside a larger one XLA fuses their reductions otherwise, and
+        # the embeddings' low bits move
+        self._condition = jax.jit(self._conditioning, static_argnames="n_br")
         # jitted init-image encode for img2img, for the same reason as the
         # text encoders above (eager per-call dispatch otherwise)
         self._encode_image = jax.jit(
@@ -1364,6 +1433,12 @@ class _DistriPipelineBase(_GenerationMixin):
             ids = np.asarray(ids)
         return self._clip_jitted[which](cparams, ids)
 
+    def _conditioning(self, *encoded, n_br):
+        """The traced body of ``self._condition``: the encoders' outputs,
+        ``[n_br * B, ...]`` -> ``(embeds [n_br, B, L, C], added_cond)`` in
+        the denoiser's dtype, as `runner.generate` takes them."""
+        raise NotImplementedError
+
     def _encode(self, prompts, negs, micro_cond=None):
         raise NotImplementedError
 
@@ -1412,7 +1487,7 @@ class _DistriPipelineBase(_GenerationMixin):
         embeds, added = self._step_pin_enc(enc)
         return self.runner.stepwise_carry_step(
             carry, i, embeds, added,
-            jnp.asarray(guidance_scale, jnp.float32), num_inference_steps)
+            np.float32(guidance_scale), num_inference_steps)
 
     def step_carry_latent(self, carry):
         return self.runner.stepwise_carry_latent(carry)
@@ -1568,34 +1643,43 @@ class DistriSDXLPipeline(_DistriPipelineBase):
                 # the negative branch keeps the caller's words
                 ids1, ids2 = [
                     ids if n_br == 1 else jnp.concatenate(
-                        [jnp.asarray(_tokenize(tok, negs), ids.dtype), ids])
+                        [np.asarray(_tokenize(tok, negs), ids.dtype), ids])
                     for tok, ids in zip(self.tokenizers, rewritten)]
+            time_ids = self._time_ids(n_br, b, micro_cond)
         with span("distri.pipe.encode"):
-            return self._encode_ids(ids1, ids2, n_br, b, micro_cond)
+            out1 = self._clip(0, ids1)
+            out2 = self._clip(1, ids2)
+            return self._condition(
+                out1["hidden_states"][-2], out2["hidden_states"][-2],
+                out2["text_embeds"], time_ids, n_br=n_br)
 
-    def _encode_ids(self, ids1, ids2, n_br, b, micro_cond):
-        """Enqueue both CLIP towers and build the conditioning from them."""
-        cfg = self.distri_config
-        out1 = self._clip(0, ids1)
-        out2 = self._clip(1, ids2)
+    def _conditioning(self, hidden1, hidden2, pooled, time_ids, *, n_br):
+        b = time_ids.shape[1]
         # SDXL conditioning: concat penultimate hidden states of both encoders
-        emb = jnp.concatenate(
-            [out1["hidden_states"][-2], out2["hidden_states"][-2]], axis=-1
-        )
+        emb = jnp.concatenate([hidden1, hidden2], axis=-1)
         emb = emb.reshape(n_br, b, *emb.shape[1:])
-        pooled = out2["text_embeds"].reshape(n_br, b, -1)
+        dtype = self.distri_config.dtype
+        return emb.astype(dtype), {
+            "text_embeds": pooled.reshape(n_br, b, -1).astype(dtype),
+            "time_ids": time_ids}
+
+    def _time_ids(self, n_br, b, micro_cond):
+        """The micro-conditioning ids ``[n_br, B, n_ids]``, float32, made on
+        the host."""
+        cfg = self.distri_config
         # time-id count is derived from the UNet's add-embedding width:
         # (proj_in - pooled) / per-id embed dim = 6 for SDXL-base
         # (orig h, w, crop top/left, target h, w) and 5 for refiner-style
         # configs (orig h, w, crop top/left, aesthetic score).
         ucfg = self.unet_config
-        extra = ucfg.projection_class_embeddings_input_dim - pooled.shape[-1]
+        pooled_dim = self.text_encoders[-1][0].projection_dim
+        extra = ucfg.projection_class_embeddings_input_dim - pooled_dim
         n_ids = extra // ucfg.addition_time_embed_dim
         if n_ids not in (5, 6) or extra % ucfg.addition_time_embed_dim:
             raise ValueError(
                 f"cannot derive time-ids: add-embedding expects {n_ids} ids "
                 f"(proj_in={ucfg.projection_class_embeddings_input_dim}, "
-                f"pooled={pooled.shape[-1]}, "
+                f"pooled={pooled_dim}, "
                 f"per-id={ucfg.addition_time_embed_dim}); only the SDXL-base "
                 "(6) and refiner-style (5) layouts are supported"
             )
@@ -1629,12 +1713,10 @@ class DistriSDXLPipeline(_DistriPipelineBase):
                     mc.get("negative_target_size") or t_sz,
                     mc.get("negative_aesthetic_score", 2.5),
                 )
-            time_ids = jnp.asarray([neg, pos], jnp.float32)[:, None]
+            branches = [neg, pos]
         else:
-            time_ids = jnp.asarray([pos], jnp.float32)[:, None]
-        time_ids = jnp.tile(time_ids, (1, b, 1))
-        added = {"text_embeds": pooled, "time_ids": time_ids}
-        return emb, added
+            branches = [pos]
+        return np.tile(np.asarray(branches, np.float32)[:, None], (1, b, 1))
 
 
 class DistriSDPipeline(_DistriPipelineBase):
@@ -1711,13 +1793,15 @@ class DistriSDPipeline(_DistriPipelineBase):
         cfg = self.distri_config
         texts = negs + prompts if cfg.do_classifier_free_guidance else prompts
         n_br = 2 if cfg.do_classifier_free_guidance else 1
-        b = len(prompts)
         with span("distri.pipe.tokenize"):
             ids = _tokenize(self.tokenizers[0], texts)
         with span("distri.pipe.encode"):
             out = self._clip(0, ids)
-            emb = out["last_hidden_state"]
-            return emb.reshape(n_br, b, *emb.shape[1:]), None
+            return self._condition(out["last_hidden_state"], n_br=n_br), None
+
+    def _conditioning(self, emb, *, n_br):
+        emb = emb.reshape(n_br, -1, *emb.shape[1:])
+        return emb.astype(self.distri_config.dtype)
 
 
 class DistriPixArtPipeline(_GenerationMixin):
@@ -1884,10 +1968,8 @@ class DistriPixArtPipeline(_GenerationMixin):
                 ])
                 mask = np.ones(ids.shape, np.float32)
             else:
-                emb = self._t5_jitted(
-                    t5p, jnp.asarray(ids, jnp.int32), jnp.asarray(mask)
-                )
-            emb = jnp.asarray(emb)
+                emb = self._t5_jitted(t5p, np.asarray(ids, np.int32),
+                                      np.asarray(mask))
             emb = emb.reshape(n_br, b, emb.shape[1], emb.shape[2])
             mask = jnp.asarray(np.asarray(mask).reshape(n_br, b, -1))
             return emb, mask
@@ -1986,7 +2068,7 @@ class DistriPixArtPipeline(_GenerationMixin):
         emb, mask = self._step_pin_enc(enc)
         return self.runner.stepwise_carry_step(
             carry, i, emb, mask,
-            jnp.asarray(guidance_scale, jnp.float32), num_inference_steps)
+            np.float32(guidance_scale), num_inference_steps)
 
     def step_carry_latent(self, carry):
         return self.runner.stepwise_carry_latent(carry)
@@ -2111,6 +2193,9 @@ class DistriSD3Pipeline(_GenerationMixin):
             self._t5_jitted = jax.jit(
                 lambda prm, ids, mask: t5_encode(prm, t5_config, ids, mask)
             )
+        # the encoders' outputs -> (joint text sequence, pooled vector) as
+        # one program (as the UNet families' ``_condition``)
+        self._condition = jax.jit(self._conditioning, static_argnames="n_br")
 
     @classmethod
     def from_pretrained(
@@ -2259,23 +2344,28 @@ class DistriSD3Pipeline(_GenerationMixin):
                     np.asarray(clip_ids[which]))
                 clip_states.append(out["hidden_states"][-2])
                 pooleds.append(out.get("text_embeds", out["pooler_output"]))
-            clip_emb = jnp.concatenate(clip_states, axis=-1)
-            pad = mcfg.joint_attention_dim - clip_emb.shape[-1]
-            clip_emb = jnp.pad(clip_emb, ((0, 0), (0, 0), (0, pad)))
-            pooled = jnp.concatenate(pooleds, axis=-1)
-            if t5p is None:
-                t5_emb = jnp.zeros(
-                    (clip_emb.shape[0], self.max_t5_tokens,
-                     mcfg.joint_attention_dim), clip_emb.dtype,
-                )
-            else:
-                t5_emb = self._t5_jitted(
-                    t5p, jnp.asarray(ids, jnp.int32), jnp.asarray(mask))
-            enc = jnp.concatenate([clip_emb, t5_emb.astype(clip_emb.dtype)],
-                                  axis=1)
-            enc = enc.reshape(n_br, b, *enc.shape[1:])
-            pooled = pooled.reshape(n_br, b, -1)
-            return enc, pooled
+            t5_emb = None if t5p is None else self._t5_jitted(
+                t5p, np.asarray(ids, np.int32), np.asarray(mask))
+            return self._condition(clip_states, pooleds, t5_emb, n_br=n_br)
+
+    def _conditioning(self, clip_states, pooleds, t5_emb, *, n_br):
+        """The traced body of ``self._condition``: the joint sequence
+        ``[n_br, B, L_clip + L_t5, C]`` and the pooled vector ``[n_br, B, P]``
+        of the CLIP towers' states and T5's (None: not loaded, zeros)."""
+        mcfg = self.mmdit_config
+        clip_emb = jnp.concatenate(clip_states, axis=-1)
+        pad = mcfg.joint_attention_dim - clip_emb.shape[-1]
+        clip_emb = jnp.pad(clip_emb, ((0, 0), (0, 0), (0, pad)))
+        pooled = jnp.concatenate(pooleds, axis=-1)
+        if t5_emb is None:
+            t5_emb = jnp.zeros(
+                (clip_emb.shape[0], self.max_t5_tokens,
+                 mcfg.joint_attention_dim), clip_emb.dtype,
+            )
+        enc = jnp.concatenate([clip_emb, t5_emb.astype(clip_emb.dtype)],
+                              axis=1)
+        return (enc.reshape(n_br, -1, *enc.shape[1:]),
+                pooled.reshape(n_br, -1, pooled.shape[-1]))
 
     @phased("distri.pipe.dispatch", stage="dispatch")
     def __call__(
@@ -2367,7 +2457,7 @@ class DistriSD3Pipeline(_GenerationMixin):
         emb, pooled = self._step_pin_enc(enc)
         return self.runner.stepwise_carry_step(
             carry, i, emb, pooled,
-            jnp.asarray(guidance_scale, jnp.float32), num_inference_steps)
+            np.float32(guidance_scale), num_inference_steps)
 
     def step_carry_latent(self, carry):
         return self.runner.stepwise_carry_latent(carry)

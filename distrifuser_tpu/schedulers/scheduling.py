@@ -60,6 +60,14 @@ def _make_alphas_cumprod(
     return np.cumprod(1.0 - betas, axis=0)
 
 
+def _table(values) -> jnp.ndarray:
+    """A float32 schedule table on the device, cast on the host:
+    ``jnp.asarray(float64 values, jnp.float32)`` dispatches a convert program,
+    and `set_timesteps` runs on every request (twice: the pipeline's and the
+    runner's re-pin)."""
+    return jnp.asarray(np.asarray(values, np.float32))
+
+
 def _leading_timesteps(num_train_timesteps: int, n: int, steps_offset: int) -> np.ndarray:
     step_ratio = num_train_timesteps // n
     ts = (np.arange(n) * step_ratio).round()[::-1].astype(np.int64) + steps_offset
@@ -142,8 +150,8 @@ class DDIMScheduler(BaseScheduler):
         alpha_t = ac[ts]
         alpha_prev = np.where(prev_ts >= 0, ac[np.clip(prev_ts, 0, None)], final_alpha)
         self._timesteps = jnp.asarray(ts)
-        self._alpha_t = jnp.asarray(alpha_t, jnp.float32)
-        self._alpha_prev = jnp.asarray(alpha_prev, jnp.float32)
+        self._alpha_t = _table(alpha_t)
+        self._alpha_prev = _table(alpha_prev)
         return self
 
     def step(self, sample, model_output, step_index, state):
@@ -166,7 +174,7 @@ class EulerDiscreteScheduler(BaseScheduler):
         sigmas_full = ((1.0 - ac) / ac) ** 0.5
         sigmas = sigmas_full[ts]
         self._timesteps = jnp.asarray(ts)
-        self._sigmas = jnp.asarray(np.append(sigmas, 0.0), jnp.float32)
+        self._sigmas = _table(np.append(sigmas, 0.0))
         self._init_noise_sigma = float((sigmas.max() ** 2 + 1) ** 0.5)
         return self
 
@@ -219,9 +227,9 @@ class DPMSolverMultistepScheduler(BaseScheduler):
         # final boundary: sigma->0, lambda->+inf; use the conventional
         # diffusers tail where the last step returns x0.
         self._timesteps = jnp.asarray(ts)
-        self._alpha = jnp.asarray(np.append(alpha, 1.0), jnp.float32)
-        self._sigma = jnp.asarray(np.append(sigma, 0.0), jnp.float32)
-        self._lambda = jnp.asarray(np.append(lam, np.inf), jnp.float32)
+        self._alpha = _table(np.append(alpha, 1.0))
+        self._sigma = _table(np.append(sigma, 0.0))
+        self._lambda = _table(np.append(lam, np.inf))
         return self
 
     def init_state(self, latent_shape, dtype=jnp.float32):
@@ -307,10 +315,8 @@ class FlowMatchEulerScheduler(BaseScheduler):
         self.num_inference_steps = n
         lin = np.linspace(1.0, 1.0 / n, n)
         sig = self.shift * lin / (1.0 + (self.shift - 1.0) * lin)
-        self._sigmas = jnp.asarray(np.append(sig, 0.0), jnp.float32)
-        self._timesteps = jnp.asarray(
-            sig * self.num_train_timesteps, jnp.float32
-        )
+        self._sigmas = _table(np.append(sig, 0.0))
+        self._timesteps = _table(sig * self.num_train_timesteps)
         return self
 
     def add_noise(self, original, noise, step_index):

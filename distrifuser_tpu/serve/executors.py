@@ -146,21 +146,15 @@ class PipelineExecutor:
 
     def _draw_latents(self, seeds: Sequence[int]):
         """Per-request seeded initial noise (scaled like _batched_generate's
-        internal draw), one vmapped draw over the stacked PRNG keys —
-        bit-identical to per-seed draws (threefry counts depend on the
-        per-image element count, not the leading axis) at one dispatch
-        instead of one per request."""
-        import jax
-        import jax.numpy as jnp
+        internal draw): row r from seed r's key alone, bit-identical to
+        per-seed draws, as one cached program (`pipelines.seeded_latents`)."""
+        from ..pipelines import seeded_latents
 
         cfg = self.pipeline.distri_config
         shape = (cfg.latent_height, cfg.latent_width, self._in_channels())
         with span("distri.pipe.latents"):
-            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-            lats = jax.vmap(
-                lambda k: jax.random.normal(k, shape, jnp.float32)
-            )(keys)
-            return lats * self.pipeline.scheduler.init_noise_sigma
+            return seeded_latents(seeds, shape,
+                                  self.pipeline.scheduler.init_noise_sigma)
 
     def _pad_batch(self, prompts, negative_prompts, seeds):
         """Pad to the compiled batch width by repeating the tail (same

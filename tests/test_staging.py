@@ -554,19 +554,33 @@ def test_executor_staged_composes_with_step_cache(devices8):
     np.testing.assert_array_equal(np.asarray(mono[0]), np.asarray(staged[0]))
 
 
-def test_draw_latents_vmapped_parity(devices8):
-    """The satellite fix: one vmapped draw over stacked PRNG keys is
-    bit-identical to the old per-seed loop, and the dispatch path no
-    longer mutates shared scheduler state."""
+@pytest.mark.parametrize("seeds", [
+    [3, 9, 12345],
+    [0], [1], [2**31 - 1],
+    # the server's validation is `int(seed)`: the largest it lets through is
+    # the largest `PRNGKey` takes
+    [2**63 - 1], [-1],
+    [0, 1, 2**31 - 1, 2**31, 2**32 + 5, 2**63 - 1, -2**63],
+], ids=lambda seeds: "-".join(map(str, seeds)))
+def test_draw_latents_vmapped_parity(devices8, seeds):
+    """One cached program (`pipelines.seeded_latents`) draws every request's
+    noise, bit-identical to the per-seed loop - `PRNGKey` + `normal` + an
+    eager multiply - for every seed `PRNGKey` takes; a second call traces
+    nothing, and the dispatch path does not mutate shared scheduler
+    state."""
     import jax
     import jax.numpy as jnp
 
     from test_pipelines import build_sd_pipeline
+    from distrifuser_tpu import pipelines
+    from distrifuser_tpu.schedulers import get_scheduler
     from distrifuser_tpu.serve.executors import PipelineExecutor
 
     pipe, dcfg = build_sd_pipeline(devices8, 1, batch_size=2)
+    # a sigma that is not 1: the scale is a multiply of its own
+    pipe.scheduler = get_scheduler("euler").set_timesteps(2)
+    assert pipe.scheduler.init_noise_sigma > 1.5
     ex = PipelineExecutor(pipe, steps=2)
-    seeds = [3, 9, 12345]
     got = np.asarray(ex._draw_latents(seeds))
     shape = (1, dcfg.latent_height, dcfg.latent_width,
              pipe.unet_config.in_channels)
@@ -574,13 +588,24 @@ def test_draw_latents_vmapped_parity(devices8):
         jax.random.normal(jax.random.PRNGKey(s), shape, jnp.float32)
         for s in seeds
     ], axis=0) * pipe.scheduler.init_noise_sigma
-    np.testing.assert_array_equal(got, np.asarray(ref))
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  np.asarray(ref).view(np.uint32))
+    # the pipelines' own draw, one key for the whole batch, is the same
+    # program at another rank
+    whole = pipelines.seeded_latents(seeds[0], (2,) + shape[1:],
+                                     pipe.scheduler.init_noise_sigma)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(
+        jax.random.normal(jax.random.PRNGKey(seeds[0]), (2,) + shape[1:],
+                          jnp.float32) * pipe.scheduler.init_noise_sigma))
 
     def boom(*a, **kw):  # noqa: ANN002
         raise AssertionError("_draw_latents must not touch the scheduler")
 
     pipe.scheduler.set_timesteps = boom
+    traced = pipelines._seeded_latents._cache_size()
     np.testing.assert_array_equal(np.asarray(ex._draw_latents(seeds)), got)
+    assert pipelines._seeded_latents._cache_size() == traced
 
 
 def test_server_staged_real_pipeline_matches_monolithic(devices8):
