@@ -7,7 +7,9 @@ the model's module:
     prefill(params, config, ids [T], max_len=)
         -> (float32 logits after the last id, the decode state, the
             counters so far [len(counters)] int32, what the model records
-            of the prompt beside ids and logits - () where nothing)
+            of the prompt beside ids and logits - () where nothing; else a
+            tree of arrays [..., T, ...] with the T positions on
+            `POSITION_AXIS`, in the prompt's order)
     decode(params, config, logits, state, counters, position=, new_tokens=)
         -> (new ids [new_tokens] int32, the float32 logits each was chosen
             from [new_tokens, ...], what it records of the decoded ids, the
@@ -31,12 +33,20 @@ left (``prefill_from`` is None where it cannot):
             ``state`` and ``counters`` as ``prefill`` returned them for the
             ``position`` ids before.  The state handed in is read, not
             consumed - the one returned is new, so one prefix serves many
-            suffixes - and the result is the prefill of all the ids.
+            suffixes - and the result is the prefill of all the ids, but
+            for the record (fourth result), which is of the T entering ids:
+            whoever kept the record of the ``position`` ids before puts the
+            two together along `POSITION_AXIS`
+            (`pipelines.PromptRewriter` does, in the request's own program).
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable, NamedTuple, Optional, Tuple
+
+
+# where a prompt's positions lie in every array of what ``prefill`` records
+POSITION_AXIS = 1
 
 
 class LanguageModel(NamedTuple):

@@ -25,12 +25,15 @@ partial result goes on to the next layer, as on one chip of the deployment
 before its exchange.  The vocabulary may be a slice: ids, logits and the
 greedy choice are then over the slice.
 
-State across calls (nothing else in models/ has any): per ``M`` layer the
-SSM state [H, P, N] (``state_dtype``, float32) and the last conv_kernel - 1
-columns of xBC; per ``*`` layer a KV cache [max_len, Hkv, D].  One sequence
-at a time (no batch axis).  The multi-token-prediction module of the
-published model is not built (its config does not say how the hidden state
-and the next token's embedding are joined).
+State across calls: per ``M`` layer the SSM state [H, P, N]
+(``state_dtype``, float32) and the last conv_kernel - 1 columns of xBC; per
+``*`` layer a KV cache [max_len, Hkv, D].  A prompt's suffix can enter the
+state its prefix left (``prefill`` at a ``position``): the scan's carry
+starts from the SSM state, the convolution's left context is the tail, the
+attention reads the cache's rows.  One sequence at a time (no batch axis).
+The multi-token-prediction module of the published model is not built (its
+config does not say how the hidden state and the next token's embedding are
+joined).
 """
 
 from __future__ import annotations
@@ -51,8 +54,8 @@ from .language_model import LanguageModel
 from .lm_common import F32, rms_norm
 
 # counters the generation returns with its ids
-COUNTERS = ("tokens_prefilled", "tokens_decoded", "expert_assignments",
-            "expert_assignments_held")
+COUNTERS = ("tokens_prefilled", "tokens_reused", "tokens_decoded",
+            "expert_assignments", "expert_assignments_held")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,9 +109,11 @@ class NemotronHConfig:
 
     def language_model(self) -> LanguageModel:
         """This model as the rewrite stage takes it: ids of words; beside
-        ids and logits it records the experts every token chose."""
+        ids and logits it records the experts every token chose; a suffix
+        can enter the state its prefix left."""
         return LanguageModel(
-            self, prefill, decode, COUNTERS, self.chunk_size, self.vocab_size)
+            self, prefill, decode, COUNTERS, self.chunk_size, self.vocab_size,
+            prefill_from=prefill)
 
 
 def nemotron_h_config_from_json(d: Dict[str, Any]) -> NemotronHConfig:
@@ -247,18 +252,21 @@ def _mamba_output(p, cfg, y, x, z, dtype):
 
 
 @jax.named_scope("lm.mamba")
-def mamba_prefill(p, cfg: NemotronHConfig, u):
-    """A whole sequence from an empty state -> (out [T, D], the layer's
-    state {"ssm", "conv"})."""
+def mamba_prefill(p, cfg: NemotronHConfig, u, state=None):
+    """A sequence (u [T, D], T whole chunks) entering the layer's ``state``
+    {"ssm", "conv"} (None: an empty one, the start of a sequence) -> (out
+    [T, D], the state after its last row)."""
     z, xbc, dt = _mamba_inputs(p, cfg, u)
-    tail = jnp.zeros((cfg.conv_kernel - 1, cfg.conv_dim), xbc.dtype)
+    tail = (jnp.zeros((cfg.conv_kernel - 1, cfg.conv_dim), xbc.dtype)
+            if state is None else state["conv"])
     conv, tail = ssm.causal_conv1d(xbc, p["conv"]["kernel"],
                                    p["conv"]["bias"], tail)
     x, b, c = _mamba_split(cfg, jax.nn.silu(conv))
     a = -jnp.exp(p["A_log"].astype(F32))
-    y, state = ssm.ssd_chunked(x, dt, a, b, c, chunk=cfg.chunk_size)
+    y, new = ssm.ssd_chunked(x, dt, a, b, c, chunk=cfg.chunk_size,
+                             state=None if state is None else state["ssm"])
     out = _mamba_output(p, cfg, y, x, z, u.dtype)
-    return out, {"ssm": state.astype(cfg.state_dtype), "conv": tail}
+    return out, {"ssm": new.astype(cfg.state_dtype), "conv": tail}
 
 
 @jax.named_scope("lm.mamba")
@@ -330,14 +338,16 @@ def _forward(params, cfg: NemotronHConfig, ids, state, position):
     """The stack over ids [T] at ``position`` onward -> (hidden [T, D], the
     new state, held expert assignments, the experts chosen [E layers, T,
     top_k]).  ``state`` has one entry a layer: an ``M`` layer's {"ssm",
-    "conv"} (None: a whole sequence from nothing), a ``*`` layer's cache,
-    None for ``E``."""
+    "conv"} (None: a sequence from nothing), a ``*`` layer's cache, None for
+    ``E``.  Several rows go through an ``M`` layer by the chunked scan,
+    whether or not they enter a state; one row through a state is a decode
+    step."""
     x = params["embed"][ids]
     new_state, chosen, held = [], [], jnp.zeros((), jnp.int32)
     for kind, lp, st in zip(cfg.pattern, params["layers"], state):
         u = rms_norm(lp["norm"]["scale"], x, cfg.norm_eps)
-        if kind == "M" and st is None:
-            out, st = mamba_prefill(lp["mixer"], cfg, u)
+        if kind == "M" and (st is None or ids.shape[0] > 1):
+            out, st = mamba_prefill(lp["mixer"], cfg, u, st)
         elif kind == "M":
             out, st = mamba_step(lp["mixer"], cfg, u, st)
         elif kind == "*":
@@ -401,19 +411,34 @@ def _assignments(cfg: NemotronHConfig, tokens: int) -> int:
     return tokens * cfg.pattern.count("E") * cfg.num_experts_per_tok
 
 
-def prefill(params, cfg: NemotronHConfig, ids, *, max_len: int):
-    """A prompt (ids [T], T a multiple of ``chunk_size``) computed in full
-    -> (float32 logits after its last token [V], the state with room for
-    ``max_len`` positions, the `COUNTERS` so far [4] int32, the experts its
-    tokens chose [E layers, T, top_k])."""
-    dtype = params["embed"].dtype
-    state = [empty_cache(cfg, max_len, dtype) if kind == "*" else None
-             for kind in cfg.pattern]
-    x, state, held, chosen = _forward(params, cfg, ids, state, 0)
+def prefill(params, cfg: NemotronHConfig, ids, *, max_len: int, state=None,
+            position: int = 0, counters=None):
+    """ids [T] (T a multiple of ``chunk_size``) at ``position`` onward,
+    computed in full -> (float32 logits after the last token [V], the state
+    with room for ``max_len`` positions, the `COUNTERS` so far [5] int32,
+    the experts the T tokens chose [E layers, T, top_k]).
+
+    `models/language_model.py`'s ``prefill`` and ``prefill_from`` both: a
+    prompt from position 0 starts every layer from nothing; a suffix enters
+    ``state`` as the prefill of the ``position`` ids before it left it (a
+    whole number of chunks, so the scan is cut where it carries one state
+    anyway; of ``tokens_prefilled``, ``tokens_reused`` = ``position``)."""
     t = ids.shape[0]
+    needed = max(max_len, position + t)
+    state, counters = lm_common.enter_state(
+        state, counters, COUNTERS, position=position,
+        empty=lambda: [
+            empty_cache(cfg, max_len, params["embed"].dtype)
+            if kind == "*" else None for kind in cfg.pattern],
+        room=lambda state: min(
+            (st["k"].shape[0] for kind, st in zip(cfg.pattern, state)
+             if kind == "*"), default=needed),
+        needed=needed)
+    x, state, held, chosen = _forward(params, cfg, ids, state, position)
     counters = lm_common.count(
-        COUNTERS, jnp.zeros((len(COUNTERS),), jnp.int32), tokens_prefilled=t,
-        expert_assignments=_assignments(cfg, t), expert_assignments_held=held)
+        COUNTERS, counters, put={"tokens_reused": position},
+        tokens_prefilled=t, expert_assignments=_assignments(cfg, t),
+        expert_assignments_held=held)
     return head(params, cfg, x[-1:])[0], state, counters, chosen
 
 

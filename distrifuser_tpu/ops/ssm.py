@@ -1,6 +1,7 @@
 """Selective state-space ops (Mamba-2 / SSD): the depthwise causal
-convolution, the chunked scan for a whole sequence, and the one-token
-recurrence, all over ONE sequence (no batch axis).
+convolution, the chunked scan for a sequence (from its start, or entering
+the state its prefix left), and the one-token recurrence, all over ONE
+sequence (no batch axis).
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t        S: [H, P, N]
     y_t = S_t C_t
@@ -53,11 +54,14 @@ def _by_group(a, groups):
                      + a.shape[3:])
 
 
-def ssd_chunked(x, dt, a, b, c, *, chunk):
-    """The recurrence over a whole sequence from a zero state.
+def ssd_chunked(x, dt, a, b, c, *, chunk, state=None):
+    """The recurrence over T tokens entering ``state`` [H, P, N] (None: a
+    zero state, the start of a sequence).
 
     ``x`` [T, H, P], ``dt`` [T, H] (after softplus), ``a`` [H] (negative),
-    ``b`` / ``c`` [T, G, N]; T a multiple of ``chunk``.
+    ``b`` / ``c`` [T, G, N]; T a multiple of ``chunk``.  Only the carry from
+    chunk to chunk starts elsewhere, so a sequence cut at a chunk boundary
+    is, chunk for chunk, the arithmetic of the whole one.
     Returns (y [T, H, P], the state after the last token [H, P, N]),
     float32."""
     t, h, p = x.shape
@@ -91,8 +95,9 @@ def ssd_chunked(x, dt, a, b, c, *, chunk):
         add, dec = chunk_terms
         return state * dec[..., None, None] + add, state
 
-    last, entering = lax.scan(carry, jnp.zeros((g, k, p, n), F32),
-                              (added, whole))
+    start = (jnp.zeros((g, k, p, n), F32) if state is None
+             else state.astype(F32).reshape(g, k, p, n))
+    last, entering = lax.scan(carry, start, (added, whole))
     # the state a chunk entered with, decayed to each of its tokens
     y = y + jnp.einsum("clgn,cgkpn->clgkp", c, entering,
                        precision=_HI) * jnp.exp(cum)[..., None]

@@ -40,6 +40,7 @@ import numpy as np
 from .models import clip as clip_mod
 from .models import unet as unet_mod
 from .models import vae as vae_mod
+from .models.language_model import POSITION_AXIS
 from .models.weights import (
     convert_clip_state_dict,
     convert_unet_state_dict,
@@ -322,12 +323,14 @@ class PromptRewriter:
     (`LanguageModel.prefill_from`), the instruction - as much of it as is
     whole ``prompt_multiple``s - is prefilled ONCE, by a program of its own
     (``rewrite_prefix``, on the first call: a server's warm-up request), and
-    its decode state and counters are kept: the **snapshot**, this
-    rewriter's, beside the weights for as long as a pipeline holds the
-    rewriter (`drop_snapshot`).  A request's prefill program then takes the
-    snapshot - read, not donated - and the remaining ids, and returns what
-    the prefill of all the ids returns.  Where the model cannot, prefill is
-    computed in full on every request.
+    its decode state, its counters and what the model records of those ids
+    are kept: the **snapshot**, this rewriter's, beside the weights for as
+    long as a pipeline holds the rewriter (`drop_snapshot`).  A request's
+    prefill program then takes the snapshot - read, not donated - and the
+    remaining ids, and returns what the prefill of all the ids returns: the
+    record too, the snapshot's positions put in front of the request's own
+    inside that program.  Where the model cannot, prefill is computed in
+    full on every request.
 
     The last ``keep`` requests' ids, logits, counters and what else the
     model records stay reachable in ``served`` (device arrays: nothing is
@@ -387,18 +390,22 @@ class PromptRewriter:
         max_len = prompt_len + spec.new_tokens
 
         def rewrite_prefix(params, ids):
-            _, state, counters, _ = lm.prefill(params, config, ids,
-                                               max_len=max_len)
-            return state, counters
+            return lm.prefill(params, config, ids, max_len=max_len)[1:]
 
         def rewrite_prefill(params, ids, snapshot=None):
-            """All of a request's ids, or those after the snapshot's."""
+            """All of a request's ids, or those after the snapshot's - and
+            then the record of the whole prompt all the same: the
+            snapshot's positions in front of the request's own."""
             if snapshot is None:
                 return lm.prefill(params, config, ids, max_len=max_len)
-            state, counters = snapshot
-            return lm.prefill_from(params, config, ids, max_len=max_len,
-                                   state=state, counters=counters,
-                                   position=prefix_len)
+            state, counters, of_prefix = snapshot
+            logits, state, counters, of_suffix = lm.prefill_from(
+                params, config, ids, max_len=max_len, state=state,
+                counters=counters, position=prefix_len)
+            of_prompt = jax.tree.map(
+                lambda *parts: jnp.concatenate(parts, axis=POSITION_AXIS),
+                of_prefix, of_suffix)
+            return logits, state, counters, of_prompt
 
         def rewrite_decode(params, logits, state, counters, tables):
             new_ids, chosen_from, recorded, state, counters = lm.decode(
@@ -438,9 +445,9 @@ class PromptRewriter:
                                np.asarray(user, np.int32)])
 
     def snapshot(self):
-        """(the decode state, the counters) after the first
-        ``_prefix_len`` ids of the instruction, made on first use and kept;
-        None where the model cannot enter a state."""
+        """(the decode state, the counters, what the model records of those
+        ids) after the first ``_prefix_len`` ids of the instruction, made on
+        first use and kept; None where the model cannot enter a state."""
         if self._snapshot is None and self._prefix_len:
             with span("distri.rewrite.prefix"):
                 self._snapshot = self._prefix(

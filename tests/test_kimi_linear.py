@@ -269,7 +269,8 @@ def test_the_rewriter_snapshots_the_instruction_for_this_model_too(params):
     second = rw.served[-1]
     for a, b in zip(jax.tree.leaves(rw.snapshot()), jax.tree.leaves(kept)):
         assert np.array_equal(np.asarray(a), b)
-    state, _ = rw.snapshot()
+    state, _, of_prefix = rw.snapshot()
+    assert of_prefix.shape[1] == 32  # the record of the snapshot's ids
     assert sorted(state["layers"][0]) == ["conv", "s"]
     assert sorted(state["layers"][3]) == ["c", "k_pe"]
     names = dict(zip(lm.COUNTERS, np.asarray(second.counters).tolist()))

@@ -46,6 +46,8 @@ class Model(NamedTuple):
     init: Callable  # (key, config) -> the seeded tree
     # (position, T) of suffixes that enter the state of the ids before them
     splits: Tuple[Tuple[int, int], ...] = ((24, 16),)
+    # the prompt's attention goes by blocks of queries (or by windows)
+    blocked: bool = True
 
 
 _LATENT = dict(
@@ -62,7 +64,13 @@ MODELS = {
         n_routed_experts=64, n_local_experts=8, first_local_expert=24,
         num_experts_per_tok=6, moe_latent_size=32, moe_intermediate_size=48,
         moe_shared_expert_intermediate_size=64),
-        nemotron_h.init_nemotron_h_params, splits=()),
+        nemotron_h.init_nemotron_h_params, splits=(
+            (32, 8),  # one chunk of the scan enters the state of several
+            (8, 32),  # several enter the state of one
+            (0, 40)),  # from zero: no state to enter
+        # one `*` layer in eleven, over a prompt of a thousand ids
+        # (`ops/attention.py causal_gqa_sdpa`), and a request's 128 enter
+        blocked=False),
     "evabyte": Model(evabyte.EvaByteConfig(
         num_hidden_layers=3, hidden_size=64, num_attention_heads=4,
         intermediate_size=96, window_size=W, chunk_size=C),
@@ -348,7 +356,7 @@ def test_seeded_weights_are_the_parents_leaf_for_leaf(name):
 # -- the compiled prompt program -------------------------------------------------
 
 
-@pytest.mark.parametrize("name", ENTERING)
+@pytest.mark.parametrize("name", [n for n in ENTERING if MODELS[n].blocked])
 def test_no_array_of_all_positions_squared_in_the_prompts_program(name):
     """Blocked by queries (and EVA by windows): the compiled prefill of 1024
     ids holds no array with the prompt's length twice among its dims."""

@@ -1,6 +1,6 @@
 """Nemotron-H at a small size, float32, seeded weights: each mixer and the
 whole stack against the plain reference (`benchmark/reference`), the chunked
-scan against its own recurrence, prefill then decode through both kinds of
+scan against its own recurrence (from zero and entering a state), prefill then decode through both kinds of
 state against the full forward, and the expert shares adding up."""
 
 import os
@@ -94,14 +94,19 @@ def test_each_mixer_against_the_reference(params, hidden, kind):
     close(got, want)
 
 
-def test_chunked_scan_is_its_own_recurrence():
-    h, p, g, n = 4, 8, 2, 16
+def scan_inputs(h=4, p=8, g=2, n=16):
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     x = jax.random.normal(keys[0], (T, h, p))
     dt = jax.nn.softplus(jax.random.normal(keys[1], (T, h)) - 2.0)
     a = -jnp.exp(jax.random.uniform(keys[2], (h,), minval=0.0, maxval=2.5))
     b = jax.random.normal(keys[3], (T, g, n))
     c = jax.random.normal(keys[4], (T, g, n))
+    return x, dt, a, b, c
+
+
+def test_chunked_scan_is_its_own_recurrence():
+    x, dt, a, b, c = scan_inputs()
+    h, p, n = *x.shape[1:], b.shape[-1]
     y, last = ssm.ssd_chunked(x, dt, a, b, c, chunk=8)
     state = jnp.zeros((h, p, n))
     for t in range(T):
@@ -111,6 +116,52 @@ def test_chunked_scan_is_its_own_recurrence():
     with pytest.raises(ValueError, match="multiple of the chunk"):
         ssm.ssd_chunked(x[:T - 1], dt[:T - 1], a, b[:T - 1], c[:T - 1],
                         chunk=8)
+
+
+@pytest.mark.parametrize("cut", [8, 16])
+def test_chunked_scan_enters_a_state(cut):
+    """The rows after a chunk boundary through the state the rows before it
+    left: the recurrence from that state row by row, and - the cut being
+    where the scan carries one state anyway - bit for bit the whole
+    sequence from zero; a state of another dtype is read as float32."""
+    x, dt, a, b, c = scan_inputs()
+    whole, last = ssm.ssd_chunked(x, dt, a, b, c, chunk=8)
+    before, state = ssm.ssd_chunked(x[:cut], dt[:cut], a, b[:cut], c[:cut],
+                                    chunk=8)
+    rest = (x[cut:], dt[cut:], a, b[cut:], c[cut:])
+    after, entered = ssm.ssd_chunked(*rest, chunk=8, state=state)
+    assert np.array_equal(jnp.concatenate([before, after]), whole)
+    assert np.array_equal(entered, last) and entered.dtype == jnp.float32
+    stepped = state
+    for t in range(cut, T):
+        y_t, stepped = ssm.ssd_step(stepped, x[t], dt[t], a, b[t], c[t])
+        close(after[t - cut], y_t)
+    close(entered, stepped)
+    # the state is entered, not ignored; None is the zero state
+    from_zero, _ = ssm.ssd_chunked(*rest, chunk=8)
+    assert float(jnp.abs(from_zero - after).max()) > 1e-2
+    zeros, _ = ssm.ssd_chunked(*rest, chunk=8, state=jnp.zeros_like(state))
+    assert np.array_equal(zeros, from_zero)
+    rounded, _ = ssm.ssd_chunked(*rest, chunk=8,
+                                 state=state.astype(jnp.bfloat16))
+    assert rounded.dtype == jnp.float32
+    close(rounded, after, tol=2e-2)
+
+
+def test_mamba_prefill_enters_the_state_and_the_convolutions_tail(
+        params, hidden):
+    p = layer(params, "M")
+    whole, last = lm.mamba_prefill(p, CFG, hidden)
+    _, state = lm.mamba_prefill(p, CFG, hidden[:16])
+    out, entered = lm.mamba_prefill(p, CFG, hidden[16:], state)
+    close(out, whole[16:])
+    for name in ("ssm", "conv"):
+        close(entered[name], last[name])
+    # either half of the state left out shows in the rows after the cut
+    for name in ("ssm", "conv"):
+        without = dict(state, **{name: jnp.zeros_like(state[name])})
+        wrong, _ = lm.mamba_prefill(p, CFG, hidden[16:], without)
+        assert float(jnp.abs(wrong - whole[16:]).max()) > 1e-4
 
 
 def test_mamba_prefill_then_steps_is_one_long_prefill(params, hidden):

@@ -160,3 +160,28 @@ def test_a_warmed_request_executes_its_model_programs_and_no_more(
     assert sum(ran.values()) <= most, (
         f"{sum(ran.values())} programs where PR 46 left {most}: an eager op "
         f"on the request path? {dict(ran)}")
+
+
+def test_a_snapshots_record_joins_the_requests_inside_its_prefill_program(
+        devices8, tmp_path):
+    """A rewriter whose model enters a snapshot (PR 47: Nemotron's too)
+    hands back the record of the WHOLE prompt - the snapshot's positions in
+    front of the request's - and dispatches nothing for it: the join is in
+    `jit(rewrite_prefill)`, the instruction's program ran in the warm-up,
+    and the request's count is where PR 46 left it."""
+    pipe = unet_with_rewriter(devices8)
+    rewriter = pipe.rewriter
+    ex = PipelineExecutor(pipe, steps=2)
+    ex.warm()
+    assert rewriter._prefix_len == 8 and rewriter._snapshot is not None
+    _, ran = executed(
+        lambda: ex(["a red fox on a hill"], [""], 0.0, [2**31 + 12345]),
+        tmp_path)
+    assert (ran["jit_rewrite_prefill"], ran["jit_rewrite_decode"]) == (1, 1)
+    assert "jit_rewrite_prefix" not in ran, ran
+    assert sum(ran.values()) <= FAMILIES["unet_with_rewriter"][1], dict(ran)
+    served = rewriter.served[-1]
+    assert served.experts[0].shape[1] == len(served.prompt_ids) == 16
+    counters = dict(zip(rewriter.lm.counters,
+                        np.asarray(served.counters).tolist()))
+    assert counters["tokens_reused"] == 8

@@ -44,11 +44,14 @@ BYTES = evabyte.EvaByteConfig(
     intermediate_size=48, window_size=32, chunk_size=4)
 
 
-class FromZero(evabyte.EvaByteConfig):
+def from_zero(config):
     """The same model, said to have no entering prefill."""
 
-    def language_model(self):
-        return super().language_model()._replace(prefill_from=None)
+    class FromZero(type(config)):
+        def language_model(self):
+            return super().language_model()._replace(prefill_from=None)
+
+    return FromZero(**dataclasses.asdict(config))
 
 
 @pytest.fixture(scope="module")
@@ -128,8 +131,7 @@ def test_the_instruction_is_prefilled_once_and_entered_by_every_request(
     params = evabyte.init_evabyte_params(jax.random.PRNGKey(2), BYTES)
     toks = [SimpleTokenizer(1000), SimpleTokenizer(777)]
     rw = PromptRewriter(BYTES, params, spec, toks)
-    full = PromptRewriter(FromZero(**dataclasses.asdict(BYTES)), params,
-                          spec, toks)
+    full = PromptRewriter(from_zero(BYTES), params, spec, toks)
     assert rw._snapshot is None and full.snapshot() is None
     prompts = ["a red fox", "an old sailor by the sea", "a red fox"]
     outs, marks = [], []
@@ -157,7 +159,8 @@ def test_the_instruction_is_prefilled_once_and_entered_by_every_request(
         assert their.pop("bytes_reused") == 0
         assert their.items() <= counters.items()
     # the snapshot: made by the first request, read by all, consumed by none
-    state, _ = rw.snapshot()
+    state, _, of_prefix = rw.snapshot()
+    assert of_prefix == ()  # this model records nothing beside ids and logits
     assert all(not leaf.is_deleted() for leaf in jax.tree.leaves(state))
     assert sum(leaf.nbytes for leaf in jax.tree.leaves(state)) == \
         counters["state_bytes"]
@@ -178,12 +181,62 @@ def test_the_instruction_is_prefilled_once_and_entered_by_every_request(
     assert rw._snapshot is None
 
 
-def test_a_model_without_the_entering_form_is_served_as_before(compiles):
-    """Nemotron's record offers no `prefill_from`: no snapshot, and the
-    prefill program is, instruction for instruction, the one a rewriter
-    without any of this lowers."""
+@pytest.mark.parametrize("instruction,user,reused", [
+    (10, 6, 8),  # a request's one chunk: the instruction's end, the caller's
+    (20, 4, 16)])  # a snapshot of several chunks of the scan
+def test_a_scan_models_record_is_of_the_whole_prompt_snapshot_or_not(
+        compiles, instruction, user, reused):
+    """Nemotron enters the snapshot's SSM states, convolution tails and KV
+    cache; what it records of the prompt - the experts every position chose,
+    which the cell's reference teacher-forces position by position - comes
+    back for ALL the prompt's positions, the snapshot's in front, as the
+    full prefill records them."""
+    spec = RewriteSpec(instruction_tokens=instruction, user_tokens=user,
+                       new_tokens=12, prompt_tokens=5, instruction_seed=1)
     params = lm.init_nemotron_h_params(jax.random.PRNGKey(9), LM)
-    rw = PromptRewriter(LM, params, SPEC, [SimpleTokenizer(1000)])
+    toks = [SimpleTokenizer(1000)]
+    rw = PromptRewriter(LM, params, spec, toks)
+    full = PromptRewriter(from_zero(LM), params, spec, toks)
+    assert (rw._prefix_len, full._prefix_len) == (reused, 0)
+    marks = []
+    for prompt in ["a red fox", "an old sailor by the sea"]:
+        mark = len(compiles)
+        got = jax.block_until_ready(rw([prompt]))
+        marks.append(compiles[mark:])
+        want = full([prompt])
+        served, theirs = rw.served[-1], full.served[-1]
+        of_prompt = np.asarray(served.experts[0])
+        assert of_prompt.shape == (LM.pattern.count("E"), instruction + user,
+                                   LM.num_experts_per_tok)
+        assert np.array_equal(of_prompt, np.asarray(theirs.experts[0]))
+        assert np.array_equal(np.asarray(served.experts[1]),
+                              np.asarray(theirs.experts[1]))
+        assert np.array_equal(np.asarray(served.new_ids),
+                              np.asarray(theirs.new_ids))
+        assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_allclose(np.asarray(served.logits),
+                                   np.asarray(theirs.logits), atol=2e-5)
+        counters, their = (dict(zip(r.lm.counters, np.asarray(
+            r.served[-1].counters).tolist())) for r in (rw, full))
+        assert counters.pop("tokens_reused") == rw._prefix_len
+        assert their.pop("tokens_reused") == 0 and counters == their
+        assert counters["tokens_prefilled"] == instruction + user
+    # the instruction's program compiled once, by the first request; the
+    # join is inside the request's own program: nothing new a request
+    assert marks[0].count("jit(rewrite_prefix)") == 1 and marks[1] == []
+    assert (rw._prefix._cache_size(), rw._prefill._cache_size(),
+            rw._decode._cache_size()) == (1, 1, 1)
+    state, _, of_prefix = rw.snapshot()
+    assert of_prefix.shape[1] == reused
+    assert all(not leaf.is_deleted() for leaf in jax.tree.leaves(state))
+
+
+def test_a_model_without_the_entering_form_is_served_as_before(compiles):
+    """A record that offers no `prefill_from` (Nemotron's, said to have
+    none): no snapshot, and the prefill program is, instruction for
+    instruction, the one a rewriter without any of this lowers."""
+    params = lm.init_nemotron_h_params(jax.random.PRNGKey(9), LM)
+    rw = PromptRewriter(from_zero(LM), params, SPEC, [SimpleTokenizer(1000)])
     assert rw.lm.prefill_from is None and rw.snapshot() is None
     before = len(compiles)
     rw(["a red fox"])
@@ -197,7 +250,10 @@ def test_a_model_without_the_entering_form_is_served_as_before(compiles):
 
     assert rw._prefill.lower(params, ids).as_text() == jax.jit(
         rewrite_prefill).lower(params, ids).as_text()
-    assert "bytes_reused" not in rw.lm.counters
+    counters = dict(zip(rw.lm.counters,
+                        np.asarray(rw.served[-1].counters).tolist()))
+    assert counters["tokens_reused"] == 0
+    assert counters["tokens_prefilled"] == len(ids)
 
 
 def test_a_rewriter_needs_the_word_hash_and_whole_chunks():

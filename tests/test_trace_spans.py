@@ -568,7 +568,10 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(
     under the `lm.moe.experts` scope, with no grouped matmul, no sort and no
     64-row padding around it, and never the dense form over all the held
     experts; the language model's scopes are on its ops; weights and state
-    fit.  Prefill: its thousands of rows stay on the grouped kernel."""
+    fit.  Prefill: its thousands of rows stay on the grouped kernel - the
+    instruction's 896 x 22 once a server (`rewrite_prefix`), a request's
+    128 x 22 entering that snapshot (PR 47), which holds no row of the
+    instruction but in the record it puts together."""
     import json
 
     from jax.sharding import SingleDeviceSharding
@@ -597,9 +600,20 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(
         lm.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
     rw = PromptRewriter(cfg, None, RewriteSpec(**config["rewrite"]),
                         [SimpleTokenizer(49408)])
-    ids = jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one)
+    def ids(t):
+        return jax.ShapeDtypeStruct((t,), jnp.int32, sharding=one)
+
+    reused = rw._prefix_len
+    assert reused == 896  # whole chunks of the 1000-id instruction
+    snapshot = jax.tree.map(
+        on_chip, jax.eval_shape(rw._prefix, params, ids(reused)))
+    assert snapshot[2].shape == (cfg.pattern.count("E"), reused,
+                                 cfg.num_experts_per_tok)
+    entering = (params, ids(1024 - reused), snapshot)
+    assert jax.eval_shape(rw._prefill, *entering) == jax.eval_shape(
+        rw._prefill, params, ids(1024))
     logits, state, counters, _ = jax.tree.map(
-        on_chip, jax.eval_shape(rw._prefill, params, ids))
+        on_chip, jax.eval_shape(rw._prefill, *entering))
     compiled = rw._decode.lower(
         params, logits, state, counters,
         [jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.int32, sharding=one)]
@@ -635,15 +649,29 @@ def test_decode_program_at_published_widths_compiles_for_the_chip(
     assert 5.4e9 < mem.argument_size_in_bytes < 5.7e9
     assert mem.temp_size_in_bytes < 0.5e9
 
-    prefill = rw._prefill.lower(params, ids).compile().as_text()
-    # (the compiler names them itself: op_name "ragged-dot-none", no scope)
-    rows = ids.shape[0] * cfg.num_experts_per_tok
-    grouped = [body for body, _ in instructions(prefill)
-               if re.match(r"\s*%ragged-dot-none[\w.\-]* = "
-                           rf"f32\[{rows},(2688|1024)\]\S* custom-call\(",
-                           body)]
-    assert len(grouped) == 2 * n_e, len(grouped)
-    assert "expert_gather_matvec" not in prefill
+    # (the instruction's program returns no logits: the last layer's
+    # experts - not its router, whose choice is recorded - compile away)
+    for program, args, t, layers in (
+            (rw._prefix, (params, ids(reused)), reused, n_e - 1),
+            (rw._prefill, entering, 1024 - reused, n_e)):
+        prefill = program.lower(*args).compile()
+        # a request's program holds the weights, the snapshot and one chunk
+        assert prefill.memory_analysis().temp_size_in_bytes < (
+            0.5e9 if t == reused else 0.1e9)
+        prefill = prefill.as_text()
+        # (the compiler names them itself: op_name "ragged-dot-none", no
+        # scope)
+        rows = t * cfg.num_experts_per_tok
+        grouped = [body for body, _ in instructions(prefill)
+                   if re.match(r"\s*%ragged-dot-none[\w.\-]* = "
+                               rf"f32\[{rows},(2688|1024)\]\S* "
+                               r"custom-call\(", body)]
+        assert len(grouped) == 2 * layers, len(grouped)
+        assert "expert_gather_matvec" not in prefill
+    # the scan of a request is one chunk: no row count of the instruction
+    # survives in its program but the record's
+    assert not re.search(rf"\[(?:\d+,)*{reused}(?:,\d+)*\]", re.sub(
+        rf"s32\[{n_e},{reused},{cfg.num_experts_per_tok}\]", "", prefill))
 
 
 @pytest.fixture(scope="module")
@@ -727,7 +755,7 @@ def test_byte_level_rewrite_programs_compile_for_the_chip(byte_programs):
     snapshot = jax.tree.map(on_chip, jax.eval_shape(
         rw._prefix, params, jax.ShapeDtypeStruct((n,), jnp.int32,
                                                  sharding=one)))
-    assert jax.tree.map(lambda a: (a.shape, a.dtype), snapshot) == \
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), snapshot[:2]) == \
         jax.tree.map(lambda a: (a.shape, a.dtype), (state, counters))
     entering = rw._prefill.lower(
         params, jax.ShapeDtypeStruct((t - n,), jnp.int32, sharding=one),
@@ -847,7 +875,7 @@ def test_latent_attention_rewrite_programs_compile_for_the_chip(
 
     assert jax.tree.map(lambda a: (a.shape, a.dtype),
                         (lp.state, lp.counters)) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot)
+        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot[:2])
     mem = lp.decode.memory_analysis()
     assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
     assert mem.temp_size_in_bytes < 0.3e9
@@ -991,7 +1019,7 @@ def test_linear_attention_rewrite_programs_compile_for_the_chip(
     assert "latent_cache_attention" not in entering.as_text()
 
     assert jax.tree.map(lambda a: (a.shape, a.dtype), (state, counters)) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), snapshot)
+        jax.tree.map(lambda a: (a.shape, a.dtype), snapshot[:2])
     mem = decode.memory_analysis()
     assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
     assert mem.temp_size_in_bytes < 0.3e9
@@ -1087,7 +1115,7 @@ def test_block_diffusion_rewrite_programs_compile_for_the_chip(
 
     assert jax.tree.map(lambda a: (a.shape, a.dtype),
                         (lp.state, lp.counters)) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot)
+        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot[:2])
     mem = lp.decode.memory_analysis()
     assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
     assert mem.temp_size_in_bytes < 0.3e9
@@ -1195,7 +1223,7 @@ def test_convolution_attention_rewrite_programs_compile_for_the_chip(
 
     assert jax.tree.map(lambda a: (a.shape, a.dtype),
                         (lp.state, lp.counters)) == \
-        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot)
+        jax.tree.map(lambda a: (a.shape, a.dtype), lp.snapshot[:2])
     mem = lp.decode.memory_analysis()
     assert 0 <= mem.alias_size_in_bytes - state_bytes < 1e6
     assert mem.temp_size_in_bytes < 0.3e9
